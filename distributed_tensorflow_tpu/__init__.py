@@ -19,8 +19,4 @@ SURVEY.md). Design principles:
 
 __version__ = "0.1.0"
 
-from distributed_tensorflow_tpu.utils import jax_compat as _jax_compat
-
-_jax_compat.install()
-
 from distributed_tensorflow_tpu import config  # noqa: F401

@@ -30,6 +30,13 @@ from distributed_tensorflow_tpu.ops import attention as A
 from distributed_tensorflow_tpu.ops.rope import apply_rope, rope_tables
 
 
+def default_compute_dtype():
+    """bf16 on TPU, f32 elsewhere — the ONE place the CLIs (train_lm,
+    serve_lm ``--demo``) and the bundle loader pick it, so a model is
+    trained, loaded and served in the same dtype on the same machine."""
+    return jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
+
+
 @dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 256

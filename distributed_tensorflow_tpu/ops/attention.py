@@ -45,6 +45,8 @@ import warnings
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30  # large-negative instead of -inf: keeps masked softmax NaN-free
 
@@ -367,15 +369,6 @@ def _flash_kernel(
         lse_ref[0] = m_ref[:, :1] + jnp.log(jnp.maximum(l_ref[:, :1], 1e-30))
 
 
-try:  # Pallas import is deferred-tolerant: CPU-only installs may lack it.
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-    HAVE_PALLAS = True
-except ImportError:  # pragma: no cover
-    HAVE_PALLAS = False
-
-
 # Ceiling on the whole-sequence fallback block below: past this, the kernel
 # would try to hold the entire K/V sequence in VMEM and fail deep inside
 # Mosaic (or OOM) far from the call site. 4096 rows × D=128 × 3 tensors ×
@@ -450,10 +443,6 @@ def _flash_forward(
     q, k, v, causal, block_q, block_kv, scale, interpret,
     with_lse: bool = False, window: int | None = None,
 ):
-    if not HAVE_PALLAS:
-        raise RuntimeError(
-            "jax.experimental.pallas unavailable — use blockwise_attention instead"
-        )
     b, h, sq, d = q.shape
     skv = k.shape[2]
     s = _scale(q, scale)
@@ -1217,10 +1206,6 @@ def _flash_forward_bshd(
 ):
     """q, k, v: (B, S, H, dh) — the layout the qkv projection produces.
     Returns out in the same layout (and lse as (B*H, Sq, 1) when asked)."""
-    if not HAVE_PALLAS:
-        raise RuntimeError(
-            "jax.experimental.pallas unavailable — use blockwise_attention instead"
-        )
     b, sq, h, d = q.shape
     skv = k.shape[1]
     if interpret is None:
@@ -1568,10 +1553,6 @@ def _flash_forward_qkv(
     IN-KERNEL (:func:`_rot_tile`) — every head rotates by the same
     position angles, so the tables are head-independent and ride the
     row index maps."""
-    if not HAVE_PALLAS:
-        raise RuntimeError(
-            "jax.experimental.pallas unavailable — use blockwise_attention instead"
-        )
     b, sq, width = qkv.shape
     if kv < 1 or h % kv:
         raise ValueError(

@@ -15,32 +15,12 @@ runtime and ``Supervisor`` chief election (``demo2/train.py:11-29,166-176``):
 
 from __future__ import annotations
 
-import os
-
 import jax
 
 from distributed_tensorflow_tpu.config import ClusterConfig
 from distributed_tensorflow_tpu.utils.logging import get_logger
 
 log = get_logger(__name__)
-
-
-def _maybe_enable_cpu_collectives() -> None:
-    """Cross-process collectives on the CPU backend need an explicit
-    implementation on older jaxlibs (gloo); without it every cross-host psum
-    dies with "Multiprocess computations aren't implemented on the CPU
-    backend". No-op on TPU/GPU platforms and on jax versions that select the
-    implementation automatically."""
-    platforms = str(getattr(jax.config, "jax_platforms", None) or "") or os.environ.get(
-        "JAX_PLATFORMS", ""
-    )
-    if "cpu" not in platforms:
-        return
-    try:
-        if not getattr(jax.config, "jax_cpu_collectives_implementation", None):
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # option absent/renamed on this jax version
-        pass
 
 
 def initialize_from_cluster(cluster: ClusterConfig) -> bool:
@@ -67,16 +47,10 @@ def initialize_from_cluster(cluster: ClusterConfig) -> bool:
             # probe via jax.process_count(): that itself initialises the
             # XLA backend, which forbids a later initialize().
             return True
-        _maybe_enable_cpu_collectives()
         kwargs = {}
         timeout = int(getattr(cluster, "initialization_timeout", 0) or 0)
         if timeout > 0:
-            import inspect
-
-            if "initialization_timeout" in inspect.signature(
-                jax.distributed.initialize
-            ).parameters:
-                kwargs["initialization_timeout"] = timeout
+            kwargs["initialization_timeout"] = timeout
         jax.distributed.initialize(
             coordinator_address=cluster.coordinator_address,
             num_processes=cluster.num_processes,

@@ -1616,6 +1616,13 @@ class SlotEngine:
         return int(self.mesh.size) if self.mesh is not None else 1
 
     @property
+    def param_device(self):
+        """A device the placed params live on. ``/healthz`` reports its
+        ``platform``/``device_kind`` so a client can refuse a server that
+        landed on CPU."""
+        return next(iter(jax.tree_util.tree_leaves(self.params)[0].devices()))
+
+    @property
     def hbm_bytes_per_device(self) -> int:
         """KV pool bytes RESIDENT per device. The sharded engine splits
         the pool's kv-head axis ``tp`` ways; everything else about the

@@ -293,6 +293,7 @@ def make_server(
                 # is healthy; a STARTED loop whose thread died is not.
                 loop_ok = thread is None or thread.is_alive()
                 ok = bool(accepting and loop_ok)
+                param_device = getattr(scheduler.engine, "param_device", None)
                 body = {
                     "ok": ok,
                     "accepting": bool(accepting),
@@ -313,6 +314,11 @@ def make_server(
                         "devices": int(
                             getattr(scheduler.engine, "mesh_device_count", 1)
                         ),
+                        # Where the placed params live, as JAX names it —
+                        # a benchmark refuses a server that landed on CPU.
+                        "platform": getattr(param_device, "platform", ""),
+                        "device_kind": getattr(
+                            param_device, "device_kind", ""),
                     },
                     # Weight quantization mode ('native'/'int8'/'int4') —
                     # the router tells quantized variants apart by this.

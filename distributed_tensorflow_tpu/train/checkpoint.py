@@ -1075,6 +1075,17 @@ def coordinated_maybe_save(
 # ---------------------------------------------------------------------------
 
 
+# No bundle file grows past this. The 403M flagship's f32 state-dict is
+# 1.6 GB, and a machine may cap the size of one file (RLIMIT_FSIZE; the chip
+# check's did — EFBIG at export, PR 21): a larger blob goes into numbered
+# part files next to ``path``.
+BUNDLE_PART_BYTES = 16 << 20
+
+
+def _bundle_part_paths(path: str, n: int) -> list[str]:
+    return [f"{path}.part-{i:05d}-of-{n:05d}" for i in range(n)]
+
+
 def export_inference_bundle(
     path: str,
     params: Any,
@@ -1083,11 +1094,25 @@ def export_inference_bundle(
     metadata: dict | None = None,
 ) -> None:
     """Write params as a msgpack state-dict (+ optional labels txt, one class
-    per line — ``retrain1/retrain.py:474-475`` parity) and a small JSON header."""
+    per line — ``retrain1/retrain.py:474-475`` parity) and a small JSON header.
+
+    A blob over ``BUNDLE_PART_BYTES`` is cut into ``<path>.part-i-of-n``
+    files and ``path`` keeps only the header (``"parts": n``); it is written
+    last, so a reader that finds it finds every part."""
     os.makedirs(os.path.dirname(os.path.abspath(path)) or ".", exist_ok=True)
     state = serialization.to_state_dict(jax.device_get(params))
-    blob = serialization.msgpack_serialize(state)
-    header = json.dumps({"format": "dtf_tpu.params.v1", **(metadata or {})}).encode()
+    blob = memoryview(serialization.msgpack_serialize(state))
+    meta = {"format": "dtf_tpu.params.v1", **(metadata or {})}
+    for stale in glob.glob(glob.escape(path) + ".part-*"):
+        os.remove(stale)
+    if len(blob) > BUNDLE_PART_BYTES:
+        n = -(-len(blob) // BUNDLE_PART_BYTES)
+        meta["parts"] = n
+        for i, part in enumerate(_bundle_part_paths(path, n)):
+            with open(part, "wb") as fh:
+                fh.write(blob[i * BUNDLE_PART_BYTES:(i + 1) * BUNDLE_PART_BYTES])
+        blob = b""
+    header = json.dumps(meta).encode()
     with open(path, "wb") as fh:
         fh.write(len(header).to_bytes(8, "little"))
         fh.write(header)
@@ -1102,7 +1127,16 @@ def load_inference_bundle(path: str, template: Any | None = None):
     with open(path, "rb") as fh:
         hlen = int.from_bytes(fh.read(8), "little")
         metadata = json.loads(fh.read(hlen).decode())
-        state = serialization.msgpack_restore(fh.read())
+        blob = fh.read()
+    parts = metadata.pop("parts", 0)
+    if parts:
+        chunks = []
+        for part in _bundle_part_paths(path, parts):
+            with open(part, "rb") as fh:
+                chunks.append(fh.read())
+        blob = b"".join(chunks)
+        del chunks
+    state = serialization.msgpack_restore(blob)
     if template is not None:
         state = serialization.from_state_dict(template, state)
     return state, metadata
@@ -1123,6 +1157,7 @@ def load_lm_bundle(path: str, fallback_shapes: dict | None = None):
     from distributed_tensorflow_tpu.models.transformer import (
         TransformerConfig,
         TransformerLM,
+        default_compute_dtype,
     )
 
     state, meta = load_inference_bundle(path)
@@ -1177,9 +1212,7 @@ def load_lm_bundle(path: str, fallback_shapes: dict | None = None):
         # --kv_cache_dtype at serve time still override.
         kv_cache_dtype=(shape_meta.get("kv_cache_dtype")
                         or fb.get("kv_cache_dtype") or None),
-        compute_dtype=jnp.bfloat16
-        if jax.default_backend() == "tpu"
-        else jnp.float32,
+        compute_dtype=default_compute_dtype(),
     )
     template = TransformerLM(cfg).init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)

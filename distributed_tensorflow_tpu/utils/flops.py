@@ -28,24 +28,32 @@ _PEAK_BF16 = (
 )
 
 
-def chip_peak_flops(device=None) -> float | None:
-    """Peak bf16 FLOP/s of ``device`` (default: jax.devices()[0]), or None
-    when unknown (e.g. the CPU backend) — callers should then report MFU as
-    null rather than invent a denominator."""
+def _chip_table_lookup(table, what: str, device=None) -> float | None:
+    """``table`` entry for ``device`` (default: jax.devices()[0]). None off
+    TPU — callers then report the derived number as absent rather than
+    invent a denominator. A TPU whose ``device_kind`` the table does not
+    know is an ERROR, not a default: a silent None there turns every MFU
+    and roofline readout on a new chip into a missing field."""
     import jax
 
     if device is None:
-        devices = jax.devices()
-        if not devices:
-            return None
-        device = devices[0]
-    kind = (getattr(device, "device_kind", "") or "").lower()
-    if getattr(device, "platform", "") != "tpu":
+        device = jax.devices()[0]
+    if device.platform != "tpu":
         return None
-    for sub, peak in _PEAK_BF16:
+    kind = device.device_kind.lower()
+    for sub, value in table:
         if sub in kind:
-            return peak
-    return None
+            return value
+    raise ValueError(
+        f"no {what} for TPU device_kind {device.device_kind!r}: add it to "
+        "utils/flops.py with its source"
+    )
+
+
+def chip_peak_flops(device=None) -> float | None:
+    """Peak bf16 FLOP/s of ``device`` — the MFU denominator
+    (:func:`_chip_table_lookup`: None off TPU, error on an unknown TPU)."""
+    return _chip_table_lookup(_PEAK_BF16, "bf16 peak FLOP/s", device)
 
 
 def transformer_train_flops(
@@ -108,20 +116,6 @@ _HBM_BW = (
 
 
 def chip_hbm_bandwidth(device=None) -> float | None:
-    """Peak HBM bytes/s of ``device`` (default: jax.devices()[0]), or None
-    when unknown — callers report roofline fractions as absent, never
-    invent a denominator."""
-    import jax
-
-    if device is None:
-        devices = jax.devices()
-        if not devices:
-            return None
-        device = devices[0]
-    kind = (getattr(device, "device_kind", "") or "").lower()
-    if getattr(device, "platform", "") != "tpu":
-        return None
-    for sub, bw in _HBM_BW:
-        if sub in kind:
-            return bw
-    return None
+    """Peak HBM bytes/s of ``device`` — the decode roofline denominator
+    (:func:`_chip_table_lookup`: None off TPU, error on an unknown TPU)."""
+    return _chip_table_lookup(_HBM_BW, "HBM bandwidth", device)

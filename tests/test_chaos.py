@@ -849,7 +849,7 @@ def test_bench_fleet_chaos_smoke_meets_gates():
     return IS the pass. Excluded from the whole-suite smoke run
     (3 subprocess jax boots + 3 loadgen waves), like the elastic bench."""
     env = {**os.environ, "BENCH_SMOKE": "1", "JAX_PLATFORMS": "cpu",
-           "DTF_COMPILATION_CACHE": "0"}
+           "JAX_ENABLE_COMPILATION_CACHE": "false"}
     env.pop("XLA_FLAGS", None)
     env.pop("DTT_FAULT", None)
     out = subprocess.run(

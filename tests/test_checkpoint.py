@@ -1,6 +1,7 @@
 """Checkpoint/export tests (reference C14 parity: Saver ckpts, Supervisor
 timed autosave + restore, frozen export → inference bundle)."""
 
+import os
 import time
 
 import jax
@@ -79,6 +80,34 @@ def test_inference_bundle_roundtrip(tmp_path, params):
         restored,
     )
     assert ckpt.load_labels(labels_path) == ["cat", "dog"]
+
+
+def test_inference_bundle_splits_over_part_limit(tmp_path, params, monkeypatch):
+    """No bundle file grows past BUNDLE_PART_BYTES (a machine may cap the
+    size of one file): a larger blob lands in part files, loads back
+    bit-identical, and a re-export leaves no stale part behind."""
+    path = str(tmp_path / "model.msgpack")
+    ckpt.export_inference_bundle(path, params, metadata={"model": "M"})
+    whole = os.path.getsize(path)
+    assert os.listdir(tmp_path) == ["model.msgpack"]
+
+    monkeypatch.setattr(ckpt, "BUNDLE_PART_BYTES", whole // 3)
+    ckpt.export_inference_bundle(path, params, metadata={"model": "M"})
+    files = sorted(os.listdir(tmp_path))
+    assert len(files) >= 4 and files[0] == "model.msgpack"
+    assert all(os.path.getsize(tmp_path / f) <= whole // 3 for f in files)
+    restored, meta = ckpt.load_inference_bundle(path, template=params)
+    assert meta == {"format": "dtf_tpu.params.v1", "model": "M"}
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_array_equal(np.asarray(a), np.asarray(b)),
+        jax.device_get(params),
+        restored,
+    )
+
+    monkeypatch.setattr(ckpt, "BUNDLE_PART_BYTES", whole)
+    ckpt.export_inference_bundle(path, params, metadata={"model": "M"})
+    assert os.listdir(tmp_path) == ["model.msgpack"]
+    ckpt.load_inference_bundle(path, template=params)
 
 
 def test_async_autosave_durable_after_next_access(tmp_path):

@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-import pytest
 
 from distributed_tensorflow_tpu.models.mnist_cnn import MnistCNN
 from distributed_tensorflow_tpu.models.transformer import (
@@ -38,30 +37,6 @@ _COLLECTIVES = (
     "reduce-scatter",
     "collective-permute",
     "all-to-all",
-)
-
-
-def _pre05_cpu() -> bool:
-    major, minor = (int(x) for x in jax.__version__.split(".")[:2])
-    return (major, minor) < (0, 5) and jax.default_backend() == "cpu"
-
-
-# Root cause of the dp/tp count failures noted in PR 6: pre-0.5 CPU XLA
-# lacks the all-reduce COMBINER pass (the same gap __graft_entry__._pre05
-# gates other features on), so the per-leaf gradient psums never merge
-# into one tuple all-reduce — dp observes 10 all-reduces (8 Adam param
-# leaves + 2 metric pmeans) where combined HLO has 1, and tp observes 47
-# where Megatron structure says 9. Pre-existing at the seed (commit
-# 1531b19, verified via git stash in PR 6), not a parallel/ regression:
-# the payload-bytes tests below are combiner-INVARIANT and keep passing,
-# pinning that the moved bytes are still exactly the gradient tree.
-# strict=True so a stack upgrade that restores the combiner flips these
-# back to hard asserts instead of rotting as stale xfails.
-_XFAIL_NO_COMBINER = pytest.mark.xfail(
-    _pre05_cpu(),
-    reason="pre-0.5 CPU XLA has no all-reduce combiner; exact counts "
-           "hold only on TPU/modern stacks (seed commit 1531b19)",
-    strict=True,
 )
 
 
@@ -88,7 +63,6 @@ def _lm_cfg() -> TransformerConfig:
     )
 
 
-@_XFAIL_NO_COMBINER
 def test_dp_step_is_one_combined_all_reduce():
     mesh = make_mesh()
     model = MnistCNN(compute_dtype=jnp.float32)
@@ -158,7 +132,6 @@ def test_fsdp_step_gathers_and_scatters_per_param():
     assert counts["collective-permute"] == 0 and counts["all-to-all"] == 0, counts
 
 
-@_XFAIL_NO_COMBINER
 def test_tp_step_all_reduce_count():
     mesh = make_mesh(model_parallel=2)
     cfg = _lm_cfg()

@@ -240,22 +240,6 @@ def test_shard_pool_truncates_to_mesh_multiple(setup):
     assert pool["label"].shape == (24, 10)
 
 
-# Pre-existing CPU float-drift failure, not a parallel/ regression: on
-# this CPU stack the accumulated-microbatch gradient mean drifts past the
-# test's tolerance vs the full-batch step (the equality holds on
-# TPU/modern stacks). Pre-existing at the seed (commit 1531b19, verified
-# via git stash in PR 8 — same pattern as test_collectives' combiner
-# note). strict=True so a stack upgrade that restores the match flips
-# this back to a hard assert instead of rotting as a stale xfail.
-_XFAIL_CPU_DRIFT = pytest.mark.xfail(
-    jax.default_backend() == "cpu",
-    reason="CPU-stack float drift; accum==full-batch holds only on "
-           "TPU/modern stacks (seed commit 1531b19)",
-    strict=True,
-)
-
-
-@_XFAIL_CPU_DRIFT
 def test_accum_step_matches_full_batch_step():
     """One accumulated step over k microbatches == one plain step over the
     concatenated batch (mean of equal-size microbatch grads == full-batch

@@ -140,22 +140,6 @@ def test_moe_lm_ep2_matches_ep1():
     )
 
 
-# Pre-existing CPU float-drift failures, not an expert_parallel/
-# regression: on this CPU stack the MoE LM's loss trajectory / remat
-# replay drift past the tests' tolerances (they hold on TPU/modern
-# stacks). Pre-existing at the seed (commit 1531b19, verified via git
-# stash in PR 8 — same pattern as test_collectives' combiner note).
-# strict=True so a stack upgrade that restores the match flips these
-# back to hard asserts instead of rotting as stale xfails.
-_XFAIL_CPU_DRIFT = pytest.mark.xfail(
-    jax.default_backend() == "cpu",
-    reason="CPU-stack float drift; MoE trajectory/remat match holds only "
-           "on TPU/modern stacks (seed commit 1531b19)",
-    strict=True,
-)
-
-
-@_XFAIL_CPU_DRIFT
 def test_moe_lm_trains_and_loss_decreases():
     import optax
     from jax.sharding import NamedSharding
@@ -212,7 +196,6 @@ def test_moe_lm_dropout_parity():
     assert len(set(l1)) > 1  # lr 0: only the dropout masks differ
 
 
-@_XFAIL_CPU_DRIFT
 def test_moe_remat_matches_plain():
     """cfg.remat replays the MoE block (incl. all_to_all) — identical step."""
     import optax

@@ -427,7 +427,7 @@ def test_bench_fleet_elastic_smoke_meets_gates():
     so a clean return IS the pass. Excluded from the whole-suite smoke
     run (5 subprocess jax boots), like the quant bench."""
     env = {**os.environ, "BENCH_SMOKE": "1", "JAX_PLATFORMS": "cpu",
-           "DTF_COMPILATION_CACHE": "0"}
+           "JAX_ENABLE_COMPILATION_CACHE": "false"}
     env.pop("XLA_FLAGS", None)
     out = subprocess.run(
         [sys.executable, "-c",

@@ -73,17 +73,15 @@ def test_opt_state_scalars_replicate(mesh):
         assert s.sharding.is_fully_replicated
 
 
-# Pre-existing CPU float-drift failure, not an fsdp/ regression: on this
-# CPU stack the FSDP step's regathered params drift bitwise from the
-# plain-DP step (the bitwise match holds on TPU/modern stacks).
-# Pre-existing at the seed (commit 1531b19, verified via git stash in
-# PR 8 — same pattern as test_collectives' combiner note). strict=True
-# so a stack upgrade that restores the match flips this back to a hard
-# assert instead of rotting as a stale xfail.
+# XLA:CPU only: the FSDP step's regathered params drift from the plain-DP
+# step by a few f32 ulps (3.4e-6 after 3 Adam steps on jax 0.9.0, re-checked
+# in PR 21 with --runxfail) because psum_scatter and psum reduce in a
+# different order there. strict=True so a stack on which the match holds
+# flips this back to a hard assert instead of rotting as a stale xfail.
 _XFAIL_CPU_DRIFT = pytest.mark.xfail(
     jax.default_backend() == "cpu",
-    reason="CPU-stack float drift; FSDP==DP bitwise match holds only on "
-           "TPU/modern stacks (seed commit 1531b19)",
+    reason="XLA:CPU reduces psum_scatter and psum in different orders; "
+           "the FSDP==DP bitwise match drifts by f32 ulps there",
     strict=True,
 )
 

@@ -735,7 +735,7 @@ def test_bench_fleet_handoff_perf_smoke_meets_gates():
     parity 1.0, zero recompiles on either tier, zero silent fallbacks."""
     env = dict(os.environ)
     env.update(BENCH_SMOKE="1", JAX_PLATFORMS="cpu",
-               DTF_COMPILATION_CACHE="0")
+               JAX_ENABLE_COMPILATION_CACHE="false")
     env.pop("XLA_FLAGS", None)  # subprocesses don't need 8 virtual devices
     out = subprocess.run(
         [sys.executable, "-c",

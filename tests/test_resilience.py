@@ -673,25 +673,37 @@ def test_initialization_timeout_config_default():
     assert ClusterConfig().initialization_timeout == 120
 
 
-def test_compilation_cache_gated_off_on_legacy_cpu(tmp_path, monkeypatch):
-    """jax < 0.5 mis-executes deserialized XLA:CPU executables (NaN grads +
-    segfault on a cache-hit resumed run — observed on 0.4.37); the persistent
-    cache must stay off for CPU-only runs there."""
+def test_compilation_cache_dir_from_env_is_left_to_jax(monkeypatch):
+    """Cache contract, placed from outside: with JAX_COMPILATION_CACHE_DIR
+    set, JAX reads it itself and no code path here touches the config."""
     import jax
 
     from distributed_tensorflow_tpu.utils import compile_cache as cc
 
-    monkeypatch.delenv("DTF_COMPILATION_CACHE", raising=False)
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    out = cc.enable_compilation_cache(str(tmp_path / "xla"))
-    major, minor = (int(x) for x in jax.__version__.split(".")[:2])
-    if (major, minor) < (0, 5):
-        assert out is None  # gated: no cache dir configured
-    else:
-        assert out == str(tmp_path / "xla")
-    # Explicit disable always wins, any version.
-    monkeypatch.setenv("DTF_COMPILATION_CACHE", "0")
-    assert cc.enable_compilation_cache(str(tmp_path / "xla")) is None
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/x")
+    assert cc.enable_compilation_cache() == "/x"
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compilation_cache_dir_default_is_fixed_in_checkout(tmp_path, monkeypatch):
+    """Unset, the cache sits at <repo>/.jax_cache whatever the cwd — the
+    path is part of the cache key, so a directory that moves never hits."""
+    import jax
+
+    from distributed_tensorflow_tpu.utils import compile_cache as cc
+
+    want = os.path.join(_REPO, ".jax_cache")
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        for cwd in (tmp_path, _REPO):
+            monkeypatch.chdir(cwd)
+            jax.config.update("jax_compilation_cache_dir", None)
+            assert cc.enable_compilation_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_vmem_budget_warns_when_jax_private_probe_is_gone(monkeypatch):

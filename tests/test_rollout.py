@@ -776,7 +776,7 @@ def test_bench_fleet_rollout_smoke_meets_gates():
     promotion."""
     env = dict(os.environ)
     env.update(BENCH_SMOKE="1", JAX_PLATFORMS="cpu",
-               DTF_COMPILATION_CACHE="0")
+               JAX_ENABLE_COMPILATION_CACHE="false")
     env.pop("XLA_FLAGS", None)  # subprocesses don't need 8 virtual devices
     out = subprocess.run(
         [sys.executable, "-c",

@@ -201,7 +201,7 @@ def test_bench_serving_smoke_meets_floor():
     ratchet is bench.FLOORS, enforced on dedicated runs (TPU full bench /
     BENCH_ENFORCE_FLOORS=1)."""
     env = {**os.environ, "BENCH_SMOKE": "1", "JAX_PLATFORMS": "cpu",
-           "DTF_COMPILATION_CACHE": "0"}
+           "JAX_ENABLE_COMPILATION_CACHE": "false"}
     # conftest forces 8 virtual CPU devices into XLA_FLAGS; inherited, it
     # splits XLA's host thread pool 8 ways and halves the engine's batched
     # step. The bench must see the machine the way a real run does.
@@ -229,7 +229,7 @@ def test_bench_serving_quant_smoke_meets_gates():
     lane RS accept metric present with its in-run asserts (0 recompiles,
     spec_rounds_sampled > 0) having held."""
     env = {**os.environ, "BENCH_SMOKE": "1", "JAX_PLATFORMS": "cpu",
-           "DTF_COMPILATION_CACHE": "0"}
+           "JAX_ENABLE_COMPILATION_CACHE": "false"}
     env.pop("XLA_FLAGS", None)
     out = subprocess.run(
         [sys.executable, "-c",

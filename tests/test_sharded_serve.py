@@ -364,7 +364,11 @@ def test_healthz_and_probe_report_mesh(engines):
             base = f"http://{host}:{port}"
             with urllib.request.urlopen(base + "/healthz", timeout=10) as r:
                 body = json.loads(r.read())
-            assert body["mesh"] == {"tp": want_tp, "devices": want_tp}
+            dev = jax.devices()[0]
+            assert body["mesh"] == {
+                "tp": want_tp, "devices": want_tp,
+                "platform": dev.platform, "device_kind": dev.device_kind,
+            }
             assert body["weight_dtype"] == "native"  # CFG is unquantized
             probe = http_probe(base, timeout_s=10.0)
             assert probe.ok and probe.tp == want_tp

@@ -901,7 +901,6 @@ def main(argv=None):
             _http_submit(target, payload, timeout_s, acct,
                          stream=args.stream)
     else:
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
         import jax
         import jax.numpy as jnp
 

@@ -113,6 +113,96 @@ class ReplicaProc:
                 self.proc.wait(5.0)
 
 
+_CHIP_PROBE = (
+    "import jax; d = jax.devices(); print('CHIPS', d[0].platform, len(d))"
+)
+
+
+_SPAWNING = object()  # a chip claimed by a spawn() whose child is starting
+
+
+class ChipPool:
+    """One TPU chip per replica process. A chip belongs to one process at
+    a time, and a ``serve_lm`` child left alone opens every chip of the
+    host — so the second replica dies at backend init ("The TPU is
+    already in use by process with pid N"). The launcher therefore
+    counts the chips WITHOUT touching JAX itself (a probe child, reaped
+    before any replica starts) and hands each replica its own chip through
+    libtpu's visibility variables. ``chips == 0`` (children held to
+    another platform, or no TPU) assigns nothing."""
+
+    def __init__(self, env=None):
+        self.env = dict(os.environ if env is None else env)
+        self.chips = self._count(self.env)
+        self._owners: list = [None] * self.chips
+        self._lock = threading.Lock()
+
+    @staticmethod
+    def _count(env) -> int:
+        platforms = env.get("JAX_PLATFORMS", "")
+        if platforms and "tpu" not in platforms.split(","):
+            return 0  # children cannot land on a chip; skip the probe
+        out = subprocess.run(
+            [sys.executable, "-c", _CHIP_PROBE], env=env,
+            capture_output=True, text=True, timeout=180,
+        )
+        if out.returncode != 0:
+            raise RuntimeError(
+                f"chip probe exited {out.returncode}:\n{out.stderr[-2000:]}")
+        platform, count = out.stdout.rsplit("CHIPS", 1)[-1].split()[:2]
+        return int(count) if platform == "tpu" else 0
+
+    def require(self, replicas: int, replica_argv) -> None:
+        """Fail BEFORE spawning when the launch cannot fit the host."""
+        if not self.chips:
+            return
+        tp_parser = argparse.ArgumentParser(add_help=False)
+        tp_parser.add_argument("--tp", type=int, default=1)
+        tp = tp_parser.parse_known_args(list(replica_argv))[0].tp
+        if tp > 1:
+            raise ValueError(
+                f"--tp {tp}: this launcher gives each replica ONE chip; "
+                "run a tp-wide replica with tools/serve_lm.py directly")
+        if replicas > self.chips:
+            raise ValueError(
+                f"{replicas} replicas need {replicas} chips but this host "
+                f"has {self.chips} — one process per chip")
+
+    def spawn(self, cmd) -> subprocess.Popen:
+        env, chip = self.env, None
+        if self.chips:
+            with self._lock:
+                free = [i for i, p in enumerate(self._owners)
+                        if p is None
+                        or (p is not _SPAWNING and p.poll() is not None)]
+                if not free:
+                    raise RuntimeError(
+                        f"all {self.chips} chips are held by live replicas")
+                chip = free[0]
+                self._owners[chip] = _SPAWNING
+            # The smallest set libtpu 0.0.34 honours (checked on the
+            # four-chip v5e host, PR 21): TPU_VISIBLE_CHIPS alone still
+            # trips "The TPU is already in use"; the two bounds variables
+            # make each child a one-chip process of its own.
+            env = dict(
+                env,
+                TPU_VISIBLE_CHIPS=str(chip),
+                TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1",
+                TPU_PROCESS_BOUNDS="1,1,1",
+            )
+        proc = None
+        try:
+            proc = subprocess.Popen(
+                cmd, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
+                text=True, env=env,
+            )
+        finally:
+            if chip is not None:
+                with self._lock:
+                    self._owners[chip] = proc  # None again if Popen raised
+        return proc
+
+
 def launch_fleet(
     num_replicas: int,
     replica_argv,
@@ -120,10 +210,13 @@ def launch_fleet(
     env=None,
     startup_timeout_s: float = 180.0,
 ) -> list[ReplicaProc]:
-    """Spawn N replicas (port 0 each) and wait for every URL. Spawning
-    is eager and waiting sequential, so the expensive part — jax import
-    + engine warmup — overlaps across replicas. On any failure the
-    already-started replicas are torn down before the raise."""
+    """Spawn N replicas (port 0 each, one chip each on a TPU host) and
+    wait for every URL. Spawning is eager and waiting sequential, so the
+    expensive part — jax import + engine warmup — overlaps across
+    replicas. On any failure the already-started replicas are torn down
+    before the raise."""
+    pool = ChipPool(env)
+    pool.require(num_replicas, replica_argv)
     replicas = []
     try:
         for _ in range(num_replicas):
@@ -131,11 +224,7 @@ def launch_fleet(
                 sys.executable, os.path.join(_TOOLS_DIR, "serve_lm.py"),
                 "--port", "0", *replica_argv,
             ]
-            proc = subprocess.Popen(
-                cmd, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-                text=True, env=env,
-            )
-            replicas.append(ReplicaProc(proc))
+            replicas.append(ReplicaProc(pool.spawn(cmd)))
         deadline = time.monotonic() + startup_timeout_s
         for replica in replicas:
             replica.wait_url(max(1.0, deadline - time.monotonic()))
@@ -221,6 +310,24 @@ def main(argv=None):
         sys.exit("a disaggregated fleet needs --prefill_replicas >= 1 "
                  "AND --decode_replicas >= 1")
 
+    if tiered:
+        initial_roles = (["prefill"] * fleet_cfg.prefill_replicas
+                         + ["decode"] * fleet_cfg.decode_replicas)
+    else:
+        initial_roles = ["mixed"] * fleet_cfg.num_replicas
+
+    pool = ChipPool()
+    try:
+        pool.require(
+            max(len(initial_roles),
+                fleet_cfg.max_replicas if fleet_cfg.supervise else 0),
+            replica_argv)
+    except ValueError as err:
+        sys.exit(f"serve_fleet: {err}")
+    if pool.chips:
+        print(f"serve_fleet: {pool.chips} TPU chips, one per replica",
+              flush=True)
+
     def spawn_replica(role: str) -> ReplicaProc:
         """Spawn one role-tagged replica and wait for its URL; every
         (re)announcement reuses serve_lm's ``serving on`` prefix so
@@ -231,21 +338,12 @@ def main(argv=None):
             sys.executable, os.path.join(_TOOLS_DIR, "serve_lm.py"),
             "--port", "0", *extra, *replica_argv,
         ]
-        proc = subprocess.Popen(
-            cmd, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-            text=True,
-        )
+        proc = pool.spawn(cmd)
         replica = ReplicaProc(proc)
         url = replica.wait_url(180.0)
         replica.role = role
         print(f"serving on {url} pid={proc.pid} role={role}", flush=True)
         return replica
-
-    if tiered:
-        initial_roles = (["prefill"] * fleet_cfg.prefill_replicas
-                         + ["decode"] * fleet_cfg.decode_replicas)
-    else:
-        initial_roles = ["mixed"] * fleet_cfg.num_replicas
 
     if fleet_cfg.router_obs_dir:
         # Router-side dump dir: breaker-open flight-recorder dumps and

@@ -311,6 +311,7 @@ def main(argv=None):
         from distributed_tensorflow_tpu.models.transformer import (
             TransformerConfig,
             TransformerLM,
+            default_compute_dtype,
         )
 
         cfg = TransformerConfig(
@@ -320,7 +321,7 @@ def main(argv=None):
             num_layers=args.num_layers,
             d_ff=args.d_ff,
             max_seq_len=args.seq_len,
-            compute_dtype=jnp.float32,
+            compute_dtype=default_compute_dtype(),
         )
         params = TransformerLM(cfg).init(
             jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
