@@ -18,6 +18,7 @@ TPU-first choices:
 
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass
 from typing import Any, Callable
 
@@ -466,6 +467,13 @@ def attention_sublayer(cfg, x, attend, train: bool = False, cache=None,
     return x + attn, cache
 
 
+def _phase_scope(cached: bool):
+    """``jax.named_scope`` on the cached branch (the serving engine's
+    programs: op metadata only, the program is unchanged), nothing on the
+    training path."""
+    return jax.named_scope if cached else (lambda name: contextlib.nullcontext())
+
+
 class Block(nn.Module):
     cfg: TransformerConfig
 
@@ -480,18 +488,23 @@ class Block(nn.Module):
         (see :func:`attention_sublayer`); ``self_mask`` is the cached-path
         tree-attention ancestor mask."""
         cfg = self.cfg
-        x, cache = attention_sublayer(
-            cfg, x, attend, train=train, cache=cache, positions=positions,
-            self_mask=self_mask,
-        )
+        # The cached (serving) branch names its phases for a profile;
+        # the training path's programs stay as they were.
+        scope = _phase_scope(cache is not None)
+        with scope("attn"):
+            x, cache = attention_sublayer(
+                cfg, x, attend, train=train, cache=cache,
+                positions=positions, self_mask=self_mask,
+            )
 
-        h = nn.LayerNorm(dtype=cfg.compute_dtype, name="ln2")(x)
-        h = matmul_dense(cfg, cfg.d_ff, "mlp_in")(h)
-        h = nn.gelu(h)
-        h = matmul_dense(cfg, cfg.d_model, "mlp_out")(h)
-        if cfg.dropout_rate:
-            h = nn.Dropout(cfg.dropout_rate, deterministic=not train)(h)
-        x = x + h
+        with scope("mlp"):
+            h = nn.LayerNorm(dtype=cfg.compute_dtype, name="ln2")(x)
+            h = matmul_dense(cfg, cfg.d_ff, "mlp_in")(h)
+            h = nn.gelu(h)
+            h = matmul_dense(cfg, cfg.d_model, "mlp_out")(h)
+            if cfg.dropout_rate:
+                h = nn.Dropout(cfg.dropout_rate, deterministic=not train)(h)
+            x = x + h
         return x if cache is None else (x, cache)
 
 
@@ -563,12 +576,13 @@ class TransformerLM(nn.Module):
                 # cache's k_scale/v_scale); 'len' is shared, not per-layer.
                 new_layers.append({k_: v_ for k_, v_ in layer.items() if k_ != "len"})
             cache = {"layers": new_layers, "len": cache["len"] + s}
-        x = nn.LayerNorm(dtype=cfg.compute_dtype, name="ln_f")(x)
-        logits = nn.Dense(
-            cfg.vocab_size, dtype=cfg.compute_dtype, name="lm_head",
-            use_bias=cfg.use_bias,
-        )(x)
-        logits = logits.astype(jnp.float32)
+        with _phase_scope(cache is not None)("lm_head"):
+            x = nn.LayerNorm(dtype=cfg.compute_dtype, name="ln_f")(x)
+            logits = nn.Dense(
+                cfg.vocab_size, dtype=cfg.compute_dtype, name="lm_head",
+                use_bias=cfg.use_bias,
+            )(x)
+            logits = logits.astype(jnp.float32)
         return logits if cache is None else (logits, cache)
 
 

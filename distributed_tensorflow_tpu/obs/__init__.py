@@ -13,9 +13,12 @@ all report into:
   is built on it; the train loops publish their step-time decomposition
   (data-wait vs device compute vs checkpoint stall), rates, and
   ``skipped_nonfinite`` into it.
-* :mod:`trace <.trace>` — Dapper-style context-manager spans with
-  parent/child nesting, wall + monotonic clocks, and the process index.
-  Closed spans feed the flight recorder.
+* :mod:`trace <.trace>` — Dapper-style context-manager spans, the
+  program's one span mechanism: a closed span lands in a bounded ring per
+  span name (``closed(name, t_lo, t_hi)`` reads them back), in the
+  profiler's trace when a session is open, and — unless it closes every
+  serving round — in the flight recorder with parent/child nesting, wall +
+  monotonic clocks, and the process index.
 * :mod:`recorder <.recorder>` — a fixed-size in-memory ring buffer of the
   last N spans/events, dumped to JSONL on preemption, rollback, or any
   unhandled exception, so every crash ships its timeline.
@@ -37,10 +40,14 @@ all report into:
   invoke registered callbacks (the autoscaling/drain hook).
 
 Everything here is stdlib-only on the hot paths (numpy appears only in the
-``SummaryWriter`` bridge) and costs nothing when disabled: ``disable()``
-swaps the process default for a :class:`~.registry.NullRegistry`, whose
-instruments are shared no-op singletons — the bench.py overhead gate holds
-the instrumented MNIST step within 1% of that no-op baseline.
+``SummaryWriter`` bridge; the profiler annotation of a span is resolved
+lazily, in a process that already imported JAX). ``disable()`` swaps the
+process default registry for a :class:`~.registry.NullRegistry`, whose
+instruments are shared no-op singletons. What the instrumentation costs
+where it is on is measured, not assumed: the benchmark's
+``sched.metrics_sync_p50_ms`` (``benchmarks/layer_metrics/``) is the
+serving round's metrics pass on the chip, and PERF.md §6 (PR 26) gives the
+spans' cost from paired runs.
 """
 
 from distributed_tensorflow_tpu.obs.aggregate import (
@@ -119,7 +126,7 @@ __all__ = [
 def disable() -> None:
     """Swap the process default registry for shared no-op instruments.
     Every call site that resolved its instruments from ``get_registry()``
-    AFTER this point records nothing (the bench.py overhead baseline)."""
+    AFTER this point records nothing."""
     set_registry(NullRegistry())
 
 

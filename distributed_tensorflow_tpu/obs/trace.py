@@ -1,50 +1,132 @@
-"""Lightweight span tracing (Dapper-style, in-process).
+"""Span tracing (Dapper-style, in-process): the program's one span mechanism.
 
-``with span("checkpoint_save", step=120):`` measures a region with BOTH
-clocks — wall (``time.time``, for correlating against logs and other hosts)
-and monotonic (``time.monotonic``, for durations that survive NTP steps) —
-and records the closed span into the flight recorder ring buffer
-(:mod:`~distributed_tensorflow_tpu.obs.recorder`), so the last N spans are
-what a crash dump ships.
+``with span("checkpoint_save", step=120):`` measures a host-side region. A
+closed span goes to three places:
 
-Nesting is tracked per thread: a span opened inside another span carries its
-``parent_id``, so the dump reconstructs the call tree (emergency_shutdown →
-checkpoint_save → …). Span ids are a process-local counter — unique within
-the process, and the recorded ``process`` index disambiguates across a
-multi-host job's per-process dumps.
+* **a bounded ring per span name** (:class:`SpanRings`), each record
+  ``(t0, t1, attrs)`` on ``time.monotonic`` — the scheduler's clock and, on
+  Linux, the clock behind ``time.perf_counter``. One ring per NAME, so a
+  thousand per-round spans never push out the one ``engine.warmup`` span.
+  :func:`closed` returns the records that overlap an interval; an interval
+  measured elsewhere (queue wait: submitted on a client thread, ended on the
+  driver thread) enters through the same door, :func:`interval`.
+* **the profiler's trace**, as a ``jax.profiler.TraceAnnotation`` of the same
+  name (``utils/profiler.annotate``). With no profiler session open that is
+  a TraceMe that records nothing; with one open (``utils/profiler.trace``
+  around a running stack) the span sits in the host plane on the device
+  trace's own clock. JAX is resolved lazily, once, and only in a process
+  that has already imported it: ``obs`` itself imports without JAX.
+* **the flight recorder** (:mod:`~distributed_tensorflow_tpu.obs.recorder`),
+  with wall clock, span id and parent id, so the last N spans are what a
+  crash dump ships. Spans that close every serving round pass
+  ``flight=False`` and stay out of it: its 1,024 events would otherwise hold
+  four seconds of rounds and nothing else.
 
-This is deliberately NOT the XPlane profiler (``utils/profiler.py``): that
-is a sampled, heavyweight device timeline you turn on for a window; spans
-are an always-on, microsecond-cost breadcrumb trail of HOST-side phases.
+There is no switch: spans are always on, and "tracing off" is no profiler
+session. A span closed with no session open costs about 2 µs
+(``tests/test_obs_trace.py`` holds it under a loose ceiling; the benchmark's
+``sched.metrics_sync_p50_ms`` and the paired runs in PERF.md §6 say what the
+instrumentation costs a serving round).
+
+Nesting is tracked per thread: a span opened inside another carries its
+``parent_id``, so a dump reconstructs the call tree (emergency_shutdown →
+checkpoint_save → …). Span ids are a process-local counter; the recorded
+``process`` index disambiguates a multi-host job's per-process dumps.
 """
 
 from __future__ import annotations
 
 import itertools
+import sys
 import threading
 import time
+from collections import deque
 from typing import Any
 
 from distributed_tensorflow_tpu.obs import recorder as _recorder
 
-__all__ = ["Span", "span", "trace_event", "current_span"]
+__all__ = [
+    "Span", "SpanRings", "span", "interval", "closed", "names",
+    "trace_event", "current_span", "RING_CAPACITY",
+]
+
+# A minute and a half of serving: 45 s is about 1,100 decode rounds.
+RING_CAPACITY = 4096
 
 _ids = itertools.count(1)
 _local = threading.local()
+_process: int | None = None
+_annotate = None  # utils/profiler.annotate, once jax is in the process
 
 
 def _process_index() -> int:
-    """jax.process_index() without importing jax at module import time (the
-    obs package must stay importable — and cheap — in non-JAX tooling)."""
-    import sys
+    """jax.process_index(), resolved once: the first call made after the
+    process imported jax settles it (the obs package must stay importable —
+    and cheap — in non-JAX tooling, where this is 0)."""
+    global _process
+    if _process is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return 0
+        try:
+            _process = int(jax.process_index())
+        except Exception:  # noqa: BLE001 — uninitialized backend
+            return 0
+    return _process
 
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return 0
-    try:
-        return int(jax.process_index())
-    except Exception:  # noqa: BLE001 — uninitialized backend
-        return 0
+
+def _annotation(name: str, attrs):
+    """A TraceAnnotation for an opening span, or None in a process without
+    jax (which can hold no profiler session either)."""
+    global _annotate
+    if _annotate is None:
+        if "jax" not in sys.modules:
+            return None
+        from distributed_tensorflow_tpu.utils.profiler import annotate
+
+        _annotate = annotate
+    return _annotate(name, **attrs) if attrs else _annotate(name)
+
+
+class SpanRings:
+    """One bounded ring of ``(t0, t1, attrs)`` per span name. Appends take
+    no lock (``deque.append`` is atomic and the deque drops its oldest
+    record itself); only the first record of a new name does."""
+
+    def __init__(self, capacity: int = RING_CAPACITY):
+        if capacity <= 0:
+            raise ValueError(f"capacity must be > 0, got {capacity}")
+        self.capacity = capacity
+        self._rings: dict[str, deque] = {}
+        self._lock = threading.Lock()
+
+    def record(self, name: str, t0: float, t1: float, attrs=None) -> None:
+        ring = self._rings.get(name)
+        if ring is None:
+            with self._lock:
+                ring = self._rings.setdefault(
+                    name, deque(maxlen=self.capacity))
+        ring.append((t0, t1, attrs))
+
+    def closed(self, name: str, t_lo: float = float("-inf"),
+               t_hi: float = float("inf")) -> list[tuple]:
+        """Records of ``name`` that overlap ``[t_lo, t_hi]``, oldest first."""
+        ring = self._rings.get(name)
+        if ring is None:
+            return []
+        while True:
+            try:
+                snapshot = tuple(ring)
+                break
+            except RuntimeError:  # an append landed mid-copy: copy again
+                continue
+        return [r for r in snapshot if r[1] >= t_lo and r[0] <= t_hi]
+
+    def names(self) -> list[str]:
+        return sorted(self._rings)
+
+
+_rings = SpanRings()
 
 
 def _stack() -> list:
@@ -66,38 +148,65 @@ class Span:
     (``error`` field) and re-raised."""
 
     __slots__ = (
-        "name", "attrs", "span_id", "parent_id", "process",
-        "t_wall", "t_mono", "end_mono", "duration_s", "error",
+        "name", "attrs", "flight", "span_id", "parent_id",
+        "t_wall", "t_mono", "end_mono", "duration_s", "error", "_ann",
     )
 
-    def __init__(self, name: str, attrs: dict[str, Any]):
+    def __init__(self, name: str, attrs: dict[str, Any] | None = None,
+                 flight: bool = True):
         self.name = name
-        self.attrs = attrs
+        self.attrs = attrs or None
+        self.flight = flight
         self.span_id = next(_ids)
-        parent = current_span()
-        self.parent_id = parent.span_id if parent is not None else 0
-        self.process = _process_index()
+        self.parent_id = 0
         self.t_wall = 0.0
         self.t_mono = 0.0
         self.end_mono = 0.0
         self.duration_s = 0.0
         self.error = ""
+        self._ann = None
+
+    @property
+    def process(self) -> int:
+        return _process_index()
+
+    def note(self, **attrs: Any) -> None:
+        """Attributes known only once the region has run (``completed``,
+        ``produced``): they ride the ring record, the flight event and the
+        profiler annotation like those given at the opening."""
+        if self.attrs is None:
+            self.attrs = attrs
+        else:
+            self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
 
     def __enter__(self) -> "Span":
-        self.t_wall = time.time()
+        stack = _stack()
+        if stack:
+            self.parent_id = stack[-1].span_id
+        stack.append(self)
+        ann = self._ann = _annotation(self.name, self.attrs)
+        if ann is not None:
+            ann.__enter__()
+        if self.flight:
+            self.t_wall = time.time()
         self.t_mono = time.monotonic()
-        _stack().append(self)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self.end_mono = time.monotonic()
         self.duration_s = self.end_mono - self.t_mono
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         if exc is not None:
             self.error = f"{type(exc).__name__}: {exc}"
         stack = _stack()
         if stack and stack[-1] is self:
             stack.pop()
-        _recorder.get_recorder().record_span(self)
+        _rings.record(self.name, self.t_mono, self.end_mono, self.attrs)
+        if self.flight:
+            _recorder.get_recorder().record_span(self)
         return None  # never swallow
 
     def to_event(self) -> dict:
@@ -119,9 +228,30 @@ class Span:
         return ev
 
 
-def span(name: str, **attrs: Any) -> Span:
-    """Open a traced region: ``with span("eval", step=200): ...``"""
-    return Span(name, attrs)
+def span(name: str, *, flight: bool = True, **attrs: Any) -> Span:
+    """Open a traced region: ``with span("eval", step=200): ...``.
+    ``flight=False`` keeps a span that closes every serving round out of
+    the flight recorder (rings and profiler only)."""
+    return Span(name, attrs, flight)
+
+
+def interval(name: str, t0: float, t1: float, **attrs: Any) -> None:
+    """Record an interval whose two ends were read elsewhere (on the
+    caller's ``time.monotonic``-compatible clock) into ``name``'s ring."""
+    _rings.record(name, t0, t1, attrs or None)
+
+
+def closed(name: str, t_lo: float = float("-inf"),
+           t_hi: float = float("inf")) -> list[tuple]:
+    """``(t0, t1, attrs)`` of every closed span or interval of ``name``
+    that overlaps ``[t_lo, t_hi]``, oldest first (``attrs`` is None for a
+    span that carried none)."""
+    return _rings.closed(name, t_lo, t_hi)
+
+
+def names() -> list[str]:
+    """Every span name that has closed at least once in this process."""
+    return _rings.names()
 
 
 def trace_event(name: str, **attrs: Any) -> None:

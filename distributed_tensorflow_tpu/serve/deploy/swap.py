@@ -240,6 +240,10 @@ class WeightSwapper:
             if result.outcome == "ok":
                 self.metrics.record_weight_version(
                     self.engine.weight_version)
+                # The gauges the engine's build fixes (weight bytes per
+                # device, dtype labels) are re-read where they can change,
+                # here, and not every round.
+                self.metrics.bind_engine(self.engine)
         self.history.append(result)
         del self.history[:-self.keep_history]
         self.last = result

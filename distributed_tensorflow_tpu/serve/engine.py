@@ -106,6 +106,20 @@ sit strictly above the filled length until the step that overwrites them.
 paged/spec parity matrix lives in ``tests/test_paged_kv.py``, the
 chunked-prefill parity matrix in ``tests/test_serve_chunked.py``.
 
+Tracing (``obs/trace.py``; always on, no switch): the host side of a round
+closes ``engine.round`` around ``engine.prefill_chunk`` (one per chunk
+spent), ``engine.dispatch`` (entry of the decode round to the jitted call's
+return: the device has work queued from here), ``engine.wait`` (the first
+blocking read of the round's outputs: the device finishing) and
+``engine.readback`` (the remaining copies and host bookkeeping);
+``engine.start`` covers an admission and ``engine.warmup`` the program set's
+compiles. The time from the end of one round's ``engine.wait`` to the end
+of the next round's ``engine.dispatch`` is the host gap in which the device
+has nothing queued. Inside the programs, ``jax.named_scope`` names the
+phases (``kv.gather``, ``attn``, ``mlp``, ``lm_head``, ``sample``,
+``kv.scatter``) in the op metadata a profile shows; scopes change no
+program.
+
 Host/device split: the big pool buffers live on device and are DONATED
 through every program (in-place turnover); the per-slot registers
 (lengths, current token, sampling params, budgets, token history for the
@@ -116,6 +130,7 @@ needs.
 
 from __future__ import annotations
 
+import contextlib
 from collections import deque
 
 import jax
@@ -134,6 +149,7 @@ from distributed_tensorflow_tpu.models.decoding import (
     tree_rejection_verify_row,
 )
 from distributed_tensorflow_tpu.models.transformer import TransformerLM
+from distributed_tensorflow_tpu.obs import trace as _trace
 from distributed_tensorflow_tpu.serve.kv_pool import (
     TRASH_PAGE,
     InsufficientPages,
@@ -230,7 +246,8 @@ class SlotEngine:
         # first post-swap round grows the compile caches (the poll-mode
         # sentinel counts that as a recompile) and re-uploads weights every
         # dispatch until then.
-        self.params = self._place_params(params)
+        with _trace.span("engine.place_weights"):
+            self.params = jax.block_until_ready(self._place_params(params))
         self.model = TransformerLM(cfg)
         self.slots = int(slots)
         self.max_len = max_len
@@ -407,13 +424,14 @@ class SlotEngine:
             return jnp.swapaxes(x, 0, 1)
 
         def gather_cache(pool_layers, row, length):
-            return {
-                "layers": [
-                    {k: gather_row(v, row)[None] for k, v in l.items()}
-                    for l in pool_layers
-                ],
-                "len": length,
-            }
+            with jax.named_scope("kv.gather"):
+                return {
+                    "layers": [
+                        {k: gather_row(v, row)[None] for k, v in l.items()}
+                        for l in pool_layers
+                    ],
+                    "len": length,
+                }
 
         def make_prefill(sampled: bool):
             if not self.paged:
@@ -453,24 +471,27 @@ class SlotEngine:
                 )
                 last = jnp.take(logits[0], length - prefix_len - 1, axis=0)
                 first = _select(sampled, last, temp, top_k, top_p, seed)
-                new_pool = [
-                    {
-                        k: pl[k].at[row].set(split_pages(cl[k][0]))
-                        for k in pl
-                    }
-                    for pl, cl in zip(pool_layers, cache["layers"])
-                ]
+                with jax.named_scope("kv.scatter"):
+                    new_pool = [
+                        {
+                            k: pl[k].at[row].set(split_pages(cl[k][0]))
+                            for k in pl
+                        }
+                        for pl, cl in zip(pool_layers, cache["layers"])
+                    ]
                 return new_pool, first
 
             return prefill_fn
 
         def _select(sampled, last, temp, top_k, top_p, seed):
-            if sampled:  # dttlint: disable=jit-purity -- static program-variant flag: the factory bakes sampled in as a Python bool (one jitted program per variant)
-                key = jax.random.fold_in(jax.random.PRNGKey(seed), 0)
-                return sample_logits_batched(
-                    last[None], key[None], temp[None], top_k[None], top_p[None]
-                )[0]
-            return jnp.argmax(last).astype(jnp.int32)
+            with jax.named_scope("sample"):
+                if sampled:  # dttlint: disable=jit-purity -- static program-variant flag: the factory bakes sampled in as a Python bool (one jitted program per variant)
+                    key = jax.random.fold_in(jax.random.PRNGKey(seed), 0)
+                    return sample_logits_batched(
+                        last[None], key[None], temp[None], top_k[None],
+                        top_p[None],
+                    )[0]
+                return jnp.argmax(last).astype(jnp.int32)
 
         def make_step(sampled: bool):
             if not self.paged:
@@ -554,10 +575,11 @@ class SlotEngine:
                         sizes = (x.shape[0], ps) + x.shape[2:]
                         return jax.lax.dynamic_slice(x, starts, sizes)
 
-                    written = [
-                        {k: grab(v[0]) for k, v in l.items()}
-                        for l in cache["layers"]
-                    ]
+                    with jax.named_scope("kv.scatter"):
+                        written = [
+                            {k: grab(v[0]) for k, v in l.items()}
+                            for l in cache["layers"]
+                        ]
                     return written, logits[0]
 
                 pool_layers_ref = [pool_layers]
@@ -569,10 +591,12 @@ class SlotEngine:
                     wp = lengths // ps
                     dest = ptabs[jnp.arange(ptabs.shape[0]), wp]
                     dest = jnp.where(active, dest, TRASH_PAGE)
-                    pool_layers = [
-                        {k: pl[k].at[dest].set(written[li][k]) for k in pl}
-                        for li, pl in enumerate(pool_layers)
-                    ]
+                    with jax.named_scope("kv.scatter"):
+                        pool_layers = [
+                            {k: pl[k].at[dest].set(written[li][k])
+                             for k in pl}
+                            for li, pl in enumerate(pool_layers)
+                        ]
                     nxt = _pick(sampled, logits, seed, made,
                                 temp, top_k, top_p)
                     nxt = jnp.where(active, nxt, tok)
@@ -595,14 +619,16 @@ class SlotEngine:
             return step_fn
 
         def _pick(sampled, logits, seed, made, temp, top_k, top_p):
-            if sampled:
-                keys = jax.vmap(
-                    lambda s, m: jax.random.fold_in(jax.random.PRNGKey(s), m)
-                )(seed, made)
-                return sample_logits_batched(
-                    logits, keys, temp, top_k, top_p
-                )
-            return jnp.argmax(logits, -1).astype(jnp.int32)
+            with jax.named_scope("sample"):
+                if sampled:
+                    keys = jax.vmap(
+                        lambda s, m: jax.random.fold_in(
+                            jax.random.PRNGKey(s), m)
+                    )(seed, made)
+                    return sample_logits_batched(
+                        logits, keys, temp, top_k, top_p
+                    )
+                return jnp.argmax(logits, -1).astype(jnp.int32)
 
         def make_spec(rs: bool):
             S = self.spec_k + 1
@@ -1042,71 +1068,81 @@ class SlotEngine:
         the first token surfaces from a later :meth:`step` once the final
         chunk lands (its row precedes that round's decode rows)."""
         prompt = np.asarray(prompt, np.int32).ravel()
+        # The spans of this module sit inside the methods they time, never
+        # in a wrapper around them: every Python frame above a jitted
+        # program's first call makes its tracing slower (warm-up is
+        # measurably longer three frames deeper).
         p = int(prompt.size)
-        if p < 1:
-            raise ValueError("prompt must contain at least one token")
-        if p > self.max_prompt_len:
-            raise ValueError(
-                f"prompt length {p} > engine prefill_len {self.prefill_len}"
-                if self.max_prompt_len == self.prefill_len
-                else f"prompt length {p} > engine max prompt "
-                     f"{self.max_prompt_len}"
+        with _trace.span("engine.start", flight=False, prompt_len=p) as sp:
+            matched0 = self.stats["prefix_tokens_matched"]
+            if p < 1:
+                raise ValueError("prompt must contain at least one token")
+            if p > self.max_prompt_len:
+                raise ValueError(
+                    f"prompt length {p} > engine prefill_len {self.prefill_len}"
+                    if self.max_prompt_len == self.prefill_len
+                    else f"prompt length {p} > engine max prompt "
+                         f"{self.max_prompt_len}"
+                )
+            if max_new_tokens < 1:
+                raise ValueError(f"max_new_tokens must be >= 1, got {max_new_tokens}")
+            if p + max_new_tokens > self.max_len:
+                raise ValueError(
+                    f"prompt {p} + {max_new_tokens} new > engine max_len "
+                    f"{self.max_len}"
+                )
+            sampled = temperature > 0.0
+            prefill = self._prefill_sampled if sampled else self._prefill_greedy
+            sargs = (
+                np.float32(temperature), np.int32(top_k), np.float32(top_p),
+                np.uint32(seed),
             )
-        if max_new_tokens < 1:
-            raise ValueError(f"max_new_tokens must be >= 1, got {max_new_tokens}")
-        if p + max_new_tokens > self.max_len:
-            raise ValueError(
-                f"prompt {p} + {max_new_tokens} new > engine max_len "
-                f"{self.max_len}"
-            )
-        sampled = temperature > 0.0
-        prefill = self._prefill_sampled if sampled else self._prefill_greedy
-        sargs = (
-            np.float32(temperature), np.int32(top_k), np.float32(top_p),
-            np.uint32(seed),
-        )
-        eos = -1 if eos_id is None else int(eos_id)
-        if self.paged:
-            first = self._start_paged(slot, prompt, p, max_new_tokens,
-                                      prefill, sargs, sampled)
-        else:
-            padded = np.zeros((1, self.prefill_len), np.int32)
-            padded[0, :p] = prompt
-            new_layers, first = prefill(self.params, padded, np.int32(p), *sargs)
-            self.pool.adopt(slot, new_layers)
-        # Registers shared by both outcomes (immediate first token vs
-        # PREFILLING): sampling params and limits are fixed at admission.
-        self.temp[slot] = temperature
-        self.top_k[slot] = top_k
-        self.top_p[slot] = top_p
-        self.seed[slot] = np.uint32(seed & 0xFFFFFFFF)
-        self.budget[slot] = max_new_tokens
-        self.eos[slot] = eos
-        if first is None:
-            # Chunked path scheduled by _start_paged; pages are all bound,
-            # chunks spend across subsequent step() calls.
-            self.active[slot] = False
-            self.lengths[slot] = 0
-            self.made[slot] = 0
+            eos = -1 if eos_id is None else int(eos_id)
+            if self.paged:
+                first = self._start_paged(slot, prompt, p, max_new_tokens,
+                                          prefill, sargs, sampled)
+            else:
+                padded = np.zeros((1, self.prefill_len), np.int32)
+                padded[0, :p] = prompt
+                new_layers, first = prefill(self.params, padded, np.int32(p), *sargs)
+                self.pool.adopt(slot, new_layers)
+            # Registers shared by both outcomes (immediate first token vs
+            # PREFILLING): sampling params and limits are fixed at admission.
+            self.temp[slot] = temperature
+            self.top_k[slot] = top_k
+            self.top_p[slot] = top_p
+            self.seed[slot] = np.uint32(seed & 0xFFFFFFFF)
+            self.budget[slot] = max_new_tokens
+            self.eos[slot] = eos
+            if first is None:
+                # Chunked path scheduled by _start_paged; pages are all bound,
+                # chunks spend across subsequent step() calls.
+                self.active[slot] = False
+                self.lengths[slot] = 0
+                self.made[slot] = 0
+                if self.spec_k:
+                    self.history[slot, :p] = prompt
+                    self.hist_len[slot] = p
+                if self.sentinel is not None:
+                    self.sentinel.poll(self.compile_count())
+                sp.note(matched=self.stats["prefix_tokens_matched"] - matched0,
+                        chunks=len(self._pf[slot]["chunks"]))
+                return None, False
+            first = int(first)
+            finished = max_new_tokens == 1 or first == eos
+            self.active[slot] = not finished
+            self.lengths[slot] = p
+            self.cur_tok[slot] = first
+            self.made[slot] = 1
             if self.spec_k:
                 self.history[slot, :p] = prompt
-                self.hist_len[slot] = p
+                self.history[slot, p] = first
+                self.hist_len[slot] = p + 1
             if self.sentinel is not None:
                 self.sentinel.poll(self.compile_count())
-            return None, False
-        first = int(first)
-        finished = max_new_tokens == 1 or first == eos
-        self.active[slot] = not finished
-        self.lengths[slot] = p
-        self.cur_tok[slot] = first
-        self.made[slot] = 1
-        if self.spec_k:
-            self.history[slot, :p] = prompt
-            self.history[slot, p] = first
-            self.hist_len[slot] = p + 1
-        if self.sentinel is not None:
-            self.sentinel.poll(self.compile_count())
-        return first, finished
+            sp.note(matched=self.stats["prefix_tokens_matched"] - matched0,
+                    chunks=0)
+            return first, finished
 
     def _start_paged(self, slot, prompt, p, max_new, prefill, sargs, sampled):
         """Page allocation + prefix adoption + tail prefill for one slot.
@@ -1286,20 +1322,24 @@ class SlotEngine:
         request's first token."""
         pool = self.pool
         prompt, p = st["prompt"], st["p"]
-        toks = np.ascontiguousarray(prompt[m : m + w][None])
-        row = np.array(pool.page_tables[st["slot"]])
-        prefill = (
-            self._prefill_sampled
-            if final and st["sampled"]
-            else self._prefill_greedy
-        )
-        length = np.int32(p if final else m + w)
-        new_pool, first = prefill(
-            pool.layers, self.params, toks, length, np.int32(m), row,
-            *st["sargs"],
-        )
-        pool.layers = new_pool
-        return int(first) if final else None
+        # Only the final chunk blocks (on its token); the others return as
+        # soon as the program is queued.
+        with _trace.span("engine.prefill_chunk", flight=False, offset=m,
+                         width=w, final=final):
+            toks = np.ascontiguousarray(prompt[m : m + w][None])
+            row = np.array(pool.page_tables[st["slot"]])
+            prefill = (
+                self._prefill_sampled
+                if final and st["sampled"]
+                else self._prefill_greedy
+            )
+            length = np.int32(p if final else m + w)
+            new_pool, first = prefill(
+                pool.layers, self.params, toks, length, np.int32(m), row,
+                *st["sargs"],
+            )
+            pool.layers = new_pool
+            return int(first) if final else None
 
     def step(self) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
         """One batch round over every slot.
@@ -1321,81 +1361,103 @@ class SlotEngine:
         extra LEADING row and joins the same call's decode round."""
         if not self.active.any() and not self.prefilling.any():
             raise RuntimeError("step() with no active slots")
-        pre_events, _ = self._advance_prefill()
-        if self.active.any():
-            toks, valid, done = self._decode_round()
-        else:
-            toks = np.zeros((0, self.slots), np.int32)
-            valid = np.zeros((0, self.slots), bool)
-            done = np.zeros(self.slots, bool)
-            if self.sentinel is not None:
-                self.sentinel.poll(self.compile_count())
-        if pre_events:
-            row_t = np.zeros((1, self.slots), np.int32)
-            row_v = np.zeros((1, self.slots), bool)
-            for slot, first, finished in pre_events:
-                row_t[0, slot] = first
-                row_v[0, slot] = True
-                if finished:
-                    done[slot] = True
-            toks = np.concatenate([row_t, toks])
-            valid = np.concatenate([row_v, valid])
-        return toks, valid, done
+        with _trace.span("engine.round", flight=False) as sp:
+            chunks0 = self.stats["prefill_chunks"]
+            pre_events, _ = self._advance_prefill()
+            act = self.active
+            # What the decode round below works on: a slot whose final chunk
+            # just landed is already among the active.
+            sp.note(
+                active=int(act.sum()),
+                live_tokens=int(self.lengths[act].sum()),
+                chunks_run=self.stats["prefill_chunks"] - chunks0,
+            )
+            if act.any():
+                toks, valid, done = self._decode_round()
+            else:
+                toks = np.zeros((0, self.slots), np.int32)
+                valid = np.zeros((0, self.slots), bool)
+                done = np.zeros(self.slots, bool)
+                if self.sentinel is not None:
+                    self.sentinel.poll(self.compile_count())
+            if pre_events:
+                row_t = np.zeros((1, self.slots), np.int32)
+                row_v = np.zeros((1, self.slots), bool)
+                for slot, first, finished in pre_events:
+                    row_t[0, slot] = first
+                    row_v[0, slot] = True
+                    if finished:
+                        done[slot] = True
+                toks = np.concatenate([row_t, toks])
+                valid = np.concatenate([row_v, valid])
+            return toks, valid, done
 
     def _decode_round(self) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-        # The sampled program handles greedy rows correctly (via `where`),
-        # so a mixed batch runs sampled; only an all-greedy batch takes the
-        # sort-free fast path (and, when enabled, the speculative one).
-        any_sampled = bool((self.temp[self.active] > 0.0).any())
-        if (
-            self.spec_k
-            and not self._force_plain
-            # Verify writes the whole fed block above each slot's length
-            # (spec_k+1 linear, 1+B*spec_k tree); a slot within that of
-            # max_len would clamp the write — fall back to plain rounds
-            # for that (rare, end-of-window) round.
-            and bool(
-                (self.lengths[self.active] + self._spec_write
-                 <= self.max_len).all()
+        was_active = self.active
+        with _trace.span("engine.dispatch", flight=False):
+            # The sampled program handles greedy rows correctly (via
+            # `where`), so a mixed batch runs sampled; only an all-greedy
+            # batch takes the sort-free fast path (and, when enabled, the
+            # speculative one).
+            any_sampled = bool((self.temp[was_active] > 0.0).any())
+            spec = bool(
+                self.spec_k
+                and not self._force_plain
+                # Verify writes the whole fed block above each slot's
+                # length (spec_k+1 linear, 1+B*spec_k tree); a slot within
+                # that of max_len would clamp the write — fall back to
+                # plain rounds for that (rare, end-of-window) round.
+                and (self.lengths[was_active] + self._spec_write
+                     <= self.max_len).all()
             )
-        ):
-            return self._spec_round(any_sampled)
-        self.stats["plain_rounds"] += 1
-        step = self._step_sampled if any_sampled else self._step_greedy
-        if self.paged:
-            out = step(
-                self.pool.layers, self.params, self.pool.page_tables,
-                self.active, self.lengths, self.cur_tok, self.temp,
-                self.top_k, self.top_p, self.seed, self.made, self.budget,
-                self.eos,
-            )
-        else:
-            out = step(
-                self.params, self.pool.layers, self.active, self.lengths,
-                self.cur_tok, self.temp, self.top_k, self.top_p, self.seed,
-                self.made, self.budget, self.eos,
-            )
-        layers, active, lengths, tok, made, toks, valid = out
-        return self._finish_round(layers, active, lengths, tok, made,
-                                  toks, valid)
+            if spec:
+                out = self._spec_dispatch(any_sampled)
+            else:
+                self.stats["plain_rounds"] += 1
+                step = self._step_sampled if any_sampled else self._step_greedy
+                if self.paged:
+                    out = step(
+                        self.pool.layers, self.params, self.pool.page_tables,
+                        was_active, self.lengths, self.cur_tok, self.temp,
+                        self.top_k, self.top_p, self.seed, self.made,
+                        self.budget, self.eos,
+                    )
+                else:
+                    out = step(
+                        self.params, self.pool.layers, was_active,
+                        self.lengths, self.cur_tok, self.temp, self.top_k,
+                        self.top_p, self.seed, self.made, self.budget,
+                        self.eos,
+                    )
+        # A verify program returns the accepted counts as one output more.
+        layers, active, lengths, tok, made, toks, valid, *accepted = out
+        result = self._finish_round(layers, active, lengths, tok, made,
+                                    toks, valid)
+        if spec:
+            self._count_spec(was_active, accepted[0], any_sampled)
+        return result
 
-    def _spec_round(
-        self, any_sampled: bool = False
-    ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    def _spec_dispatch(self, any_sampled: bool = False):
+        """Draft on the host and queue the verify program; returns its
+        (still in flight) outputs, the accepted counts last."""
         if self.spec_branches > 1:
             drafts = self._propose_tree_drafts()
             spec = self._tree_rs if any_sampled else self._tree
         else:
             drafts = self._propose_drafts()
             spec = self._spec_rs if any_sampled else self._spec
-        out = spec(
+        return spec(
             self.pool.layers, self.params, self.pool.page_tables,
             self.active, self.lengths, self.cur_tok, drafts, self.temp,
             self.top_k, self.top_p, self.seed, self.made, self.budget,
             self.eos,
         )
-        layers, active, lengths, tok, made, toks, valid, accepted = out
-        n_act = int(self.active.sum())
+
+    def _count_spec(self, was_active, accepted, any_sampled: bool) -> None:
+        """A verify round's acceptance into ``stats``; read after
+        ``_finish_round`` so that the round's one blocking read stays in
+        ``engine.wait``."""
+        n_act = int(was_active.sum())
         # "Proposed" counts the acceptable path budget (spec_k per slot)
         # in BOTH modes, so accept-rate stays comparable between linear
         # and tree rounds; the tree's extra branches only buy a better
@@ -1403,7 +1465,7 @@ class SlotEngine:
         proposed = n_act * self.spec_k
         acc_arr = np.asarray(accepted)
         accepted_n = int(acc_arr.sum())
-        self.accept_samples.extend(int(x) for x in acc_arr[self.active])
+        self.accept_samples.extend(int(x) for x in acc_arr[was_active])
         self.stats["spec_rounds"] += 1
         self.stats["spec_verifies"] += n_act
         if any_sampled:
@@ -1412,8 +1474,6 @@ class SlotEngine:
         self.stats["spec_drafts_accepted"] += accepted_n
         self.stats[f"spec_drafts_proposed_{self.drafter}"] += proposed
         self.stats[f"spec_drafts_accepted_{self.drafter}"] += accepted_n
-        return self._finish_round(layers, active, lengths, tok, made,
-                                  toks, valid)
 
     def _propose_drafts(self) -> np.ndarray:
         """(slots, spec_k) draft tokens for the active lanes: the learned
@@ -1474,21 +1534,25 @@ class SlotEngine:
         was_active = self.active
         # np.array (copy), not np.asarray: zero-copy views of jax buffers
         # are read-only, and start()/release() write these registers.
-        self.active = np.array(active)
-        self.lengths = np.array(lengths)
-        self.cur_tok = np.array(tok)
-        self.made = np.array(made)
-        done = was_active & ~self.active
-        toks = np.asarray(toks)
-        valid = np.asarray(valid)
-        if self.spec_k:
-            for s in np.nonzero(was_active)[0]:
-                emitted = toks[valid[:, s], s]
-                n = int(self.hist_len[s])
-                self.history[s, n : n + emitted.size] = emitted
-                self.hist_len[s] = n + emitted.size
-        if self.sentinel is not None:
-            self.sentinel.poll(self.compile_count())
+        with _trace.span("engine.wait", flight=False):
+            # The round's first blocking read: the host waits here for the
+            # device to finish the program.
+            self.active = np.array(active)
+        with _trace.span("engine.readback", flight=False):
+            self.lengths = np.array(lengths)
+            self.cur_tok = np.array(tok)
+            self.made = np.array(made)
+            done = was_active & ~self.active
+            toks = np.asarray(toks)
+            valid = np.asarray(valid)
+            if self.spec_k:
+                for s in np.nonzero(was_active)[0]:
+                    emitted = toks[valid[:, s], s]
+                    n = int(self.hist_len[s])
+                    self.history[s, n : n + emitted.size] = emitted
+                    self.hist_len[s] = n + emitted.size
+            if self.sentinel is not None:
+                self.sentinel.poll(self.compile_count())
         return toks, valid, done
 
     # -- warmup / zero-recompile accounting -------------------------------
@@ -1508,85 +1572,104 @@ class SlotEngine:
         and — when chunked prefill can trigger — one chunked prompt
         driven to completion (chunk calls reuse the bucket programs, so
         this compiles nothing new; it asserts that)."""
-        passes: list[dict] = [{"temperature": 0.0, "_plain": True}]
-        if self.spec_k:
-            passes.append({"temperature": 0.0})
-            # Sampled lanes take the spec path too (rejection-sampling
-            # verify), so the plain sampled step needs its own forced
-            # pass — it still serves the end-of-window fallback rounds.
-            passes.append(
-                {"temperature": 1.0, "top_k": 2, "top_p": 0.9,
-                 "_plain": True}
-            )
-        passes.append({"temperature": 1.0, "top_k": 2, "top_p": 0.9})
-        for kwargs in passes:
-            force = kwargs.pop("_plain", False)
-            slot = self.acquire_slot()
-            if slot is None:
-                raise RuntimeError("warmup needs a free slot")
-            self._force_plain = force
-            try:
-                _, finished = self.start(
-                    slot, [0], max_new_tokens=2, seed=0, **kwargs
+        with _trace.span("engine.warmup") as sp:
+            passes: list[dict] = [{"temperature": 0.0, "_plain": True}]
+            if self.spec_k:
+                passes.append({"temperature": 0.0})
+                # Sampled lanes take the spec path too (rejection-sampling
+                # verify), so the plain sampled step needs its own forced
+                # pass — it still serves the end-of-window fallback rounds.
+                passes.append(
+                    {"temperature": 1.0, "top_k": 2, "top_p": 0.9,
+                     "_plain": True}
                 )
-                if not finished:
-                    while self.active[slot]:
-                        self.step()
-                    self.active[slot] = False
+            passes.append({"temperature": 1.0, "top_k": 2, "top_p": 0.9})
+            for kwargs in passes:
+                force = kwargs.pop("_plain", False)
+                slot = self.acquire_slot()
+                if slot is None:
+                    raise RuntimeError("warmup needs a free slot")
+                self._force_plain = force
+                name = (("step" if force or not self.spec_k else "spec")
+                        + (".sampled" if kwargs["temperature"] else ".greedy"))
+                try:
+                    with self._warm_program(name):
+                        _, finished = self.start(
+                            slot, [0], max_new_tokens=2, seed=0, **kwargs
+                        )
+                        if not finished:
+                            while self.active[slot]:
+                                self.step()
+                            self.active[slot] = False
+                finally:
+                    self._force_plain = False
+                    self.release(slot)
+            # The passes above prefilled through the SMALLEST bucket (p=1);
+            # compile the remaining widths too — a length-b throwaway prompt
+            # forces bucket b exactly, and max_new=1 finishes at start() so
+            # only the prefill programs are exercised. Adoption is disabled
+            # for these passes: the greedy pass would otherwise insert its
+            # [0]*width pages and the identical SAMPLED prompt would adopt
+            # them and prefill through a smaller tail bucket, leaving the
+            # full-width sampled prefill uncompiled (first sampled
+            # prefill_len-wide prompt in traffic would then recompile).
+            variants = (("greedy", {}),
+                        ("sampled", {"temperature": 1.0, "top_k": 2}))
+            prefix, self.prefix = self.prefix, None
+            try:
+                for width in self.prefill_buckets[1:]:
+                    p_warm = min(width, self.max_len - 1)
+                    for label, kwargs in variants:
+                        slot = self.acquire_slot()
+                        try:
+                            with self._warm_program(f"prefill.{width}.{label}"):
+                                self.start(slot, [0] * p_warm, max_new_tokens=1,
+                                           seed=0, **kwargs)
+                        finally:
+                            self.release(slot)
             finally:
-                self._force_plain = False
-                self.release(slot)
-        # The passes above prefilled through the SMALLEST bucket (p=1);
-        # compile the remaining widths too — a length-b throwaway prompt
-        # forces bucket b exactly, and max_new=1 finishes at start() so
-        # only the prefill programs are exercised. Adoption is disabled
-        # for these passes: the greedy pass would otherwise insert its
-        # [0]*width pages and the identical SAMPLED prompt would adopt
-        # them and prefill through a smaller tail bucket, leaving the
-        # full-width sampled prefill uncompiled (first sampled
-        # prefill_len-wide prompt in traffic would then recompile).
-        prefix, self.prefix = self.prefix, None
-        try:
-            for width in self.prefill_buckets[1:]:
-                p_warm = min(width, self.max_len - 1)
-                for kwargs in ({}, {"temperature": 1.0, "top_k": 2}):
+                self.prefix = prefix
+            if self.paged and 0 < self.prefill_chunk_tokens < self.max_len - 1:
+                # One chunked prompt per sampling variant, driven through
+                # step() to completion (budget 1 finishes at the final chunk).
+                p_long = min(self.prefill_chunk_tokens + 1, self.max_len - 1)
+                for label, kwargs in variants:
                     slot = self.acquire_slot()
                     try:
-                        self.start(slot, [0] * p_warm, max_new_tokens=1,
-                                   seed=0, **kwargs)
+                        with self._warm_program(f"chunked.{label}"):
+                            self.start(slot, [0] * p_long, max_new_tokens=1,
+                                       seed=0, **kwargs)
+                            while self.prefilling[slot]:
+                                self.step()
                     finally:
                         self.release(slot)
-        finally:
-            self.prefix = prefix
-        if self.paged and 0 < self.prefill_chunk_tokens < self.max_len - 1:
-            # One chunked prompt per sampling variant, driven through
-            # step() to completion (budget 1 finishes at the final chunk).
-            p_long = min(self.prefill_chunk_tokens + 1, self.max_len - 1)
-            for kwargs in ({}, {"temperature": 1.0, "top_k": 2}):
-                slot = self.acquire_slot()
-                try:
-                    self.start(slot, [0] * p_long, max_new_tokens=1,
-                               seed=0, **kwargs)
-                    while self.prefilling[slot]:
-                        self.step()
-                finally:
-                    self.release(slot)
-        if self.prefix is not None:
-            # Warmup's throwaway prompts must not linger as adoptable
-            # prefixes (or skew the hit-rate counters).
-            self.prefix.clear()
-            self.prefix.tokens_matched = 0
-            self.prefix.tokens_looked_up = 0
-            self.stats["prefix_tokens_matched"] = 0
-            self.stats["prefix_tokens_total"] = 0
-        n = self.compile_count()
-        if self.sentinel is not None:
-            # Sync the poll base to the warmed cache size, then draw the
-            # warm line: any compile the sentinel sees from here on counts
-            # as recompile_events_total (the SLO-alerting condition).
-            self.sentinel.poll(n)
-            self.sentinel.mark_warm()
+            if self.prefix is not None:
+                # Warmup's throwaway prompts must not linger as adoptable
+                # prefixes (or skew the hit-rate counters).
+                self.prefix.clear()
+                self.prefix.tokens_matched = 0
+                self.prefix.tokens_looked_up = 0
+                self.stats["prefix_tokens_matched"] = 0
+                self.stats["prefix_tokens_total"] = 0
+            n = self.compile_count()
+            if self.sentinel is not None:
+                # Sync the poll base to the warmed cache size, then draw the
+                # warm line: any compile the sentinel sees from here on counts
+                # as recompile_events_total (the SLO-alerting condition).
+                self.sentinel.poll(n)
+                self.sentinel.mark_warm()
+            sp.note(programs=n)
         return n
+
+    @contextlib.contextmanager
+    def _warm_program(self, program: str):
+        """One ``engine.warmup_program`` span per program warm-up runs:
+        its name and whether ``compile_count()`` grew (False: the program
+        was there already, or came from the compile cache's memory)."""
+        n0 = self.compile_count()
+        with _trace.span("engine.warmup_program", program=program) as sp:
+            yield
+            sp.note(compiled=self.compile_count() > n0)
 
     def compile_count(self) -> int:
         """Total compiled programs across the engine's jitted callables —

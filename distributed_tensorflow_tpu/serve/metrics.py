@@ -18,9 +18,22 @@ The two latencies that matter, measured where the SLO is felt:
 
 * **TTFT** (time to first token) — submit → first sampled token; includes
   queue wait + prefill, so admission-control failures show up here first.
-* **per-token latency** — the inter-token gap on the decode path; under
-  continuous batching this is one engine round divided by the tokens it
-  produced, the number the 2x-vs-sequential bench ratchet guards.
+* **per-token latency** — one engine round divided by the tokens it
+  produced over ALL slots (a throughput reciprocal, not the gap one client
+  sees between its tokens), the number the 2x-vs-sequential bench ratchet
+  guards.
+
+And the two that say where a round's time went on the host, observed where
+the program's spans of the same name are recorded (``obs/trace.py``):
+**queue wait** (submit → ``engine.start`` entered) and **between rounds**
+(one ``engine.step`` returned → the next entered, slots still active).
+
+What the engine's BUILD fixes — mesh width, pool and weight bytes per
+device, KV bytes per token, the dtype labels, the prefill budget — is set by
+:meth:`ServingMetrics.bind_engine` (when the stack is built, and again
+where it can change: a hot swap, a variant's engine being bound);
+:meth:`ServingMetrics.sync_engine` runs every round and mirrors only what
+a round can change.
 """
 
 from __future__ import annotations
@@ -59,7 +72,17 @@ class ServingMetrics:
             "Time to first token: submit -> first sampled token.", maxlen=n)
         self.per_token = r.histogram(
             "serve_per_token_seconds",
-            "Inter-token gap: engine round time / tokens produced.", maxlen=n)
+            "Engine round time / valid tokens the round produced over all "
+            "slots (reciprocal of round throughput, not one stream's "
+            "inter-token gap).", maxlen=n)
+        self.queue_wait = r.histogram(
+            "serve_queue_wait_seconds",
+            "Admission queue wait: submit -> engine.start entered.",
+            maxlen=n)
+        self.between_rounds = r.histogram(
+            "serve_between_rounds_seconds",
+            "Host time between engine rounds: one engine.step returned -> "
+            "the next entered, while slots stayed active.", maxlen=n)
         self.queue_depth = r.histogram(
             "serve_queue_depth",
             "Admission queue depth observed at submit.",
@@ -251,11 +274,18 @@ class ServingMetrics:
         self._kv_dtype = ""
         self._peak_lock = threading.Lock()
         self._last_engine_stats: dict = {}
+        self._bound_engine = None  # whose build the fixed gauges describe
 
     # -- recording (scheduler hot path) -----------------------------------
 
     def record_ttft(self, seconds: float) -> None:
         self.ttft.observe(seconds)
+
+    def record_queue_wait(self, seconds: float) -> None:
+        self.queue_wait.observe(seconds)
+
+    def record_between_rounds(self, seconds: float) -> None:
+        self.between_rounds.observe(seconds)
 
     def record_round(self, seconds: float, tokens: int) -> None:
         """One engine decode round that produced ``tokens`` valid tokens."""
@@ -344,79 +374,101 @@ class ServingMetrics:
     def record_weight_version(self, step: int) -> None:
         self._weight_version.set(float(step))
 
-    def sync_engine(self, engine) -> None:
-        """Mirror the engine's cumulative fast-path stats into registry
-        instruments (called once per scheduler round). Counters advance by
-        delta against the last sync; rate gauges are recomputed from the
-        cumulative totals; pool gauges are point-in-time."""
-        stats = getattr(engine, "stats", None)
-        if not stats:
-            return
-        for key, counter in (
-            ("prefix_tokens_matched", self._prefix_matched),
-            ("prefix_tokens_total", self._prefix_total),
-            ("prefill_chunks", self._prefill_chunks),
-        ):
-            delta = int(stats.get(key, 0)) - self._last_engine_stats.get(
-                key, 0)
-            if delta > 0:
-                counter.inc(delta)
-                self._last_engine_stats[key] = int(stats[key])
-        tdt = str(getattr(engine, "weight_dtype", "native"))
-        ddt = str(getattr(engine, "draft_weight_dtype", "") or "none")
-        for drafter in ("ngram", "model"):
-            for suffix, family in (
-                ("accepted", self._spec_accepted),
-                ("proposed", self._spec_proposed),
-            ):
-                key = f"spec_drafts_{suffix}_{drafter}"
-                delta = (int(stats.get(key, 0))
-                         - self._last_engine_stats.get(key, 0))
-                if delta > 0:
-                    family.labels(drafter=drafter, target_dtype=tdt,
-                                  draft_dtype=ddt).inc(delta)
-                    self._last_engine_stats[key] = int(stats[key])
-            if hasattr(engine, "spec_accept_rate_for"):
-                self._spec_accept_rate_by.labels(drafter=drafter).set(
-                    float(engine.spec_accept_rate_for(drafter)))
-        self._prefix_hit_rate.set(float(engine.prefix_hit_rate))
-        self._spec_accept_rate.set(float(engine.spec_accept_rate))
+    def bind_engine(self, engine) -> None:
+        """Mirror what the engine's BUILD fixes into the registry: called
+        when the stack is built and again where it can change (a hot swap
+        adopts new weights, a variant binds its sibling engine) — never per
+        round: ``weight_bytes_per_device`` walks every parameter leaf."""
+        self._weight_dtype = str(getattr(engine, "weight_dtype", "native"))
+        self._draft_weight_dtype = str(
+            getattr(engine, "draft_weight_dtype", ""))
         self._prefill_budget.set(
             float(getattr(engine, "prefill_chunk_tokens", -1)))
-        self._prefill_last_iter.set(
-            float(stats.get("prefill_tokens_last_iter", 0)))
         pool = getattr(engine, "pool", None)
-        if getattr(engine, "paged", False) and pool is not None:
-            self._pages_free.set(float(pool.pages_free))
-            self._page_occupancy.set(float(pool.occupancy))
         if pool is not None and hasattr(pool, "hbm_bytes_per_slot"):
             self._hbm_per_slot.set(float(pool.hbm_bytes_per_slot))
         self._mesh_tp.set(float(getattr(engine, "tp", 1)))
-        if hasattr(engine, "hbm_bytes_per_device"):
-            self._hbm_per_device.set(float(engine.hbm_bytes_per_device))
-        if hasattr(engine, "weight_bytes_per_device"):
-            self._weight_bytes_per_device.set(
-                float(engine.weight_bytes_per_device))
-        if hasattr(engine, "kv_bytes_per_token"):
-            self._kv_bytes_per_token.set(float(engine.kv_bytes_per_token))
+        # getattr once each: hasattr would already walk the parameters.
+        for gauge, attr in (
+            (self._hbm_per_device, "hbm_bytes_per_device"),
+            (self._weight_bytes_per_device, "weight_bytes_per_device"),
+            (self._kv_bytes_per_token, "kv_bytes_per_token"),
+        ):
+            value = getattr(engine, attr, None)
+            if value is not None:
+                gauge.set(float(value))
         kvd = str(getattr(engine, "kv_dtype", "") or "")
         if kvd and kvd != self._kv_dtype:
             if self._kv_dtype:
                 self._kv_dtype_info.labels(dtype=self._kv_dtype).set(0.0)
             self._kv_dtype_info.labels(dtype=kvd).set(1.0)
             self._kv_dtype = kvd
-        if hasattr(engine, "spec_accept_per_verify"):
-            self._spec_accept_per_verify.set(
-                float(engine.spec_accept_per_verify))
-        samples = sorted(getattr(engine, "accept_samples", ()) or ())
+        rate_for = getattr(engine, "spec_accept_rate_for", None)
+        if rate_for is not None:
+            for drafter in ("ngram", "model"):
+                self._spec_accept_rate_by.labels(drafter=drafter).set(
+                    float(rate_for(drafter)))
+        self._bound_engine = engine
+
+    def sync_engine(self, engine) -> None:
+        """Mirror the engine's cumulative fast-path stats into registry
+        instruments (called once per scheduler round). Counters advance by
+        delta against the last sync; rate gauges are recomputed from the
+        cumulative totals; pool gauges are point-in-time. An engine this
+        has not seen (a scheduler built by hand, a variant's sibling) is
+        bound first, once."""
+        stats = getattr(engine, "stats", None)
+        if not stats:
+            return
+        if engine is not self._bound_engine:
+            self.bind_engine(engine)
+        last = self._last_engine_stats
+        for key, counter in (
+            ("prefix_tokens_matched", self._prefix_matched),
+            ("prefix_tokens_total", self._prefix_total),
+            ("prefill_chunks", self._prefill_chunks),
+        ):
+            delta = int(stats.get(key, 0)) - last.get(key, 0)
+            if delta > 0:
+                counter.inc(delta)
+                last[key] = int(stats[key])
+        self._prefix_hit_rate.set(float(engine.prefix_hit_rate))
+        self._prefill_last_iter.set(
+            float(stats.get("prefill_tokens_last_iter", 0)))
+        if getattr(engine, "paged", False):
+            pool = engine.pool
+            self._pages_free.set(float(pool.pages_free))
+            self._page_occupancy.set(float(pool.occupancy))
+        if getattr(engine, "spec_k", 0):
+            self._sync_spec(engine, stats)
+
+    def _sync_spec(self, engine, stats) -> None:
+        """The drafter families: only an engine that speculates moves them."""
+        last = self._last_engine_stats
+        tdt = self._weight_dtype
+        ddt = self._draft_weight_dtype or "none"
+        for drafter in ("ngram", "model"):
+            for suffix, family in (
+                ("accepted", self._spec_accepted),
+                ("proposed", self._spec_proposed),
+            ):
+                key = f"spec_drafts_{suffix}_{drafter}"
+                delta = int(stats.get(key, 0)) - last.get(key, 0)
+                if delta > 0:
+                    family.labels(drafter=drafter, target_dtype=tdt,
+                                  draft_dtype=ddt).inc(delta)
+                    last[key] = int(stats[key])
+            self._spec_accept_rate_by.labels(drafter=drafter).set(
+                float(engine.spec_accept_rate_for(drafter)))
+        self._spec_accept_rate.set(float(engine.spec_accept_rate))
+        self._spec_accept_per_verify.set(
+            float(engine.spec_accept_per_verify))
+        samples = sorted(engine.accept_samples)
         if samples:
             self._spec_apv_p50.set(float(samples[len(samples) // 2]))
             self._spec_apv_p99.set(
                 float(samples[min(len(samples) - 1,
                                   (len(samples) * 99) // 100)]))
-        self._weight_dtype = tdt
-        self._draft_weight_dtype = str(
-            getattr(engine, "draft_weight_dtype", ""))
 
     # -- counter readout (kept as plain ints for callers/tests) ------------
 

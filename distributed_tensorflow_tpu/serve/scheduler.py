@@ -68,6 +68,14 @@ work, admission pauses so the boundary arrives and the engine rotates.
 Without a table every request lands in the single ``""`` queue and
 behavior is exactly the pre-variant scheduler.
 
+Spans (``obs/trace.py``, always on, off the flight recorder): each
+``step()`` closes ``sched.step`` around ``sched.admit`` (boundary callbacks
+through admission; ``sched.queue_wait`` is the interval each admitted
+request waited), ``sched.metrics_sync``, the engine's ``engine.round``,
+``sched.deliver`` and ``sched.complete``. The time from one
+``engine.step`` returning to the next being entered is the
+``serve_between_rounds_seconds`` histogram: what the host adds to a round.
+
 Iteration-boundary callbacks (``at_boundary``): deploy's hot-swap needs
 a moment on the driver thread when no jitted program is mid-flight to
 canary and flip the live param reference. Callbacks run at the top of
@@ -86,6 +94,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from distributed_tensorflow_tpu.obs import trace as _trace
 from distributed_tensorflow_tpu.serve.engine import SlotEngine
 from distributed_tensorflow_tpu.serve.kv_pool import InsufficientPages
 
@@ -421,6 +430,9 @@ class Scheduler:
         self._handoff_inbox: deque = deque()  # decode side: (bundle, pending)
         self._ids = itertools.count()
         self._boundary: deque = deque()  # thread-safe append/popleft
+        # clock() when the last engine round returned with slots still
+        # in flight (None otherwise): serve_between_rounds_seconds.
+        self._round_returned_at: float | None = None
         self._thread: threading.Thread | None = None
         self._stop = threading.Event()
 
@@ -553,49 +565,65 @@ class Scheduler:
         """One serving iteration (boundary callbacks → shed → admit →
         decode → complete). Returns the number of requests completed
         this iteration."""
-        self._run_boundary()
-        now = self.clock()
-        self._shed_expired(now)
-        self._admit_handoffs(now)
-        self._admit(now)
-        if self.metrics is not None:
-            # Occupancy in the engine's native capacity unit: PAGE
-            # occupancy under the paged layout (what admission actually
-            # gates on), slot occupancy for the monolithic layout.
-            self.metrics.record_occupancy(self.engine.utilization)
-            self.metrics.sync_engine(self.engine)
-        if (self.engine.active_count == 0
-                and getattr(self.engine, "prefilling_count", 0) == 0):
-            return 0
-        t0 = self.clock()
-        toks, valid, done = self.engine.step()
-        round_s = self.clock() - t0
-        produced = 0
-        round_toks: dict[int, list] = {}
-        for k in range(toks.shape[0]):
-            for slot, fl in self._inflight.items():
-                if valid[k, slot]:
-                    tok = int(toks[k, slot])
-                    fl.tokens.append(tok)
-                    round_toks.setdefault(slot, []).append(tok)
-                    produced += 1
-        for slot, new in round_toks.items():
-            fl = self._inflight[slot]
-            if fl.ttft_s is None:
-                # Chunked-prefill admission deferred the first token to
-                # this round — TTFT is request-observed first-token time.
-                fl.ttft_s = self.clock() - fl.pending.submitted_at
-                if self.metrics is not None:
-                    self.metrics.record_ttft(fl.ttft_s)
-            fl.pending.push_tokens(new)
-        if self.metrics is not None:
-            self.metrics.record_round(round_s, produced)
-        completed = 0
-        for slot in np.nonzero(done)[0]:
-            self._complete(int(slot))
-            completed += 1
-        self._sweep_handoffs()
-        return completed
+        with _trace.span("sched.step", flight=False) as step_span:
+            with _trace.span("sched.admit", flight=False) as sp:
+                self._run_boundary()
+                now = self.clock()
+                self._shed_expired(now)
+                self._admit_handoffs(now)
+                sp.note(admitted=self._admit(now))
+            metrics = self.metrics
+            if metrics is not None:
+                with _trace.span("sched.metrics_sync", flight=False):
+                    # Occupancy in the engine's native capacity unit: PAGE
+                    # occupancy under the paged layout (what admission actually
+                    # gates on), slot occupancy for the monolithic layout.
+                    metrics.record_occupancy(self.engine.utilization)
+                    metrics.sync_engine(self.engine)
+            if (self.engine.active_count == 0
+                    and getattr(self.engine, "prefilling_count", 0) == 0):
+                self._round_returned_at = None
+                step_span.note(completed=0)
+                return 0
+            t0 = self.clock()
+            if metrics is not None and self._round_returned_at is not None:
+                metrics.record_between_rounds(t0 - self._round_returned_at)
+            toks, valid, done = self.engine.step()
+            t1 = self.clock()
+            with _trace.span("sched.deliver", flight=False) as sp:
+                produced = 0
+                round_toks: dict[int, list] = {}
+                for k in range(toks.shape[0]):
+                    for slot, fl in self._inflight.items():
+                        if valid[k, slot]:
+                            tok = int(toks[k, slot])
+                            fl.tokens.append(tok)
+                            round_toks.setdefault(slot, []).append(tok)
+                            produced += 1
+                for slot, new in round_toks.items():
+                    fl = self._inflight[slot]
+                    if fl.ttft_s is None:
+                        # Chunked-prefill admission deferred the first token to
+                        # this round — TTFT is request-observed first-token
+                        # time.
+                        fl.ttft_s = self.clock() - fl.pending.submitted_at
+                        if metrics is not None:
+                            metrics.record_ttft(fl.ttft_s)
+                    fl.pending.push_tokens(new)
+                if metrics is not None:
+                    metrics.record_round(t1 - t0, produced)
+                sp.note(produced=produced)
+            with _trace.span("sched.complete", flight=False):
+                completed = 0
+                for slot in np.nonzero(done)[0]:
+                    self._complete(int(slot))
+                    completed += 1
+                self._sweep_handoffs()
+            # The between-rounds gap is host overhead only while work remains:
+            # with every slot drained the next round waits for a request.
+            self._round_returned_at = t1 if self._inflight else None
+            step_span.note(completed=completed)
+            return completed
 
     def _shed_expired(self, now: float) -> None:
         with self._lock:
@@ -620,11 +648,13 @@ class Scheduler:
     def _current_variant(self) -> str:
         return self.engine.serving_variant if self.variants is not None else ""
 
-    def _admit(self, now: float) -> None:
+    def _admit(self, now: float) -> int:
+        """Admit queued requests into free slots; returns how many."""
+        admitted = 0
         while True:
             with self._lock:
                 if not any(len(q) for q in self._queues.values()):
-                    return
+                    return admitted
                 cur = self._current_variant()
                 curq = self._queues.get(cur)
                 cur_depth = len(curq) if curq is not None else 0
@@ -642,18 +672,18 @@ class Scheduler:
                         # an EMPTY boundary (slots pin their variant).
                         # Stop admitting so the current cohort drains;
                         # decode keeps running in step().
-                        return
+                        return admitted
                     # Rotate round-robin by name so two busy variants
                     # alternate rather than one always winning the tie.
                     switch_to = next(
                         (v for v in others if v > cur), others[0]
                     )
                 elif cur_depth == 0:
-                    return
+                    return admitted
                 if switch_to is None:
                     slot = self.engine.acquire_slot()
                     if slot is None:
-                        return
+                        return admitted
                     pending = self._queues[cur].pop()
             if switch_to is not None:
                 # Empty iteration boundary: flip the engine onto the
@@ -668,8 +698,11 @@ class Scheduler:
                 self.variants.activate(switch_to)
                 self.engine = self.variants.engine_for(switch_to)
                 self._variant_served = 0
+                if self.metrics is not None:
+                    self.metrics.bind_engine(self.engine)
                 continue
             r = pending.request
+            started_at = self.clock()
             try:
                 first, finished = self.engine.start(
                     slot, r.prompt,
@@ -686,14 +719,23 @@ class Scheduler:
                 self.engine.release(slot)
                 with self._lock:
                     self._queues[pending.variant].push_front(pending)
-                return
+                return admitted
             except Exception as exc:  # _validate should prevent this
                 self.engine.release(slot)
                 pending.finish(Rejection(r.request_id, "invalid", str(exc)))
                 self._count_shed()
                 continue
-            self._variant_served += 1
             done_at = self.clock()
+            self._variant_served += 1
+            admitted += 1
+            # The wait ends where engine.start is entered; one record per
+            # ADMITTED request (a start the page pool refused is retried).
+            _trace.interval("sched.queue_wait", pending.submitted_at,
+                            started_at, lane=r.priority,
+                            prompt_len=len(r.prompt))
+            if self.metrics is not None:
+                self.metrics.record_queue_wait(
+                    started_at - pending.submitted_at)
             wv = int(getattr(self.engine, "weight_version", 0))
             if first is None:
                 # Chunked prefill scheduled: the slot is PREFILLING and

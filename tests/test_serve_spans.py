@@ -1,0 +1,344 @@
+"""The serving round, timed from inside: which spans a scheduler round
+closes and how they nest, the queue-wait and between-rounds instruments on
+a fake clock, the named scopes of the engine's programs (and that they
+change no token), and what ``sync_engine`` no longer does every round."""
+
+import os
+import re
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_tensorflow_tpu import obs
+from distributed_tensorflow_tpu.config import ServeConfig
+from distributed_tensorflow_tpu.models.transformer import (
+    TransformerConfig,
+    TransformerLM,
+)
+from distributed_tensorflow_tpu.obs import trace
+from distributed_tensorflow_tpu.obs.export import prometheus_text
+from distributed_tensorflow_tpu.serve import (
+    Request,
+    Scheduler,
+    ServingMetrics,
+    SlotEngine,
+)
+from distributed_tensorflow_tpu.serve.deploy import WeightSwapper
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
+import serve_lm  # noqa: E402
+
+pytestmark = pytest.mark.serve
+
+CFG = TransformerConfig(
+    vocab_size=64, d_model=32, num_heads=4, num_kv_heads=2, num_layers=2,
+    d_ff=64, max_seq_len=48, position="rope", compute_dtype=jnp.float32,
+)
+PROMPTS = (
+    (60, 40, 43, 57, 37),
+    (49, 53, 14, 3, 19, 18, 55, 58, 0, 31, 52, 8, 51, 7, 29, 52, 19, 21, 17,
+     46),  # longer than prefill_len: chunked
+)
+# Greedy tokens of PROMPTS on the tree BEFORE the named scopes (parent
+# commit 08f58d2, this CFG, PRNGKey(0), CPU f32).
+GOLDEN = ((47, 4, 4, 29, 29, 4, 29, 4), (17, 19, 5, 4, 4, 4, 4, 4))
+SCOPES = ("kv.gather", "attn", "mlp", "lm_head", "sample", "kv.scatter")
+ROUND_SPANS = (
+    "sched.step", "sched.admit", "sched.queue_wait", "engine.start",
+    "sched.metrics_sync", "engine.round", "engine.prefill_chunk",
+    "engine.dispatch", "engine.wait", "engine.readback", "sched.deliver",
+    "sched.complete",
+)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return TransformerLM(CFG).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+
+
+def _engine(params, **kw):
+    return SlotEngine(CFG, params, slots=2, max_len=48, prefill_len=8, **kw)
+
+
+def _since(t_lo):
+    """{span name: records} of everything closed since ``t_lo``."""
+    out = {n: trace.closed(n, t_lo) for n in trace.names()}
+    return {n: rs for n, rs in out.items() if rs}
+
+
+def _inside(inner, outer):
+    return outer[0] <= inner[0] and inner[1] <= outer[1]
+
+
+def test_one_round_closes_each_span_once_nested_at_most_twelve(params):
+    """A round that admits one (chunked) request beside one decoding slot
+    closes every span of the table exactly once, nested as the table says,
+    and nothing else: 12 records."""
+    engine = _engine(params)
+    engine.warmup()
+    sched = Scheduler(engine, metrics=ServingMetrics())
+    sched.submit(Request(prompt=PROMPTS[0], max_new_tokens=8))
+    sched.step()  # slot 0 decodes from here on
+    sched.submit(Request(prompt=PROMPTS[1], max_new_tokens=8))
+    t_lo = time.monotonic()
+    sched.step()
+    got = _since(t_lo)
+    assert sorted(got) == sorted(ROUND_SPANS)
+    assert all(len(v) == 1 for v in got.values()), {
+        k: len(v) for k, v in got.items()}
+    assert sum(len(v) for v in got.values()) <= 12
+    r = {k: v[0] for k, v in got.items()}
+    for child, parent in (
+        ("sched.admit", "sched.step"), ("engine.start", "sched.admit"),
+        ("sched.metrics_sync", "sched.step"), ("engine.round", "sched.step"),
+        ("engine.prefill_chunk", "engine.round"),
+        ("engine.dispatch", "engine.round"), ("engine.wait", "engine.round"),
+        ("engine.readback", "engine.round"), ("sched.deliver", "sched.step"),
+        ("sched.complete", "sched.step"),
+    ):
+        assert _inside(r[child], r[parent]), (child, parent)
+    in_order = ["sched.admit", "sched.metrics_sync", "engine.prefill_chunk",
+                "engine.dispatch", "engine.wait", "engine.readback",
+                "sched.deliver", "sched.complete"]
+    for a, b in zip(in_order, in_order[1:]):
+        assert r[a][1] <= r[b][0], (a, b)
+    assert r["sched.queue_wait"][1] <= r["engine.start"][0]
+    # What each records.
+    assert r["sched.step"][2] == {"completed": 0}
+    assert r["sched.admit"][2] == {"admitted": 1}
+    assert r["sched.queue_wait"][2] == {"lane": 1, "prompt_len": 20}
+    assert r["engine.start"][2] == {"prompt_len": 20, "matched": 0,
+                                    "chunks": 3}
+    assert r["engine.prefill_chunk"][2] == {"offset": 0, "width": 8,
+                                            "final": False}
+    assert r["engine.round"][2] == {"active": 1, "live_tokens": 6,
+                                    "chunks_run": 1}
+    assert r["sched.deliver"][2] == {"produced": 1}
+    assert r["engine.dispatch"][2] is None
+
+
+def test_queue_wait_and_between_rounds_on_a_fake_clock(params):
+    """``sched.queue_wait`` and ``serve_queue_wait_seconds`` are one
+    reading of the scheduler's clock; ``serve_between_rounds_seconds``
+    observes a gap only while slots stayed in flight."""
+    ticks = [1000.0]
+
+    def clock():
+        ticks[0] += 0.5
+        return ticks[0]
+
+    metrics = ServingMetrics()
+    sched = Scheduler(_engine(params), metrics=metrics, clock=clock)
+    lo = ticks[0]
+    for i, prompt in enumerate(PROMPTS + (PROMPTS[0],)):  # 3 on 2 slots
+        sched.submit(Request(prompt=prompt, max_new_tokens=4, priority=i))
+    t_real = time.monotonic()
+    sched.run_until_idle(max_steps=100)
+    waits = [r for r in trace.closed("sched.queue_wait", lo, ticks[0])]
+    assert [r[2]["lane"] for r in waits] == [0, 1, 2]
+    assert [r[2]["prompt_len"] for r in waits] == [5, 20, 5]
+    np.testing.assert_allclose(
+        sorted(metrics.queue_wait.values()),
+        sorted(r[1] - r[0] for r in waits))
+    assert waits[2][1] - waits[2][0] > waits[0][1] - waits[0][0]
+    rounds = [r for r in trace.closed("engine.round", t_real)]
+    gaps = metrics.between_rounds.values()
+    assert len(gaps) == len(rounds) - 1 and (gaps > 0).all()
+    # After an idle stretch the first round observes nothing.
+    sched.submit(Request(prompt=PROMPTS[0], max_new_tokens=3))
+    t_real = time.monotonic()
+    sched.run_until_idle(max_steps=100)
+    more = len(trace.closed("engine.round", t_real))
+    assert more >= 2
+    assert len(metrics.between_rounds.values()) == len(gaps) + more - 1
+    text = prometheus_text(metrics.registry)
+    assert "serve_queue_wait_seconds_count 4" in text
+    assert "serve_between_rounds_seconds_bucket" in text
+
+
+def _lowered_scopes(jitted, *args):
+    text = jitted.lower(*args).as_text(debug_info=True)
+    names = set(re.findall(r'loc\("([^"]*)"', text))
+    return {s for s in SCOPES
+            if any(re.search(r"(^|[/(])%s([/)]|$)" % re.escape(s), n)
+                   for n in names)}
+
+
+@pytest.mark.parametrize("sampled", [False, True])
+def test_programs_carry_every_scope_name(params, sampled):
+    e = _engine(params)
+    step = e._step_sampled if sampled else e._step_greedy
+    prefill = e._prefill_sampled if sampled else e._prefill_greedy
+    step_args = (e.pool.layers, e.params, e.pool.page_tables, e.active,
+                 e.lengths, e.cur_tok, e.temp, e.top_k, e.top_p, e.seed,
+                 e.made, e.budget, e.eos)
+    assert _lowered_scopes(step, *step_args) == set(SCOPES)
+    prefill_args = (e.pool.layers, e.params, np.zeros((1, 8), np.int32),
+                    np.int32(5), np.int32(0),
+                    np.array(e.pool.page_tables[0]), np.float32(0.0),
+                    np.int32(0), np.float32(0.0), np.uint32(0))
+    assert _lowered_scopes(prefill, *prefill_args) == set(SCOPES)
+
+
+def test_training_path_carries_no_scope(params):
+    """The scopes are on the cached branch alone: the plain forward (what
+    a trainer compiles) lowers to the text it lowered to before."""
+    fwd = jax.jit(lambda p, t: TransformerLM(CFG).apply({"params": p}, t))
+    text = fwd.lower(params, jnp.zeros((1, 8), jnp.int32)).as_text(
+        debug_info=True)
+    names = set(re.findall(r'loc\("([^"]*)"', text))
+    assert not [n for n in names for s in ("attn", "mlp")
+                if re.search(r"(^|/)%s(/|$)" % s, n)]
+
+
+def test_greedy_tokens_are_what_they_were_before_the_scopes(params):
+    engine = _engine(params)
+    engine.warmup()
+    sched = Scheduler(engine)
+    handles = [sched.submit(Request(prompt=p, max_new_tokens=8))
+               for p in PROMPTS]
+    sched.run_until_idle(max_steps=200)
+    assert tuple(h.result(timeout=5).tokens for h in handles) == GOLDEN
+
+
+def test_warmup_is_one_span_with_a_child_per_program(params):
+    t_lo = time.monotonic()
+    engine = _engine(params)
+    n = engine.warmup()
+    (warm,) = trace.closed("engine.warmup", t_lo)
+    assert warm[2] == {"programs": n}
+    kids = trace.closed("engine.warmup_program", t_lo)
+    assert [k[2]["program"] for k in kids] == [
+        "step.greedy", "step.sampled", "chunked.greedy", "chunked.sampled"]
+    assert all(_inside(k, warm) for k in kids)
+    # The step passes compile their prefill and step programs; the chunked
+    # prompts reuse them (the zero-recompile contract).
+    assert [k[2]["compiled"] for k in kids] == [True, True, False, False]
+    assert sum(k[1] - k[0] for k in kids) <= warm[1] - warm[0]
+
+
+@pytest.fixture()
+def weight_walks(monkeypatch):
+    """Counts reads of ``SlotEngine.weight_bytes_per_device`` (a walk over
+    every parameter leaf)."""
+    walks = []
+    inner = SlotEngine.weight_bytes_per_device.fget
+    monkeypatch.setattr(
+        SlotEngine, "weight_bytes_per_device",
+        property(lambda self: walks.append(1) or inner(self)))
+    return walks
+
+
+FIXED = {  # what the parent's sync_engine set every round, for this stack
+    "serve_mesh_tp": 1.0, "serve_hbm_bytes_per_device": 28672.0,
+    "serve_hbm_bytes_per_slot": 14336.0, "serve_kv_bytes_per_token": 256.0,
+    'serve_kv_dtype{dtype="bf16"}': 1.0, "serve_prefill_tokens_budget": 8.0,
+    "serve_weight_bytes_per_device": 76800.0,
+    'serve_spec_accept_rate_by_drafter{drafter="ngram"}': 0.0,
+    'serve_spec_accept_rate_by_drafter{drafter="model"}': 0.0,
+}
+FAMILIES = {  # every family /metrics showed at the parent commit
+    "fleet_handoff_bytes_total", "fleet_handoff_chunk_ms",
+    "fleet_handoff_throughput_bytes_per_s", "recompile_events_total",
+    "serve_completed_total", "serve_handoff_stall_events_total",
+    "serve_handoff_stall_max_seconds", "serve_handoff_stall_seconds_total",
+    "serve_handoff_total", "serve_hbm_bytes_per_device",
+    "serve_hbm_bytes_per_slot", "serve_kv_bytes_per_token", "serve_kv_dtype",
+    "serve_kv_page_occupancy_current", "serve_kv_pages_free_current",
+    "serve_lane_depth_current", "serve_mesh_tp", "serve_per_token_seconds",
+    "serve_prefill_chunks_total", "serve_prefill_tokens_budget",
+    "serve_prefill_tokens_last_iter", "serve_prefix_hit_rate",
+    "serve_prefix_tokens_matched_total", "serve_prefix_tokens_total",
+    "serve_queue_depth", "serve_queue_depth_current",
+    "serve_queue_depth_peak", "serve_shed_total", "serve_slot_occupancy",
+    "serve_slot_occupancy_current", "serve_spec_accept_per_verify",
+    "serve_spec_accept_rate", "serve_spec_accept_rate_by_drafter",
+    "serve_spec_accepted_per_verify_p50",
+    "serve_spec_accepted_per_verify_p99", "serve_spec_drafts_accepted_total",
+    "serve_spec_drafts_proposed_total", "serve_swap_total",
+    "serve_tokens_out_total", "serve_ttft_seconds",
+    "serve_variant_requests_total", "serve_weight_bytes_per_device",
+    "serve_weight_version", "xla_compile_events_total",
+}
+
+
+def _samples(text):
+    return {k: float(v) for k, v in (
+        l.rsplit(" ", 1) for l in text.splitlines() if not l.startswith("#"))}
+
+
+def test_build_stack_binds_once_and_rounds_walk_no_params(
+        params, weight_walks):
+    """After ``build_stack`` the gauges the build fixes are on /metrics
+    before any traffic, with the values the parent showed; rounds never
+    read ``weight_bytes_per_device``; a hot swap reads it again."""
+    t_lo = time.monotonic()
+    serve_cfg = ServeConfig(slots=2, serve_max_len=48, prefill_len=8, port=0,
+                            slo="off")
+    engine, sched, metrics, server = serve_lm.build_stack(
+        serve_cfg, CFG, params)
+    try:
+        assert len(weight_walks) == 1
+        before = _samples(prometheus_text(metrics.registry))
+        assert {k: before[k] for k in FIXED} == FIXED
+        for p in PROMPTS:
+            sched.submit(Request(prompt=p, max_new_tokens=4))
+        sched.run_until_idle(max_steps=100)
+        assert len(weight_walks) == 1
+        text = prometheus_text(metrics.registry)
+        families = {l.split()[2] for l in text.splitlines()
+                    if l.startswith("# TYPE")}
+        assert FAMILIES <= families
+        assert families - FAMILIES == {"serve_queue_wait_seconds",
+                                       "serve_between_rounds_seconds"}
+        after = _samples(text)
+        assert {k: after[k] for k in FIXED} == FIXED
+        assert after["serve_prefill_chunks_total"] == float(
+            engine.stats["prefill_chunks"]) >= 3.0
+        assert after["serve_kv_pages_free_current"] == 4.0
+        snap = metrics.snapshot()
+        assert (snap["weight_dtype"], snap["kv_dtype"]) == ("native", "bf16")
+        # adopt_weights (through the swapper, at a boundary) refreshes them.
+        other = TransformerLM(CFG).init(
+            jax.random.PRNGKey(1), jnp.zeros((1, 8), jnp.int32))["params"]
+        swapper = WeightSwapper(engine, sched, metrics=metrics,
+                                probe_prompts=[PROMPTS[0]])
+        swapper.submit(7, other)
+        sched.submit(Request(prompt=PROMPTS[0], max_new_tokens=2))
+        sched.run_until_idle(max_steps=100)
+        assert swapper.last.outcome == "ok" and engine.weight_version == 7
+        assert len(weight_walks) == 2
+        # The build's spans: serve.build around the constructor (weights
+        # placed inside it) and the warm-up.
+        (build,) = trace.closed("serve.build", t_lo)
+        (ctor,) = trace.closed("serve.build_engine", t_lo)
+        (place,) = trace.closed("engine.place_weights", t_lo)
+        (warm,) = trace.closed("engine.warmup", t_lo)
+        assert _inside(place, ctor) and _inside(ctor, build)
+        assert _inside(warm, build) and ctor[1] <= warm[0]
+    finally:
+        server.server_close()
+
+
+def test_hand_built_scheduler_binds_its_engine_on_the_first_round(
+        params, weight_walks):
+    metrics = ServingMetrics()
+    sched = Scheduler(_engine(params), metrics=metrics)
+    sched.submit(Request(prompt=PROMPTS[0], max_new_tokens=4))
+    sched.run_until_idle(max_steps=100)
+    assert len(weight_walks) == 1
+    got = _samples(prometheus_text(metrics.registry))
+    assert got["serve_weight_bytes_per_device"] == 76800.0
+    assert got["serve_mesh_tp"] == 1.0
+
+
+def test_per_token_help_says_what_it_observes():
+    text = prometheus_text(ServingMetrics().registry)
+    (line,) = [l for l in text.splitlines()
+               if l.startswith("# HELP serve_per_token_seconds")]
+    assert "Inter-token gap:" not in line and "over all slots" in line

@@ -63,8 +63,16 @@ def build_stack(serve_cfg, cfg, params, deploy_cfg=None):
     WeightSwapper always, and a CheckpointWatcher when ``watch_dir`` is
     set — riding along as ``server.variant_table`` / ``server.swapper`` /
     ``server.watcher`` (None when absent). The caller starts/stops the
-    watcher thread."""
+    watcher thread.
+
+    The build is recorded as ``serve.build`` (``obs/trace.py``) around the
+    spans ``serve.build_engine`` (the constructor, ``engine.place_weights``
+    inside it) and ``engine.warmup``: what ``setup_s`` is made of. It is an
+    interval closed at the end and no wrapper around this function: every
+    Python frame above a jitted program's first call slows its tracing."""
     from distributed_tensorflow_tpu import obs
+
+    build_t0 = time.monotonic()
     from distributed_tensorflow_tpu.serve import (
         Scheduler,
         ServingMetrics,
@@ -147,25 +155,30 @@ def build_stack(serve_cfg, cfg, params, deploy_cfg=None):
         serve_cfg.validate_mesh(cfg)
     engine_cls = SlotEngine if tp <= 1 else ShardedSlotEngine
     tp_kw = {} if tp <= 1 else {"tp": tp}
-    engine = engine_cls(
-        cfg,
-        params,
-        **tp_kw,
-        slots=serve_cfg.slots,
-        max_len=serve_cfg.serve_max_len or None,
-        prefill_len=serve_cfg.prefill_len or None,
-        steps_per_sync=serve_cfg.steps_per_sync,
-        sentinel=sentinel,
-        page_size=getattr(serve_cfg, "engine_page_size", None),
-        kv_pages=getattr(serve_cfg, "kv_pages", 0),
-        prefix_cache=getattr(serve_cfg, "prefix_cache", True),
-        spec_k=getattr(serve_cfg, "spec_k", 0),
-        spec_branches=getattr(serve_cfg, "spec_branches", 1),
-        prefill_chunk_tokens=getattr(serve_cfg, "prefill_chunk_tokens", 0),
-        draft_params=draft_params,
-        draft_cfg=draft_cfg,
-        draft_window=getattr(serve_cfg, "draft_window", 16),
-    )
+    with obs.span("serve.build_engine"):
+        engine = engine_cls(
+            cfg,
+            params,
+            **tp_kw,
+            slots=serve_cfg.slots,
+            max_len=serve_cfg.serve_max_len or None,
+            prefill_len=serve_cfg.prefill_len or None,
+            steps_per_sync=serve_cfg.steps_per_sync,
+            sentinel=sentinel,
+            page_size=getattr(serve_cfg, "engine_page_size", None),
+            kv_pages=getattr(serve_cfg, "kv_pages", 0),
+            prefix_cache=getattr(serve_cfg, "prefix_cache", True),
+            spec_k=getattr(serve_cfg, "spec_k", 0),
+            spec_branches=getattr(serve_cfg, "spec_branches", 1),
+            prefill_chunk_tokens=getattr(
+                serve_cfg, "prefill_chunk_tokens", 0),
+            draft_params=draft_params,
+            draft_cfg=draft_cfg,
+            draft_window=getattr(serve_cfg, "draft_window", 16),
+        )
+    # What the build fixes (mesh width, bytes per device, dtype labels) is
+    # mirrored to /metrics here, once: sync_engine never walks the params.
+    metrics.bind_engine(engine)
     variants = swapper = watcher = None
     if deploy_cfg is not None:
         from distributed_tensorflow_tpu.serve.deploy import (
@@ -266,6 +279,8 @@ def build_stack(serve_cfg, cfg, params, deploy_cfg=None):
     server.variant_table = variants
     server.swapper = swapper
     server.watcher = watcher
+    obs.trace.interval("serve.build", build_t0, time.monotonic(),
+                       slots=int(serve_cfg.slots))
     return engine, scheduler, metrics, server
 
 
