@@ -39,6 +39,12 @@ class Req:
         self.matched = 0  # prompt tokens adopted from the prefix cache
         self.error = None
 
+    @property
+    def t_ref(self):
+        """What the request is timed from: when it was due (open loop) or
+        sent (closed loop)."""
+        return self.due if self.due is not None else self.sent
+
 
 class LoadGen:
     """Open- and closed-loop generators over ``Scheduler.submit``. A request
@@ -274,8 +280,7 @@ def run(ctx) -> dict:
     with gen.lock:
         all_reqs = list(gen.requests)
     for r in all_reqs:
-        t_ref = r.due if r.due is not None else r.sent
-        r.in_window = t_ref is not None and t_open <= t_ref < t_close
+        r.in_window = r.t_ref is not None and t_open <= r.t_ref < t_close
     window_reqs = [r for r in all_reqs if r.in_window]
     deadline = time.perf_counter() + float(
         t_cfg.get("follow_timeout_s", 60.0))
@@ -293,19 +298,19 @@ def run(ctx) -> dict:
 
     # ---- the window's numbers -------------------------------------------
     ttft, itl, late, qwait = [], [], [], []
-    out_tokens = 0
     failed = 0
+    counted = stats.window_tokens(
+        [(r.t_ref, r.spec["max_new_tokens"], r.token_times)
+         for r in all_reqs], t_open, t_close, plan["loop"])
     for r in all_reqs:
         ts = r.token_times
-        out_tokens += sum(1 for t in ts if t_open <= t < t_close)
         itl += [b - a for a, b in zip(ts, ts[1:]) if t_open <= b < t_close]
         if not r.in_window:
             continue
-        t_ref = r.due if r.due is not None else r.sent
         if not ts:
             failed += 1
             continue
-        ttft.append(ts[0] - t_ref)
+        ttft.append(ts[0] - r.t_ref)
         if r.due is not None:
             late.append(r.sent - r.due)
         if r.started is not None:
@@ -314,7 +319,7 @@ def run(ctx) -> dict:
                 if isinstance(r.outcome, Completion)
                 and len(r.tokens) == len(r.outcome.tokens)]
     e2e = {
-        "out_tok_s": out_tokens / seconds,
+        "out_tok_s": counted["out_tokens"] / seconds,
         "itl_p95_ms": _ms(stats.percentile(itl, 95)),
         "ttft_p95_ms": _ms(stats.percentile(ttft, 95)),
         "setup_s": setup_s,
@@ -343,7 +348,7 @@ def run(ctx) -> dict:
             - stats0["prefix_tokens_matched"],
             "prefix_tokens_total": stats1["prefix_tokens_total"]
             - stats0["prefix_tokens_total"],
-            "output_tokens": out_tokens,
+            "output_tokens": counted["delivered_tokens"],
             "prompt_spans": prompt_spans,
             "decode_rounds": [(r[0], r[1], r[2], r[3]) for r in decodes],
             "compiles_in_window": compiled_end - compiled_warm,
@@ -398,6 +403,8 @@ def run(ctx) -> dict:
                           / max(1, collected["counters"]["prefix_tokens_total"])),
              "finished_in_window": len(finished),
              "hit_in_sample": max((r.matched for r in sample), default=0)}
+    # What out_tok_s counted (stats.window_tokens), and what it left out.
+    extra.update(counted)
     # Where the tail of the gaps lies, and what the engine's spans took:
     # what a reader of a far-off itl_p95_ms needs, on the earlier line only.
     extra["itl_n"] = len(itl)

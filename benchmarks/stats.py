@@ -1,6 +1,7 @@
 """Percentile and quartile arithmetic (copied from tools/loadgen.py's
 nearest-rank-with-interpolation percentile; the original is listed in
-PERF.md's open questions for deletion)."""
+PERF.md's open questions for deletion), and what a serving window counts
+as its tokens. Nothing here imports JAX."""
 
 from __future__ import annotations
 
@@ -26,6 +27,55 @@ def spread(values) -> float:
     (``statistics.quantiles(values, n=4)``)."""
     q1, _, q3 = statistics.quantiles(values, n=4)
     return (q3 - q1) / statistics.median(values)
+
+
+def window_tokens(requests, t_open: float, t_close: float,
+                  loop: str) -> dict:
+    """What ``out_tok_s`` counts, by the loop of the cell.
+
+    ``requests`` holds one ``(t_ref, asked, token_times)`` for every request
+    of the run, the lead's included: when it was due (open loop) or sent
+    (closed loop), its ``max_new_tokens``, and when each of its tokens
+    reached the client. A request is the window's own when ``t_ref`` lies in
+    ``[t_open, t_close)``.
+
+    Closed loop: ``out_tokens`` is every token delivered inside the window,
+    whoever sent it. The clients keep the slots full, so what crosses the
+    opening is matched by what crosses the close and the count is the
+    server's rate. Open loop: the tokens delivered inside the window of the
+    window's own requests. The schedule sets what is asked for, so the count
+    is at most the offered load and rises toward it as the server gets
+    faster; counting the lead's leftovers as well (``delivered_tokens``,
+    the count until PR 28) read the HIGHER the more backlog a slow server
+    carried over the opening.
+
+    Beside it: ``delivered_tokens`` (all the work the window delivered),
+    ``carried_in_tokens`` (delivered inside the window by requests due or
+    sent before it), ``cut_at_close_tokens`` (asked for by the window's own
+    requests and not delivered by the close) and ``offered_tok_s`` (open
+    loop: what the window's own requests ask for, over its seconds; None
+    in a closed loop, where nothing is offered)."""
+    if loop not in ("open", "closed"):
+        raise ValueError(f"unknown loop {loop!r}")
+    own = carried_in = delivered = asked_own = 0
+    for t_ref, asked, times in requests:
+        inside = sum(1 for t in times if t_open <= t < t_close)
+        delivered += inside
+        if t_ref is None:
+            continue
+        if t_ref < t_open:
+            carried_in += inside
+        elif t_ref < t_close:
+            own += inside
+            asked_own += int(asked)
+    return {
+        "out_tokens": delivered if loop == "closed" else own,
+        "delivered_tokens": delivered,
+        "carried_in_tokens": carried_in,
+        "cut_at_close_tokens": asked_own - own,
+        "offered_tok_s": (asked_own / (t_close - t_open)
+                          if loop == "open" else None),
+    }
 
 
 def noise_scale(margins, gaps, lo: float = 1e-4, hi: float = 10.0,

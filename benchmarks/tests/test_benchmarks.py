@@ -118,6 +118,85 @@ def test_zipf_counts_are_exact():
     assert c.sum() == 180 and c[0] == max(c) and c[0] > 5 * c[-1]
 
 
+# ---- what out_tok_s counts, on made-up timelines (no chip, no child) -------
+# (t_ref, asked, token_times); the window is [10, 20).
+
+LEAD = (8.0, 6, [9.0, 9.5, 10.0, 10.5, 11.0, 11.5])  # 4 tokens carried in
+OWN = (12.0, 5, [12.5, 13.0, 13.5, 14.0, 14.5])
+LATE = (19.0, 6, [19.4, 19.8, 20.0, 20.4, 20.8, 21.2])  # 4 cut at the close
+
+
+def test_closed_loop_counts_every_token_in_the_window():
+    got = stats.window_tokens([LEAD, OWN, LATE], 10.0, 20.0, "closed")
+    assert got["out_tokens"] == got["delivered_tokens"] == 4 + 5 + 2
+    assert got["carried_in_tokens"] == 4
+    assert got["cut_at_close_tokens"] == 4
+    assert got["offered_tok_s"] is None  # a closed loop offers nothing
+
+
+def test_open_loop_counts_the_windows_own_requests():
+    got = stats.window_tokens([LEAD, OWN, LATE], 10.0, 20.0, "open")
+    assert got["out_tokens"] == 5 + 2  # not the lead's 4, not LATE's last 4
+    assert got["delivered_tokens"] == 11
+    assert got["carried_in_tokens"] == 4
+    assert got["cut_at_close_tokens"] == 4
+    assert got["offered_tok_s"] == pytest.approx((5 + 6) / 10.0)
+    # a request due at the close, or never sent, is nobody's
+    more = [(20.0, 3, [20.1]), (None, 3, [15.0])]
+    assert stats.window_tokens([OWN] + more, 10.0, 20.0, "open") == dict(
+        got, out_tokens=5, delivered_tokens=6, carried_in_tokens=0,
+        cut_at_close_tokens=0, offered_tok_s=0.5)
+
+
+def test_window_tokens_refuses_an_unknown_loop():
+    with pytest.raises(ValueError):
+        stats.window_tokens([OWN], 10.0, 20.0, "half-open")
+
+
+# The cell's real schedule (the same for every seed) under a server of a
+# given speed: first token ``first_s`` after the due time, then one every
+# ``gap_s``. Slowest first.
+TIMELINES = [(0.3, 0.077), (0.2, 0.044), (0.1, 0.015), (0.0, 0.0)]
+WINDOW_S = 45.0
+
+
+def _complete_open_timeline(first_s, gap_s):
+    spec = manifest.load_json("benchmarks/traffic/complete-open.json")
+    plan = traffic.serve_plan(spec, 1, WINDOW_S, 1000)
+    reqs = [(r["due_s"], r["max_new_tokens"],
+             [r["due_s"] + first_s + i * gap_s
+              for i in range(r["max_new_tokens"])])
+            for r in plan["lead"] + plan["window"]]
+    t_open = plan["lead_s"]
+    return stats.window_tokens(reqs, t_open, t_open + WINDOW_S, plan["loop"])
+
+
+def test_a_faster_server_never_reads_fewer_tokens_in_the_open_cell():
+    """What refused PR 27: on this cell's schedule the count of every token
+    in the window (the lead's leftovers too) FALLS as the server gets
+    faster; the count of the window's own requests rises to what they ask
+    for, 3878 tokens or 86.18 a second, and never passes it."""
+    got = [_complete_open_timeline(*t) for t in TIMELINES]
+    own = [g["out_tokens"] for g in got]
+    old = [g["delivered_tokens"] for g in got]
+    assert own == sorted(own) and own[0] < own[-1] == 3878
+    assert old == sorted(old, reverse=True) and old[0] > old[-1] == 3878
+    assert all(g["offered_tok_s"] * WINDOW_S == pytest.approx(3878)
+               for g in got)
+    assert got[-1]["carried_in_tokens"] == got[-1]["cut_at_close_tokens"] == 0
+
+
+@pytest.mark.parametrize("first_s,gap_s", TIMELINES)
+def test_offered_is_counted_plus_cut_at_the_close(first_s, gap_s):
+    g = _complete_open_timeline(first_s, gap_s)
+    assert g["offered_tok_s"] * WINDOW_S == pytest.approx(
+        g["out_tokens"] + g["cut_at_close_tokens"])
+    # and the old reading above what is asked for is the lead's backlog
+    # less what the close cut
+    assert g["delivered_tokens"] - 3878 == (
+        g["carried_in_tokens"] - g["cut_at_close_tokens"])
+
+
 # ---- the trace reduction, on a small recorded trace ----------------------
 
 
@@ -195,10 +274,22 @@ def test_rehearsal_runs_a_serving_cell_end_to_end():
 
 def test_rehearsal_reports_the_end_to_end_metrics():
     for cell in _cells("serve")[-1:]:
-        out = _result(_run("--workload", cell, "--seed", "5", "--seconds",
-                           "3", "--trace", "0", "--rehearse-cpu"))
+        seconds = 3
+        proc = _run("--workload", cell, "--seed", "5", "--seconds",
+                    str(seconds), "--trace", "0", "--rehearse-cpu")
+        out = _result(proc)
         assert "setup_s" in out["metrics"] and "out_tok_s" in out["metrics"]
         assert all(v["value"] > 0 for v in out["metrics"].values())
+        # the open-loop cell says what out_tok_s counted and what it left out
+        extra = json.loads(proc.stdout.strip().splitlines()[-2])["extra"]
+        assert extra["offered_tok_s"] >= out["metrics"]["out_tok_s"]["value"]
+        assert extra["out_tokens"] == pytest.approx(
+            seconds * out["metrics"]["out_tok_s"]["value"])
+        assert extra["carried_in_tokens"] >= 0
+        assert extra["cut_at_close_tokens"] == pytest.approx(
+            seconds * extra["offered_tok_s"] - extra["out_tokens"])
+        assert extra["delivered_tokens"] == (
+            extra["out_tokens"] + extra["carried_in_tokens"])
 
 
 def test_fault_token_altered_is_not_correct():
