@@ -249,7 +249,12 @@ def attention_sublayer(cfg, x, attend, train: bool = False, cache=None,
     ``self_mask[q]`` marks True (its tree ancestors, self inclusive);
     positions at or past ``len + S`` stay masked (stale junk). The callers
     pass tree-semantic ``positions`` alongside, so rotations/embeddings
-    follow tree DEPTH while cache offsets follow write order."""
+    follow tree DEPTH while cache offsets follow write order.
+
+    A cache with a ``pages`` entry is the serving engine's page pool seen
+    through its page tables (:func:`_attend_through_table`): one new token
+    per slot, each slot at its own length. It shares nothing with the dense
+    cached branch below."""
     h = nn.LayerNorm(dtype=cfg.compute_dtype, name="ln1")(x)
     b, s, _ = h.shape
     dh = cfg.d_model // cfg.num_heads
@@ -364,6 +369,16 @@ def attention_sublayer(cfg, x, attend, train: bool = False, cache=None,
             expand_kv(v.reshape(b, s, kv, dh)).transpose(0, 2, 1, 3),
         )
         attn = attn.transpose(0, 2, 1, 3).reshape(b, s, cfg.d_model)
+    elif "pages" in cache:
+        q, k, v = split_qkv()
+        q4 = q.reshape(b, s, cfg.num_heads, dh)
+        k4 = k.reshape(b, s, kv, dh)
+        if rope:
+            q4 = apply_rope(q4, cos, sin)
+            k4 = apply_rope(k4, cos, sin)
+        attn, cache = _attend_through_table(
+            cfg, cache, q4, k4, v.reshape(b, s, kv, dh)
+        )
     else:
         q, k, v = split_qkv()
         q4 = q.reshape(b, s, cfg.num_heads, dh)
@@ -467,6 +482,43 @@ def attention_sublayer(cfg, x, attend, train: bool = False, cache=None,
     return x + attn, cache
 
 
+def _attend_through_table(cfg, cache, q4, k4, v4):
+    """The decode round on a page pool: ``cache`` holds one layer's pool
+    leaves ``k`` / ``v`` as they lie, (pages, kv, page_size, dh), and, for
+    every slot of the batch, ``len`` (the position of its one new token),
+    ``pages`` (its row of the page table), ``write_page`` (the physical page
+    its new row goes to: the engine points a masked lane at its trash page)
+    and ``attend`` (how many positions it attends: ``len + 1``, 0 for a
+    masked lane). The new K and V row is written straight into the pool,
+    then ``ops.attention.paged_decode_attention`` reads the live pages
+    where they lie: no (B, kv, S_max, dh) cache is gathered or written
+    back. ``q4`` (B, 1, H, dh) and ``k4`` / ``v4`` (B, 1, kv, dh) are already
+    rotated. Returns ``(attn (B, 1, d_model), cache with the new leaves)``."""
+    b, s, heads, dh = q4.shape
+    if s != 1:
+        raise ValueError(f"a paged cache takes one token per slot, got {s}")
+    pages, kv, ps, _ = cache["k"].shape
+    # The pool seen as rows of dh: a (B * kv)-row scatter in the leaf's own
+    # layout. Indexing (page, :, offset) instead makes XLA:TPU relay the
+    # whole pool out around every write (kv heads next to dh), which cost
+    # more than the gather this path removes.
+    rows = (
+        (cache["write_page"][:, None] * kv + jnp.arange(kv)[None, :]) * ps
+        + (cache["len"] % ps)[:, None]
+    ).reshape(-1)
+
+    def write(leaf, new):
+        flat = leaf.reshape(pages * kv * ps, dh)
+        return flat.at[rows].set(new.reshape(b * kv, dh)).reshape(leaf.shape)
+
+    ks, vs = write(cache["k"], k4), write(cache["v"], v4)
+    attn = A.paged_decode_attention(
+        q4[:, 0].reshape(b, kv, heads // kv, dh), ks, vs, cache["pages"],
+        cache["attend"], window=getattr(cfg, "attention_window", None),
+    )
+    return attn.reshape(b, 1, heads * dh), dict(cache, k=ks, v=vs)
+
+
 def _phase_scope(cached: bool):
     """``jax.named_scope`` on the cached branch (the serving engine's
     programs: op metadata only, the program is unchanged), nothing on the
@@ -522,6 +574,10 @@ class TransformerLM(nn.Module):
                  self_mask=None):
         cfg = self.cfg
         b, s = tokens.shape
+        if cache is not None and positions is None and "pages" in cache:
+            # A page pool seen through its tables: every slot of the batch
+            # continues at its own length.
+            positions = cache["len"][:, None] + jnp.arange(s, dtype=jnp.int32)
         x = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.compute_dtype, name="tok_embed")(
             tokens
         )
@@ -565,17 +621,22 @@ class TransformerLM(nn.Module):
         else:
             # Cache layout: {'layers': [{'k','v'}, ...], 'len': scalar} — one
             # shared filled-length for all layers (they advance in lockstep).
+            # What else the cache carries beside 'layers' (a page pool's
+            # tables) is every layer's too.
+            shared = {k_: v_ for k_, v_ in cache.items() if k_ != "layers"}
             new_layers = []
             for i in range(cfg.num_layers):
-                layer = dict(cache["layers"][i], len=cache["len"])
+                layer = dict(cache["layers"][i], **shared)
                 x, layer = Block(cfg, name=f"block_{i}")(
                     x, attend, train=train, cache=layer,
                     positions=rope_positions, self_mask=self_mask,
                 )
                 # Preserve every per-layer buffer (k/v plus the int8
                 # cache's k_scale/v_scale); 'len' is shared, not per-layer.
-                new_layers.append({k_: v_ for k_, v_ in layer.items() if k_ != "len"})
-            cache = {"layers": new_layers, "len": cache["len"] + s}
+                new_layers.append(
+                    {k_: v_ for k_, v_ in layer.items() if k_ not in shared}
+                )
+            cache = dict(shared, layers=new_layers, len=cache["len"] + s)
         with _phase_scope(cache is not None)("lm_head"):
             x = nn.LayerNorm(dtype=cfg.compute_dtype, name="ln_f")(x)
             logits = nn.Dense(

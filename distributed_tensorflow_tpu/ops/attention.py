@@ -1937,3 +1937,262 @@ def _flash_bwd(causal, block_q, block_kv, scale, interpret, window, residuals, g
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
+
+
+# ---------------------------------------------------------------------------
+# Paged decode attention: one new token per slot attends its K and V pages
+# where they lie in the serving engine's page pool.
+#
+# The pool keeps a leaf as (pages, kv_heads, page_size, head_dim) and a slot
+# as one row of physical page ids (serve/kv_pool.py). The kernel takes that
+# row and the slot's live length as scalar-prefetched operands, copies the
+# live pages of one chunk from HBM into a VMEM buffer, page by page, while
+# it computes on the chunk before, and runs an online softmax over the
+# chunks: no logical (slots, kv, max_len, dh) cache exists anywhere, and a
+# slot costs what its live length costs. One invocation walks every slot, so
+# the copy chain also crosses from one slot's last chunk to the next slot's
+# first.
+# ---------------------------------------------------------------------------
+
+
+def _paged_decode_kernel(
+    tables_ref,
+    lens_ref,
+    q_ref,
+    k_hbm,
+    v_hbm,
+    o_ref,
+    k_buf,
+    v_buf,
+    sems,
+    *,
+    scale: float,
+    window: int | None,
+    chunk_pages: int,
+):
+    slots, kv, gp, dh = q_ref.shape
+    ps = k_hbm.shape[2]
+    pps = tables_ref.shape[0] // slots
+    t = chunk_pages * ps
+
+    # A dead row of a live chunk is multiplied by a probability of exactly
+    # zero, which only a finite value survives.
+    k_buf[...] = jnp.zeros(k_buf.shape, k_buf.dtype)
+    v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+
+    def span(b):
+        """(positions attended, first position, first page, pages) of slot b."""
+        n = lens_ref[b]
+        lo = jnp.maximum(n - window, 0) if window is not None else 0
+        p0 = lo // ps
+        return n, lo, p0, pl.cdiv(n, ps) - p0
+
+    def next_live(b):
+        """The first slot at or after ``b`` with something to attend."""
+        return lax.fori_loop(
+            b, slots,
+            lambda i, r: jnp.where((r == slots) & (lens_ref[i] > 0), i, r),
+            slots,
+        )
+
+    def for_pages(b, p0, pages, c, buf, start: bool):
+        """Start, or wait for, the copies of chunk ``c`` of slot ``b``."""
+        first = b * pps + p0 + c * chunk_pages
+
+        def body(i, _):
+            # A wait needs the shapes of its copy alone, not its source.
+            pid = tables_ref[first + i] if start else 0
+            for hbm, vmem, s in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
+                copy = pltpu.make_async_copy(
+                    hbm.at[pid], vmem.at[buf, i], sems.at[s, buf])
+                if start:
+                    copy.start()
+                else:
+                    copy.wait()
+            return 0
+
+        lax.fori_loop(0, jnp.minimum(pages - c * chunk_pages, chunk_pages),
+                      body, 0)
+
+    b0 = next_live(0)
+
+    @pl.when(b0 < slots)
+    def _():
+        _, _, p0, pages = span(b0)
+        for_pages(b0, p0, pages, 0, 0, start=True)
+
+    def slot_body(b, g):
+        n, lo, p0, pages = span(b)
+        chunks = pl.cdiv(pages, chunk_pages)
+        nb = next_live(b + 1)
+        q = [q_ref[b, h] for h in range(kv)]  # (gp, dh) each
+
+        def chunk_body(c, carry):
+            g, state = carry
+            buf = g % 2
+
+            @pl.when(c + 1 < chunks)
+            def _():
+                for_pages(b, p0, pages, c + 1, 1 - buf, start=True)
+
+            @pl.when((c + 1 == chunks) & (nb < slots))
+            def _():
+                _, _, p0n, pagesn = span(nb)
+                for_pages(nb, p0n, pagesn, 0, 1 - buf, start=True)
+
+            for_pages(b, p0, pages, c, buf, start=False)
+            pos = (p0 + c * chunk_pages) * ps + lax.broadcasted_iota(
+                jnp.int32, (1, t), 1)
+            live = pos < n
+            if window is not None:
+                live &= pos >= lo
+            new_state = []
+            for h, (m, l, acc) in enumerate(state):
+                k = k_buf[buf, :, h].reshape(t, dh)
+                v = v_buf[buf, :, h].reshape(t, dh)
+                s = lax.dot_general(
+                    q[h], k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) * scale  # (gp, t)
+                s = jnp.where(live, s, NEG_INF)
+                m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+                alpha = jnp.exp(m - m_new)
+                p = jnp.exp(s - m_new)
+                l = alpha * l + p.sum(axis=-1, keepdims=True)
+                if v.dtype == jnp.bfloat16:
+                    # p = hi + lo, two bf16 halves as rows of ONE pass over
+                    # v: 16 bits of every probability at the MXU cost of 8
+                    # (Mosaic's own f32 product rounds p to bf16: measured).
+                    hi = p.astype(jnp.bfloat16)
+                    lo_p = (p - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+                    both = jnp.dot(jnp.concatenate([hi, lo_p], axis=0), v,
+                                   preferred_element_type=jnp.float32)
+                    pv = both[:gp] + both[gp:]
+                else:
+                    pv = jnp.dot(p, v.astype(jnp.float32),
+                                 preferred_element_type=jnp.float32)
+                new_state.append((m_new, l, alpha * acc + pv))
+            return g + 1, new_state
+
+        state0 = [
+            (jnp.full((gp, 1), NEG_INF, jnp.float32),
+             jnp.zeros((gp, 1), jnp.float32),
+             jnp.zeros((gp, dh), jnp.float32))
+            for _ in range(kv)
+        ]
+        g, state = lax.fori_loop(0, chunks, chunk_body, (g, state0))
+        for h, (_, l, acc) in enumerate(state):
+            o_ref[b, h] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+        return g
+
+    lax.fori_loop(0, slots, slot_body, 0)
+
+
+def _sublane_rows(dtype) -> int:
+    """Rows of one sublane tile of ``dtype``: 8 of f32, 16 of bf16."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def paged_decode_fits(pages) -> bool:
+    """Whether :func:`paged_decode_attention` takes a pool leaf ``pages``
+    (pages, kv_heads, page_size, head_dim) as it lies: a page a whole number
+    of the dtype's sublane tiles and a head a whole number of 128 lanes, so
+    that a page is copied and viewed as rows of its chunk without a
+    relayout. A rule on shapes, the same off the TPU (interpret mode) as on
+    it: what the chip was measured with is what every backend runs."""
+    return (pages.shape[2] % _sublane_rows(pages.dtype) == 0
+            and pages.shape[3] % 128 == 0)
+
+
+def paged_decode_attention(
+    q,
+    k_pages,
+    v_pages,
+    page_tables,
+    lens,
+    *,
+    window: int | None = None,
+    pages_per_chunk: int = 32,
+    interpret: bool | None = None,
+):
+    """Attention of one query token per slot over that slot's pages, in place.
+
+    ``q`` (slots, kv_heads, group, head_dim); ``k_pages`` / ``v_pages``
+    (pages, kv_heads, page_size, head_dim), the pool's leaves as they lie;
+    ``page_tables`` (slots, pages_per_slot) int32 physical page ids, logical
+    page ``j`` of a slot holding its positions ``[j*page_size,
+    (j+1)*page_size)``; ``lens`` (slots,) int32, the positions ``0..lens-1``
+    the slot's token attends (its own included). A slot with ``lens`` 0 reads
+    nothing and returns zeros. ``window`` keeps the last ``window`` of those
+    positions and skips the pages wholly below them. Returns (slots,
+    kv_heads, group, head_dim) in ``q``'s dtype.
+
+    Scores, softmax state and the value product accumulate in f32 over the
+    operands' own dtype, as the dense cached branch of
+    ``models/transformer.py`` does. Only the pages ``ceil(lens/page_size)``
+    (less the window's skip) are copied from HBM.
+    """
+    slots, kv, _, dh = q.shape
+    if k_pages.shape != v_pages.shape or k_pages.shape[1::2] != (kv, dh):
+        raise ValueError(
+            f"q {q.shape} does not fit pages k {k_pages.shape} / v "
+            f"{v_pages.shape}"
+        )
+    if page_tables.shape[0] != slots or lens.shape != (slots,):
+        raise ValueError(
+            f"page_tables {page_tables.shape} / lens {lens.shape} do not fit "
+            f"{slots} slots"
+        )
+    if window is not None and window < 1:
+        raise ValueError(f"window must be >= 1, got {window}")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _paged_decode_call(
+        q, k_pages, v_pages, page_tables, lens,
+        scale=_scale(q, None), window=window,
+        chunk_pages=min(int(pages_per_chunk), page_tables.shape[1]),
+        interpret=bool(interpret),
+    )
+
+
+# Jitted, so that a model's layers trace and lower the kernel once between
+# them (30 separate pallas_calls cost the serving engine seconds of warm-up).
+@functools.partial(
+    jax.jit, static_argnames=("scale", "window", "chunk_pages", "interpret")
+)
+def _paged_decode_call(q, k_pages, v_pages, page_tables, lens, *, scale,
+                       window, chunk_pages, interpret):
+    _, kv, group, dh = q.shape
+    ps = k_pages.shape[2]
+    # The MXU takes whole sublane tiles of query rows.
+    rows = _sublane_rows(q.dtype)
+    gp = -(-group // rows) * rows
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, gp - group), (0, 0)))
+    kernel = functools.partial(
+        _paged_decode_kernel, scale=scale, window=window,
+        chunk_pages=chunk_pages,
+    )
+    buf = (2, chunk_pages, kv, ps, dh)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.VMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            scratch_shapes=[
+                pltpu.VMEM(buf, k_pages.dtype),
+                pltpu.VMEM(buf, v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=interpret,
+        name="paged_decode_attention",
+    )(page_tables.reshape(-1).astype(jnp.int32), lens.astype(jnp.int32),
+      q, k_pages, v_pages)
+    return out[:, :, :group]

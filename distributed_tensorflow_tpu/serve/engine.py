@@ -8,13 +8,18 @@ The engine runs one of two KV layouts behind the same slot API:
 
 * ``page_size>0`` (default) — the paged layout: one physical page pool
   (:class:`~distributed_tensorflow_tpu.serve.kv_pool.PagedKVPool`) plus
-  per-slot page tables. Every jitted program gathers a slot's logical
-  ``(kv, max_len, dh)`` cache from its table row, runs the SAME model
-  code as the monolithic path, and scatters touched pages back. The
-  table is a host numpy array passed as a TRACED operand of fixed shape
-  ``(slots, pages_per_slot)``, so rebinding pages never retraces; unbound
-  entries point at the reserved trash page, which absorbs the fixed-shape
-  scatters of masked lanes.
+  per-slot page tables. The table is a host numpy array passed as a
+  TRACED operand of fixed shape ``(slots, pages_per_slot)``, so rebinding
+  pages never retraces; unbound entries point at the reserved trash page,
+  which absorbs the fixed-shape writes of masked lanes. The prefill, chunk
+  and verify programs gather a slot's logical ``(kv, max_len, dh)`` cache
+  from its table row, run the SAME model code as the monolithic path, and
+  scatter touched pages back. The plain decode program does that only
+  where its kernel does not fit (``decode_path``, fixed at construction by
+  :meth:`SlotEngine._decode_path` from the pool's leaf kinds and dtype,
+  the page size, the head size and the engine class's hook — no config
+  field or flag): where it fits, decode attends THROUGH the table and no
+  logical cache is materialised.
 
 Jitted programs (all compiled at :meth:`SlotEngine.warmup`, after which
 the compile count must never grow — the ``RecompileSentinel`` contract):
@@ -28,12 +33,21 @@ the compile count must never grow — the ``RecompileSentinel`` contract):
   sharing) — only the prompt TAIL is computed, through a tail-sized
   bucket, which is what collapses TTFT for shared-system-prompt traffic.
 
-* **decode step** — ``steps_per_sync`` micro-steps over the whole slot
-  batch fused into one ``lax.scan``; per-slot traced lengths, per-slot
-  sampling (``sample_logits_batched``), inactive lanes masked. The paged
-  variant scatters back only the ONE page each slot wrote (its private
-  boundary page — never a shared prefix page, since writes land at
-  positions ``>= p``).
+* **decode step** (``step_fn``) — ``steps_per_sync`` micro-steps over the
+  whole slot batch fused into one ``lax.scan``; per-slot traced lengths,
+  per-slot sampling (``sample_logits_batched``), inactive lanes masked.
+  On the paged pool a micro-step is one of two forwards. ``"table"``: all
+  slots as one batch; per layer the new K and V ROW goes straight to
+  ``(page_tables[slot, length // page_size], :, length % page_size, :)``
+  (a masked lane's to the trash page) and the Pallas kernel
+  ``ops.attention.paged_decode_attention`` reads each slot's live pages
+  where they lie, up to ``length + 1`` — a slot costs what its live
+  length costs and a masked lane nothing. ``"gather"`` (int8 KV, a page
+  or head size off the chip's tile, ``ShardedSlotEngine``): ``vmap`` over
+  slots of the B=1 cached decode on each slot's gathered logical cache,
+  scattering back only the ONE page each slot wrote. Either way the
+  write lands in the slot's private boundary page — never a shared prefix
+  page, since writes land at positions ``>= p``.
 
 * **speculative verify** (``spec_k > 0``, two compiled variants) — the
   host drafts ``spec_k`` tokens by prompt-lookup (n-gram continuation of
@@ -111,7 +125,11 @@ closes ``engine.round`` around ``engine.prefill_chunk`` (one per chunk
 spent), ``engine.dispatch`` (entry of the decode round to the jitted call's
 return: the device has work queued from here), ``engine.wait`` (the first
 blocking read of the round's outputs: the device finishing) and
-``engine.readback`` (the remaining copies and host bookkeeping);
+``engine.readback`` (the remaining copies and host bookkeeping), and
+notes what the round works on: ``active``, ``live_tokens``, ``chunks_run``
+and ``kv_rows_read`` (the K and V positions per layer a micro-step of its
+decode program reads: the active slots' live pages through the table,
+``slots * max_len`` wherever a program gathers);
 ``engine.start`` covers an admission and ``engine.warmup`` the program set's
 compiles. The time from the end of one round's ``engine.wait`` to the end
 of the next round's ``engine.dispatch`` is the host gap in which the device
@@ -150,6 +168,7 @@ from distributed_tensorflow_tpu.models.decoding import (
 )
 from distributed_tensorflow_tpu.models.transformer import TransformerLM
 from distributed_tensorflow_tpu.obs import trace as _trace
+from distributed_tensorflow_tpu.ops.attention import paged_decode_fits
 from distributed_tensorflow_tpu.serve.kv_pool import (
     TRASH_PAGE,
     InsufficientPages,
@@ -355,6 +374,7 @@ class SlotEngine:
         else:
             self.pool = SlotKVPool(cfg, self.slots, max_len)
             self.prefix = None
+        self.decode_path = self._decode_path()
 
         # Per-slot host registers. Fixed dtypes — the jit signatures (and
         # therefore the zero-recompile guarantee) depend on them.
@@ -550,21 +570,16 @@ class SlotEngine:
 
                 return step_fn
 
-            def step_fn(
-                pool_layers, params, ptabs, active, lengths, tok,
-                temp, top_k, top_p, seed, made, budget, eos,
-            ):
-                """Paged decode round. Identical control flow to the
-                monolithic variant; each micro-step gathers every slot's
-                logical cache from its table row, appends one token, and
-                scatters back only the single page each slot wrote (page
-                ``length // page_size`` — always slot-private: decode
-                positions are ``>= p``, strictly above every shared full
-                prompt page). Inactive lanes scatter into the trash
-                page."""
+            def gather_forward(pool_layers, ptabs, active, lengths, tok, params):
+                """Each slot gathers its logical cache from its table row,
+                appends one token, and scatters back only the single page
+                it wrote (page ``length // page_size`` — always
+                slot-private: decode positions are ``>= p``, strictly
+                above every shared full prompt page). Inactive lanes
+                scatter into the trash page."""
 
                 def one(row, length, t):
-                    cache = gather_cache(pool_layers_ref[0], row, length)
+                    cache = gather_cache(pool_layers, row, length)
                     cache, logits = decode_step(
                         model, params, cache, t[None, None]
                     )
@@ -582,21 +597,56 @@ class SlotEngine:
                         ]
                     return written, logits[0]
 
-                pool_layers_ref = [pool_layers]
+                written, logits = jax.vmap(one)(ptabs, lengths, tok)
+                wp = lengths // ps
+                dest = ptabs[jnp.arange(ptabs.shape[0]), wp]
+                dest = jnp.where(active, dest, TRASH_PAGE)
+                with jax.named_scope("kv.scatter"):
+                    pool_layers = [
+                        {k: pl[k].at[dest].set(written[li][k])
+                         for k in pl}
+                        for li, pl in enumerate(pool_layers)
+                    ]
+                return pool_layers, logits
+
+            def table_forward(pool_layers, ptabs, active, lengths, tok, params):
+                """All slots as one batch over the pool where it lies: per
+                layer the new K and V ROW goes straight to page
+                ``length // page_size`` of its slot (a masked lane's to the
+                trash page) and attention reads the pages through the
+                table up to each slot's live length
+                (``models/transformer._attend_through_table``). Nothing is
+                gathered and nothing is cut back out."""
+                dest = ptabs[jnp.arange(ptabs.shape[0]), lengths // ps]
+                cache = {
+                    "layers": pool_layers,
+                    "len": lengths,
+                    "pages": ptabs,
+                    "write_page": jnp.where(active, dest, TRASH_PAGE),
+                    "attend": jnp.where(active, lengths + 1, 0),
+                }
+                cache, logits = decode_step(model, params, cache, tok[:, None])
+                return cache["layers"], logits
+
+            forward = (
+                table_forward if self.decode_path == "table"
+                else gather_forward
+            )
+
+            def step_fn(
+                pool_layers, params, ptabs, active, lengths, tok,
+                temp, top_k, top_p, seed, made, budget, eos,
+            ):
+                """Paged decode round. Identical control flow to the
+                monolithic variant; each micro-step runs the engine's one
+                ``forward`` over the pool (``decode_path``: through the
+                page table, or by gathering every slot's logical cache)."""
 
                 def micro(carry, _):
                     pool_layers, active, lengths, tok, made = carry
-                    pool_layers_ref[0] = pool_layers
-                    written, logits = jax.vmap(one)(ptabs, lengths, tok)
-                    wp = lengths // ps
-                    dest = ptabs[jnp.arange(ptabs.shape[0]), wp]
-                    dest = jnp.where(active, dest, TRASH_PAGE)
-                    with jax.named_scope("kv.scatter"):
-                        pool_layers = [
-                            {k: pl[k].at[dest].set(written[li][k])
-                             for k in pl}
-                            for li, pl in enumerate(pool_layers)
-                        ]
+                    pool_layers, logits = forward(
+                        pool_layers, ptabs, active, lengths, tok, params
+                    )
                     nxt = _pick(sampled, logits, seed, made,
                                 temp, top_k, top_p)
                     nxt = jnp.where(active, nxt, tok)
@@ -942,6 +992,42 @@ class SlotEngine:
         return PagedKVPool(
             cfg, self.slots, max_len, self.page_size, kv_pages
         )
+
+    def _decode_path(self) -> str:
+        """How the plain decode program (``step_fn``) reaches K and V,
+        fixed here once by what the engine can see of its own pool:
+        ``"table"`` — attention reads the pages where they lie, through the
+        page table, up to each slot's live length
+        (``ops.attention.paged_decode_attention``) — when the pool is paged,
+        its leaves are plain ``k`` / ``v`` rows (an int8 pool carries scale
+        leaves the kernel does not read) and the kernel takes their shape
+        (``ops.attention.paged_decode_fits``: a page a whole number of the
+        leaf dtype's sublane tiles, 16 rows of bf16 or 8 of f32, the head
+        size a whole number of lanes); ``"gather"`` — every slot's logical
+        cache is gathered from its table row (the monolithic pool reads
+        each slot's whole row where it lies) — everywhere else. The
+        prefill, chunk and verify programs gather on either path."""
+        if not self.paged:
+            return "gather"
+        leaves = self.pool.layers[0]
+        if set(leaves) == {"k", "v"} and paged_decode_fits(leaves["k"]):
+            return "table"
+        return "gather"
+
+    def _kv_rows_read(self, act) -> int:
+        """K and V positions per layer that one micro-step of the coming
+        decode round reads, from the host registers alone: a verify round
+        and the gather path read every slot's whole row, the table path
+        the live pages of the active slots (less those a window skips)."""
+        if not act.any():
+            return 0
+        if self.decode_path == "gather" or self._spec_round(act):
+            return self.slots * self.max_len
+        ps = self.page_size
+        n = self.lengths[act].astype(np.int64) + 1
+        window = getattr(self.cfg, "attention_window", None)
+        first = np.maximum(n - window, 0) // ps if window else 0
+        return int(((-(-n // ps) - first) * ps).sum())
 
     def _jit_program(self, fn, kind, donate):
         """Compile hook: the base engine jits on the default device; the
@@ -1371,6 +1457,7 @@ class SlotEngine:
                 active=int(act.sum()),
                 live_tokens=int(self.lengths[act].sum()),
                 chunks_run=self.stats["prefill_chunks"] - chunks0,
+                kv_rows_read=self._kv_rows_read(act),
             )
             if act.any():
                 toks, valid, done = self._decode_round()
@@ -1400,16 +1487,7 @@ class SlotEngine:
             # batch takes the sort-free fast path (and, when enabled, the
             # speculative one).
             any_sampled = bool((self.temp[was_active] > 0.0).any())
-            spec = bool(
-                self.spec_k
-                and not self._force_plain
-                # Verify writes the whole fed block above each slot's
-                # length (spec_k+1 linear, 1+B*spec_k tree); a slot within
-                # that of max_len would clamp the write — fall back to
-                # plain rounds for that (rare, end-of-window) round.
-                and (self.lengths[was_active] + self._spec_write
-                     <= self.max_len).all()
-            )
+            spec = self._spec_round(was_active)
             if spec:
                 out = self._spec_dispatch(any_sampled)
             else:
@@ -1436,6 +1514,18 @@ class SlotEngine:
         if spec:
             self._count_spec(was_active, accepted[0], any_sampled)
         return result
+
+    def _spec_round(self, act) -> bool:
+        """Whether the coming round over the ``act`` slots is a verify
+        round. Verify writes the whole fed block above each slot's length
+        (spec_k+1 linear, 1+B*spec_k tree); a slot within that of max_len
+        would clamp the write — fall back to plain rounds for that (rare,
+        end-of-window) round."""
+        return bool(
+            self.spec_k
+            and not self._force_plain
+            and (self.lengths[act] + self._spec_write <= self.max_len).all()
+        )
 
     def _spec_dispatch(self, any_sampled: bool = False):
         """Draft on the host and queue the verify program; returns its
@@ -2076,6 +2166,11 @@ class ShardedSlotEngine(SlotEngine):
             cfg, self.slots, max_len, self.page_size, kv_pages,
             kv_sharding=self._kv_shard,
         )
+
+    def _decode_path(self) -> str:
+        # GSPMD does not partition a Pallas call: reading the pages in
+        # place here would need shard_map over the kv heads.
+        return "gather"
 
     def _place_params(self, candidate):
         # Swap candidates stage through the SAME rule-table shardings as

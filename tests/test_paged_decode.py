@@ -1,0 +1,340 @@
+"""Decode through the page table: the kernel, and the engine path it serves.
+
+The contract is the paged-KV one (``test_paged_kv.py``): how K and V are
+reached may change how fast tokens arrive, never which. So the kernel
+(``ops.attention.paged_decode_attention``) is held against dense f32
+attention over the gathered rows, and an engine whose plain decode round
+reads the pages in place (``decode_path == "table"``) against the same
+engine made to gather (a subclass that overrides the ``_decode_path``
+hook, as ``ShardedSlotEngine`` does), on the churn matrices of
+``test_paged_kv.py`` at a head size and page size the table path takes.
+Shapes are small: off the TPU the kernel runs in interpret mode.
+"""
+
+from dataclasses import replace
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_tensorflow_tpu.models.transformer import (
+    TransformerConfig,
+    TransformerLM,
+)
+from distributed_tensorflow_tpu.obs import trace
+from distributed_tensorflow_tpu.ops.attention import paged_decode_attention
+from distributed_tensorflow_tpu.serve.engine import (
+    ShardedSlotEngine,
+    SlotEngine,
+)
+from distributed_tensorflow_tpu.serve.kv_pool import TRASH_PAGE
+from tests.test_paged_kv import _churn_requests, _drive
+
+pytestmark = [pytest.mark.serve, pytest.mark.paged]
+
+# dh 128: the head size the table path takes (and the benchmark's).
+CFG = TransformerConfig(
+    vocab_size=64,
+    d_model=256,
+    num_heads=2,
+    num_layers=2,
+    d_ff=64,
+    max_seq_len=48,
+    compute_dtype=jnp.float32,
+)
+
+
+class GatherEngine(SlotEngine):
+    """The same engine on the gather path, whatever its shapes."""
+
+    def _decode_path(self):
+        return "gather"
+
+
+@pytest.fixture(scope="module")
+def params():
+    return TransformerLM(CFG).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+
+
+# -- the kernel ------------------------------------------------------------
+
+
+def _dense(q, k_pages, v_pages, tables, lens, window):
+    """Dense f32 attention over each slot's gathered rows, masked as the
+    cached branch of ``attention_sublayer`` masks them."""
+    slots, kv, _, dh = q.shape
+    ps = k_pages.shape[2]
+    out = np.zeros(q.shape, np.float32)
+    for b in range(slots):
+        n = int(lens[b])
+        if not n:
+            continue
+        rows = lambda pages: np.concatenate(
+            [np.asarray(pages[p], np.float32) for p in tables[b]], axis=1
+        )  # (kv, pps * ps, dh)
+        k, v = rows(k_pages), rows(v_pages)
+        lo = max(0, n - window) if window else 0
+        for h in range(kv):
+            s = np.asarray(q[b, h], np.float32) @ k[h, lo:n].T / np.sqrt(dh)
+            p = np.exp(s - s.max(-1, keepdims=True))
+            out[b, h] = (p / p.sum(-1, keepdims=True)) @ v[h, lo:n]
+    return out
+
+
+def _pool(rng, dtype, kv, ps, pps, slots, dh=128):
+    pages = slots * pps + 3
+    k = jnp.asarray(rng.standard_normal((pages, kv, ps, dh)), dtype)
+    v = jnp.asarray(rng.standard_normal((pages, kv, ps, dh)), dtype)
+    # Out of order, and slot 1 shares slot 0's first two pages.
+    tables = rng.permutation(np.arange(1, pages))[: slots * pps]
+    tables = tables.reshape(slots, pps).astype(np.int32)
+    tables[1, :2] = tables[0, :2]
+    return k, v, tables
+
+
+@pytest.mark.parametrize("window", [None, 11], ids=["full", "window11"])
+@pytest.mark.parametrize("group", [1, 12])
+def test_kernel_matches_dense_over_ragged_lengths(group, window):
+    ps, pps, kv = 8, 6, 2
+    max_len = ps * pps
+    lens = np.array([1, ps - 1, ps, ps + 1, max_len - 1, 0, max_len],
+                    np.int32)
+    rng = np.random.default_rng(group)
+    k, v, tables = _pool(rng, jnp.float32, kv, ps, pps, lens.size)
+    q = jnp.asarray(rng.standard_normal((lens.size, kv, group, 128)),
+                    jnp.float32)
+    # Two pages a chunk: the longest slots take three chunks, so the copy
+    # chain crosses chunks and slots.
+    got = paged_decode_attention(
+        q, k, v, jnp.asarray(tables), jnp.asarray(lens), window=window,
+        pages_per_chunk=2,
+    )
+    want = _dense(q, k, v, tables, lens, window)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-6, rtol=2e-6)
+    assert not np.asarray(got)[5].any()  # lens 0: nothing read, zeros
+
+
+def test_kernel_bf16_pool_keeps_f32_probabilities():
+    """A bf16 pool at the benchmark's page size: the value product keeps
+    the probabilities' f32 precision, so what is left is the rounding of
+    the output itself."""
+    ps, pps, kv, group = 16, 4, 2, 12
+    lens = np.array([ps * pps, 3, 0, ps + 5], np.int32)
+    rng = np.random.default_rng(5)
+    k, v, tables = _pool(rng, jnp.bfloat16, kv, ps, pps, lens.size)
+    q = jnp.asarray(rng.standard_normal((lens.size, kv, group, 128)),
+                    jnp.bfloat16)
+    got = paged_decode_attention(
+        q, k, v, jnp.asarray(tables), jnp.asarray(lens)
+    )
+    assert got.dtype == jnp.bfloat16
+    want = _dense(q, k, v, tables, lens, None)
+    rounding = np.abs(
+        np.asarray(jnp.asarray(want, jnp.bfloat16), np.float32) - want
+    ).max()
+    err = np.abs(np.asarray(got, np.float32) - want).max()
+    assert err <= 2 * rounding + 1e-6, (err, rounding)
+
+
+def test_kernel_reads_only_the_live_pages():
+    """Every page the lengths do not reach is poisoned: a kernel that
+    copied a dead page of a row, or a page of no row, would read NaN."""
+    ps, pps, kv = 8, 6, 1
+    lens = np.array([ps + 1, 0, 3 * ps], np.int32)
+    rng = np.random.default_rng(3)
+    k, v, tables = _pool(rng, jnp.float32, kv, ps, pps, lens.size)
+    live = {int(p) for b, n in enumerate(lens)
+            for p in tables[b, : -(-int(n) // ps)]}
+    dead = np.array([p for p in range(k.shape[0]) if p not in live])
+    k, v = k.at[dead].set(jnp.nan), v.at[dead].set(jnp.nan)
+    q = jnp.asarray(rng.standard_normal((lens.size, kv, 4, 128)),
+                    jnp.float32)
+    got = np.asarray(paged_decode_attention(
+        q, k, v, jnp.asarray(tables), jnp.asarray(lens), pages_per_chunk=2
+    ))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(
+        got, _dense(q, np.nan_to_num(np.asarray(k)),
+                    np.nan_to_num(np.asarray(v)), tables, lens, None),
+        atol=2e-6, rtol=2e-6,
+    )
+
+
+def test_kernel_refuses_shapes_that_do_not_fit():
+    k = jnp.zeros((4, 2, 8, 128))
+    with pytest.raises(ValueError, match="does not fit pages"):
+        paged_decode_attention(jnp.zeros((2, 1, 4, 128)), k, k,
+                               jnp.zeros((2, 3), jnp.int32),
+                               jnp.zeros((2,), jnp.int32))
+    with pytest.raises(ValueError, match="slots"):
+        paged_decode_attention(jnp.zeros((2, 2, 4, 128)), k, k,
+                               jnp.zeros((3, 3), jnp.int32),
+                               jnp.zeros((2,), jnp.int32))
+
+
+# -- which engines take which path -----------------------------------------
+
+
+def _engine(cfg=CFG, cls=SlotEngine, **kw):
+    p = TransformerLM(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    kw = {"slots": 2, "max_len": 32, "prefill_len": 16, **kw}
+    return cls(cfg, p, **kw)
+
+
+_SMALL = replace(CFG, d_model=32, num_heads=4, max_seq_len=32)  # dh 8
+_BF16 = replace(CFG, compute_dtype=jnp.bfloat16, max_seq_len=32)
+
+
+@pytest.mark.parametrize("want,make", [
+    ("table", lambda: _engine(page_size=8)),
+    ("table", lambda: _engine(_BF16, page_size=16)),
+    ("table", lambda: _engine(page_size=8, spec_k=2)),
+    # The configurations no cell of the benchmark runs: all gather.
+    ("gather", lambda: _engine(page_size=0)),
+    ("gather", lambda: _engine(_BF16, page_size=8)),
+    ("gather", lambda: _engine(_SMALL, page_size=8)),
+    ("gather", lambda: _engine(replace(CFG, kv_cache_dtype="int8"),
+                               page_size=8)),
+    ("gather", lambda: _engine(replace(CFG, max_seq_len=32),
+                               cls=ShardedSlotEngine, tp=2, page_size=8)),
+], ids=["f32-page8", "bf16-page16", "spec-plain-step", "monolithic",
+        "bf16-page8", "head8", "int8-kv", "sharded"])
+def test_decode_path_is_fixed_by_what_the_engine_sees(want, make):
+    assert make().decode_path == want
+
+
+def test_kv_rows_read_counts_live_pages_on_the_table_path(params):
+    """``engine.round`` notes the positions a micro-step reads: the live
+    pages of the active slots here, every slot's whole row on the gather
+    path; and the count never costs a compile."""
+    import time
+
+    engine = SlotEngine(CFG, params, slots=3, max_len=48, prefill_len=24,
+                        page_size=8)
+    engine.warmup()
+    base = engine.compile_count()
+    for n in (9, 17):
+        engine.start(engine.acquire_slot(), list(range(1, n + 1)),
+                     max_new_tokens=4)
+    t0 = time.monotonic()
+    engine.step()
+    ((_, _, attrs),) = trace.closed("engine.round", t0, float("inf"))
+    assert attrs["active"] == 2 and attrs["live_tokens"] == 9 + 17
+    # Lengths 9 and 17 attend 10 and 18 positions: 2 and 3 pages of 8.
+    assert attrs["kv_rows_read"] == (2 + 3) * 8
+    assert engine.compile_count() == base
+
+
+def _registers(engine, lengths):
+    """Slots 0.. active at ``lengths``, set on the host registers alone:
+    ``_kv_rows_read`` reads nothing else, so no program is compiled."""
+    act = np.zeros(engine.slots, bool)
+    act[: len(lengths)] = True
+    engine.lengths[: len(lengths)] = lengths
+    return act
+
+
+@pytest.mark.parametrize("want,kw,cfg", [
+    # Gather path: every slot's whole row, however little is live.
+    (3 * 48, dict(cls=GatherEngine), CFG),
+    # A verify round gathers on a table-path engine too ...
+    (3 * 48, dict(spec_k=2), CFG),
+    # ... and a window skips the pages wholly below it: length 29 attends
+    # positions 20..29, pages 2 and 3; length 9 attends 0..9, pages 0 and 1.
+    (4 * 8, dict(), replace(CFG, attention_window=10)),
+], ids=["gather", "verify-round", "window"])
+def test_kv_rows_read_from_the_registers(want, kw, cfg):
+    engine = _engine(cfg, slots=3, max_len=48, prefill_len=24, page_size=8,
+                     **kw)
+    assert engine._kv_rows_read(_registers(engine, [29, 9])) == want
+    assert engine._kv_rows_read(np.zeros(3, bool)) == 0
+
+
+# -- the engine on the table path ------------------------------------------
+
+
+def test_inactive_lane_writes_only_the_trash_page(params):
+    engine = SlotEngine(CFG, params, slots=3, max_len=48, prefill_len=24,
+                        page_size=8, prefix_cache=False)
+    engine.warmup()
+    slot = engine.acquire_slot()
+    engine.start(slot, list(range(1, 12)), max_new_tokens=8)  # length 11
+    before = jax.device_get(engine.pool.layers)
+    engine.step()
+    after = jax.device_get(engine.pool.layers)
+    wrote = int(engine.pool.page_tables[slot, 11 // 8])
+    for b, a in zip(before, after):
+        for leaf in ("k", "v"):
+            changed = {int(p) for p in np.nonzero(
+                (b[leaf] != a[leaf]).any(axis=(1, 2, 3)))[0]}
+            assert wrote in changed
+            assert changed <= {wrote, TRASH_PAGE}
+            # The one new row, and nothing else of that page.
+            rows = (b[leaf][wrote] != a[leaf][wrote]).any(axis=(0, 2))
+            assert list(np.nonzero(rows)[0]) == [11 % 8]
+
+
+_LAYOUTS = {
+    "paged": dict(prefix_cache=False),
+    "paged+prefix": dict(prefix_cache=True),
+    "paged+prefix+spec": dict(prefix_cache=True, spec_k=4),
+    "paged+prefix+chunked": dict(prefix_cache=True, prefill_chunk_tokens=8),
+    "paged+steps2": dict(prefix_cache=False, steps_per_sync=2),
+}
+
+
+@pytest.fixture(scope="module")
+def gather_tokens(params):
+    """The churn requests through the SAME engine made to gather. Greedy
+    tokens do not depend on the layout (``test_paged_kv.py`` holds the
+    gather path to that), so one baseline serves every layout below."""
+    engine = GatherEngine(CFG, params, slots=4, max_len=48, prefill_len=26,
+                          page_size=8, prefix_cache=False)
+    assert engine.decode_path == "gather"
+    return _drive(engine, _churn_requests())
+
+
+@pytest.mark.parametrize("layout", list(_LAYOUTS))
+def test_table_path_serves_the_gather_paths_tokens(params, gather_tokens,
+                                                   layout):
+    """The churn matrix of ``test_paged_kv.py``: greedy tokens identical
+    between the two paths in every layout, zero recompiles in each
+    (``_drive`` asserts the compile count after ``warmup()``)."""
+    engine = SlotEngine(CFG, params, slots=4, max_len=48, prefill_len=26,
+                        page_size=8, **_LAYOUTS[layout])
+    assert engine.decode_path == "table"
+    got = _drive(engine, _churn_requests())
+    if engine.prefix is not None:
+        engine.prefix.clear()
+    assert engine.pool.pages_free == engine.pool.num_pages - 1
+    assert got == gather_tokens
+
+
+@pytest.mark.parametrize("variant", ["rope-gqa-window", "sampled"])
+def test_table_path_matches_the_monolithic_pool(variant):
+    """Per-slot rotation and a window inside the kernel against the
+    monolithic pool's whole-row attention; and the sampled twin of the
+    program against its own (same seeds, same draws)."""
+    cfg, extra = CFG, {}
+    if variant == "rope-gqa-window":
+        cfg = replace(CFG, position="rope", num_kv_heads=1,
+                      attention_window=12)
+    else:
+        extra = {"temperature": 0.8, "top_k": 8, "seed": 5}
+    p = TransformerLM(cfg).init(
+        jax.random.PRNGKey(1), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    requests = [(prompt, {**kw, **extra})
+                for prompt, kw in _churn_requests()[:6]]
+    got = {}
+    for page_size in (0, 8):
+        engine = SlotEngine(cfg, p, slots=3, max_len=48, prefill_len=26,
+                            page_size=page_size)
+        assert engine.decode_path == ("table" if page_size else "gather")
+        got[page_size] = _drive(engine, requests)
+    assert got[8] == got[0]

@@ -116,8 +116,10 @@ def test_one_round_closes_each_span_once_nested_at_most_twelve(params):
                                     "chunks": 3}
     assert r["engine.prefill_chunk"][2] == {"offset": 0, "width": 8,
                                             "final": False}
+    # dh 8 is no head size the table path takes: the gather path reads
+    # every slot's whole row, 2 slots of 48.
     assert r["engine.round"][2] == {"active": 1, "live_tokens": 6,
-                                    "chunks_run": 1}
+                                    "chunks_run": 1, "kv_rows_read": 2 * 48}
     assert r["sched.deliver"][2] == {"produced": 1}
     assert r["engine.dispatch"][2] is None
 
