@@ -38,6 +38,11 @@ def default_compute_dtype():
     return jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
 
 
+class EvaUnsupported(ValueError):
+    """A feature that is not extended to an EVA config (``eva_window`` set):
+    raised where the feature is asked for, never a silent fallback."""
+
+
 @dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 256
@@ -106,6 +111,33 @@ class TransformerConfig:
     # fewer weight bytes is the direct tok/s lever.
     weight_dtype: str | None = None  # None | 'int8' | 'int4'
     quant_group_size: int = 0  # int4 only; 0 elsewhere
+    # Per-config block kinds, one Block definition. ``norm``: 'layer'
+    # (LayerNorm, scale and bias, flax's epsilon) or 'rms' (RMSNorm in f32
+    # with ``norm_eps``; with ``norm_unit_offset`` the learned vector g
+    # enters as 1 + g).
+    # ``mlp``: 'gelu' (mlp_in -> GELU -> mlp_out) or 'swiglu'
+    # (silu(mlp_gate h) * mlp_up h -> mlp_out). ``residual_dtype``
+    # 'float32' keeps the residual stream in f32 while the sublayers compute
+    # in ``compute_dtype`` (None: the stream is in ``compute_dtype``).
+    # ``fp32_logits`` runs the output head in f32. ``num_pred_heads`` widens
+    # the head to that many vocabularies side by side (multi-token
+    # prediction heads); head 0 is the next-token head every caller gets.
+    norm: str = "layer"  # 'layer' | 'rms'
+    norm_eps: float = 1e-6
+    norm_unit_offset: bool = False
+    mlp: str = "gelu"  # 'gelu' | 'swiglu'
+    residual_dtype: str | None = None  # None | 'float32'
+    fp32_logits: bool = False
+    num_pred_heads: int = 1
+    # EVA attention (Zheng et al., arXiv:2302.04542, as EvaByte runs it),
+    # on when both are set: positions fall into windows of ``eva_window``;
+    # a query attends the exact K/V rows of its own window (causally) and,
+    # for every whole chunk of ``eva_chunk`` positions in an EARLIER window,
+    # one learned softmax-pooled summary row, all in one softmax
+    # (:func:`eva_attention_sublayer`). The serving cache is then two kinds
+    # of page, see ``serve/kv_pool.py``.
+    eva_window: int | None = None
+    eva_chunk: int | None = None
 
     def __post_init__(self):
         # Every string-enum field that SELECTS behavior is validated here:
@@ -120,6 +152,43 @@ class TransformerConfig:
             raise ValueError(
                 f"kv_cache_dtype must be None or 'int8', got {self.kv_cache_dtype!r}"
             )
+        if self.norm not in ("layer", "rms"):
+            raise ValueError(
+                f"norm must be 'layer' or 'rms', got {self.norm!r}")
+        if self.norm_unit_offset and self.norm != "rms":
+            raise ValueError("norm_unit_offset needs norm='rms'")
+        if self.mlp not in ("gelu", "swiglu"):
+            raise ValueError(
+                f"mlp must be 'gelu' or 'swiglu', got {self.mlp!r}")
+        if self.residual_dtype not in (None, "float32"):
+            raise ValueError(
+                f"residual_dtype must be None or 'float32', got "
+                f"{self.residual_dtype!r}")
+        if self.num_pred_heads < 1:
+            raise ValueError(
+                f"num_pred_heads must be >= 1, got {self.num_pred_heads}")
+        if (self.eva_window is None) != (self.eva_chunk is None):
+            raise ValueError("eva_window and eva_chunk go together")
+        if self.eva_window is not None:
+            w, c = int(self.eva_window), int(self.eva_chunk)
+            if c < 1 or w < c or w % c:
+                raise ValueError(
+                    f"eva_window {w} must be a whole number of eva_chunk {c}")
+            if self.attention_window is not None:
+                raise EvaUnsupported(
+                    "EVA attention has its own windows: attention_window "
+                    "must be None")
+            if self.kv_heads != self.num_heads:
+                raise EvaUnsupported(
+                    "EVA attention pools per head: no grouped kv heads")
+            if self.kv_cache_dtype is not None:
+                raise EvaUnsupported(
+                    "kv_cache_dtype='int8' is not extended to EVA's summary "
+                    "rows (they would need scale planes of their own)")
+            if self.weight_dtype is not None:
+                raise EvaUnsupported(
+                    "weight-only quantisation is not extended to an EVA "
+                    "config")
         if self.weight_dtype is not None or self.quant_group_size:
             # Lazy import: quant.py is standalone (flax/jax only), but the
             # module-level import order models/__init__ establishes should
@@ -136,6 +205,10 @@ class TransformerConfig:
     @property
     def kv_heads(self) -> int:
         return self.num_heads if self.num_kv_heads is None else self.num_kv_heads
+
+    @property
+    def eva(self) -> bool:
+        return self.eva_window is not None
 
 
 def quantize_kv_rows(x):
@@ -169,6 +242,32 @@ def matmul_dense(cfg: TransformerConfig, features: int, name: str):
     return nn.Dense(
         features, dtype=cfg.compute_dtype, name=name, use_bias=cfg.use_bias
     )
+
+
+class RMSNorm(nn.Module):
+    """x / sqrt(mean(x^2) + eps) * w in float32, cast to ``dtype``; with
+    ``unit_offset`` the learned vector g (``scale``) enters as w = 1 + g."""
+
+    eps: float = 1e-6
+    unit_offset: bool = False
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        init = nn.initializers.zeros if self.unit_offset else nn.initializers.ones
+        g = self.param("scale", init, (x.shape[-1],)).astype(jnp.float32)
+        xf = x.astype(jnp.float32)
+        y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + self.eps)
+        return (y * (1.0 + g if self.unit_offset else g)).astype(self.dtype)
+
+
+def block_norm(cfg, name: str):
+    """The norm layer ``cfg.norm`` names, declared under ``name`` on the
+    calling ``@nn.compact`` module."""
+    if getattr(cfg, "norm", "layer") == "rms":
+        return RMSNorm(eps=cfg.norm_eps, unit_offset=cfg.norm_unit_offset,
+                       dtype=cfg.compute_dtype, name=name)
+    return nn.LayerNorm(dtype=cfg.compute_dtype, name=name)
 
 
 def _attention_fn(cfg: TransformerConfig, prefer_packed: bool = False) -> Callable:
@@ -255,7 +354,11 @@ def attention_sublayer(cfg, x, attend, train: bool = False, cache=None,
     through its page tables (:func:`_attend_through_table`): one new token
     per slot, each slot at its own length. It shares nothing with the dense
     cached branch below."""
-    h = nn.LayerNorm(dtype=cfg.compute_dtype, name="ln1")(x)
+    if getattr(cfg, "eva", False):
+        raise EvaUnsupported(
+            "an EVA config attends through eva_attention_sublayer (Block); "
+            "this block kind has no EVA path")
+    h = block_norm(cfg, "ln1")(x)
     b, s, _ = h.shape
     dh = cfg.d_model // cfg.num_heads
     kv = cfg.kv_heads
@@ -519,6 +622,202 @@ def _attend_through_table(cfg, cache, q4, k4, v4):
     return attn.reshape(b, 1, heads * dh), dict(cache, k=ks, v=vs)
 
 
+def eva_summaries(k, v, phi, mu):
+    """EVA's chunk summaries. ``k`` / ``v`` (..., H, C, dh): the (rotated)
+    key and value rows of one chunk per leading index; ``phi`` / ``mu`` (H,
+    dh). With alpha = softmax over the chunk's C rows of dh^-1/2 * k.phi:
+    k~ = sum alpha k + mu and v~ = sum alpha v, each (..., H, dh) in f32."""
+    kf = k.astype(jnp.float32)
+    scores = jnp.einsum(
+        "...hcd,hd->...hc", kf, phi.astype(jnp.float32)
+    ) / np.sqrt(k.shape[-1])
+    alpha = jax.nn.softmax(scores, -1)
+    ks = jnp.einsum("...hc,...hcd->...hd", alpha, kf) + mu.astype(jnp.float32)
+    vs = jnp.einsum("...hc,...hcd->...hd", alpha, v.astype(jnp.float32))
+    return ks, vs
+
+
+def _eva_dense(cfg, qh, kh, vh, phi, mu):
+    """EVA attention of a whole sequence from position 0, no cache: ``qh`` /
+    ``kh`` / ``vh`` (B, H, T, dh), rotated. Query i attends the rows j <= i
+    of its own window and the summary of every whole chunk that lies in an
+    earlier window, in one softmax. O(T^2) scores: the training and test
+    path; serving composes the same rows from pages."""
+    b, heads, t, dh = qh.shape
+    w, c = int(cfg.eva_window), int(cfg.eva_chunk)
+    n = t // c
+    pos = jnp.arange(t)
+    own = (pos[None, :] <= pos[:, None]) & (
+        pos[None, :] // w == pos[:, None] // w)
+    scores = jnp.einsum(
+        "bhqd,bhTd->bhqT", qh, kh, preferred_element_type=jnp.float32
+    ) / np.sqrt(dh)
+    scores = jnp.where(own[None, None], scores, A.NEG_INF)
+    vals = vh.astype(jnp.float32)
+    if n:
+        ks, vs = eva_summaries(
+            kh[:, :, : n * c].reshape(b, heads, n, c, dh).transpose(0, 2, 1, 3, 4),
+            vh[:, :, : n * c].reshape(b, heads, n, c, dh).transpose(0, 2, 1, 3, 4),
+            phi, mu,
+        )  # (B, n, H, dh)
+        ks = ks.transpose(0, 2, 1, 3).astype(kh.dtype)
+        vs = vs.transpose(0, 2, 1, 3).astype(vh.dtype)
+        earlier = (jnp.arange(n)[None, :] * c) // w < pos[:, None] // w
+        s_sum = jnp.einsum(
+            "bhqd,bhnd->bhqn", qh, ks, preferred_element_type=jnp.float32
+        ) / np.sqrt(dh)
+        scores = jnp.concatenate(
+            [jnp.where(earlier[None, None], s_sum, A.NEG_INF), scores], -1)
+        vals = jnp.concatenate([vs.astype(jnp.float32), vals], 2)
+    weights = jax.nn.softmax(scores, -1)
+    return jnp.einsum("bhqT,bhTd->bhqd", weights, vals).astype(cfg.compute_dtype)
+
+
+def _eva_through_table(cfg, cache, q4, k4, v4, phi, mu):
+    """The EVA decode round on the page pool. ``cache`` is as in
+    :func:`_attend_through_table`, where a slot's table row is COMPOSED
+    [summary pages of its finished windows | pages of its current window]
+    (``serve/kv_pool.py``), so ``attend`` counts summaries and window rows
+    alike and ``write_page`` is the current window's page under ``len``;
+    and ``sum_page``: the physical page the summary of the chunk that this
+    token completes goes to (the slot's forming summary page; the trash page
+    when the token completes no chunk). A chunk is one page: after the new
+    row is written the page is read back, pooled (:func:`eva_summaries`) and
+    its summary row written at ``(len % window) // chunk`` modulo the page.
+    Attention is ``paged_decode_attention`` over the composed table where
+    the pool's leaves fit it, and the same sum in ``jax.numpy`` where they
+    do not (a page or head size off the chip's tiles: the CPU tests)."""
+    b, s, heads, dh = q4.shape
+    if s != 1:
+        raise ValueError(f"a paged cache takes one token per slot, got {s}")
+    pages, kv, ps, _ = cache["k"].shape
+    w, c = int(cfg.eva_window), int(cfg.eva_chunk)
+    if c != ps:
+        raise EvaUnsupported(f"eva_chunk {c} must be the page size {ps}")
+    head_rows = jnp.arange(kv)[None, :]
+
+    def write(leaf, page, offset, new):
+        rows = ((page[:, None] * kv + head_rows) * ps + offset[:, None])
+        flat = leaf.reshape(pages * kv * ps, dh)
+        return flat.at[rows.reshape(-1)].set(
+            new.reshape(b * kv, dh).astype(leaf.dtype)).reshape(leaf.shape)
+
+    offset = cache["len"] % ps
+    ks = write(cache["k"], cache["write_page"], offset, k4)
+    vs = write(cache["v"], cache["write_page"], offset, v4)
+    with jax.named_scope("eva.summary"):
+        sk, sv = eva_summaries(
+            ks[cache["write_page"]], vs[cache["write_page"]], phi, mu)
+        row = ((cache["len"] % w) // c) % ps
+        ks = write(ks, cache["sum_page"], row, sk)
+        vs = write(vs, cache["sum_page"], row, sv)
+    q = q4[:, 0].reshape(b, kv, 1, dh)
+    if A.paged_decode_fits(ks):
+        # 32 kv heads make a page 16 times StarCoder2's: hold the kernel's
+        # four chunk buffers (K and V, double) to 8 MiB of VMEM.
+        page_bytes = kv * ps * dh * ks.dtype.itemsize
+        attn = A.paged_decode_attention(
+            q, ks, vs, cache["pages"], cache["attend"],
+            pages_per_chunk=max(1, min(32, (2 << 20) // page_bytes)),
+        )
+    else:
+        tables = cache["pages"]
+        rows_k = ks[tables].transpose(0, 2, 1, 3, 4).reshape(b, kv, -1, dh)
+        rows_v = vs[tables].transpose(0, 2, 1, 3, 4).reshape(b, kv, -1, dh)
+        live = jnp.arange(rows_k.shape[2])[None, :] < cache["attend"][:, None]
+        scores = jnp.einsum(
+            "bkgd,bkTd->bkgT", q, rows_k, preferred_element_type=jnp.float32
+        ) / np.sqrt(dh)
+        live = live[:, None, None, :]
+        weights = jnp.where(
+            live, jax.nn.softmax(jnp.where(live, scores, A.NEG_INF), -1), 0.0)
+        attn = jnp.einsum(
+            "bkgT,bkTd->bkgd", weights, rows_v.astype(jnp.float32)
+        ).astype(q.dtype)
+    return attn.reshape(b, 1, heads * dh), dict(cache, k=ks, v=vs)
+
+
+def eva_attention_sublayer(mod, cfg, x, train: bool = False, cache=None,
+                           positions=None):
+    """Pre-norm EVA self-attention + residual, beside
+    :func:`attention_sublayer`; called from ``mod``'s ``@nn.compact`` body
+    (layers ``ln1`` / ``qkv`` / ``proj`` and the per-head vectors
+    ``eva_phi`` / ``eva_mu`` are declared on ``mod``). Three paths:
+
+    * ``cache=None``: the whole sequence from position 0 (:func:`_eva_dense`).
+    * a cache with ``pages``: the serving engine's decode round through its
+      composed page table (:func:`_eva_through_table`).
+    * a dense cache ``{'k','v','len','win_base'}``: one prefill segment of the
+      serving engine. The cache is a slot's LOGICAL rows, gathered from its
+      composed table: summaries of finished windows, then the current
+      window's rows, ``len`` of them filled; the segment lies inside the
+      current window, so it appends at ``len`` and attends causally over
+      logical rows, which is EVA's set exactly. ``positions`` (absolute, for
+      the rotation) must be given: logical offsets are not positions. The
+      returned layer carries ``sum_k`` / ``sum_v`` (B, H, window/chunk, dh):
+      the summary of every chunk of the current window's region
+      (``win_base`` onward) after the append, for the engine to write those
+      of whole chunks into the slot's forming summary pages."""
+    h = block_norm(cfg, "ln1")(x)
+    b, s, _ = h.shape
+    heads = cfg.num_heads
+    dh = cfg.d_model // heads
+    qkv = matmul_dense(cfg, 3 * cfg.d_model, "qkv")(h)
+    vec_init = nn.initializers.normal(dh ** -0.5)
+    phi = mod.param("eva_phi", vec_init, (heads, dh))
+    mu = mod.param("eva_mu", vec_init, (heads, dh))
+    if cache is not None and "pages" not in cache and positions is None:
+        raise EvaUnsupported(
+            "a monolithic cache does not compose EVA's summaries and "
+            "windows: serve an EVA config through SlotEngine's page pool")
+    q, k, v = jnp.split(qkv, 3, axis=-1)
+    q4 = q.reshape(b, s, heads, dh)
+    k4 = k.reshape(b, s, heads, dh)
+    v4 = v.reshape(b, s, heads, dh)
+    if getattr(cfg, "position", "learned") == "rope":
+        cos, sin = rope_tables(dh, s, cfg.rope_theta, positions=positions)
+        q4 = apply_rope(q4, cos, sin)
+        k4 = apply_rope(k4, cos, sin)
+    to_heads = lambda t4: t4.transpose(0, 2, 1, 3)
+    if cache is None:
+        attn = to_heads(_eva_dense(
+            cfg, to_heads(q4), to_heads(k4), to_heads(v4), phi, mu))
+        attn = attn.reshape(b, s, cfg.d_model)
+    elif "pages" in cache:
+        attn, cache = _eva_through_table(cfg, cache, q4, k4, v4, phi, mu)
+    else:
+        w, c = int(cfg.eva_window), int(cfg.eva_chunk)
+        ks = jax.lax.dynamic_update_slice(
+            cache["k"], to_heads(k4), (0, 0, cache["len"], 0))
+        vs = jax.lax.dynamic_update_slice(
+            cache["v"], to_heads(v4), (0, 0, cache["len"], 0))
+        scores = jnp.einsum(
+            "bhqd,bhTd->bhqT", to_heads(q4), ks,
+            preferred_element_type=jnp.float32,
+        ) / np.sqrt(dh)
+        q_pos = cache["len"] + jnp.arange(s)
+        allowed = jnp.arange(ks.shape[2])[None, :] <= q_pos[:, None]
+        weights = jax.nn.softmax(
+            jnp.where(allowed[None, None], scores, A.NEG_INF), -1)
+        attn = jnp.einsum(
+            "bhqT,bhTd->bhqd", weights, vs.astype(jnp.float32)
+        ).astype(cfg.compute_dtype)
+        attn = to_heads(attn).reshape(b, s, cfg.d_model)
+        with jax.named_scope("eva.summary"):
+            region = lambda t: jax.lax.dynamic_slice(
+                t, (0, 0, cache["win_base"], 0), (b, heads, w, dh)
+            ).reshape(b, heads, w // c, c, dh).transpose(0, 2, 1, 3, 4)
+            sk, sv = eva_summaries(region(ks), region(vs), phi, mu)
+        cache = {"k": ks, "v": vs,
+                 "sum_k": sk.transpose(0, 2, 1, 3).astype(ks.dtype),
+                 "sum_v": sv.transpose(0, 2, 1, 3).astype(vs.dtype),
+                 "len": cache["len"] + s}
+    attn = matmul_dense(cfg, cfg.d_model, "proj")(attn)
+    if cfg.dropout_rate:
+        attn = nn.Dropout(cfg.dropout_rate, deterministic=not train)(attn)
+    return x + attn, cache
+
+
 def _phase_scope(cached: bool):
     """``jax.named_scope`` on the cached branch (the serving engine's
     programs: op metadata only, the program is unchanged), nothing on the
@@ -544,15 +843,28 @@ class Block(nn.Module):
         # the training path's programs stay as they were.
         scope = _phase_scope(cache is not None)
         with scope("attn"):
-            x, cache = attention_sublayer(
-                cfg, x, attend, train=train, cache=cache,
-                positions=positions, self_mask=self_mask,
-            )
+            if cfg.eva:
+                if self_mask is not None:
+                    raise EvaUnsupported(
+                        "tree attention (self_mask) is not extended to EVA")
+                x, cache = eva_attention_sublayer(
+                    self, cfg, x, train=train, cache=cache,
+                    positions=positions,
+                )
+            else:
+                x, cache = attention_sublayer(
+                    cfg, x, attend, train=train, cache=cache,
+                    positions=positions, self_mask=self_mask,
+                )
 
         with scope("mlp"):
-            h = nn.LayerNorm(dtype=cfg.compute_dtype, name="ln2")(x)
-            h = matmul_dense(cfg, cfg.d_ff, "mlp_in")(h)
-            h = nn.gelu(h)
+            h = block_norm(cfg, "ln2")(x)
+            if cfg.mlp == "swiglu":
+                h = nn.silu(matmul_dense(cfg, cfg.d_ff, "mlp_gate")(h)) * (
+                    matmul_dense(cfg, cfg.d_ff, "mlp_up")(h))
+            else:
+                h = matmul_dense(cfg, cfg.d_ff, "mlp_in")(h)
+                h = nn.gelu(h)
             h = matmul_dense(cfg, cfg.d_model, "mlp_out")(h)
             if cfg.dropout_rate:
                 h = nn.Dropout(cfg.dropout_rate, deterministic=not train)(h)
@@ -571,7 +883,9 @@ class TransformerLM(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, positions=None, train: bool = False, cache=None,
-                 self_mask=None):
+                 self_mask=None, pred_heads: bool = False):
+        """``pred_heads`` returns every prediction head's logits, (B, S,
+        num_pred_heads, vocab), in place of head 0's (B, S, vocab)."""
         cfg = self.cfg
         b, s = tokens.shape
         if cache is not None and positions is None and "pages" in cache:
@@ -581,6 +895,8 @@ class TransformerLM(nn.Module):
         x = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.compute_dtype, name="tok_embed")(
             tokens
         )
+        if cfg.residual_dtype is not None:
+            x = x.astype(cfg.residual_dtype)
         if cfg.position == "rope":
             # No position table at all: positions enter as the q/k rotation
             # inside every attention sublayer (ops/rope.py). The blocks
@@ -638,12 +954,18 @@ class TransformerLM(nn.Module):
                 )
             cache = dict(shared, layers=new_layers, len=cache["len"] + s)
         with _phase_scope(cache is not None)("lm_head"):
-            x = nn.LayerNorm(dtype=cfg.compute_dtype, name="ln_f")(x)
+            x = block_norm(cfg, "ln_f")(x)
             logits = nn.Dense(
-                cfg.vocab_size, dtype=cfg.compute_dtype, name="lm_head",
-                use_bias=cfg.use_bias,
+                cfg.vocab_size * cfg.num_pred_heads,
+                dtype=jnp.float32 if cfg.fp32_logits else cfg.compute_dtype,
+                name="lm_head", use_bias=cfg.use_bias,
             )(x)
             logits = logits.astype(jnp.float32)
+            if pred_heads:
+                logits = logits.reshape(
+                    b, s, cfg.num_pred_heads, cfg.vocab_size)
+            elif cfg.num_pred_heads > 1:
+                logits = logits[..., : cfg.vocab_size]  # head 0 is served
         return logits if cache is None else (logits, cache)
 
 

@@ -120,6 +120,24 @@ sit strictly above the filled length until the step that overwrites them.
 paged/spec parity matrix lives in ``tests/test_paged_kv.py``, the
 chunked-prefill parity matrix in ``tests/test_serve_chunked.py``.
 
+An EVA config (``cfg.eva_window`` set; ``models/transformer.
+eva_attention_sublayer``) runs the same engine on the pool's composed rows
+(``serve/kv_pool.py``: summary pages of a slot's finished windows, then its
+current window's pages, then its forming summary pages): decode always goes
+through the table (``eva_table_forward``; ``attend`` counts summaries and
+window rows, the program also pools the page a token fills into the forming
+page); prefill is planned as SEGMENTS of at most a chunk that never cross a
+window's end, each through ``eva_prefill_fn`` (the gathered logical rows,
+absolute positions for the rotation, summaries of the window's whole chunks
+re-formed into the forming pages); between two rounds, or two segments, a
+slot that has filled a window is ROLLED on the host (``_roll_window``, span
+``engine.window_roll``). ``stats`` counts ``eva_windows_rolled``,
+``eva_window_pages_released`` and ``eva_summary_pages_adopted``, and a
+round's record carries ``summary_rows_read`` / ``window_rows_read``. Slot
+export/import, speculation, ``steps_per_sync > 1``, chunking off, the
+monolithic pool and the sharded engine refuse such a config
+(``EvaUnsupported``).
+
 Tracing (``obs/trace.py``; always on, no switch): the host side of a round
 closes ``engine.round`` around ``engine.prefill_chunk`` (one per chunk
 spent), ``engine.dispatch`` (entry of the decode round to the jitted call's
@@ -166,7 +184,10 @@ from distributed_tensorflow_tpu.models.decoding import (
     sample_logits_batched,
     tree_rejection_verify_row,
 )
-from distributed_tensorflow_tpu.models.transformer import TransformerLM
+from distributed_tensorflow_tpu.models.transformer import (
+    EvaUnsupported,
+    TransformerLM,
+)
 from distributed_tensorflow_tpu.obs import trace as _trace
 from distributed_tensorflow_tpu.ops.attention import paged_decode_fits
 from distributed_tensorflow_tpu.serve.kv_pool import (
@@ -257,6 +278,29 @@ class SlotEngine:
                     f"exceeds max_len - 1 ({max_len - 1}); shrink "
                     "spec_branches/spec_k"
                 )
+        self._eva = bool(getattr(cfg, "eva", False))
+        if self._eva:
+            # What is not extended to the composed table refuses here, by
+            # name, rather than serving something else.
+            if not page_size:
+                raise EvaUnsupported("an EVA config needs the paged KV layout")
+            if spec_k:
+                raise EvaUnsupported(
+                    "speculation (spec_k > 0, tree or linear) is not "
+                    "extended to EVA: a rejected draft would have to roll "
+                    "back summaries and window rolls")
+            if steps_per_sync != 1:
+                raise EvaUnsupported(
+                    "an EVA config needs steps_per_sync == 1: a window "
+                    "rolls on the host between rounds")
+            if getattr(self, "tp", 1) > 1:
+                raise EvaUnsupported("ShardedSlotEngine has no EVA path")
+            c = int(prefill_chunk_tokens) or prefill_len
+            if c < 0 or int(cfg.eva_window) % c:
+                raise EvaUnsupported(
+                    f"an EVA config needs chunked prefill with a chunk "
+                    f"({c}) that divides eva_window {cfg.eva_window}: a "
+                    f"prefill segment never straddles a window")
         self.cfg = cfg
         # Place params through the same path swap candidates stage through
         # (``_place_params``): a checkpoint bundle arrives as host numpy,
@@ -418,7 +462,13 @@ class SlotEngine:
             "plain_rounds": 0,
             "prefill_chunks": 0,
             "prefill_tokens_last_iter": 0,
+            "eva_windows_rolled": 0,
+            "eva_summary_pages_adopted": 0,
+            "eva_window_pages_released": 0,
         }
+        # EVA: positions each slot's request ends at (prompt + budget),
+        # which sizes the pages a window roll binds.
+        self._eva_total = np.zeros(n, np.int64)
         # Per-slot accepted-draft counts, one sample per (slot, verify
         # round) — loadgen/metrics read accepted-per-verify p50/p99 off
         # this bounded window.
@@ -502,6 +552,66 @@ class SlotEngine:
                 return new_pool, first
 
             return prefill_fn
+
+        def make_eva_prefill(sampled: bool):
+            w, spw = self.pool.window, self.pool.sum_pages
+            n_sum = spw * ps  # summaries of one window
+
+            def eva_prefill_fn(
+                pool_layers, params, tokens, n_real, abs_start, row,
+                temp, top_k, top_p, seed,
+            ):
+                """One prefill segment of an EVA slot: ``n_real`` tokens
+                (padded to the bucket) at absolute position ``abs_start``,
+                all inside one window. The slot's LOGICAL rows are gathered
+                from its composed row (summaries of finished windows, then
+                the window so far; a bucket's worth of trash entries behind
+                it takes the padding's junk rows), the segment appends
+                behind them and attends causally over logical rows
+                (``eva_attention_sublayer``), every page of the row is
+                scattered back, and the summaries of the window's whole
+                chunks so far go to the forming pages (the row's last
+                entries): recomputed from the rows, so that chunks adopted
+                with a prefix are covered too."""
+                width = tokens.shape[1]
+                table = jnp.concatenate([
+                    row[:pps],
+                    jnp.full((-(-width // ps),), TRASH_PAGE, row.dtype),
+                ])
+                win_base = (abs_start // w) * n_sum
+                cache = gather_cache(
+                    pool_layers, table, win_base + abs_start % w)
+                cache["win_base"] = win_base
+                positions = abs_start + jnp.arange(width, dtype=jnp.int32)
+                logits, cache = model.apply(
+                    {"params": params}, tokens, cache=cache,
+                    positions=positions[None],
+                )
+                last = jnp.take(logits[0], n_real - 1, axis=0)
+                first = _select(sampled, last, temp, top_k, top_p, seed)
+                ci = jnp.arange(n_sum)
+                whole = ci < (abs_start % w + n_real) // ps
+                page = jnp.where(whole, row[pps + ci // ps], TRASH_PAGE)
+                with jax.named_scope("kv.scatter"):
+                    new_pool = []
+                    for pl, cl in zip(pool_layers, cache["layers"]):
+                        layer = {}
+                        for name in pl:
+                            logical = cl[name][0]  # (kv, rows, dh)
+                            kv, dh = logical.shape[0], logical.shape[-1]
+                            leaf = pl[name].at[table].set(jnp.swapaxes(
+                                logical.reshape(kv, -1, ps, dh), 0, 1))
+                            rows = (
+                                (page[:, None] * kv + jnp.arange(kv)[None, :])
+                                * ps + (ci % ps)[:, None]
+                            ).reshape(-1)
+                            sums = jnp.swapaxes(cl["sum_" + name][0], 0, 1)
+                            layer[name] = leaf.reshape(-1, dh).at[rows].set(
+                                sums.reshape(-1, dh)).reshape(leaf.shape)
+                        new_pool.append(layer)
+                return new_pool, first
+
+            return eva_prefill_fn
 
         def _select(sampled, last, temp, top_k, top_p, seed):
             with jax.named_scope("sample"):
@@ -628,8 +738,36 @@ class SlotEngine:
                 cache, logits = decode_step(model, params, cache, tok[:, None])
                 return cache["layers"], logits
 
+            def eva_table_forward(pool_layers, ptabs, active, lengths, tok,
+                                  params):
+                """``table_forward`` on EVA's composed rows
+                (``serve/kv_pool.py``): the new row goes to the current
+                window's page under ``length``, which lies behind the
+                summary pages of the slot's finished windows; a slot
+                attends those summaries and its window's rows up to itself;
+                and a token that fills a chunk sends the chunk's summary to
+                the slot's forming page (the row's last entries)."""
+                w, spw = self.pool.window, self.pool.sum_pages
+                lanes = jnp.arange(ptabs.shape[0])
+                done, off = lengths // w, lengths % w
+                dest = ptabs[lanes, done * spw + off // ps]
+                form = ptabs[lanes, pps + off // ps // ps]
+                fills = active & ((lengths + 1) % ps == 0)
+                cache = {
+                    "layers": pool_layers,
+                    "len": lengths,
+                    "pages": ptabs[:, :pps],
+                    "write_page": jnp.where(active, dest, TRASH_PAGE),
+                    "attend": jnp.where(
+                        active, done * (spw * ps) + off + 1, 0),
+                    "sum_page": jnp.where(fills, form, TRASH_PAGE),
+                }
+                cache, logits = decode_step(model, params, cache, tok[:, None])
+                return cache["layers"], logits
+
             forward = (
-                table_forward if self.decode_path == "table"
+                eva_table_forward if self._eva
+                else table_forward if self.decode_path == "table"
                 else gather_forward
             )
 
@@ -936,11 +1074,12 @@ class SlotEngine:
         # rounds when spec_k > 0. Still a fixed set: warmup compiles every
         # member, and the compile-count assert covers the lot.
         donate = (0,) if self.paged else ()
+        prefill_of = make_eva_prefill if self._eva else make_prefill
         self._prefill_greedy = self._jit_program(
-            make_prefill(False), "prefill", donate
+            prefill_of(False), "prefill", donate
         )
         self._prefill_sampled = self._jit_program(
-            make_prefill(True), "prefill", donate
+            prefill_of(True), "prefill", donate
         )
         step_donate = (0,) if self.paged else (1,)
         self._step_greedy = self._jit_program(
@@ -1010,20 +1149,39 @@ class SlotEngine:
         if not self.paged:
             return "gather"
         leaves = self.pool.layers[0]
+        if self._eva:
+            # Always through the table: the composed row IS the cache.
+            # Where the kernel does not take the leaves the sublayer sums
+            # the same rows in jax.numpy (models/transformer.py).
+            return "table"
         if set(leaves) == {"k", "v"} and paged_decode_fits(leaves["k"]):
             return "table"
         return "gather"
 
     def _kv_rows_read(self, act) -> int:
         """K and V positions per layer that one micro-step of the coming
-        decode round reads, from the host registers alone: a verify round
-        and the gather path read every slot's whole row, the table path
-        the live pages of the active slots (less those a window skips)."""
+        decode round reads, from the host registers alone. It counts for
+        three layouts: a verify round and the gather path (monolithic
+        pool, int8 pages, the sharded engine) read every slot's whole row;
+        the table path over the plain layout reads the live pages of the
+        active slots, less those a sliding window skips; the table path
+        over EVA's composed rows reads the pages that hold each active
+        slot's summaries and its current window up to its token (a row is
+        a row to the kernel: ``summary_rows_read`` and ``window_rows_read``
+        on the same span split them). ``kv.decode_read_amplification``
+        divides this by the round's live tokens, which means what it says
+        on the plain layout only: an EVA slot's live tokens are not its
+        rows."""
         if not act.any():
             return 0
         if self.decode_path == "gather" or self._spec_round(act):
             return self.slots * self.max_len
         ps = self.page_size
+        if self._eva:
+            # The composed row: summaries and window rows are one run of
+            # pages to the kernel.
+            sums, rows = self._eva_rows(act)
+            return int((-(-(sums + rows) // ps) * ps).sum())
         n = self.lengths[act].astype(np.int64) + 1
         window = getattr(self.cfg, "attention_window", None)
         first = np.maximum(n - window, 0) // ps if window else 0
@@ -1125,6 +1283,7 @@ class SlotEngine:
                 self._pf_queue.remove(slot)
             except ValueError:
                 pass
+        self._eva_total[slot] = 0
         self.pool.free(slot)
 
     def start(
@@ -1234,6 +1393,8 @@ class SlotEngine:
         """Page allocation + prefix adoption + tail prefill for one slot.
         Returns the first token, or ``None`` when the tail exceeds every
         bucket and a chunked-prefill plan was scheduled instead."""
+        if self._eva:
+            return self._start_eva(slot, prompt, p, max_new, sargs, sampled)
         pool, ps = self.pool, self.page_size
         n_pages = pool.pages_needed(p, max_new)
         # Adoption cap: the tail must keep >= 1 real token (the first-
@@ -1264,10 +1425,7 @@ class SlotEngine:
         for pid in matched[m_pages:]:
             pool.decref(pid)
         matched = matched[:m_pages]
-        own = pool.alloc_pages(n_pages - len(matched))
-        if own is None and self.prefix is not None:
-            self.prefix.evict_for(n_pages - len(matched))
-            own = pool.alloc_pages(n_pages - len(matched))
+        own = self._alloc_pages(n_pages - len(matched))
         if own is None:
             for pid in matched:
                 pool.decref(pid)
@@ -1318,10 +1476,7 @@ class SlotEngine:
         for pid in matched[a:]:
             pool.decref(pid)
         matched = matched[:a]
-        own = pool.alloc_pages(n_pages - len(matched))
-        if own is None and self.prefix is not None:
-            self.prefix.evict_for(n_pages - len(matched))
-            own = pool.alloc_pages(n_pages - len(matched))
+        own = self._alloc_pages(n_pages - len(matched))
         if own is None:
             for pid in matched:
                 pool.decref(pid)
@@ -1353,6 +1508,124 @@ class SlotEngine:
             self.stats["prefix_tokens_matched"] = self.prefix.tokens_matched
             self.stats["prefix_tokens_total"] = self.prefix.tokens_looked_up
         return None
+
+    def _alloc_pages(self, n: int):
+        """``n`` pages from the pool, after asking the prefix cache to give
+        up entries where the free list is short; None if still short."""
+        own = self.pool.alloc_pages(n)
+        if own is None and self.prefix is not None:
+            self.prefix.evict_for(n)
+            own = self.pool.alloc_pages(n)
+        return own
+
+    def _start_eva(self, slot, prompt, p, max_new, sargs, sampled):
+        """Admission of an EVA slot: reserve the most pages the request
+        will hold at once, adopt what the prefix cache has of the prompt
+        (whole windows as summary pages, then full K/V pages of the window
+        after them; the tail keeps at least one token), bind the window
+        the prefill starts in and, if the request goes past it, its forming
+        summary pages, and plan the prefill as SEGMENTS: at most a chunk
+        wide, none across a window's end. One segment runs here and its
+        token is returned; more are spent by :meth:`step` like any chunk
+        plan (``None`` is returned)."""
+        pool, ps, w = self.pool, self.page_size, self.pool.window
+        total = p + max_new
+        most = pool.pages_needed(p, max_new)
+        if not pool.reserve(slot, most):
+            raise InsufficientPages(
+                f"slot {slot}: {most} pages at the most for prompt {p} + "
+                f"{max_new} new would pass the pool's "
+                f"{pool.pages_allocatable} beside what is reserved")
+        sums, wins = (self.prefix.match_eva(prompt, p - 1)
+                      if self.prefix is not None else ([], []))
+        done = len(sums) // pool.sum_pages
+        m0 = done * w + len(wins) * ps
+        n_win = -(-(min((done + 1) * w, total) - done * w) // ps) - len(wins)
+        forming = pool.sum_pages if total > (done + 1) * w else 0
+        own = self._alloc_pages(n_win + forming)
+        if own is None:
+            for pid in sums + wins:
+                pool.decref(pid)
+            pool.reserved[slot] = 0
+            raise InsufficientPages(
+                f"need {n_win + forming} pages, {pool.pages_free} free "
+                f"(slot {slot}, prompt {p} + {max_new} new, EVA)")
+        pool.bind_eva(slot, sums, wins + own[:n_win], own[n_win:])
+        self._eva_total[slot] = total
+        self.stats["eva_summary_pages_adopted"] += len(sums)
+        chunks, m = [], m0
+        while m < p:
+            r = min(self.prefill_chunk_tokens, (m // w + 1) * w - m, p - m)
+            chunks.append((m, r, m + r == p))
+            m += r
+        st = {
+            "slot": slot, "prompt": prompt, "p": p, "chunks": chunks,
+            "idx": 0, "sampled": sampled, "sargs": sargs, "m0": m0,
+        }
+        if self.prefix is not None:
+            self.prefix.record_lookup(m0, p)
+            self.stats["prefix_tokens_matched"] = self.prefix.tokens_matched
+            self.stats["prefix_tokens_total"] = self.prefix.tokens_looked_up
+        if len(chunks) == 1:
+            return self._run_chunk(st, *chunks[0])
+        self._pf[slot] = st
+        self.prefilling[slot] = True
+        self._pf_queue.append(slot)
+        return None
+
+    def _run_eva_segment(self, st, m, r, final):
+        """One prefill segment of an EVA slot (``r`` real tokens at
+        absolute ``m``, padded to the narrowest bucket), then what its end
+        brings: a window that the prompt fills is indexed in the prefix
+        cache by its summary pages and rolled; at the prompt's end the full
+        K/V pages of the window it ends in are indexed."""
+        pool, w, slot = self.pool, self.pool.window, st["slot"]
+        prompt = st["prompt"]
+        width = next(b for b in self.prefill_buckets if b >= r)
+        toks = np.zeros((1, width), np.int32)
+        toks[0, :r] = prompt[m : m + r]
+        prefill = (self._prefill_sampled if final and st["sampled"]
+                   else self._prefill_greedy)
+        new_pool, first = prefill(
+            pool.layers, self.params, toks, np.int32(r), np.int32(m),
+            np.array(pool.page_tables[slot]), *st["sargs"],
+        )
+        pool.layers = new_pool
+        end = m + r
+        if end % w == 0:
+            if self.prefix is not None:
+                self.prefix.insert_summaries(
+                    prompt, end // w - 1, pool.forming_row(slot))
+            self._roll_window(slot, end)
+        elif final and self.prefix is not None:
+            self.prefix.insert(prompt, pool.window_row(slot),
+                               first_page=end // w * w // self.page_size)
+        return int(first) if final else None
+
+    def _roll_window(self, slot: int, length: int) -> None:
+        """``slot`` has filled a window (``length`` is a whole number of
+        them): release its pages, move its summaries into the table and
+        bind the next window's (``PagedKVPool.roll_window``)."""
+        pool = self.pool
+        left = int(self._eva_total[slot]) - length
+        with _trace.span("engine.window_roll", flight=False) as sp:
+            released = pool.roll_window(
+                slot, -(-min(pool.window, left) // self.page_size),
+                forming=left > pool.window,
+                evict=self.prefix.evict_for if self.prefix is not None
+                else None,
+            )
+            sp.note(pages_released=released)
+        self.stats["eva_windows_rolled"] += 1
+        self.stats["eva_window_pages_released"] += released
+
+    def _eva_rows(self, act):
+        """(summary rows, window rows) each active slot's next token
+        attends, per layer: 'window/chunk' summaries for every finished
+        window, and the current window up to the token itself."""
+        n = self.lengths[act].astype(np.int64)
+        w = self.pool.window
+        return (n // w * (self.pool.sum_pages * self.page_size), n % w + 1)
 
     def _advance_prefill(self):
         """Spend up to ``prefill_chunk_tokens`` of prefill this iteration
@@ -1388,9 +1661,10 @@ class SlotEngine:
                 if self.spec_k:
                     self.history[slot, p] = first
                     self.hist_len[slot] = p + 1
-                if self.prefix is not None:
+                if self.prefix is not None and not self._eva:
                     # Pages only become adoptable once every position is
-                    # filled — insert at completion, not at start().
+                    # filled — insert at completion, not at start(). (An
+                    # EVA segment indexes its own, _run_eva_segment.)
                     self.prefix.insert(prompt, st["page_ids"])
                 events.append((slot, first, finished))
             else:
@@ -1412,6 +1686,8 @@ class SlotEngine:
         # soon as the program is queued.
         with _trace.span("engine.prefill_chunk", flight=False, offset=m,
                          width=w, final=final):
+            if self._eva:
+                return self._run_eva_segment(st, m, w, final)
             toks = np.ascontiguousarray(prompt[m : m + w][None])
             row = np.array(pool.page_tables[st["slot"]])
             prefill = (
@@ -1459,6 +1735,10 @@ class SlotEngine:
                 chunks_run=self.stats["prefill_chunks"] - chunks0,
                 kv_rows_read=self._kv_rows_read(act),
             )
+            if self._eva:
+                sums, rows = self._eva_rows(act)
+                sp.note(summary_rows_read=int(sums.sum()),
+                        window_rows_read=int(rows.sum()))
             if act.any():
                 toks, valid, done = self._decode_round()
             else:
@@ -1511,6 +1791,13 @@ class SlotEngine:
         layers, active, lengths, tok, made, toks, valid, *accepted = out
         result = self._finish_round(layers, active, lengths, tok, made,
                                     toks, valid)
+        if self._eva:
+            # A slot whose new length opens a window rolls before the next
+            # round: its table still ends in the window it has filled.
+            due = self.active & (
+                self.lengths // self.pool.window > self.pool.windows_done)
+            for slot in np.nonzero(due)[0]:
+                self._roll_window(int(slot), int(self.lengths[slot]))
         if spec:
             self._count_spec(was_active, accepted[0], any_sampled)
         return result
@@ -1917,6 +2204,14 @@ class SlotEngine:
     # page table — no new jitted program on either side, so the
     # zero-recompile contract holds on both tiers.
 
+    def _refuse_eva_handoff(self) -> None:
+        """Slot handoff moves a plain page list: an EVA slot's composed
+        row (kinds, windows done, forming pages) is not in that bundle."""
+        if self._eva:
+            raise EvaUnsupported(
+                "slot export/import is not extended to EVA's composed "
+                "page table")
+
     def export_slot(self, slot: int, *, history=None) -> dict:
         """Capture ``slot``'s decode state as a host-serializable bundle.
 
@@ -1927,6 +2222,7 @@ class SlotEngine:
         register wins. The slot stays live here — the caller releases it
         only once the peer acknowledged the import (fallback to local
         decode otherwise, so no request is ever lost)."""
+        self._refuse_eva_handoff()
         if not self.paged:
             raise RuntimeError("slot handoff requires the paged KV layout")
         if self.prefilling[slot]:
@@ -1962,6 +2258,7 @@ class SlotEngine:
         rows to host chunk by chunk while streaming. Same preconditions
         and the same exporter-keeps-the-slot contract as
         :meth:`export_slot`."""
+        self._refuse_eva_handoff()
         if not self.paged:
             raise RuntimeError("slot handoff requires the paged KV layout")
         if self.prefilling[slot]:
@@ -1996,6 +2293,7 @@ class SlotEngine:
         decode locally) when the pool cannot back the payload. On success
         the slot is active and the next :meth:`step` continues the
         request exactly where the exporter stopped."""
+        self._refuse_eva_handoff()
         self.validate_handoff_header(bundle)
         self.pool.import_pages(slot, bundle["pages"])
         self._adopt_handoff_registers(slot, bundle)
@@ -2008,6 +2306,7 @@ class SlotEngine:
         counterpart of :meth:`import_slot` — the all-or-nothing contract
         holds because nothing is bound or activated until this call, and
         the abort path frees the staged pages without touching a slot."""
+        self._refuse_eva_handoff()
         self.validate_handoff_header(bundle)
         self.pool.bind(slot, list(page_ids))
         self._adopt_handoff_registers(slot, bundle)

@@ -32,6 +32,49 @@ whole-row scatter-back, which round-trips the gathered values).
 The :class:`PrefixCache` keys pages by the EXACT BYTES of the token prefix
 they complete (not a hash digest), so a lookup can never adopt a colliding
 request's KV; entries are LRU-evicted when the pool runs out of pages.
+
+**Two kinds of page (an EVA config, ``cfg.eva_window`` set).** The same
+leaves ``(num_pages, heads, page_size, head_dim)`` and the same refcounts
+hold two kinds of page, told apart by where a slot's table points at them:
+
+* a WINDOW page: ``page_size`` K/V rows of consecutive positions, as above;
+* a SUMMARY page: ``page_size`` chunk summaries (k~, v~), one for every
+  ``eva_chunk`` positions (a chunk is one page), so it stands for
+  ``page_size * eva_chunk`` positions.
+
+A slot's table row is COMPOSED: ``[summary pages of its finished windows |
+pages of its current window | ... trash ... | forming pages]``. The first
+two runs are what its next token attends, in that order, as one run of
+logical rows (``attend = summaries + window rows so far + 1``): the paged
+decode kernel and the prefill's gathered logical cache see nothing else.
+The row's last ``sum_pages`` entries are the FORMING summary pages of the
+current window: written as chunks fill (by the decode program, or by a
+prefill segment for every whole chunk of the window so far), never attended.
+``pages_per_slot`` counts the attended entries: ``(windows - 1) * sum_pages +
+window_pages`` (248 at 32768 positions, windows of 2048, pages of 16).
+
+**When a page is released.** At a window's end (:meth:`PagedKVPool.
+roll_window`, on the host between two rounds): the slot drops its reference
+on each of the window's pages, so a page returns to the free list unless the
+prefix cache or another slot still holds it; the forming pages become the
+window's summary pages in the table; fresh pages are bound for the next
+window, as many as the request will fill, with fresh forming pages if it
+will finish that window too. So pages ARE allocated mid-request, which the
+plain layout never does; :meth:`PagedKVPool.reserve` keeps that safe: at
+admission a slot sets aside the most it will hold at once
+(:meth:`PagedKVPool.pages_needed`), admission fails with
+:class:`InsufficientPages` when the slots' reservations together would pass
+the pool, and until they do every page a roll asks for is free or held by
+the prefix cache alone, which gives it up (``evict``).
+
+**What a prefix adopts.** A prompt's finished windows are indexed by their
+summary pages (every page of a window, each keyed by the prefix its
+summaries end at, under a key one byte longer than any K/V page's), and the
+window the prompt ends in by its full K/V pages, keyed as in the plain
+layout. A request adopts the summary pages of every leading window cached
+whole, then the chain of full K/V pages of the window after them
+(:meth:`PrefixCache.match_eva`); the summaries of the chunks it adopted as
+K/V pages are formed again from those rows by its first prefill segment.
 """
 
 from __future__ import annotations
@@ -212,12 +255,33 @@ class PagedKVPool:
         self.max_len = int(max_len)
         self.page_size = int(page_size)
         self.pages_per_slot = max_len // page_size
+        # EVA (cfg.eva_window set): the composed layout of the module
+        # docstring. ``window_pages`` pages hold one window's K/V rows and
+        # ``sum_pages`` pages its summaries; a table row is the summary
+        # pages of every window but the last, then one window's pages
+        # (``pages_per_slot``: what is ever attended), then the
+        # ``sum_pages`` forming pages, which are written and not attended.
+        self.eva = bool(getattr(cfg, "eva", False))
+        self.window_pages = self.sum_pages = 0
+        if self.eva:
+            w, c = int(cfg.eva_window), int(cfg.eva_chunk)
+            if c != page_size or (w // c) % page_size or max_len % w:
+                raise ValueError(
+                    f"an EVA pool needs page_size {page_size} == eva_chunk "
+                    f"{c}, a window's {w // c} summaries a whole number of "
+                    f"pages, and max_len {max_len} a whole number of "
+                    f"windows of {w}")
+            self.window = w
+            self.window_pages = w // page_size
+            self.sum_pages = w // c // page_size
+            self.pages_per_slot = (
+                (max_len // w - 1) * self.sum_pages + self.window_pages)
         if num_pages == 0:
             # Default: worst case for every slot + the trash page — paging
             # with no oversubscription. Sizing BELOW this is the point:
             # short requests only claim what they use, so the same HBM
             # admits more concurrent requests.
-            num_pages = self.slots * self.pages_per_slot + 1
+            num_pages = self.slots * (self.pages_per_slot + self.sum_pages) + 1
         if num_pages < self.pages_per_slot + 1:
             raise ValueError(
                 f"num_pages {num_pages} cannot back even one worst-case "
@@ -243,8 +307,13 @@ class PagedKVPool:
         self.refcount = np.zeros(self.num_pages, np.int64)
         self.refcount[TRASH_PAGE] = 1  # pinned — never allocatable
         self.page_tables = np.full(
-            (self.slots, self.pages_per_slot), TRASH_PAGE, np.int32
+            (self.slots, self.pages_per_slot + self.sum_pages), TRASH_PAGE,
+            np.int32,
         )
+        # EVA: finished windows in each slot's table, and the most pages
+        # each slot may hold at once (:meth:`reserve`).
+        self.windows_done = np.zeros(self.slots, np.int64)
+        self.reserved = np.zeros(self.slots, np.int64)
         self._free_slots: list[int] = list(range(slots - 1, -1, -1))
         self._free_slot_set: set[int] = set(self._free_slots)
 
@@ -291,7 +360,19 @@ class PagedKVPool:
         return self.hbm_bytes / (self.num_pages * self.page_size)
 
     def pages_needed(self, prompt_len: int, max_new_tokens: int) -> int:
+        """Pages a request of ``prompt_len + max_new_tokens`` positions
+        holds. Plain layout: one page for every ``page_size`` positions,
+        all bound at admission. EVA layout: the MOST it holds at once, which
+        is what admission reserves (:meth:`reserve`): with ``n`` windows
+        behind the one its last position lies in, ``n`` windows of summary
+        pages and, where n > 0, one whole window of pages (in the window
+        before, the forming summary pages stand in for the last window's
+        summaries); pages are bound window by window (:meth:`roll_window`)."""
         total = prompt_len + max_new_tokens
+        if self.eva:
+            n = (total - 1) // self.window
+            if n:
+                return n * self.sum_pages + self.window_pages
         return -(-total // self.page_size)  # ceil
 
     def pages_bound(self, slot: int) -> int:
@@ -325,6 +406,8 @@ class PagedKVPool:
             if pid != TRASH_PAGE:
                 self.decref(int(pid))
         self.page_tables[slot, :] = TRASH_PAGE
+        self.windows_done[slot] = 0
+        self.reserved[slot] = 0
         self._free_slots.append(slot)
         self._free_slot_set.add(slot)
 
@@ -370,6 +453,84 @@ class PagedKVPool:
         self.page_tables[slot, : len(page_ids)] = np.asarray(
             page_ids, np.int32
         )
+
+    # -- EVA: the composed table ---------------------------------------------
+
+    def reserve(self, slot: int, n: int) -> bool:
+        """Set aside ``n`` pages as the most ``slot`` will hold at once.
+        False when the slots' reservations together would pass the pool:
+        then a later :meth:`roll_window` could find no page. While they do
+        not, every page a roll asks for is free or held by the prefix cache
+        alone, and the cache gives it up."""
+        if self.reserved.sum() - self.reserved[slot] + n > self.pages_allocatable:
+            return False
+        self.reserved[slot] = n
+        return True
+
+    def bind_eva(self, slot: int, summary_pages, window_pages,
+                 forming_pages) -> None:
+        """Point ``slot``'s table at its composed row: the summary pages of
+        its finished windows (``sum_pages`` each), its current window's
+        pages, and, in the row's last ``sum_pages`` entries, the forming
+        summary pages (none: the window will not be finished)."""
+        n_sum = len(summary_pages)
+        if n_sum % self.sum_pages or len(window_pages) > self.window_pages:
+            raise ValueError(
+                f"{n_sum} summary pages / {len(window_pages)} window pages "
+                f"do not compose a row")
+        row = self.page_tables[slot]
+        row[:] = TRASH_PAGE
+        row[:n_sum] = np.asarray(summary_pages, np.int32)
+        row[n_sum:n_sum + len(window_pages)] = np.asarray(
+            window_pages, np.int32)
+        row[self.pages_per_slot:self.pages_per_slot + len(forming_pages)] = (
+            np.asarray(forming_pages, np.int32))
+        self.windows_done[slot] = n_sum // self.sum_pages
+
+    def window_row(self, slot: int) -> np.ndarray:
+        """The entries of ``slot``'s row that hold its current window."""
+        lo = int(self.windows_done[slot]) * self.sum_pages
+        return self.page_tables[slot, lo:lo + self.window_pages]
+
+    def forming_row(self, slot: int) -> np.ndarray:
+        return self.page_tables[slot, self.pages_per_slot:]
+
+    def roll_window(self, slot: int, n_window: int, forming: bool,
+                    evict=None) -> int:
+        """``slot``'s current window is finished: its pages are released
+        (one reference each: a page the prefix cache or another slot still
+        holds stays resident), its forming summary pages join the summaries
+        in the table, and ``n_window`` fresh pages are bound for the next
+        window, with fresh forming pages if ``forming``. ``evict(n)`` is
+        asked to free pages where the free list is short
+        (``PrefixCache.evict_for``). Returns the pages released."""
+        row = self.page_tables[slot]
+        done = int(self.windows_done[slot])
+        lo = done * self.sum_pages
+        old = [int(p) for p in self.window_row(slot) if p != TRASH_PAGE]
+        formed = self.forming_row(slot).copy()
+        if (formed == TRASH_PAGE).any():
+            raise RuntimeError(
+                f"slot {slot} finished a window without forming pages")
+        for pid in old:
+            self.decref(pid)
+        want = n_window + (self.sum_pages if forming else 0)
+        fresh = self.alloc_pages(want)
+        if fresh is None and evict is not None:
+            evict(want)
+            fresh = self.alloc_pages(want)
+        if fresh is None:
+            raise InsufficientPages(
+                f"window roll of slot {slot} needs {want} pages, "
+                f"{self.pages_free} free: reservations were passed over")
+        row[lo:] = TRASH_PAGE
+        row[lo:lo + self.sum_pages] = formed
+        lo += self.sum_pages
+        row[lo:lo + n_window] = np.asarray(fresh[:n_window], np.int32)
+        row[self.pages_per_slot:self.pages_per_slot + want - n_window] = (
+            np.asarray(fresh[n_window:], np.int32))
+        self.windows_done[slot] = done + 1
+        return len(old)
 
     def compile_count(self) -> int:
         return 0  # all jitted programs live in the engine
@@ -552,6 +713,59 @@ class PrefixCache:
             self.pool.incref(pid)
         return pages
 
+    @staticmethod
+    def _summary_key(prompt: np.ndarray, n_tokens: int) -> bytes:
+        # One byte longer than any K/V page's key: the kinds never collide.
+        return b"S" + prompt[:n_tokens].astype("<i4").tobytes()
+
+    def match_eva(self, prompt: np.ndarray, cap_tokens: int):
+        """EVA layout: the longest cached prefix of ``prompt`` of at most
+        ``cap_tokens`` tokens, as ``(summary pages, window pages)``: every
+        summary page of each leading window that is cached whole, then the
+        chain of cached full K/V pages of the window after them. Each page
+        is increffed for the adopting slot."""
+        pool = self.pool
+        ps, w = pool.page_size, pool.window
+        span = w // pool.sum_pages  # positions one summary page covers
+        sums: list[int] = []
+        done = 0
+        while (done + 1) * w <= cap_tokens:
+            keys = [self._summary_key(prompt, done * w + (i + 1) * span)
+                    for i in range(pool.sum_pages)]
+            if any(k not in self._entries for k in keys):
+                break
+            for k in keys:
+                self._entries.move_to_end(k)
+                sums.append(self._entries[k])
+            done += 1
+        base = done * w // ps
+        wins: list[int] = []
+        for i in range(1, min(pool.window_pages,
+                              cap_tokens // ps - base) + 1):
+            key = self._key(prompt, base + i, ps)
+            pid = self._entries.get(key)
+            if pid is None:
+                break
+            self._entries.move_to_end(key)
+            wins.append(pid)
+        for pid in sums + wins:
+            pool.incref(pid)
+        return sums, wins
+
+    def insert_summaries(self, prompt: np.ndarray, window: int,
+                         page_ids) -> None:
+        """Index the summary pages of ``prompt``'s finished window
+        ``window`` (all of them, each by the prefix its summaries end at)."""
+        w = self.pool.window
+        span = w // self.pool.sum_pages
+        for i, pid in enumerate(page_ids):
+            key = self._summary_key(prompt, window * w + (i + 1) * span)
+            if key in self._entries:
+                self._entries.move_to_end(key)
+                continue
+            self.pool.incref(int(pid))
+            self._entries[key] = int(pid)
+
     def record_lookup(self, matched_tokens: int, prompt_tokens: int) -> None:
         self.tokens_matched += matched_tokens
         self.tokens_looked_up += prompt_tokens
@@ -562,13 +776,15 @@ class PrefixCache:
             return 0.0
         return self.tokens_matched / self.tokens_looked_up
 
-    def insert(self, prompt: np.ndarray, page_ids) -> None:
-        """Index ``prompt``'s full pages (``page_ids[i]`` backs page ``i``).
+    def insert(self, prompt: np.ndarray, page_ids, first_page: int = 0) -> None:
+        """Index ``prompt``'s full pages (``page_ids[i]`` backs page
+        ``first_page + i`` of the prompt; an EVA slot passes its current
+        window's pages and the page that window starts at).
         Already-indexed prefixes keep their existing (shared) page."""
         ps = self.pool.page_size
-        n_full = min(len(page_ids), len(prompt) // ps)
+        n_full = min(len(page_ids), len(prompt) // ps - first_page)
         for i in range(n_full):
-            key = self._key(prompt, i + 1, ps)
+            key = self._key(prompt, first_page + i + 1, ps)
             if key in self._entries:
                 self._entries.move_to_end(key)
                 continue

@@ -57,3 +57,27 @@ def test_paged_decode_kernel_compiles_for_v5e(one_chip, dtype, slots, kv,
         arg((slots,), jnp.int32),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_paged_decode_kernel_compiles_for_evabyte_rows(one_chip):
+    """evabyte-6.5b as benchmarks/configs runs it: 16 slots, 32 kv heads of
+    128 and no groups, pages of 16, a composed row of 248 pages (15 windows
+    of summaries and one window of K/V rows), chunks of 16 pages: a page is
+    16 times StarCoder2's, so the default 32 would ask for 16.8 MB of
+    VMEM (``models/transformer._eva_through_table`` picks the chunk)."""
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    slots, kv, ps, pps = 16, 32, 16, 248
+    pages = slots * (pps + 8) + 1
+    compiled = jax.jit(
+        lambda q, k, v, tables, lens: paged_decode_attention(
+            q, k, v, tables, lens, pages_per_chunk=16, interpret=False)
+    ).lower(
+        arg((slots, kv, 1, 128), jnp.bfloat16),
+        arg((pages, kv, ps, 128), jnp.bfloat16),
+        arg((pages, kv, ps, 128), jnp.bfloat16),
+        arg((slots, pps), jnp.int32),
+        arg((slots,), jnp.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
