@@ -149,24 +149,34 @@ and ``kv_rows_read`` (the K and V positions per layer a micro-step of its
 decode program reads: the active slots' live pages through the table,
 ``slots * max_len`` wherever a program gathers);
 ``engine.start`` covers an admission and ``engine.warmup`` the program set's
-compiles. The time from the end of one round's ``engine.wait`` to the end
-of the next round's ``engine.dispatch`` is the host gap in which the device
-has nothing queued. Inside the programs, ``jax.named_scope`` names the
+compiles. ``engine.round`` also says ``ahead``: whether the round whose
+tokens the call returns was queued before the round before it was read
+(``stats["rounds_ahead"]`` counts those dispatches). The time from the end
+of one round's ``engine.wait`` to the end of the next round's
+``engine.dispatch`` is the host's work between two rounds; the device has
+nothing queued in it only where the round was not run ahead. Inside the
+programs, ``jax.named_scope`` names the
 phases (``kv.gather``, ``attn``, ``mlp``, ``lm_head``, ``sample``,
 ``kv.scatter``) in the op metadata a profile shows; scopes change no
 program.
 
 Host/device split: the big pool buffers live on device and are DONATED
-through every program (in-place turnover); the per-slot registers
+through every program (in-place turnover). The per-slot registers
 (lengths, current token, sampling params, budgets, token history for the
-drafter) are small host numpy arrays passed in each call — the host is
-the scheduler's view, the device never holds control state the host also
-needs.
+drafter) are small host numpy arrays, the scheduler's view; the plain
+decode program carries ``active, lengths, tok, made`` through itself, so
+the device holds a copy of them that is one round NEWER than the host's
+while a round is in flight (run-ahead of depth one, :meth:`SlotEngine.
+step`): a round is queued from the device's copy wherever the host has
+changed nothing since the last dispatch, and from the host's, copied and
+uploaded once, wherever it has. Those four are read back after they were
+fed to the next round, so they are never donated; the pool's leaves are.
 """
 
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 from collections import deque
 
 import jax
@@ -199,6 +209,23 @@ from distributed_tensorflow_tpu.serve.kv_pool import (
 )
 
 __all__ = ["SlotEngine", "ShardedSlotEngine"]
+
+
+@dataclasses.dataclass
+class _Round:
+    """A decode round that is queued on the device and not yet read."""
+
+    # The program's outputs after the pool, still on the device: (active,
+    # lengths, tok, made, toks, valid) and a verify round's accepted counts.
+    out: tuple
+    # Host copies of the ``active`` and ``lengths`` the round ran with. A
+    # round queued from the registers of the round before it learns them
+    # when that round is read.
+    was_active: np.ndarray | None
+    lengths: np.ndarray | None
+    ahead: bool  # queued before the round before it was read
+    spec: bool  # a verify round
+    any_sampled: bool
 
 
 class SlotEngine:
@@ -460,6 +487,7 @@ class SlotEngine:
             "spec_rounds_sampled": 0,
             "spec_verifies": 0,
             "plain_rounds": 0,
+            "rounds_ahead": 0,
             "prefill_chunks": 0,
             "prefill_tokens_last_iter": 0,
             "eva_windows_rolled": 0,
@@ -474,6 +502,15 @@ class SlotEngine:
         # this bounded window.
         self.accept_samples: deque[int] = deque(maxlen=4096)
         self._force_plain = False  # warmup hook: compile the non-spec path
+        # Run-ahead of depth one (see step()): the round that is queued on
+        # the device and not yet read, the round the last step() returned,
+        # the slots the host has changed since the last dispatch, and the
+        # device copies of what only the host changes (sampling parameters,
+        # budget, eos, the page table), as uploaded with that dispatch.
+        self._flight: _Round | None = None
+        self._returned: _Round | None = None
+        self._touched = np.zeros(n, bool)
+        self._dev_consts: tuple = ()
 
         model, k_sync = self.model, self.steps_per_sync
         ps, pps = self.page_size, getattr(self.pool, "pages_per_slot", 0)
@@ -1158,9 +1195,11 @@ class SlotEngine:
             return "table"
         return "gather"
 
-    def _kv_rows_read(self, act) -> int:
-        """K and V positions per layer that one micro-step of the coming
-        decode round reads, from the host registers alone. It counts for
+    def _kv_rows_read(self, act, lengths=None, spec=None) -> int:
+        """K and V positions per layer that one micro-step of a decode
+        round over the ``act`` slots at ``lengths`` reads (the coming round
+        from the host registers where they are left out; ``spec``: whether
+        it is a verify round). It counts for
         three layouts: a verify round and the gather path (monolithic
         pool, int8 pages, the sharded engine) read every slot's whole row;
         the table path over the plain layout reads the live pages of the
@@ -1174,15 +1213,18 @@ class SlotEngine:
         rows."""
         if not act.any():
             return 0
-        if self.decode_path == "gather" or self._spec_round(act):
+        lengths = self.lengths if lengths is None else lengths
+        if spec is None:
+            spec = self._spec_round(act)
+        if self.decode_path == "gather" or spec:
             return self.slots * self.max_len
         ps = self.page_size
         if self._eva:
             # The composed row: summaries and window rows are one run of
             # pages to the kernel.
-            sums, rows = self._eva_rows(act)
+            sums, rows = self._eva_rows(act, lengths)
             return int((-(-(sums + rows) // ps) * ps).sum())
-        n = self.lengths[act].astype(np.int64) + 1
+        n = lengths[act].astype(np.int64) + 1
         window = getattr(self.cfg, "attention_window", None)
         first = np.maximum(n - window, 0) // ps if window else 0
         return int(((-(-n // ps) - first) * ps).sum())
@@ -1275,6 +1317,11 @@ class SlotEngine:
         return self.pool.alloc()
 
     def release(self, slot: int) -> None:
+        if self.active[slot]:
+            # A slot that is still decoding (a cancel, a deadline): a round
+            # in flight carries it on, so the host's word has to win at the
+            # merge. A slot that finished is masked on the device already.
+            self._touched[slot] = True
         self.active[slot] = False
         if self.prefilling[slot]:
             self.prefilling[slot] = False
@@ -1285,6 +1332,16 @@ class SlotEngine:
                 pass
         self._eva_total[slot] = 0
         self.pool.free(slot)
+
+    def pause(self, slot: int) -> None:
+        """Stop decoding ``slot`` and keep its registers and pages (a slot
+        parked for a handoff); :meth:`resume` takes it up where it stood."""
+        self._touched[slot] = True
+        self.active[slot] = False
+
+    def resume(self, slot: int) -> None:
+        self._touched[slot] = True
+        self.active[slot] = True
 
     def start(
         self,
@@ -1353,6 +1410,7 @@ class SlotEngine:
                 self.pool.adopt(slot, new_layers)
             # Registers shared by both outcomes (immediate first token vs
             # PREFILLING): sampling params and limits are fixed at admission.
+            self._touched[slot] = True
             self.temp[slot] = temperature
             self.top_k[slot] = top_k
             self.top_p[slot] = top_p
@@ -1608,6 +1666,8 @@ class SlotEngine:
         bind the next window's (``PagedKVPool.roll_window``)."""
         pool = self.pool
         left = int(self._eva_total[slot]) - length
+        if self.active[slot]:
+            self._touched[slot] = True  # its row of the page table changes
         with _trace.span("engine.window_roll", flight=False) as sp:
             released = pool.roll_window(
                 slot, -(-min(pool.window, left) // self.page_size),
@@ -1619,11 +1679,12 @@ class SlotEngine:
         self.stats["eva_windows_rolled"] += 1
         self.stats["eva_window_pages_released"] += released
 
-    def _eva_rows(self, act):
-        """(summary rows, window rows) each active slot's next token
-        attends, per layer: 'window/chunk' summaries for every finished
-        window, and the current window up to the token itself."""
-        n = self.lengths[act].astype(np.int64)
+    def _eva_rows(self, act, lengths):
+        """(summary rows, window rows) that the next token of each ``act``
+        slot at ``lengths`` attends, per layer: 'window/chunk' summaries
+        for every finished window, and the current window up to the token
+        itself."""
+        n = lengths[act].astype(np.int64)
         w = self.pool.window
         return (n // w * (self.pool.sum_pages * self.page_size), n % w + 1)
 
@@ -1637,7 +1698,14 @@ class SlotEngine:
         spent = 0
         chunks_run = 0
         budget = self.prefill_chunk_tokens
-        while self._pf_queue:
+        # A round in flight that this call reads was queued before the
+        # chunk plan was known: a chunk behind it would still be running
+        # when the call returns, and the next call would queue a second one
+        # behind it (step(): chunks never run ahead of the host). The plan
+        # waits this one call; the next finds no round in flight.
+        hold = (bool(self._pf_queue) and self._flight is not None
+                and self.active.any())
+        while self._pf_queue and not hold:
             slot = self._pf_queue[0]
             st = self._pf[slot]
             m, w, final = st["chunks"][st["idx"]]
@@ -1654,6 +1722,7 @@ class SlotEngine:
                 prompt, p = st["prompt"], st["p"]
                 eos = int(self.eos[slot])
                 finished = int(self.budget[slot]) == 1 or first == eos
+                self._touched[slot] = True
                 self.active[slot] = not finished
                 self.lengths[slot] = p
                 self.cur_tok[slot] = first
@@ -1720,33 +1789,71 @@ class SlotEngine:
         chunks), then runs the normal decode round over the ACTIVE slots
         — long prefills never stall co-resident decodes. A slot whose
         final chunk lands this call contributes its first token as one
-        extra LEADING row and joins the same call's decode round."""
+        extra LEADING row and joins the next round queued.
+
+        **Run-ahead of depth one.** The call that returns round n's tokens
+        first QUEUES round n+1 from round n's output registers, which are
+        still on the device (``active, lengths, tok, made``; the sampling
+        parameters, budgets, eos ids and the page table are device copies
+        of the last upload), then waits for round n and reads it: the
+        device runs n+1 while the host reads back, delivers, completes and
+        comes in again. Never two rounds ahead of the host's reading, so
+        an admission's prefill waits behind one round at the most. The
+        host's own changes (:meth:`start`, :meth:`release` of a slot still
+        decoding, a final prefill chunk, :meth:`import_slot`,
+        :meth:`pause` / :meth:`resume`, a window's roll) mark the slot
+        ``_touched``: with a slot touched nothing is queued early; the
+        round in flight is read, touched slots keep the HOST's registers
+        and yield nothing from that round, the rest take the device's, and
+        the next round goes out from the merged registers, uploaded once,
+        in the same call. Four more things the engine sees in its own
+        state keep a round from being queued early: it may be a verify
+        round (``spec_k > 0``: the drafts need round n's tokens), an EVA
+        slot fills its window in round n (the roll precedes n+1), no slot
+        outlives round n's budgets, or a slot is PREFILLING. While chunks
+        are being spent every call is the synchronous one it was (chunk,
+        round, wait, read): a call that returned before its chunk had run
+        would let the next call queue a second chunk behind it, and an
+        admission's prefill would wait behind both; so a chunk plan also
+        waits one call where a round queued before it is still to be read
+        (:meth:`_advance_prefill`). A slot released while a round in
+        flight still carries it (a cancel) may get one more K/V row
+        written: into a page of its own at or above its prompt's end,
+        which the prefix cache never holds, and BEFORE any program of the
+        page's next owner, which is queued behind it and writes every row
+        it will attend (the module's overwrite invariant)."""
         if not self.active.any() and not self.prefilling.any():
             raise RuntimeError("step() with no active slots")
         with _trace.span("engine.round", flight=False) as sp:
             chunks0 = self.stats["prefill_chunks"]
             pre_events, _ = self._advance_prefill()
-            act = self.active
-            # What the decode round below works on: a slot whose final chunk
-            # just landed is already among the active.
-            sp.note(
-                active=int(act.sum()),
-                live_tokens=int(self.lengths[act].sum()),
-                chunks_run=self.stats["prefill_chunks"] - chunks0,
-                kv_rows_read=self._kv_rows_read(act),
-            )
-            if self._eva:
-                sums, rows = self._eva_rows(act)
-                sp.note(summary_rows_read=int(sums.sum()),
-                        window_rows_read=int(rows.sum()))
-            if act.any():
+            # A slot whose final chunk just landed is among the active.
+            if self.active.any():
                 toks, valid, done = self._decode_round()
+                rnd = self._returned
+                act, lengths = rnd.was_active, rnd.lengths
             else:
+                rnd = None
+                act, lengths = self.active, self.lengths
                 toks = np.zeros((0, self.slots), np.int32)
                 valid = np.zeros((0, self.slots), bool)
                 done = np.zeros(self.slots, bool)
                 if self.sentinel is not None:
                     self.sentinel.poll(self.compile_count())
+            # What the decode round whose tokens this call returns worked
+            # on: the registers it ran with, not those the host holds now.
+            sp.note(
+                active=int(act.sum()),
+                live_tokens=int(lengths[act].sum()),
+                chunks_run=self.stats["prefill_chunks"] - chunks0,
+                kv_rows_read=self._kv_rows_read(
+                    act, lengths, rnd is not None and rnd.spec),
+                ahead=rnd is not None and rnd.ahead,
+            )
+            if self._eva:
+                sums, rows = self._eva_rows(act, lengths)
+                sp.note(summary_rows_read=int(sums.sum()),
+                        window_rows_read=int(rows.sum()))
             if pre_events:
                 row_t = np.zeros((1, self.slots), np.int32)
                 row_v = np.zeros((1, self.slots), bool)
@@ -1760,37 +1867,17 @@ class SlotEngine:
             return toks, valid, done
 
     def _decode_round(self) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-        was_active = self.active
-        with _trace.span("engine.dispatch", flight=False):
-            # The sampled program handles greedy rows correctly (via
-            # `where`), so a mixed batch runs sampled; only an all-greedy
-            # batch takes the sort-free fast path (and, when enabled, the
-            # speculative one).
-            any_sampled = bool((self.temp[was_active] > 0.0).any())
-            spec = self._spec_round(was_active)
-            if spec:
-                out = self._spec_dispatch(any_sampled)
-            else:
-                self.stats["plain_rounds"] += 1
-                step = self._step_sampled if any_sampled else self._step_greedy
-                if self.paged:
-                    out = step(
-                        self.pool.layers, self.params, self.pool.page_tables,
-                        was_active, self.lengths, self.cur_tok, self.temp,
-                        self.top_k, self.top_p, self.seed, self.made,
-                        self.budget, self.eos,
-                    )
-                else:
-                    out = step(
-                        self.params, self.pool.layers, was_active,
-                        self.lengths, self.cur_tok, self.temp, self.top_k,
-                        self.top_p, self.seed, self.made, self.budget,
-                        self.eos,
-                    )
-        # A verify program returns the accepted counts as one output more.
-        layers, active, lengths, tok, made, toks, valid, *accepted = out
-        result = self._finish_round(layers, active, lengths, tok, made,
-                                    toks, valid)
+        """Read one decode round, with the next one queued behind it
+        wherever the host has nothing to say before it (run-ahead of depth
+        one; the order and what keeps a round from being queued early are in
+        :meth:`step`)."""
+        rnd = self._flight
+        if rnd is None:
+            rnd = self._dispatch()
+        nxt = None
+        if not self._touched.any() and self._host_silent():
+            nxt = self._dispatch(rnd)
+        result = self._finish_round(rnd, nxt)
         if self._eva:
             # A slot whose new length opens a window rolls before the next
             # round: its table still ends in the window it has filled.
@@ -1798,9 +1885,91 @@ class SlotEngine:
                 self.lengths // self.pool.window > self.pool.windows_done)
             for slot in np.nonzero(due)[0]:
                 self._roll_window(int(slot), int(self.lengths[slot]))
-        if spec:
-            self._count_spec(was_active, accepted[0], any_sampled)
+        if rnd.spec:
+            self._count_spec(rnd.was_active, rnd.out[6], rnd.any_sampled)
+        if (nxt is None and self.active.any() and not self._drafts_on_host
+                and not self.prefilling.any()):
+            # The host had its say (a merge, a roll): the next round goes
+            # out from its registers here, and not a whole call later.
+            nxt = self._dispatch()
+        self._flight, self._returned = nxt, rnd
         return result
+
+    @property
+    def _drafts_on_host(self) -> bool:
+        """A verify round's drafts are made from the tokens of the round
+        before it, on the host: such an engine queues nothing early."""
+        return bool(self.spec_k) and not self._force_plain
+
+    def _host_silent(self) -> bool:
+        """Whether the round after the one in flight needs nothing from the
+        host, by the registers the one in flight ran with (no slot is
+        touched, so they are the host's): it is no verify round, no slot
+        is PREFILLING (the next call's chunk has to precede it), a slot
+        stays active after the one in flight (a budget's end is known here,
+        an eos is not), and no EVA slot fills its window in it (the roll
+        has to precede the round that writes into the next window)."""
+        if self._drafts_on_host or self.prefilling.any():
+            return False
+        act = self.active
+        if not (act & (self.made + self.steps_per_sync < self.budget)).any():
+            return False
+        return not (self._eva and (
+            (self.lengths[act] + 1) % self.pool.window == 0).any())
+
+    def _dispatch(self, prev: _Round | None = None) -> _Round:
+        """Queue one decode round and return its record. Without ``prev``
+        from the host's registers, copied (the host writes them in place
+        while the device may still be reading) and uploaded once; with
+        ``prev``, a round not yet read, from its output registers and the
+        device copies of the last upload: nothing crosses from the host."""
+        with _trace.span("engine.dispatch", flight=False):
+            if prev is None:
+                was_active, lengths = self.active.copy(), self.lengths.copy()
+                self._touched[:] = False
+            else:
+                was_active = lengths = None
+            # The sampled program handles greedy rows correctly (via
+            # `where`), so a mixed batch runs sampled; only an all-greedy
+            # batch takes the sort-free fast path (and, when enabled, the
+            # speculative one). Queued ahead, the batch is at most the one
+            # `prev` ran with.
+            any_sampled = bool((self.temp[self.active] > 0.0).any())
+            spec = prev is None and self._spec_round(was_active)
+            if spec:
+                layers, *out = self._spec_dispatch(any_sampled)
+            else:
+                self.stats["plain_rounds"] += 1
+                if prev is None:
+                    consts = [self.temp, self.top_k, self.top_p, self.seed,
+                              self.budget, self.eos]
+                    if self.paged:
+                        consts.append(self.pool.page_tables)
+                    active, length, tok, made, *self._dev_consts = self._put(
+                        (was_active, lengths, self.cur_tok.copy(),
+                         self.made.copy(), *(np.array(c) for c in consts)))
+                else:
+                    self.stats["rounds_ahead"] += 1
+                    active, length, tok, made = prev.out[:4]
+                temp, top_k, top_p, seed, budget, eos, *ptabs = (
+                    self._dev_consts)
+                step = self._step_sampled if any_sampled else self._step_greedy
+                lead = ((self.pool.layers, self.params, *ptabs) if self.paged
+                        else (self.params, self.pool.layers))
+                layers, *out = step(
+                    *lead, active, length, tok, temp, top_k, top_p, seed,
+                    made, budget, eos,
+                )
+            # The pool is donated through: whatever is queued next (a
+            # prefill chunk, the next round) takes this round's output.
+            self.pool.layers = layers
+        return _Round(tuple(out), was_active, lengths, prev is not None,
+                      spec, any_sampled)
+
+    def _put(self, host: tuple) -> tuple:
+        """Host registers to the device arrays the step programs take
+        (the sharded engine places them replicated over its mesh)."""
+        return jax.device_put(host)
 
     def _spec_round(self, act) -> bool:
         """Whether the coming round over the ``act`` slots is a verify
@@ -1906,24 +2075,38 @@ class SlotEngine:
                 tree[s, 1:, :] = alt[1:]
         return tree
 
-    def _finish_round(self, layers, active, lengths, tok, made, toks, valid):
-        self.pool.layers = layers
-        was_active = self.active
+    def _finish_round(self, rnd: _Round, nxt: _Round | None):
+        """Wait for ``rnd`` and read it into the host registers. Slots the
+        host touched while it was in flight keep the host's values and
+        yield nothing from it; ``nxt``, queued from ``rnd``'s registers,
+        learns here what it ran with."""
+        active, lengths, tok, made, toks, valid = rnd.out[:6]
         # np.array (copy), not np.asarray: zero-copy views of jax buffers
         # are read-only, and start()/release() write these registers.
         with _trace.span("engine.wait", flight=False):
             # The round's first blocking read: the host waits here for the
             # device to finish the program.
-            self.active = np.array(active)
+            active = np.array(active)
         with _trace.span("engine.readback", flight=False):
-            self.lengths = np.array(lengths)
-            self.cur_tok = np.array(tok)
-            self.made = np.array(made)
-            done = was_active & ~self.active
+            lengths = np.array(lengths)
+            tok = np.array(tok)
+            made = np.array(made)
             toks = np.asarray(toks)
             valid = np.asarray(valid)
+            if nxt is not None:
+                nxt.was_active, nxt.lengths = active.copy(), lengths.copy()
+            touched = self._touched
+            if touched.any():
+                for new, host in ((active, self.active),
+                                  (lengths, self.lengths),
+                                  (tok, self.cur_tok), (made, self.made)):
+                    new[touched] = host[touched]
+                valid = valid & ~touched
+            self.active, self.lengths = active, lengths
+            self.cur_tok, self.made = tok, made
+            done = rnd.was_active & ~active & ~touched
             if self.spec_k:
-                for s in np.nonzero(was_active)[0]:
+                for s in np.nonzero(rnd.was_active & ~touched)[0]:
                     emitted = toks[valid[:, s], s]
                     n = int(self.hist_len[s])
                     self.history[s, n : n + emitted.size] = emitted
@@ -2344,6 +2527,7 @@ class SlotEngine:
             )
 
     def _adopt_handoff_registers(self, slot: int, bundle: dict) -> None:
+        self._touched[slot] = True
         self.active[slot] = True
         self.prefilling[slot] = False
         self.lengths[slot] = int(bundle["length"])
@@ -2476,6 +2660,11 @@ class ShardedSlotEngine(SlotEngine):
         # the boot-time params, so the jitted programs' in_shardings keep
         # matching and the flip stays resharding- and recompile-free.
         return jax.device_put(candidate, self._param_sh)
+
+    def _put(self, host: tuple) -> tuple:
+        # As the step programs give them back (out_shardings): the same
+        # signature whether a round is queued from the host or run ahead.
+        return jax.device_put(host, self._rep)
 
     def _jit_program(self, fn, kind, donate):
         """Jit under the mesh with explicit in/out shardings per program

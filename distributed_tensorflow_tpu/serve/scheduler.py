@@ -829,7 +829,7 @@ class Scheduler:
         # Park: decode stops (active masked off) but registers + pages
         # stay intact, and the pool still owns the slot — nothing can
         # re-acquire it until release() or a fallback reactivates it.
-        self.engine.active[slot] = False
+        self.engine.pause(slot)
         del self._inflight[slot]
         self._parked[slot] = fl
         if self.metrics is not None:
@@ -860,7 +860,7 @@ class Scheduler:
             self.engine.release(slot)
             return
         fl.handoff_banned = True
-        self.engine.active[slot] = True
+        self.engine.resume(slot)
         self._inflight[slot] = fl
         if self.metrics is not None:
             self.metrics.record_handoff("fallback")

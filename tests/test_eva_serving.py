@@ -33,6 +33,7 @@ from distributed_tensorflow_tpu.serve.kv_pool import (
     PagedKVPool,
     PrefixCache,
 )
+from tests.test_serve_engine import SyncEngine
 
 pytestmark = [pytest.mark.serve, pytest.mark.paged]
 
@@ -124,9 +125,9 @@ class LogitSpy:
         return out
 
 
-def make_engine(params, **kw):
+def make_engine(params, cls=SlotEngine, **kw):
     kw = dict(dict(slots=3, max_len=4 * W, prefill_len=16, page_size=C), **kw)
-    return SlotEngine(toy_cfg(), params, **kw)
+    return cls(toy_cfg(), params, **kw)
 
 
 def serve_logits(eng, spy, slot, prompt, max_new):
@@ -242,6 +243,50 @@ def test_adopting_a_prefix_that_ends_mid_window_gives_the_cold_logits(
 
 
 # -- (d): the roll ----------------------------------------------------------
+
+
+def test_a_roll_precedes_the_round_it_opens_and_the_tokens_stay(params):
+    """A request that decodes across two rolls, run ahead: the round that
+    would carry a slot into a new window is not queued before the round
+    that fills the old one is read (the roll comes between them, on the
+    host), every other round is, and the tokens and the rolls are those of
+    the engine that never runs ahead."""
+    p, new = 3, 75
+    prompt = tokens(p, seed=p)
+
+    def serve(cls):
+        eng = make_engine(params, cls=cls)
+        slot = eng.acquire_slot()
+        t_lo = trace.closed("engine.round")[-1][1] if trace.closed(
+            "engine.round") else 0.0
+        first, _ = eng.start(slot, prompt, max_new_tokens=new)
+        toks = [first]
+        while eng.active[slot]:
+            t, v, _ = eng.step()
+            toks += [int(x) for x in t[v[:, slot], slot]]
+        rounds = [r[2] for r in sorted(trace.closed("engine.round"))
+                  if r[0] > t_lo]
+        eng.release(slot)
+        return eng, toks, rounds
+
+    sync, want, sync_rounds = serve(SyncEngine)
+    assert sync.stats["rounds_ahead"] == 0
+    assert not any(r["ahead"] for r in sync_rounds)
+    eng, got, rounds = serve(SlotEngine)
+    assert got == want and len(got) == new
+    assert eng.stats["eva_windows_rolled"] == 2 == (
+        sync.stats["eva_windows_rolled"])
+    assert eng.stats["eva_window_pages_released"] == (
+        sync.stats["eva_window_pages_released"])
+    # One slot: live_tokens is the length its round ran with. The first
+    # round and the first round of each window come from the host.
+    assert [r["live_tokens"] for r in rounds] == list(range(p, p + new - 1))
+    assert [r["ahead"] for r in rounds] == [
+        n != p and n % W != 0 for n in range(p, p + new - 1)]
+    assert eng.stats["rounds_ahead"] == len(rounds) - 3
+    for a, b in zip(rounds, sync_rounds):  # what a round read is unchanged
+        assert {k: v for k, v in a.items() if k != "ahead"} == {
+            k: v for k, v in b.items() if k != "ahead"}
 
 
 def test_a_roll_frees_the_window_and_attend_counts_both_kinds(params):

@@ -265,8 +265,12 @@ def test_inactive_lane_writes_only_the_trash_page(params):
     slot = engine.acquire_slot()
     engine.start(slot, list(range(1, 12)), max_new_tokens=8)  # length 11
     before = jax.device_get(engine.pool.layers)
+    rounds0 = engine.stats["plain_rounds"]
     engine.step()
     after = jax.device_get(engine.pool.layers)
+    # step() returns one round and has queued the next behind it.
+    queued = engine.stats["plain_rounds"] - rounds0
+    assert queued == 2 and engine.stats["rounds_ahead"] == 1
     wrote = int(engine.pool.page_tables[slot, 11 // 8])
     for b, a in zip(before, after):
         for leaf in ("k", "v"):
@@ -274,9 +278,9 @@ def test_inactive_lane_writes_only_the_trash_page(params):
                 (b[leaf] != a[leaf]).any(axis=(1, 2, 3)))[0]}
             assert wrote in changed
             assert changed <= {wrote, TRASH_PAGE}
-            # The one new row, and nothing else of that page.
+            # One new row a round, and nothing else of that page.
             rows = (b[leaf][wrote] != a[leaf][wrote]).any(axis=(0, 2))
-            assert list(np.nonzero(rows)[0]) == [11 % 8]
+            assert list(np.nonzero(rows)[0]) == [11 % 8, 12 % 8]
 
 
 _LAYOUTS = {

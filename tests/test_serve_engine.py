@@ -278,3 +278,205 @@ def test_sample_logits_batched_matches_static_sampler():
             top_k=k or None, top_p=p or None,
         )
         assert int(batched[i]) == int(ref[0]), f"row {i} ({t}, {k}, {p})"
+
+
+# -- run-ahead of depth one: round n+1 queued before round n is read --------
+
+# dh 128: the head size whose plain decode goes through the page table.
+CFG_TABLE = TransformerConfig(
+    vocab_size=64, d_model=256, num_heads=2, num_layers=2, d_ff=64,
+    max_seq_len=48, compute_dtype=jnp.float32,
+)
+_AHEAD_LAYOUTS = {
+    # name: (config, engine keywords, the decode path it must take)
+    "table": (CFG_TABLE, dict(page_size=8, prefill_chunk_tokens=8), "table"),
+    "gather": (CFG, dict(page_size=8, prefill_chunk_tokens=8), "gather"),
+    "monolithic": (CFG, dict(page_size=0), "gather"),
+}
+_SAMPLING = {
+    "greedy": {},
+    "sampled": {"temperature": 0.9, "top_k": 12, "top_p": 0.9},
+}
+
+
+class SyncEngine(SlotEngine):
+    """The engine with run-ahead off: every round is queued from the
+    host's registers after the round before it was read, as before."""
+
+    def _host_silent(self):
+        return False
+
+
+@pytest.fixture(scope="module")
+def layout_params():
+    cache = {}
+
+    def get(cfg):
+        if id(cfg) not in cache:
+            cache[id(cfg)] = TransformerLM(cfg).init(
+                jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+        return cache[id(cfg)]
+
+    return get
+
+
+def _alone(cfg, params, kw, prompt, kwargs):
+    """One request alone on a synchronous engine: the oracle for a sampled
+    request (greedy ones are also held to ``build_generate_fn``)."""
+    eng = SyncEngine(cfg, params, slots=1, max_len=48, prefill_len=12, **kw)
+    out = _drive_chunked(eng, [(prompt, kwargs)])[0]
+    assert eng.stats["rounds_ahead"] == 0
+    return out
+
+
+def _drive_chunked(engine, requests, cancel=None, log=None):
+    """``_drive`` for engines that chunk a long prompt (``start`` returns
+    ``(None, False)`` and the first token comes from a later round), with
+    ``cancel``: {request index: tokens after which the driver releases the
+    slot while the request is still decoding}."""
+    cancel = cancel or {}
+    pending = list(range(len(requests)))
+    busy: dict[int, int] = {}
+    acc = {i: [] for i in range(len(requests))}
+    results = {}
+
+    def finish(slot):
+        i = busy.pop(slot)
+        results[i] = acc[i]
+        engine.release(slot)
+
+    while pending or busy:
+        while pending:
+            slot = engine.acquire_slot()
+            if slot is None:
+                break
+            i = pending.pop(0)
+            prompt, kwargs = requests[i]
+            first, finished = engine.start(slot, prompt, **kwargs)
+            busy[slot] = i
+            if first is not None:
+                acc[i].append(first)
+            if finished:
+                finish(slot)
+        if busy:
+            toks, valid, done = engine.step()
+            if log is not None:
+                log.append((valid.copy(), done.copy()))
+            for k in range(toks.shape[0]):
+                for slot, i in busy.items():
+                    if valid[k, slot]:
+                        acc[i].append(int(toks[k, slot]))
+            for slot in list(busy):
+                i = busy[slot]
+                if done[slot]:
+                    finish(slot)
+                elif i in cancel and len(acc[i]) >= cancel[i]:
+                    assert engine.active[slot]
+                    finish(slot)  # a cancel: the device still carries it
+    return results
+
+
+def _ahead_requests(cfg, params, kw, sampling, paged):
+    """Seven requests on three slots: admissions all along, a budget's end,
+    an eos met mid-decode, and (paged) two prompts longer than the chunk,
+    whose final chunk lands while a round of the others is in flight."""
+    rng = np.random.default_rng(5)
+    lens = [5, 20, 3, 9, 26, 2, 7] if paged else [5, 11, 3, 9, 12, 2, 7]
+    news = [9, 6, 12, 4, 7, 10, 8]
+    requests = []
+    for i, (p, n) in enumerate(zip(lens, news)):
+        kwargs = {"max_new_tokens": n, **sampling}
+        if sampling:
+            kwargs["seed"] = 100 + i
+        requests.append((rng.integers(0, cfg.vocab_size, p).tolist(), kwargs))
+    want = [_alone(cfg, params, kw, p, k) for p, k in requests]
+    # An eos that request 2 meets in mid-decode: the first token of its
+    # stream that did not occur before it.
+    j = next(j for j in range(1, len(want[2]))
+             if want[2][j] not in want[2][:j])
+    requests[2][1]["eos_id"] = want[2][j]
+    want[2] = want[2][:j + 1]
+    return requests, want
+
+
+@pytest.mark.parametrize("sampling", sorted(_SAMPLING))
+@pytest.mark.parametrize("layout", sorted(_AHEAD_LAYOUTS))
+def test_run_ahead_serves_the_oracles_tokens(layout_params, layout, sampling):
+    """Run ahead or not, the tokens are the same: same program, same
+    inputs, another moment of dispatch. With a cancel of a slot that the
+    round in flight still carries, and the slot taken again at once."""
+    cfg, kw, path = _AHEAD_LAYOUTS[layout]
+    params = layout_params(cfg)
+    requests, want = _ahead_requests(
+        cfg, params, kw, _SAMPLING[sampling], paged=bool(kw["page_size"]))
+    engine = SlotEngine(cfg, params, slots=3, max_len=48, prefill_len=12, **kw)
+    assert engine.decode_path == path
+    compiled = engine.warmup()
+    got = _drive_chunked(engine, requests, cancel={0: 4, 5: 3})
+    for i, w in enumerate(want):
+        if i in (0, 5):  # cancelled: what it was served is the oracle's head
+            assert 3 <= len(got[i]) < len(w) and got[i] == w[:len(got[i])]
+        else:
+            assert got[i] == w, f"request {i} diverged"
+    if not sampling:
+        for i, (prompt, kwargs) in enumerate(requests):
+            if i == 2 or cfg is not CFG:
+                continue
+            assert want[i] == _reference_greedy(
+                params, prompt, kwargs["max_new_tokens"])
+    assert engine.stats["rounds_ahead"] > 0
+    assert engine.stats["rounds_ahead"] < engine.stats["plain_rounds"]
+    assert engine.compile_count() == compiled
+    assert engine.free_slots == 3
+
+
+def test_a_slot_admitted_under_a_round_in_flight_keeps_the_hosts_word(params):
+    """The merge. Fifteen slots decode and a round is in flight when the
+    sixteenth is admitted: the next call reads that round, the fifteen take
+    the device's registers, the sixteenth keeps the host's (its prompt's
+    length, its own first token) and yields nothing from a round it was not
+    in; the next round goes out from the merged registers in the same call,
+    uploaded once, and the quiet rounds before and after upload nothing."""
+    engine = SlotEngine(CFG, params, slots=16, max_len=48, prefill_len=12)
+    uploads = []
+    put = engine._put
+    engine._put = lambda host: uploads.append(len(host)) or put(host)
+    rng = np.random.default_rng(9)
+    prompts = [rng.integers(0, CFG.vocab_size, 3 + i % 5).tolist()
+               for i in range(16)]
+    want = [_reference_greedy(params, p, 10) for p in prompts]
+    got = {}
+    for s in range(15):
+        slot = engine.acquire_slot()
+        assert slot == s
+        got[s] = [engine.start(slot, prompts[s], max_new_tokens=10)[0]]
+
+    def step():
+        toks, valid, done = engine.step()
+        for s in got:
+            got[s] += [int(t) for t in toks[valid[:, s], s]]
+        return valid, done
+
+    step()
+    assert uploads == [11]  # the first round: ten registers and the table
+    step(), step()
+    assert uploads == [11] and engine.stats["rounds_ahead"] == 3
+    assert engine._flight is not None and engine._flight.ahead
+    lengths = engine.lengths.copy()
+    slot = engine.acquire_slot()
+    assert slot == 15
+    first, _ = engine.start(slot, prompts[15], max_new_tokens=10)
+    got[15] = [first]
+    valid, _ = step()
+    assert not valid[:, 15].any() and valid[:, :15].all()
+    # One upload, in the same call; nothing queued ahead of this reading.
+    assert uploads == [11, 11] and engine.stats["rounds_ahead"] == 3
+    assert engine._flight is not None and not engine._flight.ahead
+    assert not engine._touched.any()
+    assert (engine.lengths[:15] == lengths[:15] + 1).all()
+    assert engine.lengths[15] == len(prompts[15])
+    assert engine.made[15] == 1 and engine.cur_tok[15] == first
+    while engine.active.any():
+        step()
+    assert uploads == [11, 11]
+    assert [got[s] for s in range(16)] == want

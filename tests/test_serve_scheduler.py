@@ -42,6 +42,19 @@ def params():
     ]
 
 
+@pytest.fixture(scope="module")
+def layout_params():
+    cache = {}
+
+    def get(cfg):
+        if id(cfg) not in cache:
+            cache[id(cfg)] = TransformerLM(cfg).init(
+                jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+        return cache[id(cfg)]
+
+    return get
+
+
 def _engine(params, slots=2):
     return SlotEngine(CFG, params, slots=slots, max_len=32, prefill_len=12)
 
@@ -181,3 +194,46 @@ def test_result_timeout_raises_not_hangs(params):
         h.result(timeout=0.01)  # nothing is driving the scheduler
     sched.run_until_idle(max_steps=50)
     assert isinstance(h.result(0), Completion)
+
+
+# -- run-ahead of depth one, through the scheduler ---------------------------
+
+
+@pytest.mark.parametrize("sampling", ["greedy", "sampled"])
+@pytest.mark.parametrize("layout", ["gather", "monolithic", "table"])
+def test_scheduler_over_run_ahead_serves_the_oracles_tokens(
+        layout_params, layout, sampling):
+    """Seven requests queue for three slots: every completion is followed
+    by an admission while a round is in flight, one request stops at an eos
+    and the others at their budgets, and (paged) two prompts are chunked in
+    beside the decoding slots. Each request's tokens are those it is served
+    alone on an engine that never runs ahead."""
+    from tests.test_serve_engine import (
+        _AHEAD_LAYOUTS,
+        _SAMPLING,
+        _ahead_requests,
+    )
+
+    cfg, kw, path = _AHEAD_LAYOUTS[layout]
+    params = layout_params(cfg)
+    requests, want = _ahead_requests(
+        cfg, params, kw, _SAMPLING[sampling], paged=bool(kw["page_size"]))
+    engine = SlotEngine(cfg, params, slots=3, max_len=48, prefill_len=12, **kw)
+    assert engine.decode_path == path
+    compiled = engine.warmup()
+    metrics = ServingMetrics()
+    sched = Scheduler(engine, max_queue_depth=16, metrics=metrics)
+    handles = []
+    for prompt, kwargs in requests:
+        kwargs = dict(kwargs)
+        handles.append(sched.submit(Request(
+            prompt=tuple(prompt), max_new_tokens=kwargs.pop("max_new_tokens"),
+            **kwargs)))
+    assert sched.run_until_idle(max_steps=400) == len(requests)
+    outs = [h.result(timeout=1) for h in handles]
+    assert [list(o.tokens) for o in outs] == want
+    assert [o.finish_reason for o in outs] == [
+        "eos" if i == 2 else "length" for i in range(len(outs))]
+    assert 0 < engine.stats["rounds_ahead"] < engine.stats["plain_rounds"]
+    assert engine.compile_count() == compiled
+    assert metrics.snapshot()["completed"] == len(requests)

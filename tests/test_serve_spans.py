@@ -76,52 +76,152 @@ def _inside(inner, outer):
 
 
 def test_one_round_closes_each_span_once_nested_at_most_twelve(params):
-    """A round that admits one (chunked) request beside one decoding slot
-    closes every span of the table exactly once, nested as the table says,
-    and nothing else: 12 records."""
+    """Admitting one (chunked) request beside one decoding slot closes every
+    span of the table, nested as the table says, and nothing else: the
+    call that admits it reads the round in flight and queues nothing (the
+    chunk plan waits one call: no chunk runs ahead of the host), the next
+    spends the first chunk and is the synchronous round it always was:
+    chunk, dispatch, wait, readback. Twelve records at the most a call."""
     engine = _engine(params)
     engine.warmup()
     sched = Scheduler(engine, metrics=ServingMetrics())
     sched.submit(Request(prompt=PROMPTS[0], max_new_tokens=8))
-    sched.step()  # slot 0 decodes from here on
+    sched.step()  # slot 0 decodes from here on, a round queued ahead
     sched.submit(Request(prompt=PROMPTS[1], max_new_tokens=8))
     t_lo = time.monotonic()
     sched.step()
-    got = _since(t_lo)
-    assert sorted(got) == sorted(ROUND_SPANS)
-    assert all(len(v) == 1 for v in got.values()), {
-        k: len(v) for k, v in got.items()}
-    assert sum(len(v) for v in got.values()) <= 12
-    r = {k: v[0] for k, v in got.items()}
-    for child, parent in (
-        ("sched.admit", "sched.step"), ("engine.start", "sched.admit"),
-        ("sched.metrics_sync", "sched.step"), ("engine.round", "sched.step"),
-        ("engine.prefill_chunk", "engine.round"),
-        ("engine.dispatch", "engine.round"), ("engine.wait", "engine.round"),
-        ("engine.readback", "engine.round"), ("sched.deliver", "sched.step"),
-        ("sched.complete", "sched.step"),
+    admit = _since(t_lo)
+    t_mid = time.monotonic()
+    sched.step()
+    chunk = _since(t_mid)
+    quiet = {"sched.step", "sched.admit", "sched.metrics_sync",
+             "engine.round", "engine.wait", "engine.readback",
+             "sched.deliver", "sched.complete"}
+    assert set(admit) == quiet | {"sched.queue_wait", "engine.start"}
+    assert set(chunk) == quiet | {"engine.prefill_chunk", "engine.dispatch"}
+    assert set(admit) | set(chunk) == set(ROUND_SPANS)
+    for got in (admit, chunk):
+        assert all(len(v) == 1 for v in got.values()), {
+            k: len(v) for k, v in got.items()}
+        assert sum(len(v) for v in got.values()) <= 12
+    a = {k: v[0] for k, v in admit.items()}
+    c = {k: v[0] for k, v in chunk.items()}
+    for r, pairs in ((a, (("engine.start", "sched.admit"),)),
+                     (c, (("engine.prefill_chunk", "engine.round"),
+                          ("engine.dispatch", "engine.round")))):
+        for child, parent in pairs + (
+            ("sched.admit", "sched.step"), ("sched.metrics_sync", "sched.step"),
+            ("engine.round", "sched.step"), ("engine.wait", "engine.round"),
+            ("engine.readback", "engine.round"),
+            ("sched.deliver", "sched.step"), ("sched.complete", "sched.step"),
+        ):
+            assert _inside(r[child], r[parent]), (child, parent)
+    for r, in_order in (
+        (a, ["sched.admit", "sched.metrics_sync", "engine.wait",
+             "engine.readback", "sched.deliver", "sched.complete"]),
+        (c, ["sched.admit", "sched.metrics_sync", "engine.prefill_chunk",
+             "engine.dispatch", "engine.wait", "engine.readback",
+             "sched.deliver", "sched.complete"]),
     ):
-        assert _inside(r[child], r[parent]), (child, parent)
-    in_order = ["sched.admit", "sched.metrics_sync", "engine.prefill_chunk",
-                "engine.dispatch", "engine.wait", "engine.readback",
-                "sched.deliver", "sched.complete"]
-    for a, b in zip(in_order, in_order[1:]):
-        assert r[a][1] <= r[b][0], (a, b)
-    assert r["sched.queue_wait"][1] <= r["engine.start"][0]
+        for x, y in zip(in_order, in_order[1:]):
+            assert r[x][1] <= r[y][0], (x, y)
+    assert a["sched.queue_wait"][1] <= a["engine.start"][0]
     # What each records.
-    assert r["sched.step"][2] == {"completed": 0}
-    assert r["sched.admit"][2] == {"admitted": 1}
-    assert r["sched.queue_wait"][2] == {"lane": 1, "prompt_len": 20}
-    assert r["engine.start"][2] == {"prompt_len": 20, "matched": 0,
+    assert a["sched.step"][2] == c["sched.step"][2] == {"completed": 0}
+    assert a["sched.admit"][2] == {"admitted": 1}
+    assert c["sched.admit"][2] == {"admitted": 0}
+    assert a["sched.queue_wait"][2] == {"lane": 1, "prompt_len": 20}
+    assert a["engine.start"][2] == {"prompt_len": 20, "matched": 0,
                                     "chunks": 3}
-    assert r["engine.prefill_chunk"][2] == {"offset": 0, "width": 8,
+    assert c["engine.prefill_chunk"][2] == {"offset": 0, "width": 8,
                                             "final": False}
     # dh 8 is no head size the table path takes: the gather path reads
-    # every slot's whole row, 2 slots of 48.
-    assert r["engine.round"][2] == {"active": 1, "live_tokens": 6,
-                                    "chunks_run": 1, "kv_rows_read": 2 * 48}
-    assert r["sched.deliver"][2] == {"produced": 1}
-    assert r["engine.dispatch"][2] is None
+    # every slot's whole row, 2 slots of 48. The round the admitting call
+    # read was queued by the call before it, ahead of its reading: it ran
+    # with slot 0 alone, one token on from its prompt of 5; the chunk
+    # call's round went out from the host behind the chunk.
+    assert a["engine.round"][2] == {"active": 1, "live_tokens": 6,
+                                    "chunks_run": 0, "kv_rows_read": 2 * 48,
+                                    "ahead": True}
+    assert c["engine.round"][2] == {"active": 1, "live_tokens": 7,
+                                    "chunks_run": 1, "kv_rows_read": 2 * 48,
+                                    "ahead": False}
+    assert a["sched.deliver"][2] == c["sched.deliver"][2] == {"produced": 1}
+    assert c["engine.dispatch"][2] is None
+    assert engine._flight is None and engine.prefilling.any()
+
+
+def _round_parts(t_lo):
+    """[(attrs of engine.round, its dispatch, wait and readback records)]
+    of every round closed since ``t_lo``, oldest first."""
+    parts = {n: sorted(trace.closed("engine." + n, t_lo))
+             for n in ("dispatch", "wait", "readback")}
+    out = []
+    for t0, t1, attrs in sorted(trace.closed("engine.round", t_lo)):
+        inside = {n: [r for r in rs if t0 <= r[0] and r[1] <= t1]
+                  for n, rs in parts.items()}
+        out.append((attrs, inside))
+    return out
+
+
+@pytest.mark.parametrize("kw", [dict(), dict(page_size=0),
+                                dict(steps_per_sync=2)],
+                         ids=["paged", "monolithic", "steps2"])
+def test_quiet_rounds_are_queued_before_the_round_before_is_read(params, kw):
+    """N rounds with nothing for the host to say: the first goes out from
+    the host's registers, every other one is queued (engine.dispatch)
+    before the round before it is waited for and read, so ``rounds_ahead``
+    is N - 1 and each ``engine.round`` says which kind its round was. The
+    last call queues nothing: the budget ends in the round it reads."""
+    engine = _engine(params, **kw)
+    engine.warmup()
+    ahead0 = engine.stats["rounds_ahead"]
+    slot = engine.acquire_slot()
+    k = engine.steps_per_sync
+    t_lo = time.monotonic()
+    engine.start(slot, PROMPTS[0], max_new_tokens=1 + 6 * k)
+    n = 0
+    while engine.active[slot]:
+        engine.step()
+        n += 1
+    assert n == 6 and engine.stats["rounds_ahead"] - ahead0 == n - 1
+    rounds = _round_parts(t_lo)
+    assert [a["ahead"] for a, _ in rounds] == [False] + [True] * (n - 1)
+    assert [a["live_tokens"] for a, _ in rounds] == [
+        5 + i * k for i in range(n)]
+    # First call: this round from the host, the next ahead, then the read.
+    # Middle calls: one dispatch, before the wait. Last call: the read alone.
+    assert [len(p["dispatch"]) for _, p in rounds] == [2] + [1] * (n - 2) + [0]
+    for _, p in rounds:
+        assert len(p["wait"]) == len(p["readback"]) == 1
+        assert all(d[1] <= p["wait"][0][0] for d in p["dispatch"])
+        assert p["wait"][0][1] <= p["readback"][0][0]
+    assert engine._flight is None
+    engine.release(slot)
+
+
+def test_a_verify_round_is_never_queued_early(params):
+    """Drafts are made on the host from the tokens of the round before: an
+    engine that speculates reads every round before it queues the next."""
+    engine = _engine(params, spec_k=2)
+    engine.warmup()
+    slot = engine.acquire_slot()
+    t_lo = time.monotonic()
+    first, _ = engine.start(slot, PROMPTS[0], max_new_tokens=8)
+    toks = [first]
+    while engine.active[slot]:
+        t, v, _ = engine.step()
+        toks += [int(x) for x in t[v[:, slot], slot]]
+        assert engine._flight is None
+    assert tuple(toks) == GOLDEN[0]
+    assert engine.stats["spec_rounds"] > 0
+    assert engine.stats["rounds_ahead"] == 0
+    rounds = _round_parts(t_lo)
+    assert rounds and not any(a["ahead"] for a, _ in rounds)
+    for _, p in rounds:  # dispatch, wait, readback: once each, in that order
+        assert [len(p[n]) for n in ("dispatch", "wait", "readback")] == [1] * 3
+        assert p["dispatch"][0][1] <= p["wait"][0][0]
+    engine.release(slot)
 
 
 def test_queue_wait_and_between_rounds_on_a_fake_clock(params):

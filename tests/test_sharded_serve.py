@@ -145,6 +145,33 @@ def test_sharded_token_parity(engines, variant):
     )
 
 
+@pytest.fixture(scope="module")
+def plain_engines(params):
+    """The pair without speculation: plain decode rounds, which run ahead
+    of the host's reading (a verify round never does)."""
+    kw = dict(ENGINE_KW, spec_k=0)
+    single = SlotEngine(CFG, params, **kw)
+    single.warmup()
+    sharded = ShardedSlotEngine(CFG, params, tp=2, **kw)
+    sharded.warmup()
+    return single, sharded
+
+
+@pytest.mark.parametrize("variant", sorted(_VARIANTS))
+def test_sharded_token_parity_run_ahead(plain_engines, variant):
+    """The sharded engine queues round n+1 from round n's registers as the
+    single-device one does (replicated over its mesh, as its programs give
+    them back: no recompile, ``_drive`` asserts) and serves its tokens."""
+    single, sharded = plain_engines
+    requests = _VARIANTS[variant]
+    ahead0 = [e.stats["rounds_ahead"] for e in plain_engines]
+    assert _drive(sharded, requests) == _drive(single, requests)
+    ahead = [e.stats["rounds_ahead"] - a
+             for e, a in zip(plain_engines, ahead0)]
+    assert ahead[0] == ahead[1] > 0
+    assert sharded.stats["plain_rounds"] == single.stats["plain_rounds"]
+
+
 def test_page_accounting_matches_single_device(engines, params):
     """The pool's host-side bookkeeping must not know it is sharded:
     pages_free tracks the single engine's exactly through a churn, the
