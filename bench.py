@@ -619,14 +619,11 @@ def bench_serving() -> list[dict]:
         ServingMetrics,
         SlotEngine,
     )
-    from distributed_tensorflow_tpu.serve.kv_pool import SlotKVPool
 
     if SMOKE:
         dm, h, nl, dff, vocab = 512, 8, 4, 2048, 1024
         P, n_new, n_req, slots = 48, 32, 8, 8
         n_groups, prefix_len, page_size = 2, 32, 16
-        # One steps_per_sync: CPU dispatch is cheap and stable.
-        sync_candidates = (8,)
         dtype = jnp.float32
     else:
         if jax.default_backend() != "tpu":
@@ -636,10 +633,6 @@ def bench_serving() -> list[dict]:
         dm, h, nl, dff, vocab = 1024, 8, 8, 4096, 256
         P, n_new, n_req, slots = 128, 256, 16, 8
         n_groups, prefix_len, page_size = 4, 96, 32
-        # Per-dispatch latency swung 2.5-95 ms in the r1-r5 records;
-        # steps_per_sync is the serving config that amortizes it, so the
-        # bench picks the best of two configs rather than hard-coding one.
-        sync_candidates = (32, 128)
         dtype = jnp.bfloat16
     # Speculation is measured, never assumed: the drafter's accept rate on
     # a random-init model is low, so spec_k=0 usually wins the clock while
@@ -686,72 +679,64 @@ def bench_serving() -> list[dict]:
     best = None
     ref_tokens = None
     spec_accept = 0.0
-    for k_sync in sync_candidates:
-        for spec_k in spec_candidates:
-            engine = SlotEngine(
-                cfg, params, slots=slots, max_len=P + n_new, prefill_len=P,
-                steps_per_sync=k_sync, page_size=page_size, prefix_cache=True,
-                spec_k=spec_k,
-                # A tail-width bucket: groupmates that adopt the shared
-                # prefix prefill through a (P - prefix_len)-wide program
-                # instead of the full P-wide one — the TTFT payoff.
-                prefill_buckets=(P - prefix_len,),
+    for spec_k in spec_candidates:
+        engine = SlotEngine(
+            cfg, params, slots=slots, max_len=P + n_new, prefill_len=P,
+            page_size=page_size, prefix_cache=True, spec_k=spec_k,
+            # A tail-width bucket: groupmates that adopt the shared
+            # prefix prefill through a (P - prefix_len)-wide program
+            # instead of the full P-wide one — the TTFT payoff.
+            prefill_buckets=(P - prefix_len,),
+        )
+        compiled = engine.warmup()
+        point = None
+        for _ in range(repeats):
+            metrics = ServingMetrics()
+            sched = Scheduler(engine, max_queue_depth=n_req + 1,
+                              metrics=metrics)
+            pendings = [
+                sched.submit(Request(prompt=tuple(prompts[i]),
+                                     max_new_tokens=n_new))
+                for i in range(n_req)
+            ]
+            t0 = time.perf_counter()
+            done = sched.run_until_idle(max_steps=n_req * n_new + 16)
+            wall_s = time.perf_counter() - t0
+            assert done == n_req and all(p.done() for p in pendings)
+            recompiles = engine.compile_count() - compiled
+            assert recompiles == 0, (
+                f"serving bench recompiled after warmup "
+                f"(spec_k={spec_k}): {recompiles}"
             )
-            compiled = engine.warmup()
-            point = None
-            for _ in range(repeats):
-                metrics = ServingMetrics()
-                sched = Scheduler(engine, max_queue_depth=n_req + 1,
-                                  metrics=metrics)
-                pendings = [
-                    sched.submit(Request(prompt=tuple(prompts[i]),
-                                         max_new_tokens=n_new))
-                    for i in range(n_req)
-                ]
-                t0 = time.perf_counter()
-                done = sched.run_until_idle(max_steps=n_req * n_new + 16)
-                wall_s = time.perf_counter() - t0
-                assert done == n_req and all(p.done() for p in pendings)
-                recompiles = engine.compile_count() - compiled
-                assert recompiles == 0, (
-                    f"serving bench recompiled after warmup "
-                    f"(k_sync={k_sync} spec_k={spec_k}): {recompiles}"
-                )
-                # The fast path must not change a single token: every
-                # config (paged/prefix, with and without speculation, any
-                # sync cadence) must emit the same greedy streams.
-                tokens = [tuple(p.result(timeout=1).tokens)
-                          for p in pendings]
-                if ref_tokens is None:
-                    ref_tokens = tokens
-                assert tokens == ref_tokens, (
-                    f"greedy parity broken at k_sync={k_sync} "
-                    f"spec_k={spec_k}"
-                )
-                attempt = {
-                    "tok_s": n_req * n_new / wall_s,
-                    "k_sync": k_sync,
-                    "spec_k": spec_k,
-                    "ttft_p99_ms": metrics.ttft.percentile(99) * 1e3,
-                    "prefix_hit_rate": engine.prefix_hit_rate,
-                    "hbm_per_slot": engine.pool.hbm_bytes_per_slot,
-                }
-                if point is None or attempt["tok_s"] > point["tok_s"]:
-                    point = attempt
-            if spec_k:
-                spec_accept = max(spec_accept, engine.spec_accept_rate)
-            if best is None or point["tok_s"] > best["tok_s"]:
-                best = point
+            # The fast path must not change a single token: every
+            # config (paged/prefix, with and without speculation) must
+            # emit the same greedy streams.
+            tokens = [tuple(p.result(timeout=1).tokens)
+                      for p in pendings]
+            if ref_tokens is None:
+                ref_tokens = tokens
+            assert tokens == ref_tokens, (
+                f"greedy parity broken at spec_k={spec_k}"
+            )
+            attempt = {
+                "tok_s": n_req * n_new / wall_s,
+                "spec_k": spec_k,
+                "ttft_p99_ms": metrics.ttft.percentile(99) * 1e3,
+                "prefix_hit_rate": engine.prefix_hit_rate,
+                "hbm_per_slot": engine.pool.hbm_bytes_per_slot,
+            }
+            if point is None or attempt["tok_s"] > point["tok_s"]:
+                point = attempt
+        if spec_k:
+            spec_accept = max(spec_accept, engine.spec_accept_rate)
+        if best is None or point["tok_s"] > best["tok_s"]:
+            best = point
 
     speedup = best["tok_s"] / seq_tok_s
-    # Same-HBM framing: what the monolithic pool would spend per lane at
-    # this max_len (the paged pool allocates page-granular, shares prefix
-    # pages, and wastes at most one page per request to fragmentation).
-    mono_per_slot = SlotKVPool(cfg, slots=1, max_len=P + n_new).hbm_bytes
     shape_note = (
         f"{dm}d/{nl}L vocab {vocab}, prompt {P} ({prefix_len} shared x "
         f"{n_groups} groups) + {n_new} new x {n_req} req, {slots} slots, "
-        f"page_size {page_size}, steps_per_sync {best['k_sync']}, "
+        f"page_size {page_size}, "
         f"spec_k {best['spec_k']}, greedy"
     )
     out = [
@@ -802,8 +787,7 @@ def bench_serving() -> list[dict]:
             "value": round(best["hbm_per_slot"], 0),
             "unit": "bytes",
             "detail": (
-                f"paged pool HBM / {slots} lanes vs {mono_per_slot:,.0f} "
-                f"for a monolithic slot at max_len {P + n_new}, "
+                f"paged pool HBM / {slots} lanes at max_len {P + n_new}, "
                 f"{shape_note}"
             ),
         },
@@ -1478,7 +1462,6 @@ def bench_serving_quant() -> list[dict]:
         dm, h, nl, dff, vocab = 512, 8, 4, 2048, 1024
         P, n_new, n_req, slots = 48, 32, 8, 8
         n_groups, prefix_len, page_size = 2, 32, 16
-        k_sync = 8
         dtype = jnp.float32
     else:
         if jax.default_backend() != "tpu":
@@ -1486,7 +1469,6 @@ def bench_serving_quant() -> list[dict]:
         dm, h, nl, dff, vocab = 1024, 8, 8, 4096, 256
         P, n_new, n_req, slots = 128, 256, 16, 8
         n_groups, prefix_len, page_size = 4, 96, 32
-        k_sync = 32
         dtype = jnp.bfloat16
     gs4 = 64  # int4 group size: serving default, divides dm and dff here
 
@@ -1569,7 +1551,7 @@ def bench_serving_quant() -> list[dict]:
         qparams = quantize_lm_params(params, mode, group_size=gs)
         engine = SlotEngine(
             qcfg, qparams, slots=slots, max_len=P + n_new, prefill_len=P,
-            steps_per_sync=k_sync, page_size=page_size, prefix_cache=True,
+            page_size=page_size, prefix_cache=True,
             spec_k=0, prefill_buckets=(P - prefix_len,),
         )
         engines[mode] = (qcfg, qparams)
@@ -1614,8 +1596,8 @@ def bench_serving_quant() -> list[dict]:
             "unit": "tokens/s",
             "detail": (
                 f"{mode}{gs_note} SlotEngine on the shared-prefix burst "
-                f"({n_req} req x {n_new} new, {slots} slots, steps_per_"
-                f"sync {k_sync}, greedy); 0 recompiles after warmup and "
+                f"({n_req} req x {n_new} new, {slots} slots, "
+                f"greedy); 0 recompiles after warmup and "
                 f"repeat determinism ASSERTED in-run — informational, "
                 f"the gated claim is the speedup below"
             ),
@@ -1669,7 +1651,7 @@ def bench_serving_quant() -> list[dict]:
     draft_qparams = quantize_lm_params(draft_params, "int4", group_size=gs4)
     engine_rs = SlotEngine(
         qcfg8, qparams8, slots=slots, max_len=P + n_new, prefill_len=P,
-        steps_per_sync=k_sync, page_size=page_size, prefix_cache=True,
+        page_size=page_size, prefix_cache=True,
         spec_k=4, draft_params=draft_qparams, draft_cfg=draft_qcfg,
         prefill_buckets=(P - prefix_len,),
     )
@@ -3018,7 +3000,7 @@ def bench_hotswap() -> list[dict]:
 
     serve_cfg = ServeConfig(
         slots=slots, serve_max_len=seq_len, prefill_len=seq_len // 2,
-        steps_per_sync=1, max_queue_depth=n_req + 8,
+        max_queue_depth=n_req + 8,
     )
     deploy_cfg = DeployConfig(canary_rows=2, canary_len=12, canary_probes=1)
 

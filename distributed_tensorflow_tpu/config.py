@@ -401,10 +401,8 @@ class ServeConfig:
 
     Beyond-reference: the source demos never serve. Defaults target the
     small-LM CPU/TPU demo path; production knobs are the slot count (batch
-    capacity — more slots amortize weight reads until the KV read bound),
-    ``steps_per_sync`` (decode micro-steps fused per host round-trip —
-    raise on TPU where per-dispatch latency dominates small models), and
-    the admission pair ``max_queue_depth``/``request_timeout_s``."""
+    capacity — more slots amortize weight reads until the KV read bound)
+    and the admission pair ``max_queue_depth``/``request_timeout_s``."""
 
     host: str = field(default="127.0.0.1", metadata={"help": "bind address"})
     port: int = field(default=8000, metadata={"help": "bind port; 0 = ephemeral"})
@@ -418,13 +416,6 @@ class ServeConfig:
     prefill_len: int = field(
         default=0,
         metadata={"help": "padded prompt capacity; 0 = serve_max_len // 2"},
-    )
-    steps_per_sync: int = field(
-        default=1,
-        metadata={
-            "help": "decode micro-steps per jitted engine round (amortizes "
-            "host dispatch; tokens are delivered in bursts of this size)"
-        },
     )
     max_queue_depth: int = field(
         default=64,
@@ -476,8 +467,8 @@ class ServeConfig:
         default=-1,
         metadata={
             "help": "KV page size in tokens: -1 = auto (16 when it divides "
-            "serve_max_len, else one whole-row page), 0 = monolithic "
-            "per-slot KV (legacy layout), >0 = explicit page size"
+            "serve_max_len, else one whole-row page), >0 = explicit page "
+            "size (a divisor of serve_max_len)"
         },
     )
     kv_pages: int = field(
@@ -531,8 +522,8 @@ class ServeConfig:
     prefill_chunk_tokens: int = field(
         default=0,
         metadata={
-            "help": "chunked-prefill budget per engine iteration (paged "
-            "layout): prompts whose tail exceeds this width prefill in "
+            "help": "chunked-prefill budget per engine iteration: "
+            "prompts whose tail exceeds this width prefill in "
             "chunks interleaved with decode steps, so prompts beyond "
             "prefill_len are admissible and long prefills never stall "
             "co-resident decodes. 0 = auto (prefill_len), -1 = off "
@@ -641,7 +632,7 @@ class ServeConfig:
     @property
     def engine_page_size(self) -> int | None:
         """Resolve the ``page_size`` flag for SlotEngine: None = engine
-        auto-pick, 0 = monolithic, else the explicit value."""
+        auto-pick, else the explicit value (the engine refuses 0)."""
         return None if self.page_size < 0 else self.page_size
 
     def validate_mesh(self, model_cfg) -> None:

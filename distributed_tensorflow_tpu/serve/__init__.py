@@ -22,7 +22,6 @@ from distributed_tensorflow_tpu.serve.engine import (
     ShardedSlotEngine,
     SlotEngine,
 )
-from distributed_tensorflow_tpu.serve.kv_pool import SlotKVPool
 from distributed_tensorflow_tpu.serve.metrics import Histogram, ServingMetrics
 from distributed_tensorflow_tpu.serve.scheduler import (
     Completion,
@@ -34,7 +33,6 @@ from distributed_tensorflow_tpu.serve.scheduler import (
 __all__ = [
     "SlotEngine",
     "ShardedSlotEngine",
-    "SlotKVPool",
     "Histogram",
     "ServingMetrics",
     "Request",

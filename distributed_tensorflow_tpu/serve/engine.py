@@ -1,25 +1,19 @@
 """Continuous-batching decode engine: fixed shapes, zero recompiles.
 
-The engine runs one of two KV layouts behind the same slot API:
-
-* ``page_size=0`` — the PR-4 monolithic layout: per-slot worst-case rows
-  in a :class:`~distributed_tensorflow_tpu.serve.kv_pool.SlotKVPool`.
-  Kept verbatim as the parity baseline.
-
-* ``page_size>0`` (default) — the paged layout: one physical page pool
-  (:class:`~distributed_tensorflow_tpu.serve.kv_pool.PagedKVPool`) plus
-  per-slot page tables. The table is a host numpy array passed as a
-  TRACED operand of fixed shape ``(slots, pages_per_slot)``, so rebinding
-  pages never retraces; unbound entries point at the reserved trash page,
-  which absorbs the fixed-shape writes of masked lanes. The prefill, chunk
-  and verify programs gather a slot's logical ``(kv, max_len, dh)`` cache
-  from its table row, run the SAME model code as the monolithic path, and
-  scatter touched pages back. The plain decode program does that only
-  where its kernel does not fit (``decode_path``, fixed at construction by
-  :meth:`SlotEngine._decode_path` from the pool's leaf kinds and dtype,
-  the page size, the head size and the engine class's hook — no config
-  field or flag): where it fits, decode attends THROUGH the table and no
-  logical cache is materialised.
+The engine has one KV layout: one physical page pool
+(:class:`~distributed_tensorflow_tpu.serve.kv_pool.PagedKVPool`) plus
+per-slot page tables. The table is a host numpy array passed as a TRACED
+operand of fixed shape ``(slots, pages_per_slot)``, so rebinding pages
+never retraces; unbound entries point at the reserved trash page, which
+absorbs the fixed-shape writes of masked lanes. The prefill, chunk and
+verify programs gather a slot's logical ``(kv, max_len, dh)`` cache from
+its table row, run the model's B=1 cached forward on it, and scatter
+touched pages back. The plain decode program has two ways to reach K and
+V (``decode_path``, fixed at construction by
+:meth:`SlotEngine._decode_path` from the pool's leaf kinds and dtype, the
+page size, the head size and the engine class's hook — no config field or
+flag): where its kernel fits, decode attends THROUGH the table and no
+logical cache is materialised; elsewhere it gathers like the others.
 
 Jitted programs (all compiled at :meth:`SlotEngine.warmup`, after which
 the compile count must never grow — the ``RecompileSentinel`` contract):
@@ -28,15 +22,17 @@ the compile count must never grow — the ``RecompileSentinel`` contract):
   prompt where ``width`` is the narrowest compiled bucket (a fixed set,
   ``prefill_buckets``, largest always ``prefill_len``) holding the real
   tokens, then the request's FIRST token sampled at its true last prompt
-  position. Under paging the forward starts at cache ``len = m0`` where
+  position. The forward starts at cache ``len = m0`` where
   ``m0`` tokens of KV were ADOPTED from the prefix cache (copy-free page
   sharing) — only the prompt TAIL is computed, through a tail-sized
   bucket, which is what collapses TTFT for shared-system-prompt traffic.
 
-* **decode step** (``step_fn``) — ``steps_per_sync`` micro-steps over the
-  whole slot batch fused into one ``lax.scan``; per-slot traced lengths,
-  per-slot sampling (``sample_logits_batched``), inactive lanes masked.
-  On the paged pool a micro-step is one of two forwards. ``"table"``: all
+* **decode step** (``step_fn``) — ONE micro-step over the whole slot
+  batch a dispatch (run-ahead, :meth:`SlotEngine.step`, hides the host's
+  round behind the device; nothing fuses several steps into a dispatch);
+  per-slot traced lengths, per-slot sampling
+  (``sample_logits_batched``), inactive lanes masked. The micro-step is
+  one of two forwards. ``"table"``: all
   slots as one batch; per layer the new K and V ROW goes straight to
   ``(page_tables[slot, length // page_size], :, length % page_size, :)``
   (a masked lane's to the trash page) and the Pallas kernel
@@ -84,9 +80,9 @@ the compile count must never grow — the ``RecompileSentinel`` contract):
   accepted branch's KV block is compacted onto the slot's canonical
   timeline inside the program before the page scatter.
 
-* **chunked prefill** (``prefill_chunk_tokens > 0``, paged only) — a
+* **chunked prefill** (``prefill_chunk_tokens > 0``) — a
   prompt whose post-adoption tail exceeds the chunk width is fed across
-  ENGINE ITERATIONS instead of one monolithic forward: full-width
+  ENGINE ITERATIONS instead of one forward: full-width
   intermediate chunks through the SAME compiled bucket programs (their
   sampled token is discarded), then one suffix-aligned final chunk whose
   fed window ends exactly at position ``p-1`` so the first token is
@@ -134,9 +130,8 @@ slot that has filled a window is ROLLED on the host (``_roll_window``, span
 ``engine.window_roll``). ``stats`` counts ``eva_windows_rolled``,
 ``eva_window_pages_released`` and ``eva_summary_pages_adopted``, and a
 round's record carries ``summary_rows_read`` / ``window_rows_read``. Slot
-export/import, speculation, ``steps_per_sync > 1``, chunking off, the
-monolithic pool and the sharded engine refuse such a config
-(``EvaUnsupported``).
+export/import, speculation, chunking off and the sharded engine refuse
+such a config (``EvaUnsupported``).
 
 Tracing (``obs/trace.py``; always on, no switch): the host side of a round
 closes ``engine.round`` around ``engine.prefill_chunk`` (one per chunk
@@ -187,7 +182,6 @@ from distributed_tensorflow_tpu.models.decoding import (
     build_draft_fn,
     decode_step,
     filter_logits_batched,
-    init_cache,
     propose_ngram_drafts,
     propose_ngram_tree,
     rejection_verify_row,
@@ -205,7 +199,6 @@ from distributed_tensorflow_tpu.serve.kv_pool import (
     InsufficientPages,
     PagedKVPool,
     PrefixCache,
-    SlotKVPool,
 )
 
 __all__ = ["SlotEngine", "ShardedSlotEngine"]
@@ -235,10 +228,10 @@ class SlotEngine:
     Scheduler` (request queue + admission control) or directly:
     ``acquire_slot`` → ``start`` (prefill, returns the first token) →
     repeated ``step`` (one batch round; token count varies — plain rounds
-    yield ``steps_per_sync`` rows, speculative rounds up to ``spec_k+1``)
+    yield one row, speculative rounds up to ``spec_k+1``)
     → ``release``. Single-threaded by contract: one thread owns the
-    engine. ``start`` raises :class:`InsufficientPages` when the paged
-    pool cannot back the request right now — release the slot and retry
+    engine. ``start`` raises :class:`InsufficientPages` when the pool
+    cannot back the request right now — release the slot and retry
     once in-flight requests free pages.
     """
 
@@ -250,7 +243,6 @@ class SlotEngine:
         slots: int = 4,
         max_len: int | None = None,
         prefill_len: int | None = None,
-        steps_per_sync: int = 1,
         sentinel=None,
         page_size: int | None = None,
         kv_pages: int = 0,
@@ -273,16 +265,19 @@ class SlotEngine:
             raise ValueError(
                 f"prefill_len {prefill_len} outside [1, max_len {max_len}]"
             )
-        if steps_per_sync < 1:
-            raise ValueError(f"steps_per_sync must be >= 1, got {steps_per_sync}")
         if page_size is None:
-            # Default to paging; degrade to one whole-row page per slot
-            # when 16 doesn't divide max_len rather than erroring.
+            # One whole-row page per slot when 16 doesn't divide max_len,
+            # rather than erroring.
             page_size = 16 if max_len % 16 == 0 else max_len
+        if page_size <= 0:
+            # Arrives from outside (--page_size, a ServeConfig file).
+            raise ValueError(
+                f"page_size must be a positive divisor of max_len "
+                f"{max_len} (or None: 16 where it divides max_len, else "
+                f"one page of max_len), got {page_size}"
+            )
         if spec_k < 0:
             raise ValueError(f"spec_k must be >= 0, got {spec_k}")
-        if spec_k and not page_size:
-            raise ValueError("spec_k > 0 requires the paged KV layout")
         spec_branches = int(spec_branches)
         if spec_branches < 1:
             raise ValueError(
@@ -309,17 +304,11 @@ class SlotEngine:
         if self._eva:
             # What is not extended to the composed table refuses here, by
             # name, rather than serving something else.
-            if not page_size:
-                raise EvaUnsupported("an EVA config needs the paged KV layout")
             if spec_k:
                 raise EvaUnsupported(
                     "speculation (spec_k > 0, tree or linear) is not "
                     "extended to EVA: a rejected draft would have to roll "
                     "back summaries and window rolls")
-            if steps_per_sync != 1:
-                raise EvaUnsupported(
-                    "an EVA config needs steps_per_sync == 1: a window "
-                    "rolls on the host between rounds")
             if getattr(self, "tp", 1) > 1:
                 raise EvaUnsupported("ShardedSlotEngine has no EVA path")
             c = int(prefill_chunk_tokens) or prefill_len
@@ -342,9 +331,7 @@ class SlotEngine:
         self.slots = int(slots)
         self.max_len = max_len
         self.prefill_len = prefill_len
-        self.steps_per_sync = int(steps_per_sync)
         self.page_size = int(page_size)
-        self.paged = self.page_size > 0
         self.spec_k = int(spec_k)
         self.spec_branches = spec_branches
         # Positions a verify round writes above each slot's length: the
@@ -355,16 +342,16 @@ class SlotEngine:
             if spec_branches > 1
             else self.spec_k + 1
         )
-        # Prefill width buckets (paged only): the prefill program is
-        # shape-polymorphic in its tokens width, so a FIXED set of widths
-        # is just a fixed set of compiled programs — warmup compiles every
-        # member and the zero-recompile invariant is untouched. A request
+        # Prefill width buckets: the prefill program is shape-polymorphic
+        # in its tokens width, so a FIXED set of widths is just a fixed set
+        # of compiled programs — warmup compiles every member and the
+        # zero-recompile invariant is untouched. A request
         # whose post-adoption tail fits a narrow bucket prefills through
         # it instead of paying the full prefill_len-wide forward; this is
         # what turns prefix-cache hits into TTFT wins (without buckets the
         # padded tail costs the same compute as a cold prompt). The
         # largest bucket is always prefill_len — the cold-prompt path.
-        buckets = {int(b) for b in prefill_buckets} if self.paged else set()
+        buckets = {int(b) for b in prefill_buckets}
         for b in buckets:
             if not 1 <= b <= prefill_len:
                 raise ValueError(
@@ -372,7 +359,7 @@ class SlotEngine:
                     f"{prefill_len}]"
                 )
         buckets.add(prefill_len)
-        # Chunked prefill (paged only): 0 = auto (chunk width =
+        # Chunked prefill: 0 = auto (chunk width =
         # prefill_len, i.e. prompts up to prefill_len keep the one-shot
         # path byte-for-byte and only LONGER prompts chunk), -1 = off
         # (prefill_len stays a hard prompt cap, the pre-chunking
@@ -380,7 +367,7 @@ class SlotEngine:
         # set — the one-shot path never sees a tail wider than the chunk
         # once chunking is on, so they would be dead compiled programs.
         c = int(prefill_chunk_tokens)
-        if self.paged and c >= 0:
+        if c >= 0:
             if c == 0:
                 c = prefill_len
             if not 1 <= c <= prefill_len:
@@ -439,12 +426,8 @@ class SlotEngine:
         if not hasattr(self, "tp"):
             self.tp = 1
             self.mesh = None
-        if self.paged:
-            self.pool = self._build_pool(cfg, max_len, kv_pages)
-            self.prefix = PrefixCache(self.pool) if prefix_cache else None
-        else:
-            self.pool = SlotKVPool(cfg, self.slots, max_len)
-            self.prefix = None
+        self.pool = self._build_pool(cfg, max_len, kv_pages)
+        self.prefix = PrefixCache(self.pool) if prefix_cache else None
         self.decode_path = self._decode_path()
 
         # Per-slot host registers. Fixed dtypes — the jit signatures (and
@@ -512,10 +495,10 @@ class SlotEngine:
         self._touched = np.zeros(n, bool)
         self._dev_consts: tuple = ()
 
-        model, k_sync = self.model, self.steps_per_sync
-        ps, pps = self.page_size, getattr(self.pool, "pages_per_slot", 0)
+        model = self.model
+        ps, pps = self.page_size, self.pool.pages_per_slot
 
-        # -- paged layout plumbing ---------------------------------------
+        # -- page plumbing -----------------------------------------------
         # A slot's logical cache is the gather of its table row; the
         # inverse reshape splits a logical buffer back into pages. Both
         # are layout-generic over the cache leaf kinds (k/v rows
@@ -541,23 +524,6 @@ class SlotEngine:
                 }
 
         def make_prefill(sampled: bool):
-            if not self.paged:
-
-                def prefill_fn(params, tokens, length, temp, top_k, top_p, seed):
-                    """(1, prefill_len) padded prompt → (fresh (1, max_len)
-                    cache layers, first sampled token). ``length`` is the
-                    true prompt length (traced — heterogeneous prompts
-                    share the compile)."""
-                    cache = init_cache(cfg, 1, max_len)
-                    logits, cache = model.apply(
-                        {"params": params}, tokens, cache=cache
-                    )
-                    last = jnp.take(logits[0], length - 1, axis=0)  # (V,)
-                    first = _select(sampled, last, temp, top_k, top_p, seed)
-                    return cache["layers"], first
-
-                return prefill_fn
-
             def prefill_fn(
                 pool_layers, params, tokens, length, prefix_len, row,
                 temp, top_k, top_p, seed,
@@ -661,62 +627,6 @@ class SlotEngine:
                 return jnp.argmax(last).astype(jnp.int32)
 
         def make_step(sampled: bool):
-            if not self.paged:
-
-                def step_fn(
-                    params, layers, active, lengths, tok,
-                    temp, top_k, top_p, seed, made, budget, eos,
-                ):
-                    """One engine round = ``steps_per_sync`` scanned
-                    micro-steps. Returns the new pool/registers plus
-                    ``(k, slots)`` sampled tokens and their validity mask
-                    (a slot's tokens are valid while it was active at
-                    sampling time — the final token of a finishing slot is
-                    valid, the masked lanes after it are not)."""
-
-                    def one(slot_layers, length, t):
-                        cache = {
-                            "layers": [
-                                {k: v[None] for k, v in l.items()}
-                                for l in slot_layers
-                            ],
-                            "len": length,
-                        }
-                        cache, logits = decode_step(
-                            model, params, cache, t[None, None]
-                        )
-                        out_layers = [
-                            {k: v[0] for k, v in l.items()}
-                            for l in cache["layers"]
-                        ]
-                        return out_layers, logits[0]
-
-                    def micro(carry, _):
-                        layers, active, lengths, tok, made = carry
-                        layers, logits = jax.vmap(one)(layers, lengths, tok)
-                        nxt = _pick(sampled, logits, seed, made,
-                                    temp, top_k, top_p)
-                        nxt = jnp.where(active, nxt, tok)
-                        new_lengths = jnp.where(active, lengths + 1, lengths)
-                        new_made = jnp.where(active, made + 1, made)
-                        finished = active & (
-                            (new_made >= budget) | (nxt == eos)
-                        )
-                        return (
-                            (layers, active & ~finished, new_lengths, nxt,
-                             new_made),
-                            (nxt, active),
-                        )
-
-                    carry, (toks, valid) = jax.lax.scan(
-                        micro, (layers, active, lengths, tok, made), None,
-                        length=k_sync,
-                    )
-                    layers, active, lengths, tok, made = carry
-                    return layers, active, lengths, tok, made, toks, valid
-
-                return step_fn
-
             def gather_forward(pool_layers, ptabs, active, lengths, tok, params):
                 """Each slot gathers its logical cache from its table row,
                 appends one token, and scatters back only the single page
@@ -812,40 +722,33 @@ class SlotEngine:
                 pool_layers, params, ptabs, active, lengths, tok,
                 temp, top_k, top_p, seed, made, budget, eos,
             ):
-                """Paged decode round. Identical control flow to the
-                monolithic variant; each micro-step runs the engine's one
+                """One decode round: one micro-step of the engine's one
                 ``forward`` over the pool (``decode_path``: through the
-                page table, or by gathering every slot's logical cache)."""
-
-                def micro(carry, _):
-                    pool_layers, active, lengths, tok, made = carry
-                    pool_layers, logits = forward(
-                        pool_layers, ptabs, active, lengths, tok, params
-                    )
-                    nxt = _pick(sampled, logits, seed, made,
-                                temp, top_k, top_p)
-                    nxt = jnp.where(active, nxt, tok)
-                    new_lengths = jnp.where(active, lengths + 1, lengths)
-                    new_made = jnp.where(active, made + 1, made)
-                    finished = active & ((new_made >= budget) | (nxt == eos))
-                    return (
-                        (pool_layers, active & ~finished, new_lengths, nxt,
-                         new_made),
-                        (nxt, active),
-                    )
-
-                carry, (toks, valid) = jax.lax.scan(
-                    micro, (pool_layers, active, lengths, tok, made), None,
-                    length=k_sync,
+                page table, or by gathering every slot's logical cache).
+                Returns the new pool and registers plus the ``(1, slots)``
+                sampled tokens and their validity mask (a slot's token is
+                valid where it was active at sampling time: the final
+                token of a finishing slot is valid). The leading axis is
+                :meth:`SlotEngine.step`'s row axis: a verify round fills
+                more than one row."""
+                pool_layers, logits = forward(
+                    pool_layers, ptabs, active, lengths, tok, params
                 )
-                pool_layers, active, lengths, tok, made = carry
-                return pool_layers, active, lengths, tok, made, toks, valid
+                nxt = _pick(sampled, logits, seed, made, temp, top_k, top_p)
+                nxt = jnp.where(active, nxt, tok)
+                new_lengths = jnp.where(active, lengths + 1, lengths)
+                new_made = jnp.where(active, made + 1, made)
+                finished = active & ((new_made >= budget) | (nxt == eos))
+                return (
+                    pool_layers, active & ~finished, new_lengths, nxt,
+                    new_made, nxt[None], active[None],
+                )
 
             return step_fn
 
         def _pick(sampled, logits, seed, made, temp, top_k, top_p):
             with jax.named_scope("sample"):
-                if sampled:
+                if sampled:  # dttlint: disable=jit-purity -- static program-variant flag, as in _select
                     keys = jax.vmap(
                         lambda s, m: jax.random.fold_in(
                             jax.random.PRNGKey(s), m)
@@ -1110,7 +1013,7 @@ class SlotEngine:
         # not pay them. Plus the speculative verify program for all-greedy
         # rounds when spec_k > 0. Still a fixed set: warmup compiles every
         # member, and the compile-count assert covers the lot.
-        donate = (0,) if self.paged else ()
+        donate = (0,)  # the pool's leaves, through every program
         prefill_of = make_eva_prefill if self._eva else make_prefill
         self._prefill_greedy = self._jit_program(
             prefill_of(False), "prefill", donate
@@ -1118,12 +1021,11 @@ class SlotEngine:
         self._prefill_sampled = self._jit_program(
             prefill_of(True), "prefill", donate
         )
-        step_donate = (0,) if self.paged else (1,)
         self._step_greedy = self._jit_program(
-            make_step(False), "step", step_donate
+            make_step(False), "step", donate
         )
         self._step_sampled = self._jit_program(
-            make_step(True), "step", step_donate
+            make_step(True), "step", donate
         )
         # Tree mode (spec_branches > 1) REPLACES the linear verify
         # programs — a round is either linear or tree for an engine's
@@ -1174,17 +1076,14 @@ class SlotEngine:
         fixed here once by what the engine can see of its own pool:
         ``"table"`` — attention reads the pages where they lie, through the
         page table, up to each slot's live length
-        (``ops.attention.paged_decode_attention``) — when the pool is paged,
-        its leaves are plain ``k`` / ``v`` rows (an int8 pool carries scale
+        (``ops.attention.paged_decode_attention``) — when the pool's
+        leaves are plain ``k`` / ``v`` rows (an int8 pool carries scale
         leaves the kernel does not read) and the kernel takes their shape
         (``ops.attention.paged_decode_fits``: a page a whole number of the
         leaf dtype's sublane tiles, 16 rows of bf16 or 8 of f32, the head
         size a whole number of lanes); ``"gather"`` — every slot's logical
-        cache is gathered from its table row (the monolithic pool reads
-        each slot's whole row where it lies) — everywhere else. The
+        cache is gathered from its table row — everywhere else. The
         prefill, chunk and verify programs gather on either path."""
-        if not self.paged:
-            return "gather"
         leaves = self.pool.layers[0]
         if self._eva:
             # Always through the table: the composed row IS the cache.
@@ -1200,8 +1099,8 @@ class SlotEngine:
         round over the ``act`` slots at ``lengths`` reads (the coming round
         from the host registers where they are left out; ``spec``: whether
         it is a verify round). It counts for
-        three layouts: a verify round and the gather path (monolithic
-        pool, int8 pages, the sharded engine) read every slot's whole row;
+        three cases: a verify round and the gather path (int8 pages,
+        off-tile shapes, the sharded engine) read every slot's whole row;
         the table path over the plain layout reads the live pages of the
         active slots, less those a sliding window skips; the table path
         over EVA's composed rows reads the pages that hold each active
@@ -1256,19 +1155,14 @@ class SlotEngine:
         when chunked prefill is off; with it on, any prompt that leaves
         room for one generated token fits (p + max_new <= max_len is
         validated separately)."""
-        if self.paged and self.prefill_chunk_tokens > 0:
+        if self.prefill_chunk_tokens > 0:
             return self.max_len - 1
         return self.prefill_len
 
     @property
-    def pages_free(self) -> int | None:
-        return self.pool.pages_free if self.paged else None
-
-    @property
     def utilization(self) -> float:
-        """Capacity in use, in the layout's native unit: PAGE occupancy
-        under paging (the unit admission is actually gated on), slot
-        occupancy for the monolithic layout."""
+        """Capacity in use: PAGE occupancy, the unit admission is gated
+        on."""
         return self.pool.occupancy
 
     @property
@@ -1359,10 +1253,10 @@ class SlotEngine:
 
         Returns ``(first_token, finished)``; a request that is already done
         after one token (budget 1, or the first token is its eos) comes
-        back ``finished=True`` and the caller releases the slot. Under
-        paging, raises :class:`InsufficientPages` (slot untouched, no
-        references leaked) when the pool cannot back the request even
-        after evicting prefix-cache entries.
+        back ``finished=True`` and the caller releases the slot. Raises
+        :class:`InsufficientPages` (slot untouched, no references leaked)
+        when the pool cannot back the request even after evicting
+        prefix-cache entries.
 
         When the post-adoption tail exceeds the chunk width (possible only
         with chunked prefill enabled), no forward runs here: the slot
@@ -1400,14 +1294,8 @@ class SlotEngine:
                 np.uint32(seed),
             )
             eos = -1 if eos_id is None else int(eos_id)
-            if self.paged:
-                first = self._start_paged(slot, prompt, p, max_new_tokens,
-                                          prefill, sargs, sampled)
-            else:
-                padded = np.zeros((1, self.prefill_len), np.int32)
-                padded[0, :p] = prompt
-                new_layers, first = prefill(self.params, padded, np.int32(p), *sargs)
-                self.pool.adopt(slot, new_layers)
+            first = self._bind_and_prefill(
+                slot, prompt, p, max_new_tokens, prefill, sargs, sampled)
             # Registers shared by both outcomes (immediate first token vs
             # PREFILLING): sampling params and limits are fixed at admission.
             self._touched[slot] = True
@@ -1418,8 +1306,8 @@ class SlotEngine:
             self.budget[slot] = max_new_tokens
             self.eos[slot] = eos
             if first is None:
-                # Chunked path scheduled by _start_paged; pages are all bound,
-                # chunks spend across subsequent step() calls.
+                # Chunked path scheduled by _bind_and_prefill; pages are all
+                # bound, chunks spend across subsequent step() calls.
                 self.active[slot] = False
                 self.lengths[slot] = 0
                 self.made[slot] = 0
@@ -1447,7 +1335,8 @@ class SlotEngine:
                     chunks=0)
             return first, finished
 
-    def _start_paged(self, slot, prompt, p, max_new, prefill, sargs, sampled):
+    def _bind_and_prefill(self, slot, prompt, p, max_new, prefill, sargs,
+                          sampled):
         """Page allocation + prefix adoption + tail prefill for one slot.
         Returns the first token, or ``None`` when the tail exceeds every
         bucket and a chunked-prefill plan was scheduled instead."""
@@ -1776,13 +1665,12 @@ class SlotEngine:
         """One batch round over every slot.
 
         Returns ``(tokens (k, slots) int32, valid (k, slots) bool,
-        done (slots,) bool)`` — ``k`` is ``steps_per_sync`` for plain
-        rounds and ``spec_k + 1`` for speculative rounds (callers already
-        iterate rows under the valid mask, so the burst size is opaque to
-        them). ``done`` marks slots that finished during this round — the
-        caller collects their output and ``release``s them, which is what
-        lets the NEXT round admit replacements (iteration-level
-        batching).
+        done (slots,) bool)`` — ``k`` is 1 for plain rounds and
+        ``spec_k + 1`` for speculative rounds (callers iterate rows under
+        the valid mask, so the burst size is opaque to them). ``done``
+        marks slots that finished during this round — the caller collects
+        their output and ``release``s them, which is what lets the NEXT
+        round admit replacements (iteration-level batching).
 
         With chunked prefill in flight, each call first spends one
         iteration's prefill budget (PREFILLING slots advance one or more
@@ -1912,7 +1800,7 @@ class SlotEngine:
         if self._drafts_on_host or self.prefilling.any():
             return False
         act = self.active
-        if not (act & (self.made + self.steps_per_sync < self.budget)).any():
+        if not (act & (self.made + 1 < self.budget)).any():
             return False
         return not (self._eva and (
             (self.lengths[act] + 1) % self.pool.window == 0).any())
@@ -1941,24 +1829,20 @@ class SlotEngine:
             else:
                 self.stats["plain_rounds"] += 1
                 if prev is None:
-                    consts = [self.temp, self.top_k, self.top_p, self.seed,
-                              self.budget, self.eos]
-                    if self.paged:
-                        consts.append(self.pool.page_tables)
+                    consts = (self.temp, self.top_k, self.top_p, self.seed,
+                              self.budget, self.eos, self.pool.page_tables)
                     active, length, tok, made, *self._dev_consts = self._put(
                         (was_active, lengths, self.cur_tok.copy(),
                          self.made.copy(), *(np.array(c) for c in consts)))
                 else:
                     self.stats["rounds_ahead"] += 1
                     active, length, tok, made = prev.out[:4]
-                temp, top_k, top_p, seed, budget, eos, *ptabs = (
+                temp, top_k, top_p, seed, budget, eos, ptabs = (
                     self._dev_consts)
                 step = self._step_sampled if any_sampled else self._step_greedy
-                lead = ((self.pool.layers, self.params, *ptabs) if self.paged
-                        else (self.params, self.pool.layers))
                 layers, *out = step(
-                    *lead, active, length, tok, temp, top_k, top_p, seed,
-                    made, budget, eos,
+                    self.pool.layers, self.params, ptabs, active, length,
+                    tok, temp, top_k, top_p, seed, made, budget, eos,
                 )
             # The pool is donated through: whatever is queued next (a
             # prefill chunk, the next round) takes this round's output.
@@ -2189,7 +2073,7 @@ class SlotEngine:
                             self.release(slot)
             finally:
                 self.prefix = prefix
-            if self.paged and 0 < self.prefill_chunk_tokens < self.max_len - 1:
+            if 0 < self.prefill_chunk_tokens < self.max_len - 1:
                 # One chunked prompt per sampling variant, driven through
                 # step() to completion (budget 1 finishes at the final chunk).
                 p_long = min(self.prefill_chunk_tokens + 1, self.max_len - 1)
@@ -2406,8 +2290,6 @@ class SlotEngine:
         only once the peer acknowledged the import (fallback to local
         decode otherwise, so no request is ever lost)."""
         self._refuse_eva_handoff()
-        if not self.paged:
-            raise RuntimeError("slot handoff requires the paged KV layout")
         if self.prefilling[slot]:
             raise RuntimeError(f"slot {slot} is mid-chunked-prefill")
         if not self.active[slot]:
@@ -2442,8 +2324,6 @@ class SlotEngine:
         and the same exporter-keeps-the-slot contract as
         :meth:`export_slot`."""
         self._refuse_eva_handoff()
-        if not self.paged:
-            raise RuntimeError("slot handoff requires the paged KV layout")
         if self.prefilling[slot]:
             raise RuntimeError(f"slot {slot} is mid-chunked-prefill")
         if not self.active[slot]:
@@ -2496,10 +2376,8 @@ class SlotEngine:
 
     def validate_handoff_header(self, bundle: dict) -> None:
         """Typed pre-import validation (page geometry, KV format, length
-        headroom) — shared by the monolithic and staged import paths, and
+        headroom) — shared by the whole-bundle and staged import paths, and
         cheap enough for a receiver to run BEFORE reading page bytes."""
-        if not self.paged:
-            raise RuntimeError("slot handoff requires the paged KV layout")
         if bundle["page_size"] != self.page_size:
             raise ValueError(
                 f"handoff page_size {bundle['page_size']} != engine "
@@ -2573,7 +2451,7 @@ class ShardedSlotEngine(SlotEngine):
     rewrite: XLA partitions the matmuls along the annotated dims and
     inserts the collectives, and the emitted TOKENS are identical to the
     single-device engine (asserted by the sharded_serve parity tests and
-    in ``bench_serving_sharded``). Requires the paged KV layout.
+    in ``bench_serving_sharded``).
     """
 
     def __init__(
@@ -2612,12 +2490,6 @@ class ShardedSlotEngine(SlotEngine):
             validate_weight_quant(
                 cfg.weight_dtype, cfg.quant_group_size, cfg.d_model,
                 cfg.d_ff, tp=tp,
-            )
-        page_size = kw.get("page_size")
-        if page_size is not None and page_size <= 0:
-            raise ValueError(
-                "ShardedSlotEngine requires the paged KV layout "
-                f"(page_size > 0), got page_size={page_size}"
             )
         devices = list(devices) if devices is not None else list(jax.devices())
         if len(devices) < tp:
@@ -2668,7 +2540,7 @@ class ShardedSlotEngine(SlotEngine):
 
     def _jit_program(self, fn, kind, donate):
         """Jit under the mesh with explicit in/out shardings per program
-        kind. Arg layouts are the paged ones (position 0 = pool layers,
+        kind. Arg layouts are the base engine's (position 0 = pool layers,
         position 1 = params, everything after is a replicated host
         register); the pool position takes ONE sharding as a pytree
         prefix for all its leaves."""

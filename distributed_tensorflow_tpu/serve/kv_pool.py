@@ -1,30 +1,24 @@
-"""KV storage for the serving engine: slot-pooled and block-paged layouts.
+"""KV storage for the serving engine: the block-paged pool.
 
-Two pool classes share the engine-facing bookkeeping contract
-(``alloc``/``free``/``num_free``/``occupancy``):
-
-* :class:`SlotKVPool` — the PR-4 monolithic layout: per-layer K/V buffers
-  shaped ``(slots, kv_heads, max_len, head_dim)``, one worst-case row per
-  slot. Kept as the ``page_size=0`` engine mode and the parity baseline.
-
-* :class:`PagedKVPool` — the vLLM PagedAttention layout: ONE physical pool
-  of fixed-size pages per layer, shaped ``(num_pages, kv_heads, page_size,
-  head_dim)``, plus a host-side per-slot page table ``(slots,
-  pages_per_slot)`` of physical page ids. A slot's logical ``(kv, max_len,
-  dh)`` cache is the gather of its table row; capacity is PAGES-free, not
-  slots-free, so short requests stop reserving worst-case HBM and the same
-  pool admits more concurrent requests. Physical page 0 is a reserved
-  TRASH page: unbound table entries point at it, masked/inactive lanes
-  scatter into it, and nothing ever reads it — which is what lets every
-  jitted program keep fixed shapes (full-width table rows, full-width
-  scatters) with zero recompiles.
+:class:`PagedKVPool` is the vLLM PagedAttention layout: ONE physical pool
+of fixed-size pages per layer, shaped ``(num_pages, kv_heads, page_size,
+head_dim)``, plus a host-side per-slot page table ``(slots,
+pages_per_slot)`` of physical page ids, and the slot free list
+(``alloc``/``free``/``num_free``/``occupancy``). A slot's logical ``(kv,
+max_len, dh)`` cache is the gather of its table row; capacity is
+PAGES-free, not slots-free, so short requests reserve no worst-case HBM
+and the same pool admits more concurrent requests. Physical page 0 is a
+reserved TRASH page: unbound table entries point at it, masked/inactive
+lanes scatter into it, and nothing ever reads it — which is what lets
+every jitted program keep fixed shapes (full-width table rows, full-width
+scatters) with zero recompiles.
 
 Pages are REFCOUNTED so immutable full-prompt pages can be shared between
 slots (and held by the :class:`PrefixCache`): a slot's allocation holds one
 reference, prefix adoption adds one per adopting slot, and the cache holds
 one of its own. A page returns to the free list only at refcount zero.
-Safety of sharing rests on the same overwrite invariant the monolithic
-layout relies on (see ``engine.py``): decode writes start at the filled
+Safety of sharing rests on the overwrite invariant of slot reuse (see
+``engine.py``): decode writes start at the filled
 length ``p`` (strictly above every full prompt page), so a shared page is
 written only with byte-identical content (the prefill program's
 whole-row scatter-back, which round-trips the gathered values).
@@ -87,7 +81,6 @@ import numpy as np
 from distributed_tensorflow_tpu.models.decoding import init_cache
 
 __all__ = [
-    "SlotKVPool",
     "PagedKVPool",
     "PrefixCache",
     "InsufficientPages",
@@ -119,111 +112,6 @@ class InsufficientPages(RuntimeError):
     scheduler requeues the request at the head of its lane — pages free as
     in-flight requests complete, so progress is guaranteed (every active
     request holds ALL its pages up front; nothing allocates mid-decode)."""
-
-
-class SlotKVPool:
-    """Fixed-capacity pooled KV buffers + free-slot bookkeeping.
-
-    ``layers`` is the live device pytree (list of per-layer dicts with
-    leading ``slots`` axis). The jitted mutators donate it, so holders of a
-    stale reference are invalidated — always read ``pool.layers`` fresh.
-    Host-side per-slot state (filled lengths, sampling params) lives in the
-    engine; the pool owns only the big buffers and the free list.
-    """
-
-    def __init__(self, cfg, slots: int, max_len: int):
-        if slots < 1:
-            raise ValueError(f"slots must be >= 1, got {slots}")
-        if max_len < 2:
-            raise ValueError(f"max_len must be >= 2, got {max_len}")
-        self.cfg = cfg
-        self.slots = int(slots)
-        self.max_len = int(max_len)
-        self.layers = init_cache(cfg, slots, max_len)["layers"]
-        # LIFO reuse: the most recently freed slot's buffers are the most
-        # likely to still be resident in any cache hierarchy. The companion
-        # set keeps free/double-free checks O(1) under high churn (the old
-        # `slot in list` scan was O(slots) per free).
-        self._free: list[int] = list(range(slots - 1, -1, -1))
-        self._free_set: set[int] = set(self._free)
-
-        def adopt_fn(layers, slot, new_layers):
-            # new_layers leaves are (1, kv, max_len, dh) — a single-request
-            # prefill cache; strip the unit batch dim and scatter into the
-            # pool row. Donating `layers` lets XLA write the pool in place.
-            return jax.tree_util.tree_map(
-                lambda pool, new: pool.at[slot].set(new[0]), layers, new_layers
-            )
-
-        def reset_fn(layers, slot):
-            return jax.tree_util.tree_map(
-                lambda pool: pool.at[slot].set(0), layers
-            )
-
-        self._adopt = jax.jit(adopt_fn, donate_argnums=(0,))
-        self._reset = jax.jit(reset_fn, donate_argnums=(0,))
-
-    # -- host-side bookkeeping -------------------------------------------
-
-    @property
-    def num_free(self) -> int:
-        return len(self._free)
-
-    @property
-    def occupancy(self) -> float:
-        return 1.0 - len(self._free) / self.slots
-
-    @property
-    def hbm_bytes(self) -> int:
-        return sum(
-            buf.size * buf.dtype.itemsize
-            for layer in self.layers
-            for buf in layer.values()
-        )
-
-    @property
-    def hbm_bytes_per_slot(self) -> float:
-        return self.hbm_bytes / self.slots
-
-    @property
-    def bytes_per_token(self) -> float:
-        """KV bytes one token position costs across all layers in the
-        live cache format (int8 rows include their f32 scale planes)."""
-        return self.hbm_bytes / (self.slots * self.max_len)
-
-    def alloc(self) -> int | None:
-        """Claim a slot index, or None when the pool is full."""
-        if not self._free:
-            return None
-        slot = self._free.pop()
-        self._free_set.discard(slot)
-        return slot
-
-    def free(self, slot: int) -> None:
-        if not 0 <= slot < self.slots:
-            raise ValueError(f"slot {slot} outside [0, {self.slots})")
-        if slot in self._free_set:
-            raise ValueError(f"double free of slot {slot}")
-        self._free.append(slot)
-        self._free_set.add(slot)
-
-    # -- jitted in-place mutators ----------------------------------------
-
-    def adopt(self, slot: int, new_layers) -> None:
-        """Scatter a prefilled (1, …) cache into ``slot`` in place."""
-        self.layers = self._adopt(self.layers, np.int32(slot), new_layers)
-
-    def reset(self, slot: int) -> None:
-        """Zero a slot's rows (hygiene only — see module docstring)."""
-        self.layers = self._reset(self.layers, np.int32(slot))
-
-    def compile_count(self) -> int:
-        """Compiled-program count across the pool's jitted mutators (the
-        engine sums this into its zero-recompile-after-warmup assert)."""
-        return sum(
-            f._cache_size() if hasattr(f, "_cache_size") else 0
-            for f in (self._adopt, self._reset)
-        )
 
 
 class PagedKVPool:
@@ -300,8 +188,10 @@ class PagedKVPool:
         self.layers = init_cache(
             cfg, self.num_pages, page_size, sharding=kv_sharding
         )["layers"]
-        # Page 0 is TRASH (reserved, refcount pinned). LIFO free list with
-        # an O(1) companion set, same discipline as the slot pool.
+        # Page 0 is TRASH (reserved, refcount pinned). LIFO free lists
+        # (the page or slot freed last is the likeliest still resident in a
+        # cache hierarchy), each with a companion set that keeps the
+        # double-free check O(1) under churn.
         self._free_pages: list[int] = list(range(self.num_pages - 1, 0, -1))
         self._free_page_set: set[int] = set(self._free_pages)
         self.refcount = np.zeros(self.num_pages, np.int64)
@@ -388,6 +278,7 @@ class PagedKVPool:
     # -- slot bookkeeping --------------------------------------------------
 
     def alloc(self) -> int | None:
+        """Claim a slot index, or None when every slot is taken."""
         if not self._free_slots:
             return None
         slot = self._free_slots.pop()

@@ -435,10 +435,9 @@ class ServingMetrics:
         self._prefix_hit_rate.set(float(engine.prefix_hit_rate))
         self._prefill_last_iter.set(
             float(stats.get("prefill_tokens_last_iter", 0)))
-        if getattr(engine, "paged", False):
-            pool = engine.pool
-            self._pages_free.set(float(pool.pages_free))
-            self._page_occupancy.set(float(pool.occupancy))
+        pool = engine.pool
+        self._pages_free.set(float(pool.pages_free))
+        self._page_occupancy.set(float(pool.occupancy))
         if getattr(engine, "spec_k", 0):
             self._sync_spec(engine, stats)
 

@@ -332,13 +332,12 @@ def make_server(
                         getattr(scheduler.engine, "kv_dtype", "bf16")
                     ),
                 }
-                if getattr(scheduler.engine, "paged", False):
-                    # Page capacity is the real admission gate under the
-                    # paged KV layout — routers dispatching on free_slots
-                    # alone would overfill an oversubscribed pool.
-                    pool = scheduler.engine.pool
-                    body["pages_free"] = pool.pages_free
-                    body["pages_total"] = pool.pages_allocatable
+                # Page capacity is the real admission gate — routers
+                # dispatching on free_slots alone would overfill an
+                # oversubscribed pool.
+                pool = scheduler.engine.pool
+                body["pages_free"] = pool.pages_free
+                body["pages_total"] = pool.pages_allocatable
                 # Deploy state: which checkpoint step is live and which
                 # variants this replica can serve — the fleet registry
                 # reads this to route variant-pinned traffic.

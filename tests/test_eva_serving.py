@@ -403,12 +403,10 @@ def test_config_refuses_what_eva_does_not_extend(over, match):
 @pytest.mark.parametrize("kw,match", [
     (dict(spec_k=2), "speculation"),
     (dict(spec_k=2, spec_branches=2), "speculation"),
-    (dict(steps_per_sync=2), "steps_per_sync"),
-    (dict(page_size=0), "paged"),
     (dict(prefill_chunk_tokens=-1), "chunked prefill"),
     (dict(prefill_len=24), "divides eva_window"),
-], ids=["linear-spec", "tree-spec", "two-steps-a-sync", "monolithic",
-        "chunking-off", "chunk-across-a-window"])
+], ids=["linear-spec", "tree-spec", "chunking-off",
+        "chunk-across-a-window"])
 def test_engine_refuses_what_eva_does_not_extend(params, kw, match):
     with pytest.raises(EvaUnsupported, match=match):
         make_engine(params, **kw)

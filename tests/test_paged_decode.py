@@ -195,14 +195,13 @@ _BF16 = replace(CFG, compute_dtype=jnp.bfloat16, max_seq_len=32)
     ("table", lambda: _engine(_BF16, page_size=16)),
     ("table", lambda: _engine(page_size=8, spec_k=2)),
     # The configurations no cell of the benchmark runs: all gather.
-    ("gather", lambda: _engine(page_size=0)),
     ("gather", lambda: _engine(_BF16, page_size=8)),
     ("gather", lambda: _engine(_SMALL, page_size=8)),
     ("gather", lambda: _engine(replace(CFG, kv_cache_dtype="int8"),
                                page_size=8)),
     ("gather", lambda: _engine(replace(CFG, max_seq_len=32),
                                cls=ShardedSlotEngine, tp=2, page_size=8)),
-], ids=["f32-page8", "bf16-page16", "spec-plain-step", "monolithic",
+], ids=["f32-page8", "bf16-page16", "spec-plain-step",
         "bf16-page8", "head8", "int8-kv", "sharded"])
 def test_decode_path_is_fixed_by_what_the_engine_sees(want, make):
     assert make().decode_path == want
@@ -288,7 +287,7 @@ _LAYOUTS = {
     "paged+prefix": dict(prefix_cache=True),
     "paged+prefix+spec": dict(prefix_cache=True, spec_k=4),
     "paged+prefix+chunked": dict(prefix_cache=True, prefill_chunk_tokens=8),
-    "paged+steps2": dict(prefix_cache=False, steps_per_sync=2),
+    "paged+prefix+tree": dict(prefix_cache=True, spec_k=4, spec_branches=2),
 }
 
 
@@ -319,11 +318,33 @@ def test_table_path_serves_the_gather_paths_tokens(params, gather_tokens,
     assert got == gather_tokens
 
 
+@pytest.mark.parametrize("cls", [SlotEngine, GatherEngine],
+                         ids=["table", "gather"])
+def test_a_plain_round_is_one_micro_step(params, cls):
+    """One dispatch, one micro-step: a plain round returns exactly one row
+    of tokens and every active slot advances by one position, on either
+    decode path (a verify round and a final prefill chunk are what yield
+    more rows)."""
+    engine = cls(CFG, params, slots=3, max_len=48, prefill_len=24,
+                 page_size=8)
+    engine.warmup()
+    for n in (5, 11):
+        engine.start(engine.acquire_slot(), list(range(1, n + 1)),
+                     max_new_tokens=6)
+    lengths = engine.lengths.copy()
+    for i in range(1, 4):
+        toks, valid, done = engine.step()
+        assert toks.shape == valid.shape == (1, 3)
+        assert valid[0].tolist() == [True, True, False] and not done.any()
+        assert (engine.lengths[:2] == lengths[:2] + i).all()
+        assert (engine.made[:2] == 1 + i).all()
+
+
 @pytest.mark.parametrize("variant", ["rope-gqa-window", "sampled"])
-def test_table_path_matches_the_monolithic_pool(variant):
-    """Per-slot rotation and a window inside the kernel against the
-    monolithic pool's whole-row attention; and the sampled twin of the
-    program against its own (same seeds, same draws)."""
+def test_table_path_matches_whole_row_attention(variant):
+    """Per-slot rotation and a window inside the kernel against the gather
+    path's attention over each slot's whole logical row; and the sampled
+    twin of the program against its own (same seeds, same draws)."""
     cfg, extra = CFG, {}
     if variant == "rope-gqa-window":
         cfg = replace(CFG, position="rope", num_kv_heads=1,
@@ -336,9 +357,9 @@ def test_table_path_matches_the_monolithic_pool(variant):
     requests = [(prompt, {**kw, **extra})
                 for prompt, kw in _churn_requests()[:6]]
     got = {}
-    for page_size in (0, 8):
-        engine = SlotEngine(cfg, p, slots=3, max_len=48, prefill_len=26,
-                            page_size=page_size)
-        assert engine.decode_path == ("table" if page_size else "gather")
-        got[page_size] = _drive(engine, requests)
-    assert got[8] == got[0]
+    for cls, path in ((GatherEngine, "gather"), (SlotEngine, "table")):
+        engine = cls(cfg, p, slots=3, max_len=48, prefill_len=26,
+                     page_size=8)
+        assert engine.decode_path == path
+        got[path] = _drive(engine, requests)
+    assert got["table"] == got["gather"]

@@ -3,10 +3,10 @@
 The contract under test is ISSUE 8's: the decode fast path may change how
 fast tokens arrive, NEVER which tokens arrive. The anchor test churns
 mixed-length, shared- and disjoint-prefix greedy requests through a
-4-slot engine in all four KV configurations — {monolithic, paged,
-paged+prefix, paged+prefix+speculative} — and requires byte-identical
-outputs (monolithic-vs-sequential parity is already pinned in
-``test_serve_engine.py``, so equality here chains all the way down).
+4-slot engine in three configurations of the pool — {paged, paged+prefix,
+paged+prefix+speculative} — and requires each to serve the tokens of the
+oracle that is not an engine: ``decoding.build_generate_fn``, one request
+at a time on the model's own B=1 cache.
 Around it: page refcount hygiene (everything free after drain),
 double-free / stale-page-table units, prefix-adoption accounting, and
 pages-exhausted admission requeue through the scheduler.
@@ -27,7 +27,6 @@ from distributed_tensorflow_tpu.serve.kv_pool import (
     InsufficientPages,
     PagedKVPool,
     PrefixCache,
-    SlotKVPool,
 )
 from distributed_tensorflow_tpu.serve.scheduler import (
     Completion,
@@ -117,8 +116,20 @@ def _churn_requests():
     ]
 
 
+def _sequential(cfg, params, requests):
+    """The oracle: each greedy request alone through ``build_generate_fn``
+    (the model's B=1 logical cache at the engines' length; no pool, no
+    page, no slot)."""
+    from tests.test_serve_engine import _reference_greedy
+
+    return {
+        i: _reference_greedy(params, prompt, kwargs["max_new_tokens"],
+                             cfg=cfg, cache_len=48)
+        for i, (prompt, kwargs) in enumerate(requests)
+    }
+
+
 _LAYOUTS = {
-    "monolithic": dict(page_size=0),
     "paged": dict(page_size=8, prefix_cache=False),
     "paged+prefix": dict(page_size=8, prefix_cache=True),
     "paged+prefix+spec": dict(page_size=8, prefix_cache=True, spec_k=4),
@@ -127,31 +138,26 @@ _LAYOUTS = {
 
 @pytest.mark.spec
 def test_churn_parity_across_kv_layouts(params):
-    """ISSUE 8 anchor: greedy tokens byte-identical across all four KV
-    configurations under 4-slot churn, zero recompiles in each."""
+    """ISSUE 8 anchor: greedy tokens byte-identical to the sequential
+    oracle in every configuration of the pool under 4-slot churn, zero
+    recompiles in each."""
     requests = _churn_requests()
-    results = {}
+    baseline = _sequential(CFG, params, requests)
     for name, kw in _LAYOUTS.items():
         engine = SlotEngine(
             CFG, params, slots=4, max_len=48, prefill_len=26, **kw
         )
-        results[name] = _drive(engine, requests)
-        if engine.paged:
-            if engine.prefix is not None:
-                engine.prefix.clear()
-            assert engine.pool.pages_free == engine.pool.num_pages - 1, (
-                f"{name}: leaked pages after drain"
-            )
-    baseline = results["monolithic"]
-    for name, got in results.items():
+        got = _drive(engine, requests)
+        if engine.prefix is not None:
+            engine.prefix.clear()
+        assert engine.pool.pages_free == engine.pool.num_pages - 1, (
+            f"{name}: leaked pages after drain"
+        )
         for i in range(len(requests)):
             assert got[i] == baseline[i], (
-                f"{name} diverged from monolithic on request {i}: "
+                f"{name} diverged from build_generate_fn on request {i}: "
                 f"{got[i]} != {baseline[i]}"
             )
-    # The fast paths actually engaged (otherwise this test proves nothing).
-    # fam_a shares 20 tokens = 2 full pages with page_size 8.
-    # (engines are rebuilt per layout, so inspect via fresh runs' stats)
 
 
 @pytest.mark.spec
@@ -268,21 +274,29 @@ def test_paged_pool_refcount_sharing():
     assert pool.pages_free == pool.num_pages - 1
 
 
-def test_slot_pool_free_set_is_consistent():
-    """Satellite: SlotKVPool free/double-free checks run on a companion
-    set; under churn the set and list must stay mirrors."""
-    pool = SlotKVPool(CFG, slots=4, max_len=16)
-    assert pool._free_set == set(pool._free)
+def test_paged_pool_slot_bookkeeping():
+    """The slot free list: every slot once, ``None`` at exhaustion, the
+    slot freed last is the next one handed out, and the companion set that
+    the double-free check reads mirrors the list under churn."""
+    pool = PagedKVPool(CFG, slots=4, max_len=16, page_size=8)
+    assert pool.num_free == 4
+    assert pool._free_slot_set == set(pool._free_slots)
     slots = [pool.alloc() for _ in range(4)]
-    assert pool.alloc() is None
-    assert pool._free_set == set()
+    assert sorted(slots) == [0, 1, 2, 3] and pool.alloc() is None
+    assert pool.num_free == 0 and pool._free_slot_set == set()
     for s in slots[::-1]:
         pool.free(s)
-        assert pool._free_set == set(pool._free)
+        assert pool._free_slot_set == set(pool._free_slots)
     with pytest.raises(ValueError, match="double free"):
         pool.free(slots[0])
-    # LIFO reuse preserved.
-    assert pool.alloc() == slots[0]
+    with pytest.raises(ValueError, match="outside"):
+        pool.free(99)
+    assert pool.alloc() == slots[0]  # LIFO
+    pool.free(slots[0])
+    a, b = pool.alloc(), pool.alloc()
+    pool.free(a)
+    assert pool.alloc() == a and pool.num_free == 2
+    assert pool._free_slot_set == set(pool._free_slots) == set(slots) - {a, b}
 
 
 def test_insufficient_pages_requeues_instead_of_rejecting(params):
@@ -338,10 +352,10 @@ def test_engine_start_raises_insufficient_pages_directly(params):
 
 
 # int8-KV rows of the churn matrix (ISSUE 14 satellite): same contract as
-# the bf16 matrix above, baselined against int8 MONOLITHIC (int8 changes
-# numerics vs bf16 by design; it must not change them across layouts).
+# the bf16 matrix above, baselined against the sequential oracle on the
+# int8 config (int8 changes numerics vs bf16 by design; it must not change
+# them between the model's own cache and the pool's pages).
 _INT8_LAYOUTS = {
-    "monolithic": dict(page_size=0),
     "paged+prefix": dict(page_size=8, prefix_cache=True),
     "paged+prefix+spec": dict(page_size=8, prefix_cache=True, spec_k=4),
     "paged+prefix+tree": dict(page_size=8, prefix_cache=True, spec_k=4,
@@ -355,31 +369,28 @@ _INT8_LAYOUTS = {
 @pytest.mark.kvquant
 def test_churn_parity_int8_kv_layouts(params):
     """Quantize-on-write int8 KV as the LIVE decode format: greedy tokens
-    byte-identical across {monolithic, paged+prefix, +spec, +tree,
-    +chunked} at kv_dtype=int8, zero recompiles in each."""
+    those of the sequential oracle on the int8 config in {paged+prefix,
+    +spec, +tree, +chunked}, zero recompiles in each."""
     from dataclasses import replace
 
     cfg8 = replace(CFG, kv_cache_dtype="int8")
     requests = _churn_requests()
-    results = {}
+    baseline = _sequential(cfg8, params, requests)
     for name, kw in _INT8_LAYOUTS.items():
         engine = SlotEngine(
             cfg8, params, slots=4, max_len=48, prefill_len=26, **kw
         )
         assert engine.kv_dtype == "int8"
-        results[name] = _drive(engine, requests)
-        if engine.paged:
-            if engine.prefix is not None:
-                engine.prefix.clear()
-            assert engine.pool.pages_free == engine.pool.num_pages - 1, (
-                f"{name}: leaked pages after drain"
-            )
-    baseline = results["monolithic"]
-    for name, got in results.items():
+        got = _drive(engine, requests)
+        if engine.prefix is not None:
+            engine.prefix.clear()
+        assert engine.pool.pages_free == engine.pool.num_pages - 1, (
+            f"{name}: leaked pages after drain"
+        )
         for i in range(len(requests)):
             assert got[i] == baseline[i], (
-                f"int8 {name} diverged from int8 monolithic on request "
-                f"{i}: {got[i]} != {baseline[i]}"
+                f"int8 {name} diverged from int8 build_generate_fn on "
+                f"request {i}: {got[i]} != {baseline[i]}"
             )
 
 
@@ -418,7 +429,7 @@ def test_kv_bytes_per_token_accounting(params):
 
     cfg8 = replace(CFG, kv_cache_dtype="int8")
     kw = dict(slots=2, max_len=48, prefill_len=24)
-    for page_size in (0, 8):
+    for page_size in (8, 48):
         hi = SlotEngine(CFG, params, page_size=page_size, **kw)
         lo = SlotEngine(cfg8, params, page_size=page_size, **kw)
         assert hi.kv_dtype == "bf16" and lo.kv_dtype == "int8"
@@ -431,7 +442,7 @@ def test_kv_bytes_per_token_accounting(params):
 def test_paged_int8_kv_parity(params):
     """int8 KV rows + f32 scales page through gather/scatter untouched
     (no requantization), so quantized paged/spec output must equal
-    quantized monolithic output."""
+    the sequential oracle's on the quantized B=1 cache."""
     from dataclasses import replace
 
     cfg8 = replace(CFG, kv_cache_dtype="int8")
@@ -440,11 +451,9 @@ def test_paged_int8_kv_parity(params):
         (rng.integers(1, 64, int(n)).tolist(), {"max_new_tokens": b})
         for n, b in ((7, 6), (15, 9), (21, 5))
     ]
-    mono = SlotEngine(cfg8, params, slots=2, max_len=48, prefill_len=24,
-                      page_size=0)
     fast = SlotEngine(cfg8, params, slots=2, max_len=48, prefill_len=24,
                       page_size=8, prefix_cache=True, spec_k=3)
-    out_mono = _drive(mono, requests)
+    want = _sequential(cfg8, params, requests)
     out_fast = _drive(fast, requests)
     for i in range(len(requests)):
-        assert out_fast[i] == out_mono[i]
+        assert out_fast[i] == want[i]

@@ -12,6 +12,8 @@
    invariant in serve/engine.py's module docstring).
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -22,7 +24,7 @@ from distributed_tensorflow_tpu.models.transformer import (
     TransformerConfig,
     TransformerLM,
 )
-from distributed_tensorflow_tpu.serve import SlotEngine, SlotKVPool
+from distributed_tensorflow_tpu.serve import SlotEngine
 
 pytestmark = pytest.mark.serve
 
@@ -79,8 +81,11 @@ def _drive(engine, requests):
     return results
 
 
-def _reference_greedy(params, prompt, n_new):
-    gen = decoding.build_generate_fn(CFG, n_new, temperature=0.0)
+def _reference_greedy(params, prompt, n_new, cfg=CFG, cache_len=None):
+    """The oracle that is no engine: one request alone through
+    ``build_generate_fn`` on the model's own B=1 cache."""
+    gen = decoding.build_generate_fn(cfg, n_new, temperature=0.0,
+                                     cache_len=cache_len)
     out = gen(
         params, jnp.asarray([prompt], jnp.int32), jax.random.PRNGKey(0)
     )
@@ -106,8 +111,7 @@ def test_greedy_parity_with_build_generate_fn(params):
 def test_zero_recompiles_under_heterogeneous_churn(params):
     """ISSUE 4 acceptance: >= 32 heterogeneous requests through a 4-slot
     engine, compiled-program count frozen after warmup."""
-    engine = SlotEngine(CFG, params, slots=4, max_len=48, prefill_len=16,
-                        steps_per_sync=2)
+    engine = SlotEngine(CFG, params, slots=4, max_len=48, prefill_len=16)
     compiled = engine.warmup()
     assert compiled == engine.compile_count()
     rng = np.random.default_rng(1)
@@ -224,34 +228,25 @@ def test_start_validates_limits(params):
         engine.step()
 
 
-def test_kv_pool_alloc_free_adopt(params):
-    """Pool bookkeeping: LIFO alloc, double-free guard, adopt scatters a
-    (1, ...) cache into the right slot row without touching others."""
-    pool = SlotKVPool(CFG, slots=3, max_len=16)
-    assert pool.num_free == 3 and pool.occupancy == 0.0
-    s0, s1 = pool.alloc(), pool.alloc()
-    assert {s0, s1} == {0, 1} and pool.num_free == 1
-    s2 = pool.alloc()
-    assert s2 == 2 and pool.alloc() is None  # exhausted
-    pool.free(s2)
-    with pytest.raises(ValueError, match="double free"):
-        pool.free(s2)
-    with pytest.raises(ValueError, match="outside"):
-        pool.free(99)
-    pool.free(s1)
-    assert pool.alloc() == s1  # LIFO: most recently freed first
-    donor = decoding.init_cache(CFG, 1, 16)
-    filled = jax.tree_util.tree_map(
-        lambda x: jnp.full_like(x, 3), donor["layers"]
-    )
-    before_other = np.asarray(pool.layers[0]["k"][s1])
-    pool.adopt(s0, filled)
-    assert np.all(np.asarray(pool.layers[0]["k"][s0]) == 3)
-    np.testing.assert_array_equal(
-        np.asarray(pool.layers[0]["k"][s1]), before_other
-    )
-    pool.reset(s0)
-    assert np.all(np.asarray(pool.layers[0]["k"][s0]) == 0)
+def test_page_size_zero_is_refused_by_name(params):
+    """There is one KV layout. ``page_size=0`` comes from outside the
+    program (``--page_size``, a ServeConfig file): it is refused where the
+    value is resolved, with the sizes the engine takes, before a pool is
+    built."""
+    built = []
+    build = SlotEngine._build_pool
+
+    class Spy(SlotEngine):
+        def _build_pool(self, *a):
+            built.append(a)
+            return build(self, *a)
+
+    for bad in (0, -1):
+        with pytest.raises(ValueError, match="positive divisor of max_len 32"):
+            Spy(CFG, params, slots=2, max_len=32, page_size=bad)
+    assert not built
+    assert Spy(CFG, params, slots=2, max_len=32, page_size=None).page_size == 16
+    assert len(built) == 1
 
 
 def test_sample_logits_batched_matches_static_sampler():
@@ -287,11 +282,14 @@ CFG_TABLE = TransformerConfig(
     vocab_size=64, d_model=256, num_heads=2, num_layers=2, d_ff=64,
     max_seq_len=48, compute_dtype=jnp.float32,
 )
+# Quantize-on-write pages: the pool carries scale leaves beside its rows,
+# and they are donated and run ahead like the rows.
+CFG_INT8 = dataclasses.replace(CFG, kv_cache_dtype="int8")
 _AHEAD_LAYOUTS = {
     # name: (config, engine keywords, the decode path it must take)
     "table": (CFG_TABLE, dict(page_size=8, prefill_chunk_tokens=8), "table"),
     "gather": (CFG, dict(page_size=8, prefill_chunk_tokens=8), "gather"),
-    "monolithic": (CFG, dict(page_size=0), "gather"),
+    "int8-kv": (CFG_INT8, dict(page_size=8, prefill_chunk_tokens=8), "gather"),
 }
 _SAMPLING = {
     "greedy": {},
@@ -376,12 +374,12 @@ def _drive_chunked(engine, requests, cancel=None, log=None):
     return results
 
 
-def _ahead_requests(cfg, params, kw, sampling, paged):
+def _ahead_requests(cfg, params, kw, sampling):
     """Seven requests on three slots: admissions all along, a budget's end,
-    an eos met mid-decode, and (paged) two prompts longer than the chunk,
-    whose final chunk lands while a round of the others is in flight."""
+    an eos met mid-decode, and two prompts longer than the chunk, whose
+    final chunk lands while a round of the others is in flight."""
     rng = np.random.default_rng(5)
-    lens = [5, 20, 3, 9, 26, 2, 7] if paged else [5, 11, 3, 9, 12, 2, 7]
+    lens = [5, 20, 3, 9, 26, 2, 7]
     news = [9, 6, 12, 4, 7, 10, 8]
     requests = []
     for i, (p, n) in enumerate(zip(lens, news)):
@@ -407,8 +405,7 @@ def test_run_ahead_serves_the_oracles_tokens(layout_params, layout, sampling):
     round in flight still carries, and the slot taken again at once."""
     cfg, kw, path = _AHEAD_LAYOUTS[layout]
     params = layout_params(cfg)
-    requests, want = _ahead_requests(
-        cfg, params, kw, _SAMPLING[sampling], paged=bool(kw["page_size"]))
+    requests, want = _ahead_requests(cfg, params, kw, _SAMPLING[sampling])
     engine = SlotEngine(cfg, params, slots=3, max_len=48, prefill_len=12, **kw)
     assert engine.decode_path == path
     compiled = engine.warmup()

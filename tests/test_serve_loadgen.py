@@ -248,7 +248,11 @@ def test_bench_serving_quant_smoke_meets_gates():
         assert byte_rec["frac"] <= bench.FRAC_CEILS[byte_rec["metric"]], byte_rec
         loss_rec = recs[f"serve_quant_evalloss_delta_{mode}"]
         assert loss_rec["frac"] <= bench.FRAC_CEILS[loss_rec["metric"]], loss_rec
-    assert recs["serve_speedup_vs_sequential_int8"]["value"] >= 1.5
+    # A CPU clock, one decode micro-step a dispatch: 1.4 solo on this box.
+    # It read 1.9 while the bench fused 8 micro-steps a dispatch, which no
+    # deployment ran (the unquantized smoke reads 3.3 for 3.0 without it).
+    # The gate says batching beats one request at a time.
+    assert recs["serve_speedup_vs_sequential_int8"]["value"] >= 1.1
     rs = recs["serve_spec_accept_rate_sampled"]
     assert 0.0 <= rs["value"] <= 1.0
     assert "sampled spec rounds" in rs["detail"]

@@ -200,12 +200,12 @@ def test_result_timeout_raises_not_hangs(params):
 
 
 @pytest.mark.parametrize("sampling", ["greedy", "sampled"])
-@pytest.mark.parametrize("layout", ["gather", "monolithic", "table"])
+@pytest.mark.parametrize("layout", ["gather", "int8-kv", "table"])
 def test_scheduler_over_run_ahead_serves_the_oracles_tokens(
         layout_params, layout, sampling):
     """Seven requests queue for three slots: every completion is followed
     by an admission while a round is in flight, one request stops at an eos
-    and the others at their budgets, and (paged) two prompts are chunked in
+    and the others at their budgets, and two prompts are chunked in
     beside the decoding slots. Each request's tokens are those it is served
     alone on an engine that never runs ahead."""
     from tests.test_serve_engine import (
@@ -216,8 +216,7 @@ def test_scheduler_over_run_ahead_serves_the_oracles_tokens(
 
     cfg, kw, path = _AHEAD_LAYOUTS[layout]
     params = layout_params(cfg)
-    requests, want = _ahead_requests(
-        cfg, params, kw, _SAMPLING[sampling], paged=bool(kw["page_size"]))
+    requests, want = _ahead_requests(cfg, params, kw, _SAMPLING[sampling])
     engine = SlotEngine(cfg, params, slots=3, max_len=48, prefill_len=12, **kw)
     assert engine.decode_path == path
     compiled = engine.warmup()

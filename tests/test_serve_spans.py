@@ -3,6 +3,7 @@ closes and how they nest, the queue-wait and between-rounds instruments on
 a fake clock, the named scopes of the engine's programs (and that they
 change no token), and what ``sync_engine`` no longer does every round."""
 
+import dataclasses
 import os
 import re
 import sys
@@ -61,8 +62,8 @@ def params():
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
 
 
-def _engine(params, **kw):
-    return SlotEngine(CFG, params, slots=2, max_len=48, prefill_len=8, **kw)
+def _engine(params, cfg=CFG, **kw):
+    return SlotEngine(cfg, params, slots=2, max_len=48, prefill_len=8, **kw)
 
 
 def _since(t_lo):
@@ -164,31 +165,30 @@ def _round_parts(t_lo):
     return out
 
 
-@pytest.mark.parametrize("kw", [dict(), dict(page_size=0),
-                                dict(steps_per_sync=2)],
-                         ids=["paged", "monolithic", "steps2"])
-def test_quiet_rounds_are_queued_before_the_round_before_is_read(params, kw):
+@pytest.mark.parametrize("kv", [None, "int8"], ids=["native-kv", "int8-kv"])
+def test_quiet_rounds_are_queued_before_the_round_before_is_read(params, kv):
     """N rounds with nothing for the host to say: the first goes out from
     the host's registers, every other one is queued (engine.dispatch)
     before the round before it is waited for and read, so ``rounds_ahead``
     is N - 1 and each ``engine.round`` says which kind its round was. The
-    last call queues nothing: the budget ends in the round it reads."""
-    engine = _engine(params, **kw)
+    last call queues nothing: the budget ends in the round it reads. An
+    int8 pool's scale leaves are queued ahead with its rows."""
+    engine = _engine(params, dataclasses.replace(CFG, kv_cache_dtype=kv))
     engine.warmup()
     ahead0 = engine.stats["rounds_ahead"]
     slot = engine.acquire_slot()
-    k = engine.steps_per_sync
     t_lo = time.monotonic()
-    engine.start(slot, PROMPTS[0], max_new_tokens=1 + 6 * k)
+    engine.start(slot, PROMPTS[0], max_new_tokens=7)
     n = 0
     while engine.active[slot]:
-        engine.step()
+        toks, _, _ = engine.step()
+        assert toks.shape[0] == 1  # one micro-step a dispatch
         n += 1
     assert n == 6 and engine.stats["rounds_ahead"] - ahead0 == n - 1
     rounds = _round_parts(t_lo)
     assert [a["ahead"] for a, _ in rounds] == [False] + [True] * (n - 1)
     assert [a["live_tokens"] for a, _ in rounds] == [
-        5 + i * k for i in range(n)]
+        5 + i for i in range(n)]
     # First call: this round from the host, the next ahead, then the read.
     # Middle calls: one dispatch, before the wait. Last call: the read alone.
     assert [len(p["dispatch"]) for _, p in rounds] == [2] + [1] * (n - 2) + [0]
@@ -372,6 +372,22 @@ FAMILIES = {  # every family /metrics showed at the parent commit
 def _samples(text):
     return {k: float(v) for k, v in (
         l.rsplit(" ", 1) for l in text.splitlines() if not l.startswith("#"))}
+
+
+def test_build_stack_refuses_page_size_zero_before_any_pool(
+        params, monkeypatch):
+    """``--page_size 0`` reaches the engine as it was given and is refused
+    there, with the sizes it takes, before a pool is allocated."""
+    from distributed_tensorflow_tpu.serve import kv_pool
+
+    def no_pool(*a, **kw):
+        raise AssertionError("a pool was built")
+
+    monkeypatch.setattr(kv_pool.PagedKVPool, "__init__", no_pool)
+    serve_cfg = ServeConfig(slots=2, serve_max_len=48, prefill_len=8, port=0,
+                            slo="off", page_size=0)
+    with pytest.raises(ValueError, match="positive divisor of max_len 48"):
+        serve_lm.build_stack(serve_cfg, CFG, params)
 
 
 def test_build_stack_binds_once_and_rounds_walk_no_params(

@@ -205,7 +205,7 @@ def test_page_accounting_matches_single_device(engines, params):
 def test_sharded_constructor_guards(params):
     with pytest.raises(ValueError, match="tp >= 2"):
         ShardedSlotEngine(CFG, params, tp=1, **ENGINE_KW)
-    with pytest.raises(ValueError, match="paged KV layout"):
+    with pytest.raises(ValueError, match="positive divisor of max_len"):
         kw = dict(ENGINE_KW, page_size=0)
         ShardedSlotEngine(CFG, params, tp=2, **kw)
     with pytest.raises(ValueError, match="num_kv_heads"):

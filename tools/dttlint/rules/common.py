@@ -36,7 +36,7 @@ def self_attr(node: ast.AST) -> str | None:
 
 def int_tuple(node: ast.AST) -> set[int] | None:
     """The ints a donate_argnums expression can evaluate to, unioned over
-    both arms of an IfExp (``(0,) if self.paged else ()`` → {0}); None
+    both arms of an IfExp (``(0, 1, 2) if donate else ()`` → {0, 1, 2}); None
     when the expression is not statically resolvable."""
     if isinstance(node, ast.Constant) and isinstance(node.value, int) and not isinstance(node.value, bool):
         return {node.value}

@@ -5,13 +5,13 @@ the Python reference points at freed (or aliased-output) memory, and a
 read produces garbage or a crash *only under real allocators*, so CPU
 tests pass while TPU serving corrupts KV pages. The engines and the
 kv_pool pool-scatter entry points all follow the rebind idiom
-(``self.layers = self._adopt(self.layers, ...)``); this rule flags any
+(``self.layers = _fused_page_scatter(self.layers, ...)``); this rule flags any
 call site that reads a donated argument again before rebinding it.
 
 Detection is module-local and name-based: a binding whose value is
 ``jax.jit(..., donate_argnums=...)`` or ``*._jit_program(fn, kind,
 donate)`` records its donated positions (unioning both arms of the
-engines' ``(0,) if self.paged else ()`` conditional); at each call of
+trainers' ``(0, 1, 2) if donate else ()`` conditional); at each call of
 that binding, a plain-Name or ``self.X`` argument in a donated position
 must not be loaded again in the enclosing function until rebound.
 """

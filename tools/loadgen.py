@@ -797,10 +797,9 @@ def main(argv=None):
     # Self-serve engine shape (ignored with --url).
     parser.add_argument("--slots", type=int, default=4)
     parser.add_argument("--seq_len", type=int, default=64)
-    parser.add_argument("--steps_per_sync", type=int, default=1)
     parser.add_argument(
         "--page_size", type=int, default=-1,
-        help="self-serve KV page size (-1 auto, 0 monolithic)",
+        help="self-serve KV page size (-1 auto)",
     )
     parser.add_argument(
         "--spec_k", type=int, default=4,
@@ -922,7 +921,6 @@ def main(argv=None):
             slots=args.slots,
             serve_max_len=args.seq_len,
             prefill_len=max(args.prompt_len, args.seq_len // 2),
-            steps_per_sync=args.steps_per_sync,
             page_size=args.page_size,
             spec_k=args.spec_k,
             spec_branches=args.spec_branches,

@@ -163,7 +163,6 @@ def build_stack(serve_cfg, cfg, params, deploy_cfg=None):
             slots=serve_cfg.slots,
             max_len=serve_cfg.serve_max_len or None,
             prefill_len=serve_cfg.prefill_len or None,
-            steps_per_sync=serve_cfg.steps_per_sync,
             sentinel=sentinel,
             page_size=getattr(serve_cfg, "engine_page_size", None),
             kv_pages=getattr(serve_cfg, "kv_pages", 0),
@@ -380,8 +379,6 @@ def main(argv=None):
         f"spec_k={engine.spec_k} spec_branches={engine.spec_branches} "
         f"drafter={engine.drafter} kv_dtype={engine.kv_dtype} "
         f"chunk={engine.prefill_chunk_tokens})"
-        if engine.paged
-        else "monolithic"
     )
     print(
         f"serving on http://{host}:{port}  slots={engine.slots} "
