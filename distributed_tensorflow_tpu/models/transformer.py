@@ -685,8 +685,10 @@ def _eva_through_table(cfg, cache, q4, k4, v4, phi, mu):
     row is written the page is read back, pooled (:func:`eva_summaries`) and
     its summary row written at ``(len % window) // chunk`` modulo the page.
     Attention is ``paged_decode_attention`` over the composed table where
-    the pool's leaves fit it, and the same sum in ``jax.numpy`` where they
-    do not (a page or head size off the chip's tiles: the CPU tests)."""
+    the pool's leaves fit it (one query row a kv head: the kernel's row
+    form, in chunks of 1 MiB a buffer), and the same sum in ``jax.numpy``
+    where they do not (a page or head size off the chip's tiles: the CPU
+    tests)."""
     b, s, heads, dh = q4.shape
     if s != 1:
         raise ValueError(f"a paged cache takes one token per slot, got {s}")
@@ -713,12 +715,12 @@ def _eva_through_table(cfg, cache, q4, k4, v4, phi, mu):
         vs = write(vs, cache["sum_page"], row, sv)
     q = q4[:, 0].reshape(b, kv, 1, dh)
     if A.paged_decode_fits(ks):
-        # 32 kv heads make a page 16 times StarCoder2's: hold the kernel's
-        # four chunk buffers (K and V, double) to 8 MiB of VMEM.
+        # 32 kv heads make a page 16 times StarCoder2's: a chunk of 1 MiB
+        # a buffer (8 pages; four buffers, K and V, double) measured best.
         page_bytes = kv * ps * dh * ks.dtype.itemsize
         attn = A.paged_decode_attention(
             q, ks, vs, cache["pages"], cache["attend"],
-            pages_per_chunk=max(1, min(32, (2 << 20) // page_bytes)),
+            pages_per_chunk=max(1, min(32, (1 << 20) // page_bytes)),
         )
     else:
         tables = cache["pages"]

@@ -49,6 +49,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30  # large-negative instead of -inf: keeps masked softmax NaN-free
+_LOG2_E = math.log2(math.e)
 
 
 def _scale(q, scale):
@@ -1952,7 +1953,128 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 # slot costs what its live length costs. One invocation walks every slot, so
 # the copy chain also crosses from one slot's last chunk to the next slot's
 # first.
+#
+# How a head's chunk (t rows of K and of V) meets its query rows is the
+# kernel's TILE, and there are two (paged_decode_form picks by the group
+# size). Each is four functions over the same chain: ``init(b)`` -> slot
+# b's query rows as the tile holds them, and their softmax state;
+# ``live(pos0, n, lo)`` -> the mask of the chunk that starts at position
+# pos0; ``attend(q, i, k, v, live, state)`` -> the state of query unit i
+# after the chunk; ``finish(b, i, state)`` writes unit i's output. ``units``
+# of them belong to one kv head.
 # ---------------------------------------------------------------------------
+
+
+def _group_tile(q_ref, o_ref, *, scale, window, t):
+    """The query group as the MXU's streamed rows and K and V as its latched
+    operand: one unit a kv head, its group padded to ``gp`` rows (a whole
+    sublane tile), scores (gp, t), state (gp, 1) / (gp, 1) / (gp, dh). Four
+    latches a head a chunk whatever the group: right for a group that fills
+    a good part of the tile."""
+    _, kv, gp, dh = q_ref.shape
+
+    def init(b):
+        q = [q_ref[b, h] for h in range(kv)]  # (gp, dh) each
+        return q, [
+            (jnp.full((gp, 1), NEG_INF, jnp.float32),
+             jnp.zeros((gp, 1), jnp.float32),
+             jnp.zeros((gp, dh), jnp.float32))
+            for _ in range(kv)
+        ]
+
+    def live(pos0, n, lo):
+        pos = pos0 + lax.broadcasted_iota(jnp.int32, (1, t), 1)
+        return (pos < n) if window is None else (pos < n) & (pos >= lo)
+
+    def attend(q, h, k, v, live, state):
+        m, l, acc = state
+        s = lax.dot_general(
+            q[h], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # (gp, t)
+        s = jnp.where(live, s, NEG_INF)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l = alpha * l + p.sum(axis=-1, keepdims=True)
+        if v.dtype == jnp.bfloat16:
+            # p = hi + lo, two bf16 halves as rows of ONE pass over v: 16
+            # bits of every probability at the MXU cost of 8 (Mosaic's own
+            # f32 product rounds p to bf16: measured).
+            hi = p.astype(jnp.bfloat16)
+            lo_p = (p - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+            both = jnp.dot(jnp.concatenate([hi, lo_p], axis=0), v,
+                           preferred_element_type=jnp.float32)
+            pv = both[:gp] + both[gp:]
+        else:
+            pv = jnp.dot(p, v.astype(jnp.float32),
+                         preferred_element_type=jnp.float32)
+        return m_new, l, alpha * acc + pv
+
+    def finish(b, h, state):
+        _, l, acc = state
+        o_ref[b, h] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+    return 1, init, live, attend, finish
+
+
+def _row_tile(q_ref, o_ref, w_buf, *, scale, window, t, group):
+    """One query row a unit, ``group`` units a kv head, for a group that
+    would leave most of a sublane tile empty: nothing of K or V is latched
+    for it. The row, replicated over ``dh`` columns (``w_buf``, written once
+    a slot), is the MXU's latched operand and the chunk's K rows stream past
+    it, so the scores come out (t, dh) with row r's score on every lane:
+    the layout ``p[r] * V[r, :]`` wants. Softmax and the value product run
+    on the VPU in f32 (every probability enters the product with all its
+    bits), each of the 8 sublanes carrying the online softmax of the chunk
+    rows that fall on it: (8, dh) each of m, l and acc a query row where
+    the group form pads to a tile; one sublane reduction a slot joins the
+    eight. ``q_ref`` and ``o_ref`` are flat f32 rows (slots, units, dh), so
+    that one row is read or written without a packed tile around it."""
+    _, units, dh = q_ref.shape
+
+    def init(b):
+        for j in range(units):
+            w_buf[j] = jnp.broadcast_to(
+                q_ref[b, pl.ds(j, 1), :], (dh, dh)).astype(w_buf.dtype)
+        return None, [
+            (jnp.full((8, dh), NEG_INF, jnp.float32),
+             jnp.zeros((8, dh), jnp.float32),
+             jnp.zeros((8, dh), jnp.float32))
+            for _ in range(units)
+        ]
+
+    def live(pos0, n, lo):
+        pos = pos0 + lax.broadcasted_iota(jnp.int32, (t, dh), 0)
+        return (pos < n) if window is None else (pos < n) & (pos >= lo)
+
+    def attend(_, j, k, v, live, state):
+        m, l, acc = state
+        # Scores in units of log 2, so that the scale and the exponential's
+        # change of base are one multiplication.
+        s = lax.dot_general(
+            k, w_buf[j], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * (scale * _LOG2_E)  # (t, dh), a row's score on every lane
+        # A sublane all of whose rows are dead keeps its finite m, and
+        # exp2(-inf - m) is exactly zero.
+        s = jnp.where(live, s, -jnp.inf).reshape(t // 8, 8, dh)
+        m_new = jnp.maximum(m, s.max(axis=0))
+        alpha = jnp.exp2(m - m_new)
+        p = jnp.exp2(s - m_new)
+        l = alpha * l + p.sum(axis=0)
+        v = v.astype(jnp.float32).reshape(t // 8, 8, dh)
+        return m_new, l, alpha * acc + (p * v).sum(axis=0)
+
+    def finish(b, j, state):
+        m, l, acc = state
+        w = jnp.exp2(m - m.max(axis=0, keepdims=True))
+        l = (w * l).sum(axis=0, keepdims=True)
+        o_ref[b, pl.ds(j, 1), :] = (
+            (w * acc).sum(axis=0, keepdims=True) / jnp.where(l == 0.0, 1.0, l)
+        ).astype(o_ref.dtype)
+
+    return group, init, live, attend, finish
 
 
 def _paged_decode_kernel(
@@ -1965,15 +2087,22 @@ def _paged_decode_kernel(
     k_buf,
     v_buf,
     sems,
-    *,
+    *w_buf,
     scale: float,
     window: int | None,
     chunk_pages: int,
 ):
-    slots, kv, gp, dh = q_ref.shape
-    ps = k_hbm.shape[2]
+    slots = q_ref.shape[0]
+    _, kv, ps, dh = k_hbm.shape
     pps = tables_ref.shape[0] // slots
     t = chunk_pages * ps
+    # The row form's call hands over a scratch for its replicated rows.
+    if w_buf:
+        tile = _row_tile(q_ref, o_ref, *w_buf, scale=scale, window=window,
+                         t=t, group=q_ref.shape[1] // kv)
+    else:
+        tile = _group_tile(q_ref, o_ref, scale=scale, window=window, t=t)
+    units, init, live_rows, attend, finish = tile
 
     # A dead row of a live chunk is multiplied by a probability of exactly
     # zero, which only a finite value survives.
@@ -2025,7 +2154,7 @@ def _paged_decode_kernel(
         n, lo, p0, pages = span(b)
         chunks = pl.cdiv(pages, chunk_pages)
         nb = next_live(b + 1)
-        q = [q_ref[b, h] for h in range(kv)]  # (gp, dh) each
+        q, state0 = init(b)
 
         def chunk_body(c, carry):
             g, state = carry
@@ -2041,48 +2170,20 @@ def _paged_decode_kernel(
                 for_pages(nb, p0n, pagesn, 0, 1 - buf, start=True)
 
             for_pages(b, p0, pages, c, buf, start=False)
-            pos = (p0 + c * chunk_pages) * ps + lax.broadcasted_iota(
-                jnp.int32, (1, t), 1)
-            live = pos < n
-            if window is not None:
-                live &= pos >= lo
+            live = live_rows((p0 + c * chunk_pages) * ps, n, lo)
             new_state = []
-            for h, (m, l, acc) in enumerate(state):
+            for h in range(kv):
                 k = k_buf[buf, :, h].reshape(t, dh)
                 v = v_buf[buf, :, h].reshape(t, dh)
-                s = lax.dot_general(
-                    q[h], k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                ) * scale  # (gp, t)
-                s = jnp.where(live, s, NEG_INF)
-                m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-                alpha = jnp.exp(m - m_new)
-                p = jnp.exp(s - m_new)
-                l = alpha * l + p.sum(axis=-1, keepdims=True)
-                if v.dtype == jnp.bfloat16:
-                    # p = hi + lo, two bf16 halves as rows of ONE pass over
-                    # v: 16 bits of every probability at the MXU cost of 8
-                    # (Mosaic's own f32 product rounds p to bf16: measured).
-                    hi = p.astype(jnp.bfloat16)
-                    lo_p = (p - hi.astype(jnp.float32)).astype(jnp.bfloat16)
-                    both = jnp.dot(jnp.concatenate([hi, lo_p], axis=0), v,
-                                   preferred_element_type=jnp.float32)
-                    pv = both[:gp] + both[gp:]
-                else:
-                    pv = jnp.dot(p, v.astype(jnp.float32),
-                                 preferred_element_type=jnp.float32)
-                new_state.append((m_new, l, alpha * acc + pv))
+                new_state += [
+                    attend(q, i, k, v, live, state[i])
+                    for i in range(h * units, (h + 1) * units)
+                ]
             return g + 1, new_state
 
-        state0 = [
-            (jnp.full((gp, 1), NEG_INF, jnp.float32),
-             jnp.zeros((gp, 1), jnp.float32),
-             jnp.zeros((gp, dh), jnp.float32))
-            for _ in range(kv)
-        ]
         g, state = lax.fori_loop(0, chunks, chunk_body, (g, state0))
-        for h, (_, l, acc) in enumerate(state):
-            o_ref[b, h] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+        for i, unit in enumerate(state):
+            finish(b, i, unit)
         return g
 
     lax.fori_loop(0, slots, slot_body, 0)
@@ -2091,6 +2192,25 @@ def _paged_decode_kernel(
 def _sublane_rows(dtype) -> int:
     """Rows of one sublane tile of ``dtype``: 8 of f32, 16 of bf16."""
     return 32 // jnp.dtype(dtype).itemsize
+
+
+def paged_decode_form(group: int) -> str:
+    """Which way :func:`paged_decode_attention` forms its two products for
+    a query group of ``group`` rows a kv head: ``"row"`` or ``"group"`` (the
+    two tiles of ``_paged_decode_kernel``). A function of the shape alone:
+    no argument or configuration chooses.
+
+    The group form latches a head's K and V tiles into the MXU (four
+    latches a head a chunk) whatever the group, the row form passes the VPU
+    over the chunk once a query row: they cross between 2 and 4 rows. The
+    kernel alone on a v5e, 16 slots attending 1,552-3,409 rows, 32 kv heads
+    of 128, bf16, 16 pages a chunk, the live bytes at the HBM rate 0.721 ms
+    (PR 34; ms, row / group): group 1 0.833 / 1.192, group 2 0.906 / 1.191,
+    group 4 1.571 / 1.194; an f32 pool (pages of 8, floor 0.854): group 1
+    0.961 / 0.974, group 2 0.962 / 0.968; 2 kv heads, group 12, window
+    4096, 32 pages a chunk (``starcoder2-3b``; floor 0.054): 0.543 / 0.203.
+    A group that was not measured stays on the group form."""
+    return "row" if group <= 2 else "group"
 
 
 def paged_decode_fits(pages) -> bool:
@@ -2130,7 +2250,12 @@ def paged_decode_attention(
     Scores, softmax state and the value product accumulate in f32 over the
     operands' own dtype, as the dense cached branch of
     ``models/transformer.py`` does. Only the pages ``ceil(lens/page_size)``
-    (less the window's skip) are copied from HBM.
+    (less the window's skip) are copied from HBM, ``pages_per_chunk`` of
+    them a buffer. How the two products are formed follows from ``group``
+    alone (:func:`paged_decode_form`): up to two query rows a kv head
+    stream K past the row and finish on the VPU, where a probability keeps
+    all its f32 bits; larger groups stream past latched K and V tiles, where
+    it keeps 16.
     """
     slots, kv, _, dh = q.shape
     if k_pages.shape != v_pages.shape or k_pages.shape[1::2] != (kv, dh):
@@ -2162,17 +2287,34 @@ def paged_decode_attention(
 )
 def _paged_decode_call(q, k_pages, v_pages, page_tables, lens, *, scale,
                        window, chunk_pages, interpret):
-    _, kv, group, dh = q.shape
+    slots, kv, group, dh = q.shape
     ps = k_pages.shape[2]
-    # The MXU takes whole sublane tiles of query rows.
-    rows = _sublane_rows(q.dtype)
-    gp = -(-group // rows) * rows
-    q = jnp.pad(q, ((0, 0), (0, 0), (0, gp - group), (0, 0)))
+    dtype = q.dtype
+    buf = (2, chunk_pages, kv, ps, dh)
+    row_form = paged_decode_form(group) == "row"
+    scratch, params = [], {}
+    if row_form:
+        # Flat rows of f32, so that one row is read and written without a
+        # packed tile around it (the kernel rounds q back to its dtype), a
+        # scratch for the replicated rows, and the VMEM the shapes need:
+        # the chunk buffers, that scratch, q and the output, and 4 MiB for
+        # what the heads' straight-line code spills. (The group form's call
+        # stays as it was: it fits the default at the sizes it is run at.)
+        q = q.reshape(slots, kv * group, dh).astype(jnp.float32)
+        scratch.append(pltpu.VMEM((kv * group, dh, dh), dtype))
+        params["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=2 * math.prod(buf) * k_pages.dtype.itemsize
+            + kv * group * dh * dh * dtype.itemsize
+            + 4 * q.size * 4 + (4 << 20))
+    else:
+        # The MXU takes whole sublane tiles of query rows.
+        rows = _sublane_rows(dtype)
+        gp = -(-group // rows) * rows
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, gp - group), (0, 0)))
     kernel = functools.partial(
         _paged_decode_kernel, scale=scale, window=window,
         chunk_pages=chunk_pages,
     )
-    buf = (2, chunk_pages, kv, ps, dh)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -2188,11 +2330,15 @@ def _paged_decode_call(q, k_pages, v_pages, page_tables, lens, *, scale,
                 pltpu.VMEM(buf, k_pages.dtype),
                 pltpu.VMEM(buf, v_pages.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
+                *scratch,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
         name="paged_decode_attention",
+        **params,
     )(page_tables.reshape(-1).astype(jnp.int32), lens.astype(jnp.int32),
       q, k_pages, v_pages)
+    if row_form:
+        return out.reshape(slots, kv, group, dh).astype(dtype)
     return out[:, :, :group]

@@ -193,7 +193,10 @@ from distributed_tensorflow_tpu.models.transformer import (
     TransformerLM,
 )
 from distributed_tensorflow_tpu.obs import trace as _trace
-from distributed_tensorflow_tpu.ops.attention import paged_decode_fits
+from distributed_tensorflow_tpu.ops.attention import (
+    paged_decode_fits,
+    paged_decode_form,
+)
 from distributed_tensorflow_tpu.serve.kv_pool import (
     TRASH_PAGE,
     InsufficientPages,
@@ -429,6 +432,7 @@ class SlotEngine:
         self.pool = self._build_pool(cfg, max_len, kv_pages)
         self.prefix = PrefixCache(self.pool) if prefix_cache else None
         self.decode_path = self._decode_path()
+        self.decode_kernel_form = self._decode_kernel_form()
 
         # Per-slot host registers. Fixed dtypes — the jit signatures (and
         # therefore the zero-recompile guarantee) depend on them.
@@ -476,6 +480,9 @@ class SlotEngine:
             "eva_windows_rolled": 0,
             "eva_summary_pages_adopted": 0,
             "eva_window_pages_released": 0,
+            # No counter: fixed with the decode program, kept here for
+            # whoever reads the rounds' counts beside it.
+            "decode_kernel_form": self.decode_kernel_form,
         }
         # EVA: positions each slot's request ends at (prompt + budget),
         # which sizes the pages a window roll binds.
@@ -1093,6 +1100,20 @@ class SlotEngine:
         if set(leaves) == {"k", "v"} and paged_decode_fits(leaves["k"]):
             return "table"
         return "gather"
+
+    def _decode_kernel_form(self) -> str | None:
+        """How the paged kernel of the plain decode program forms its two
+        products (``ops.attention.paged_decode_form``): ``"group"`` — the
+        query group streams past latched K and V tiles — or ``"row"`` — K
+        streams past one replicated query row and the VPU does the rest —
+        by the group size it is handed, like ``decode_path`` a
+        fact of the build and no option. ``None`` where ``step_fn`` does
+        not reach the kernel: the gather path, and an EVA pool whose leaves
+        the kernel does not take."""
+        k = self.pool.layers[0]["k"]
+        if self.decode_path != "table" or not paged_decode_fits(k):
+            return None
+        return paged_decode_form(self.cfg.num_heads // k.shape[1])
 
     def _kv_rows_read(self, act, lengths=None, spec=None) -> int:
         """K and V positions per layer that one micro-step of a decode

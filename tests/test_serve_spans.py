@@ -439,6 +439,9 @@ def test_build_stack_binds_once_and_rounds_walk_no_params(
         (warm,) = trace.closed("engine.warmup", t_lo)
         assert _inside(place, ctor) and _inside(ctor, build)
         assert _inside(warm, build) and ctor[1] <= warm[0]
+        # What the build fixed of the decode program rides the span.
+        assert ctor[2]["decode_path"] == engine.decode_path
+        assert ctor[2]["decode_kernel_form"] == engine.decode_kernel_form
     finally:
         server.server_close()
 

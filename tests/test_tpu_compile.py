@@ -33,15 +33,24 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("dtype,slots,kv,group,ps,pps,window", [
+def _kernel_line(compiled) -> str:
+    """The custom call of the kernel in an optimised module's text."""
+    (line,) = [l for l in compiled.as_text().splitlines()
+               if "tpu_custom_call" in l and "custom-call(" in l]
+    return line
+
+
+@pytest.mark.parametrize("dtype,slots,kv,group,ps,pps,window,result", [
     # starcoder2-3b as benchmarks/configs runs it: 16 slots of 4096, GQA
-    # 24 / 2, pages of 16, the window equal to serve_max_len.
-    (jnp.bfloat16, 16, 2, 12, 16, 256, 4096),
-    # An f32 pool at its own tile, MHA, a window that skips pages.
-    (jnp.float32, 4, 2, 1, 8, 12, 20),
+    # 24 / 2, pages of 16, the window equal to serve_max_len. Group 12
+    # stays on the form it has: q padded to 16 rows a head, bf16 out.
+    (jnp.bfloat16, 16, 2, 12, 16, 256, 4096, "bf16[16,2,16,128]"),
+    # An f32 pool at its own tile, MHA, a window that skips pages: the
+    # row form, flat f32 rows out.
+    (jnp.float32, 4, 2, 1, 8, 12, 20, "f32[4,2,128]"),
 ], ids=["sc2-3b-bf16", "f32-page8-window"])
 def test_paged_decode_kernel_compiles_for_v5e(one_chip, dtype, slots, kv,
-                                              group, ps, pps, window):
+                                              group, ps, pps, window, result):
     def arg(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
@@ -56,15 +65,19 @@ def test_paged_decode_kernel_compiles_for_v5e(one_chip, dtype, slots, kv,
         arg((slots, pps), jnp.int32),
         arg((slots,), jnp.int32),
     ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert f"= {result}" in _kernel_line(compiled)
 
 
-def test_paged_decode_kernel_compiles_for_evabyte_rows(one_chip):
+@pytest.mark.parametrize("pages_per_chunk", [8, 32], ids=["chunk8", "chunk32"])
+def test_paged_decode_kernel_compiles_for_evabyte_rows(one_chip,
+                                                       pages_per_chunk):
     """evabyte-6.5b as benchmarks/configs runs it: 16 slots, 32 kv heads of
     128 and no groups, pages of 16, a composed row of 248 pages (15 windows
-    of summaries and one window of K/V rows), chunks of 16 pages: a page is
-    16 times StarCoder2's, so the default 32 would ask for 16.8 MB of
-    VMEM (``models/transformer._eva_through_table`` picks the chunk)."""
+    of summaries and one window of K/V rows): the row form, at the 8 pages
+    a chunk ``models/transformer._eva_through_table`` picks (a page is 16
+    times StarCoder2's) and at the default 32, whose 16 MiB of chunk
+    buffers fit only because the call asks Mosaic for the VMEM its shapes
+    need (Mosaic refuses a kernel over the limit it was given)."""
     def arg(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
@@ -72,7 +85,8 @@ def test_paged_decode_kernel_compiles_for_evabyte_rows(one_chip):
     pages = slots * (pps + 8) + 1
     compiled = jax.jit(
         lambda q, k, v, tables, lens: paged_decode_attention(
-            q, k, v, tables, lens, pages_per_chunk=16, interpret=False)
+            q, k, v, tables, lens, pages_per_chunk=pages_per_chunk,
+            interpret=False)
     ).lower(
         arg((slots, kv, 1, 128), jnp.bfloat16),
         arg((pages, kv, ps, 128), jnp.bfloat16),
@@ -80,4 +94,4 @@ def test_paged_decode_kernel_compiles_for_evabyte_rows(one_chip):
         arg((slots, pps), jnp.int32),
         arg((slots,), jnp.int32),
     ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert "= f32[16,32,128]" in _kernel_line(compiled)

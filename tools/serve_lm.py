@@ -155,7 +155,7 @@ def build_stack(serve_cfg, cfg, params, deploy_cfg=None):
         serve_cfg.validate_mesh(cfg)
     engine_cls = SlotEngine if tp <= 1 else ShardedSlotEngine
     tp_kw = {} if tp <= 1 else {"tp": tp}
-    with obs.span("serve.build_engine"):
+    with obs.span("serve.build_engine") as built:
         engine = engine_cls(
             cfg,
             params,
@@ -175,6 +175,8 @@ def build_stack(serve_cfg, cfg, params, deploy_cfg=None):
             draft_cfg=draft_cfg,
             draft_window=getattr(serve_cfg, "draft_window", 16),
         )
+        built.note(decode_path=engine.decode_path,
+                   decode_kernel_form=engine.decode_kernel_form)
     # What the build fixes (mesh width, bytes per device, dtype labels) is
     # mirrored to /metrics here, once: sync_engine never walks the params.
     metrics.bind_engine(engine)
