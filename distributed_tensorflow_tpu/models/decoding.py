@@ -214,7 +214,7 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
     passes ``P(None, 'model')`` to split the kv-head axis (axis 1 on every
     leaf) without a replicated round-trip through host memory. The scalar
     ``len`` register stays default-placed."""
-    dh = cfg.d_model // cfg.num_heads
+    dh = getattr(cfg, "dh", None) or cfg.d_model // cfg.num_heads
     kv = cfg.kv_heads
     quant = getattr(cfg, "kv_cache_dtype", None)
     if quant not in (None, "int8"):

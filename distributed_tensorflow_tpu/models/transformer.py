@@ -27,8 +27,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from distributed_tensorflow_tpu.models.moe import routed_experts
 from distributed_tensorflow_tpu.ops import attention as A
-from distributed_tensorflow_tpu.ops.rope import apply_rope, rope_tables
+from distributed_tensorflow_tpu.ops.rope import apply_rope as _rotate_heads
+from distributed_tensorflow_tpu.ops.rope import rope_tables
 
 
 def default_compute_dtype():
@@ -38,9 +40,26 @@ def default_compute_dtype():
     return jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
 
 
+def apply_rope(x, cos, sin):
+    """``ops.rope.apply_rope`` on the leading ``2 * cos.shape[-1]``
+    dimensions of each head (all of them unless ``rope_fraction`` < 1); the
+    rest pass unrotated."""
+    rot = 2 * cos.shape[-1]
+    if rot == x.shape[-1]:
+        return _rotate_heads(x, cos, sin)
+    return jnp.concatenate(
+        [_rotate_heads(x[..., :rot], cos, sin), x[..., rot:]], axis=-1)
+
+
 class EvaUnsupported(ValueError):
     """A feature that is not extended to an EVA config (``eva_window`` set):
     raised where the feature is asked for, never a silent fallback."""
+
+
+class CcaUnsupported(ValueError):
+    """A feature that is not extended to a CCA config (``cca_time0`` set) or
+    to routed experts (``num_experts`` set): raised where the feature is asked
+    for, never a silent fallback."""
 
 
 @dataclass(frozen=True)
@@ -138,6 +157,35 @@ class TransformerConfig:
     # of page, see ``serve/kv_pool.py``.
     eva_window: int | None = None
     eva_chunk: int | None = None
+    # Attention in a latent of ``num_heads * head_dim`` where that is not
+    # d_model (None: d_model // num_heads). ``tie_embeddings``: the output
+    # head is the token embedding (logits = h E^T accumulated in f32, no
+    # ``lm_head`` parameters). ``rope_fraction``: the share of each head's
+    # dimensions, from the first, that RoPE rotates; the rest pass unrotated.
+    head_dim: int | None = None
+    tie_embeddings: bool = False
+    rope_fraction: float = 1.0
+    # Compressed convolutional attention (Zyphra, arXiv:2510.04476), on when
+    # both are set (:func:`cca_attention_sublayer`): q and k are projected
+    # into the latent, pass a depthwise causal convolution of ``cca_time0``
+    # taps and a per-head one of ``cca_time1`` taps, take the q-k mean of the
+    # pre-convolution latents, are L2-normalised (k with a learned
+    # temperature) and rotated; half of v's heads come from the token before.
+    # The serving cache is then K/V pages plus a per-slot state of the last
+    # positions' pre-convolution latents, see ``serve/kv_pool.py``.
+    cca_time0: int | None = None
+    cca_time1: int | None = None
+    # Routed experts in place of the block's dense MLP, on when
+    # ``num_experts`` > 0 (``models/moe.py``): an MLP router of width
+    # ``router_hidden`` over all ``num_experts``, top-1, gated-SiLU experts
+    # of width ``expert_width``, no token dropped.
+    # ``experts_held``: the experts THIS chip holds (all by default); the
+    # layer routes over all of them and returns the part of the result the
+    # held ones give.
+    num_experts: int = 0
+    router_hidden: int = 0
+    expert_width: int = 0
+    experts_held: tuple | None = None
 
     def __post_init__(self):
         # Every string-enum field that SELECTS behavior is validated here:
@@ -189,6 +237,67 @@ class TransformerConfig:
                 raise EvaUnsupported(
                     "weight-only quantisation is not extended to an EVA "
                     "config")
+        if self.head_dim is not None and self.head_dim < 1:
+            raise ValueError(f"head_dim must be >= 1, got {self.head_dim}")
+        latent = self.head_dim is not None and (
+            self.head_dim * self.num_heads != self.d_model)
+        if latent and (self.eva or self.weight_dtype is not None):
+            raise ValueError(
+                f"head_dim {self.head_dim} x {self.num_heads} heads is not "
+                f"d_model {self.d_model}: a latent width is not extended to "
+                "EVA attention or to weight-only quantisation")
+        if not 0.0 < self.rope_fraction <= 1.0 or (
+                self.position == "rope" and self.rope_fraction != 1.0
+                and (self.rope_dims % 2 or not self.rope_dims)):
+            raise ValueError(
+                f"rope_fraction {self.rope_fraction} of head_dim {self.dh} "
+                "must rotate an even, non-zero number of dimensions")
+        if (self.cca_time0 is None) != (self.cca_time1 is None):
+            raise ValueError("cca_time0 and cca_time1 go together")
+        if self.cca:
+            if self.cca_time0 < 1 or self.cca_time1 < 1:
+                raise ValueError(
+                    f"cca_time0 / cca_time1 must be >= 1, got "
+                    f"{self.cca_time0} / {self.cca_time1}")
+            kv = self.kv_heads
+            if kv < 2 or kv % 2 or self.num_heads % kv:
+                raise ValueError(
+                    f"CCA shifts the value of half its kv heads by one "
+                    f"position: num_kv_heads {kv} must be even and divide "
+                    f"num_heads {self.num_heads}")
+            for name in ("eva_window", "attention_window", "kv_cache_dtype",
+                         "weight_dtype"):
+                if getattr(self, name) is not None:
+                    raise CcaUnsupported(
+                        f"{name} is not extended to a CCA config")
+            if self.attention != "dense":
+                raise CcaUnsupported(
+                    "a CCA config attends with attention='dense' (no flash, "
+                    "blockwise or ring path takes its q and k)")
+        if self.num_experts:
+            if (self.num_experts < 2 or self.router_hidden < 1
+                    or self.expert_width < 1):
+                raise ValueError(
+                    "routed experts need num_experts >= 2 and positive "
+                    "router_hidden and expert_width")
+            if self.weight_dtype is not None:
+                raise CcaUnsupported(
+                    "weight-only quantisation is not extended to routed "
+                    "experts")
+            if self.dropout_rate:
+                raise CcaUnsupported("routed experts have no dropout")
+            if self.experts_held is not None:
+                held = tuple(int(e) for e in self.experts_held)
+                if (not held or list(held) != sorted(set(held))
+                        or held[0] < 0 or held[-1] >= self.num_experts):
+                    raise ValueError(
+                        f"experts_held {self.experts_held} must be distinct "
+                        f"ascending ids inside num_experts "
+                        f"{self.num_experts}")
+                # A list from a JSON file: the config stays hashable.
+                object.__setattr__(self, "experts_held", held)
+        elif self.experts_held is not None:
+            raise ValueError("experts_held needs num_experts")
         if self.weight_dtype is not None or self.quant_group_size:
             # Lazy import: quant.py is standalone (flax/jax only), but the
             # module-level import order models/__init__ establishes should
@@ -209,6 +318,40 @@ class TransformerConfig:
     @property
     def eva(self) -> bool:
         return self.eva_window is not None
+
+    @property
+    def dh(self) -> int:
+        """Width of one attention head."""
+        return (self.d_model // self.num_heads if self.head_dim is None
+                else self.head_dim)
+
+    @property
+    def rope_dims(self) -> int:
+        """Leading dimensions of a head that RoPE rotates."""
+        return int(round(self.rope_fraction * self.dh))
+
+    @property
+    def cca(self) -> bool:
+        return self.cca_time0 is not None
+
+    @property
+    def cca_hist(self) -> int:
+        """Positions of pre-convolution latents a CCA layer needs of the
+        tokens before: the two convolutions' reach, and at least the one
+        position the value shift reads."""
+        return max(1, self.cca_time0 + self.cca_time1 - 2)
+
+    @property
+    def cca_state_width(self) -> int:
+        """Values one position of the CCA state holds: the pre-convolution
+        q and k latents and the shifted half of v's projection."""
+        return (self.num_heads + self.kv_heads + self.kv_heads // 2) * self.dh
+
+    @property
+    def held(self) -> tuple:
+        """The experts this chip holds (all of them by default)."""
+        return (tuple(range(self.num_experts)) if self.experts_held is None
+                else self.experts_held)
 
 
 def quantize_kv_rows(x):
@@ -358,9 +501,14 @@ def attention_sublayer(cfg, x, attend, train: bool = False, cache=None,
         raise EvaUnsupported(
             "an EVA config attends through eva_attention_sublayer (Block); "
             "this block kind has no EVA path")
+    if getattr(cfg, "cca", False):
+        raise CcaUnsupported(
+            "a CCA config attends through cca_attention_sublayer (Block); "
+            "this block kind has no CCA path")
     h = block_norm(cfg, "ln1")(x)
     b, s, _ = h.shape
-    dh = cfg.d_model // cfg.num_heads
+    dh = getattr(cfg, "dh", None) or cfg.d_model // cfg.num_heads
+    qw = cfg.num_heads * dh  # the latent's width: d_model unless head_dim says
     kv = cfg.kv_heads
     if not (1 <= kv <= cfg.num_heads) or cfg.num_heads % kv:
         raise ValueError(
@@ -369,7 +517,7 @@ def attention_sublayer(cfg, x, attend, train: bool = False, cache=None,
         )
     group = cfg.num_heads // kv
     # GQA shrinks the fused projection: [q (H·dh) | k (KV·dh) | v (KV·dh)].
-    qkv = matmul_dense(cfg, cfg.d_model + 2 * kv * dh, "qkv")(h)
+    qkv = matmul_dense(cfg, qw + 2 * kv * dh, "qkv")(h)
 
     rope = getattr(cfg, "position", "learned") == "rope"
     layout = getattr(attend, "input_layout", "bhsd")
@@ -382,13 +530,18 @@ def attention_sublayer(cfg, x, attend, train: bool = False, cache=None,
         # MFU points slower on the flagship (62.1 vs 72.7%): Mosaic's
         # per-tile cos/sin transcendentals cost far more than the table
         # DMA they save (BASELINE.md r5 negative result).
+        rot = getattr(cfg, "rope_dims", dh)
+        if rot != dh and layout == "packed_qkv":
+            raise ValueError(
+                "rope_fraction < 1 is not extended to the packed flash "
+                "kernels, which rotate whole heads")
         cos, sin = rope_tables(
-            dh, s, cfg.rope_theta, positions=positions,
+            rot, s, cfg.rope_theta, positions=positions,
             start=cache["len"] if cache is not None else 0,
         )
 
     def split_qkv():
-        return jnp.split(qkv, [cfg.d_model, cfg.d_model + kv * dh], axis=-1)
+        return jnp.split(qkv, [qw, qw + kv * dh], axis=-1)
 
     def expand_kv(t4):
         # (B, S, KV, dh) -> (B, S, H, dh): each query-head group reads its
@@ -435,7 +588,7 @@ def attention_sublayer(cfg, x, attend, train: bool = False, cache=None,
             k = apply_rope(k.reshape(b, s, kv, dh), cos, sin)
             attn = attend(
                 jnp.concatenate(
-                    [q.reshape(b, s, cfg.d_model),
+                    [q.reshape(b, s, qw),
                      k.reshape(b, s, kv * dh), v],
                     axis=-1,
                 )
@@ -457,7 +610,7 @@ def attention_sublayer(cfg, x, attend, train: bool = False, cache=None,
             kh = apply_rope(kh, cos, sin)  # pre-expand: kv heads rotate once
         kh = expand_kv(kh)
         vh = expand_kv(v.reshape(b, s, kv, dh))
-        attn = attend(qh, kh, vh).reshape(b, s, cfg.d_model)
+        attn = attend(qh, kh, vh).reshape(b, s, qw)
     elif cache is None:
         q, k, v = split_qkv()
         qh = q.reshape(b, s, cfg.num_heads, dh)
@@ -471,7 +624,7 @@ def attention_sublayer(cfg, x, attend, train: bool = False, cache=None,
             expand_kv(kh).transpose(0, 2, 1, 3),
             expand_kv(v.reshape(b, s, kv, dh)).transpose(0, 2, 1, 3),
         )
-        attn = attn.transpose(0, 2, 1, 3).reshape(b, s, cfg.d_model)
+        attn = attn.transpose(0, 2, 1, 3).reshape(b, s, qw)
     elif "pages" in cache:
         q, k, v = split_qkv()
         q4 = q.reshape(b, s, cfg.num_heads, dh)
@@ -573,7 +726,7 @@ def attention_sublayer(cfg, x, attend, train: bool = False, cache=None,
         attn = (
             attn.reshape(b, cfg.num_heads, s, dh)
             .transpose(0, 2, 1, 3)
-            .reshape(b, s, cfg.d_model)
+            .reshape(b, s, qw)
         )
         cache = {"k": ks, "v": vs, "len": cache["len"] + s}
         if quant == "int8":
@@ -615,11 +768,38 @@ def _attend_through_table(cfg, cache, q4, k4, v4):
         return flat.at[rows].set(new.reshape(b * kv, dh)).reshape(leaf.shape)
 
     ks, vs = write(cache["k"], k4), write(cache["v"], v4)
-    attn = A.paged_decode_attention(
-        q4[:, 0].reshape(b, kv, heads // kv, dh), ks, vs, cache["pages"],
-        cache["attend"], window=getattr(cfg, "attention_window", None),
-    )
+    q = q4[:, 0].reshape(b, kv, heads // kv, dh)
+    if A.paged_decode_fits(ks):
+        attn = A.paged_decode_attention(
+            q, ks, vs, cache["pages"], cache["attend"],
+            window=getattr(cfg, "attention_window", None),
+        )
+    else:
+        # Only a config that always decodes through the table comes here
+        # (CCA: its state makes the gather path's per-slot vmap a second
+        # program): the engine sends every other one down the gather path.
+        attn = _table_attention_sum(q, ks, vs, cache["pages"], cache["attend"])
     return attn.reshape(b, 1, heads * dh), dict(cache, k=ks, v=vs)
+
+
+def _table_attention_sum(q, ks, vs, tables, attend):
+    """What ``paged_decode_attention`` computes, in ``jax.numpy``, for pool
+    leaves the kernel does not take (a page or head size off the chip's
+    tiles: the CPU tests): ``q`` (B, kv, group, dh) over the rows of
+    ``tables`` (B, pages), the first ``attend`` (B,) of them live."""
+    b, kv, _, dh = q.shape
+    rows_k = ks[tables].transpose(0, 2, 1, 3, 4).reshape(b, kv, -1, dh)
+    rows_v = vs[tables].transpose(0, 2, 1, 3, 4).reshape(b, kv, -1, dh)
+    live = jnp.arange(rows_k.shape[2])[None, :] < attend[:, None]
+    scores = jnp.einsum(
+        "bkgd,bkTd->bkgT", q, rows_k, preferred_element_type=jnp.float32
+    ) / np.sqrt(dh)
+    live = live[:, None, None, :]
+    weights = jnp.where(
+        live, jax.nn.softmax(jnp.where(live, scores, A.NEG_INF), -1), 0.0)
+    return jnp.einsum(
+        "bkgT,bkTd->bkgd", weights, rows_v.astype(jnp.float32)
+    ).astype(q.dtype)
 
 
 def eva_summaries(k, v, phi, mu):
@@ -723,19 +903,7 @@ def _eva_through_table(cfg, cache, q4, k4, v4, phi, mu):
             pages_per_chunk=max(1, min(32, (1 << 20) // page_bytes)),
         )
     else:
-        tables = cache["pages"]
-        rows_k = ks[tables].transpose(0, 2, 1, 3, 4).reshape(b, kv, -1, dh)
-        rows_v = vs[tables].transpose(0, 2, 1, 3, 4).reshape(b, kv, -1, dh)
-        live = jnp.arange(rows_k.shape[2])[None, :] < cache["attend"][:, None]
-        scores = jnp.einsum(
-            "bkgd,bkTd->bkgT", q, rows_k, preferred_element_type=jnp.float32
-        ) / np.sqrt(dh)
-        live = live[:, None, None, :]
-        weights = jnp.where(
-            live, jax.nn.softmax(jnp.where(live, scores, A.NEG_INF), -1), 0.0)
-        attn = jnp.einsum(
-            "bkgT,bkTd->bkgd", weights, rows_v.astype(jnp.float32)
-        ).astype(q.dtype)
+        attn = _table_attention_sum(q, ks, vs, cache["pages"], cache["attend"])
     return attn.reshape(b, 1, heads * dh), dict(cache, k=ks, v=vs)
 
 
@@ -820,6 +988,132 @@ def eva_attention_sublayer(mod, cfg, x, train: bool = False, cache=None,
     return x + attn, cache
 
 
+def _l2_heads(x, dh):
+    """Each head of ``x`` (..., dh) f32 scaled to length sqrt(dh)."""
+    return x * (np.sqrt(dh) * jax.lax.rsqrt(
+        jnp.sum(x * x, -1, keepdims=True) + 1e-6))
+
+
+def cca_attention_sublayer(mod, cfg, x, train: bool = False, cache=None,
+                           positions=None):
+    """Pre-norm compressed convolutional attention (CCA, arXiv:2510.04476,
+    as ZAYA1 runs it) + residual, beside :func:`attention_sublayer`; called
+    from ``mod``'s ``@nn.compact`` body. With H query heads, G kv heads of
+    ``dh`` and h = norm(x):
+
+    1. one projection ``cca_in`` gives the latents q~ (H dh), k~ (G dh) and
+       the two halves of the value, ``va`` (kv heads 0 .. G/2-1, from this
+       token) and ``vb`` (kv heads G/2 .. G-1): ``v_t = [va_t ; vb_{t-1}]``;
+    2. q~ and k~ pass two causal convolutions over positions: depthwise
+       (``cca_conv0`` (H+G) dh x time0), then per head (``cca_conv1`` (H+G) x
+       time1 x dh x dh, mixing a head's channels); positions before 0 are 0;
+    3. the q-k mean of the PRE-convolution latents is added: query head n of
+       kv group g gets (q~[n] + k~[g]) / 2, kv head g gets (mean_n q~[n] +
+       k~[g]) / 2;
+    4. every head of q and k is scaled to length sqrt(dh), k times the
+       learned temperature ``cca_temp`` (G,); RoPE on the leading
+       ``cfg.rope_dims`` dimensions;
+    5. causal attention in the latent (GQA), ``proj`` back to d_model.
+
+    What steps 1-3 need of the tokens before is the STATE: the last
+    ``cfg.cca_hist`` positions' [q~ | k~ | vb], (B, cca_hist,
+    ``cfg.cca_state_width``). Three paths:
+
+    * ``cache=None``: a whole sequence from position 0, zero state.
+    * a cache with ``pages``: the engine's decode round, one token a slot,
+      K and V through the page table (:func:`_attend_through_table`: no new
+      attention kernel); ``cache['cca']`` is the pool's state leaf, one row a
+      slot.
+    * a dense cache ``{'k','v','len','cca'}``: one prefill segment appended
+      at ``len`` behind a slot's gathered rows.
+
+    On both cached paths ``cache['n_real']`` (B,) says how many of the fed
+    rows are real: the returned ``cca`` is the state behind the last real
+    row (a padded chunk's at its last real token; a masked lane's, with 0
+    real rows, unchanged)."""
+    heads, kv, dh = cfg.num_heads, cfg.kv_heads, cfg.dh
+    group = heads // kv
+    cq, ck = heads * dh, kv * dh
+    cvb = (kv // 2) * dh
+    n_hist, t0, t1 = cfg.cca_hist, cfg.cca_time0, cfg.cca_time1
+    h = block_norm(cfg, "ln1")(x)
+    b, s, _ = h.shape
+    with jax.named_scope("cca.proj"):
+        z = nn.Dense(cq + 2 * ck, dtype=cfg.compute_dtype,
+                     use_bias=cfg.use_bias, name="cca_in")(h)
+    va = z[..., cq + ck:cq + 2 * ck - cvb]
+    cur = jnp.concatenate([z[..., :cq + ck], z[..., cq + 2 * ck - cvb:]], -1)
+    hist = (jnp.zeros((b, n_hist, cur.shape[-1]), cur.dtype)
+            if cache is None else cache["cca"].astype(cur.dtype))
+    full = jnp.concatenate([hist, cur], 1)  # row i is position i - n_hist
+    with jax.named_scope("cca.conv"):
+        lat = full[..., :cq + ck].astype(jnp.float32)
+        w0 = mod.param("cca_conv0", nn.initializers.normal(t0 ** -0.5),
+                       (cq + ck, t0)).astype(jnp.float32)
+        w1 = mod.param("cca_conv1", nn.initializers.normal((t1 * dh) ** -0.5),
+                       (heads + kv, t1, dh, dh)).astype(jnp.float32)
+        # a at positions -(t1 - 1) .. s - 1, then b at 0 .. s - 1.
+        off, n_a = n_hist - (t0 - 1) - (t1 - 1), s + t1 - 1
+        a = sum(lat[:, off + t0 - 1 - j:off + t0 - 1 - j + n_a] * w0[:, j]
+                for j in range(t0)).reshape(b, n_a, heads + kv, dh)
+        conv = sum(jnp.einsum("bthd,hde->bthe", a[:, t1 - 1 - j:t1 - 1 - j + s],
+                              w1[:, j], precision=jax.lax.Precision.HIGHEST)
+                   for j in range(t1))
+        q_lat = lat[:, n_hist:, :cq].reshape(b, s, kv, group, dh)
+        k_lat = lat[:, n_hist:, cq:].reshape(b, s, kv, dh)
+        q = conv[:, :, :heads].reshape(b, s, kv, group, dh) + 0.5 * (
+            q_lat + k_lat[:, :, :, None])
+        k = conv[:, :, heads:] + 0.5 * (q_lat.mean(3) + k_lat)
+        temp = mod.param("cca_temp", nn.initializers.ones, (kv,))
+        q = _l2_heads(q, dh).reshape(b, s, heads, dh)
+        k = _l2_heads(k, dh) * temp.astype(jnp.float32)[:, None]
+    if getattr(cfg, "position", "learned") == "rope":
+        cos, sin = rope_tables(
+            cfg.rope_dims, s, cfg.rope_theta, positions=positions,
+            start=0 if cache is None or "pages" in cache else cache["len"])
+        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+    q4, k4 = q.astype(cfg.compute_dtype), k.astype(cfg.compute_dtype)
+    v4 = jnp.concatenate(
+        [va, full[:, n_hist - 1:n_hist - 1 + s, cq + ck:]], -1
+    ).reshape(b, s, kv, dh)
+    to_heads = lambda t4: t4.transpose(0, 2, 1, 3)
+    if cache is None:
+        attn = to_heads(A.dense_attention(
+            to_heads(q4), to_heads(jnp.repeat(k4, group, axis=2)),
+            to_heads(jnp.repeat(v4, group, axis=2)), causal=True))
+        attn = attn.reshape(b, s, cq)
+    else:
+        with jax.named_scope("cca.state"):
+            state = jax.vmap(
+                lambda rows, n: jax.lax.dynamic_slice_in_dim(rows, n, n_hist)
+            )(full, cache["n_real"]).astype(cache["cca"].dtype)
+        if "pages" in cache:
+            attn, cache = _attend_through_table(cfg, cache, q4, k4, v4)
+        else:
+            ks = jax.lax.dynamic_update_slice(
+                cache["k"], to_heads(k4), (0, 0, cache["len"], 0))
+            vs = jax.lax.dynamic_update_slice(
+                cache["v"], to_heads(v4), (0, 0, cache["len"], 0))
+            scores = jnp.einsum(
+                "bkgqd,bkTd->bkgqT",
+                to_heads(q4).reshape(b, kv, group, s, dh), ks,
+                preferred_element_type=jnp.float32,
+            ) / np.sqrt(dh)
+            q_pos = cache["len"] + jnp.arange(s)
+            allowed = jnp.arange(ks.shape[2])[None, :] <= q_pos[:, None]
+            weights = jax.nn.softmax(
+                jnp.where(allowed[None, None, None], scores, A.NEG_INF), -1)
+            attn = jnp.einsum(
+                "bkgqT,bkTd->bkgqd", weights, vs.astype(jnp.float32)
+            ).astype(cfg.compute_dtype)
+            attn = to_heads(attn.reshape(b, heads, s, dh)).reshape(b, s, cq)
+            cache = dict(cache, k=ks, v=vs, len=cache["len"] + s)
+        cache = dict(cache, cca=state)
+    attn = nn.Dense(cfg.d_model, dtype=cfg.compute_dtype,
+                    use_bias=cfg.use_bias, name="proj")(attn)
+    return x + attn, cache
+
+
 def _phase_scope(cached: bool):
     """``jax.named_scope`` on the cached branch (the serving engine's
     programs: op metadata only, the program is unchanged), nothing on the
@@ -832,20 +1126,35 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, attend, train: bool = False, cache=None,
-                 positions=None, self_mask=None):
+                 positions=None, self_mask=None, router_state=None):
         """``cache=None`` — training/prefill path. With a cache dict
         ``{'k','v','len'}`` (K/V laid out (B, KV_heads, S_max, dh) —
         num_heads for MHA, num_kv_heads under GQA; ``len`` the filled
         prefix length), runs cached decode and returns
         ``(x, new_cache)``. ``positions`` feeds the RoPE rotation only
         (see :func:`attention_sublayer`); ``self_mask`` is the cached-path
-        tree-attention ancestor mask."""
+        tree-attention ancestor mask.
+
+        A config with routed experts (``cfg.num_experts``) takes the router
+        vector of the layer before as ``router_state`` (None in a stage's
+        first layer) and returns its own as one more element, after the
+        cache; the cache it returns carries ``moe_counts`` (the tokens each
+        held expert received), and a cache's ``route_mask`` (B, S) leaves
+        masked lanes and padding out of the routing."""
         cfg = self.cfg
         # The cached (serving) branch names its phases for a profile;
         # the training path's programs stay as they were.
         scope = _phase_scope(cache is not None)
         with scope("attn"):
-            if cfg.eva:
+            if cfg.cca:
+                if self_mask is not None:
+                    raise CcaUnsupported(
+                        "tree attention (self_mask) is not extended to CCA")
+                x, cache = cca_attention_sublayer(
+                    self, cfg, x, train=train, cache=cache,
+                    positions=positions,
+                )
+            elif cfg.eva:
                 if self_mask is not None:
                     raise EvaUnsupported(
                         "tree attention (self_mask) is not extended to EVA")
@@ -859,6 +1168,21 @@ class Block(nn.Module):
                     positions=positions, self_mask=self_mask,
                 )
 
+        if cfg.num_experts:
+            with scope("mlp"):
+                # The router reads the norm in float32; the experts take it
+                # in compute_dtype.
+                norm = (RMSNorm(eps=cfg.norm_eps,
+                                unit_offset=cfg.norm_unit_offset, name="ln2")
+                        if cfg.norm == "rms"
+                        else nn.LayerNorm(dtype=jnp.float32, name="ln2"))
+                y, router_state, counts = routed_experts(
+                    self, cfg, norm(x), router_state,
+                    None if cache is None else cache.get("route_mask"))
+                x = x + y.astype(x.dtype)
+            if cache is None:
+                return x, router_state
+            return x, dict(cache, moe_counts=counts), router_state
         with scope("mlp"):
             h = block_norm(cfg, "ln2")(x)
             if cfg.mlp == "swiglu":
@@ -885,18 +1209,21 @@ class TransformerLM(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, positions=None, train: bool = False, cache=None,
-                 self_mask=None, pred_heads: bool = False):
+                 self_mask=None, pred_heads: bool = False, logit_rows=None):
         """``pred_heads`` returns every prediction head's logits, (B, S,
-        num_pred_heads, vocab), in place of head 0's (B, S, vocab)."""
+        num_pred_heads, vocab), in place of head 0's (B, S, vocab).
+        ``logit_rows`` (B,) int32, cached branches only: the head is applied
+        to that one row of each sequence and the logits are (B, 1, vocab): a
+        prefill chunk's caller reads its last real row and nothing else."""
         cfg = self.cfg
         b, s = tokens.shape
         if cache is not None and positions is None and "pages" in cache:
             # A page pool seen through its tables: every slot of the batch
             # continues at its own length.
             positions = cache["len"][:, None] + jnp.arange(s, dtype=jnp.int32)
-        x = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.compute_dtype, name="tok_embed")(
-            tokens
-        )
+        embed = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.compute_dtype,
+                         name="tok_embed")
+        x = embed(tokens)
         if cfg.residual_dtype is not None:
             x = x.astype(cfg.residual_dtype)
         if cfg.position == "rope":
@@ -925,6 +1252,8 @@ class TransformerLM(nn.Module):
         rope_positions = positions if cfg.position == "rope" else None
         attend = _attention_fn(cfg, prefer_packed=cache is None)
         if cache is None:
+            if logit_rows is not None:
+                raise ValueError("logit_rows is for the cached branches")
             # static_argnums count self at 0: attend (callable) and train
             # (bool) are compile-time constants. Param tree is unchanged —
             # remat is a transform, not a module; positions is a traced
@@ -932,10 +1261,14 @@ class TransformerLM(nn.Module):
             block_cls = (
                 nn.remat(Block, static_argnums=(2, 3)) if cfg.remat else Block
             )
+            router = None  # routed experts: the layer before's router vector
             for i in range(cfg.num_layers):
                 x = block_cls(cfg, name=f"block_{i}")(
-                    x, attend, train, positions=rope_positions
+                    x, attend, train, positions=rope_positions,
+                    router_state=router,
                 )
+                if cfg.num_experts:
+                    x, router = x
         else:
             # Cache layout: {'layers': [{'k','v'}, ...], 'len': scalar} — one
             # shared filled-length for all layers (they advance in lockstep).
@@ -943,25 +1276,49 @@ class TransformerLM(nn.Module):
             # tables) is every layer's too.
             shared = {k_: v_ for k_, v_ in cache.items() if k_ != "layers"}
             new_layers = []
+            router, counts = None, []
             for i in range(cfg.num_layers):
                 layer = dict(cache["layers"][i], **shared)
-                x, layer = Block(cfg, name=f"block_{i}")(
+                out = Block(cfg, name=f"block_{i}")(
                     x, attend, train=train, cache=layer,
                     positions=rope_positions, self_mask=self_mask,
+                    router_state=router,
                 )
+                if cfg.num_experts:
+                    x, layer, router = out
+                    counts.append(layer.pop("moe_counts"))
+                else:
+                    x, layer = out
                 # Preserve every per-layer buffer (k/v plus the int8
                 # cache's k_scale/v_scale); 'len' is shared, not per-layer.
                 new_layers.append(
                     {k_: v_ for k_, v_ in layer.items() if k_ not in shared}
                 )
             cache = dict(shared, layers=new_layers, len=cache["len"] + s)
+            if counts:
+                # (layers, held): the tokens each held expert received.
+                cache["moe_counts"] = jnp.stack(counts)
+            if logit_rows is not None:
+                x = jnp.take_along_axis(
+                    x, logit_rows.astype(jnp.int32)[:, None, None], axis=1)
+                s = 1
         with _phase_scope(cache is not None)("lm_head"):
             x = block_norm(cfg, "ln_f")(x)
-            logits = nn.Dense(
-                cfg.vocab_size * cfg.num_pred_heads,
-                dtype=jnp.float32 if cfg.fp32_logits else cfg.compute_dtype,
-                name="lm_head", use_bias=cfg.use_bias,
-            )(x)
+            if cfg.tie_embeddings:
+                if cfg.num_pred_heads != 1:
+                    raise ValueError("a tied head is one vocabulary wide")
+                # logits = h E^T over the embedding as it lies, accumulated
+                # in float32: no f32 copy of E, no rounding of a logit.
+                logits = jnp.einsum(
+                    "bsd,vd->bsv", x,
+                    embed.embedding.astype(cfg.compute_dtype),
+                    preferred_element_type=jnp.float32)
+            else:
+                logits = nn.Dense(
+                    cfg.vocab_size * cfg.num_pred_heads,
+                    dtype=jnp.float32 if cfg.fp32_logits else cfg.compute_dtype,
+                    name="lm_head", use_bias=cfg.use_bias,
+                )(x)
             logits = logits.astype(jnp.float32)
             if pred_heads:
                 logits = logits.reshape(

@@ -133,6 +133,21 @@ round's record carries ``summary_rows_read`` / ``window_rows_read``. Slot
 export/import, speculation, chunking off and the sharded engine refuse
 such a config (``EvaUnsupported``).
 
+A CCA config (``cfg.cca_time0`` set; ``models/transformer.
+cca_attention_sublayer``) runs the same engine on plain K/V pages plus the
+pool's per-slot convolution state (``serve/kv_pool.py``: the ``cca`` leaf of
+every layer, donated through every program with the pages): decode always
+goes through the table (``table_forward``, which also tells the model which
+lanes are live: a masked lane leaves its state alone and reaches no expert);
+prefill is planned as segments that never overlap (``_start_cca``), each
+through ``cca_prefill_fn``, which reads zeros for the state at position 0
+and writes the state behind the segment's last real token. A config with
+routed experts (``cfg.num_experts``; ``models/moe.py``) makes ``step_fn``
+return three counts beside its tokens, which ``engine.round`` and ``stats``
+carry (``experts_touched``, ``expert_tokens_max``; ``moe_tokens_routed``,
+``moe_experts_touched``). The prefix cache, speculation, slot export/import
+and the sharded engine refuse such a config (``CcaUnsupported``).
+
 Tracing (``obs/trace.py``; always on, no switch): the host side of a round
 closes ``engine.round`` around ``engine.prefill_chunk`` (one per chunk
 spent), ``engine.dispatch`` (entry of the decode round to the jitted call's
@@ -189,6 +204,7 @@ from distributed_tensorflow_tpu.models.decoding import (
     tree_rejection_verify_row,
 )
 from distributed_tensorflow_tpu.models.transformer import (
+    CcaUnsupported,
     EvaUnsupported,
     TransformerLM,
 )
@@ -222,6 +238,9 @@ class _Round:
     ahead: bool  # queued before the round before it was read
     spec: bool  # a verify round
     any_sampled: bool
+    # Routed experts, read with the round: (layer, expert) pairs that got a
+    # token, the most tokens one got, the tokens routed. None without them.
+    moe: np.ndarray | None = None
 
 
 class SlotEngine:
@@ -320,6 +339,30 @@ class SlotEngine:
                     f"an EVA config needs chunked prefill with a chunk "
                     f"({c}) that divides eva_window {cfg.eva_window}: a "
                     f"prefill segment never straddles a window")
+        self._cca = bool(getattr(cfg, "cca", False))
+        self._moe = bool(getattr(cfg, "num_experts", 0))
+        if self._cca:
+            # What the per-slot convolution state is not extended to refuses
+            # here, by name, rather than serving something else.
+            if spec_k:
+                raise CcaUnsupported(
+                    "speculation (spec_k > 0) is not extended to CCA: a "
+                    "rejected draft would have to roll the convolution "
+                    "state back")
+            if prefix_cache:
+                raise CcaUnsupported(
+                    "the prefix cache is not extended to CCA: an adopted "
+                    "boundary needs a snapshot of the convolution state at "
+                    "it (pass prefix_cache=False)")
+        if self._moe and not self._cca:
+            raise CcaUnsupported(
+                "routed experts (num_experts) are served only with CCA "
+                "(cca_time0 / cca_time1): the engine keeps masked lanes "
+                "from the experts, and counts the experts a round touches, "
+                "on the CCA programs alone")
+        if (self._cca or self._moe) and getattr(self, "tp", 1) > 1:
+            raise CcaUnsupported(
+                "ShardedSlotEngine has no path for CCA or routed experts")
         self.cfg = cfg
         # Place params through the same path swap candidates stage through
         # (``_place_params``): a checkpoint bundle arrives as host numpy,
@@ -480,6 +523,10 @@ class SlotEngine:
             "eva_windows_rolled": 0,
             "eva_summary_pages_adopted": 0,
             "eva_window_pages_released": 0,
+            # Routed experts, over the decode rounds read so far: tokens that
+            # reached a held expert, and (layer, expert) pairs with a token.
+            "moe_tokens_routed": 0,
+            "moe_experts_touched": 0,
             # No counter: fixed with the decode program, kept here for
             # whoever reads the rounds' counts beside it.
             "decode_kernel_form": self.decode_kernel_form,
@@ -547,10 +594,11 @@ class SlotEngine:
                 unbound tail entries land in the trash page."""
                 cache = gather_cache(pool_layers, row, prefix_len)
                 logits, cache = model.apply(
-                    {"params": params}, tokens, cache=cache
+                    {"params": params}, tokens, cache=cache,
+                    logit_rows=(length - prefix_len - 1)[None],
                 )
-                last = jnp.take(logits[0], length - prefix_len - 1, axis=0)
-                first = _select(sampled, last, temp, top_k, top_p, seed)
+                first = _select(
+                    sampled, logits[0, 0], temp, top_k, top_p, seed)
                 with jax.named_scope("kv.scatter"):
                     new_pool = [
                         {
@@ -596,9 +644,10 @@ class SlotEngine:
                 logits, cache = model.apply(
                     {"params": params}, tokens, cache=cache,
                     positions=positions[None],
+                    logit_rows=(n_real - 1)[None],
                 )
-                last = jnp.take(logits[0], n_real - 1, axis=0)
-                first = _select(sampled, last, temp, top_k, top_p, seed)
+                first = _select(
+                    sampled, logits[0, 0], temp, top_k, top_p, seed)
                 ci = jnp.arange(n_sum)
                 whole = ci < (abs_start % w + n_real) // ps
                 page = jnp.where(whole, row[pps + ci // ps], TRASH_PAGE)
@@ -622,6 +671,51 @@ class SlotEngine:
                 return new_pool, first
 
             return eva_prefill_fn
+
+        def make_cca_prefill(sampled: bool):
+            def cca_prefill_fn(
+                pool_layers, params, tokens, n_real, abs_start, row, slot,
+                temp, top_k, top_p, seed,
+            ):
+                """One prefill segment of a CCA slot: ``n_real`` tokens
+                (padded to the bucket) at position ``abs_start``, appended
+                behind the slot's gathered rows (a bucket's worth of trash
+                entries behind the row takes the padding's junk rows). The
+                slot's convolution state (the pool's ``cca`` leaf, row
+                ``slot``) is read where the segment continues a prompt and
+                is ZERO where it starts one (``abs_start`` 0: a reused slot
+                starts from zeros), and is written back as it stands behind
+                the last real token: the next segment, or the first decode
+                round, continues from it. Padding reaches no expert."""
+                width = tokens.shape[1]
+                table = jnp.concatenate([
+                    row, jnp.full((-(-width // ps),), TRASH_PAGE, row.dtype)])
+                cache = gather_cache(
+                    [{k: l[k] for k in ("k", "v")} for l in pool_layers],
+                    table, abs_start)
+                for cl, pl in zip(cache["layers"], pool_layers):
+                    cl["cca"] = jnp.where(
+                        abs_start == 0, 0, pl["cca"][slot])[None]
+                cache["n_real"] = n_real[None]
+                cache["route_mask"] = (jnp.arange(width) < n_real)[None]
+                logits, cache = model.apply(
+                    {"params": params}, tokens, cache=cache,
+                    logit_rows=(n_real - 1)[None],
+                )
+                first = _select(sampled, logits[0, 0], temp, top_k, top_p, seed)
+                with jax.named_scope("kv.scatter"):
+                    new_pool = []
+                    for pl, cl in zip(pool_layers, cache["layers"]):
+                        layer = {"cca": pl["cca"].at[slot].set(cl["cca"][0])}
+                        for name in ("k", "v"):
+                            logical = cl[name][0]  # (kv, rows, dh)
+                            kv, dh = logical.shape[0], logical.shape[-1]
+                            layer[name] = pl[name].at[table].set(jnp.swapaxes(
+                                logical.reshape(kv, -1, ps, dh), 0, 1))
+                        new_pool.append(layer)
+                return new_pool, first
+
+            return cca_prefill_fn
 
         def _select(sampled, last, temp, top_k, top_p, seed):
             with jax.named_scope("sample"):
@@ -671,7 +765,7 @@ class SlotEngine:
                          for k in pl}
                         for li, pl in enumerate(pool_layers)
                     ]
-                return pool_layers, logits
+                return pool_layers, logits, None
 
             def table_forward(pool_layers, ptabs, active, lengths, tok, params):
                 """All slots as one batch over the pool where it lies: per
@@ -689,8 +783,13 @@ class SlotEngine:
                     "write_page": jnp.where(active, dest, TRASH_PAGE),
                     "attend": jnp.where(active, lengths + 1, 0),
                 }
+                if self._cca:
+                    # A masked lane feeds no real row: its convolution state
+                    # stands, and its token reaches no expert.
+                    cache["n_real"] = active.astype(jnp.int32)
+                    cache["route_mask"] = active[:, None]
                 cache, logits = decode_step(model, params, cache, tok[:, None])
-                return cache["layers"], logits
+                return cache["layers"], logits, cache.get("moe_counts")
 
             def eva_table_forward(pool_layers, ptabs, active, lengths, tok,
                                   params):
@@ -717,7 +816,7 @@ class SlotEngine:
                     "sum_page": jnp.where(fills, form, TRASH_PAGE),
                 }
                 cache, logits = decode_step(model, params, cache, tok[:, None])
-                return cache["layers"], logits
+                return cache["layers"], logits, None
 
             forward = (
                 eva_table_forward if self._eva
@@ -738,7 +837,7 @@ class SlotEngine:
                 token of a finishing slot is valid). The leading axis is
                 :meth:`SlotEngine.step`'s row axis: a verify round fills
                 more than one row."""
-                pool_layers, logits = forward(
+                pool_layers, logits, counts = forward(
                     pool_layers, ptabs, active, lengths, tok, params
                 )
                 nxt = _pick(sampled, logits, seed, made, temp, top_k, top_p)
@@ -746,10 +845,18 @@ class SlotEngine:
                 new_lengths = jnp.where(active, lengths + 1, lengths)
                 new_made = jnp.where(active, made + 1, made)
                 finished = active & ((new_made >= budget) | (nxt == eos))
-                return (
+                out = (
                     pool_layers, active & ~finished, new_lengths, nxt,
                     new_made, nxt[None], active[None],
                 )
+                if counts is None:
+                    return out
+                # Routed experts: (layer, expert) pairs with a token, the
+                # most tokens one of them got, the tokens routed; read back
+                # with the round's tokens.
+                return out + (jnp.stack([
+                    (counts > 0).sum(), counts.max(), counts.sum()
+                ]).astype(jnp.int32),)
 
             return step_fn
 
@@ -1021,7 +1128,8 @@ class SlotEngine:
         # rounds when spec_k > 0. Still a fixed set: warmup compiles every
         # member, and the compile-count assert covers the lot.
         donate = (0,)  # the pool's leaves, through every program
-        prefill_of = make_eva_prefill if self._eva else make_prefill
+        prefill_of = (make_eva_prefill if self._eva
+                      else make_cca_prefill if self._cca else make_prefill)
         self._prefill_greedy = self._jit_program(
             prefill_of(False), "prefill", donate
         )
@@ -1092,8 +1200,9 @@ class SlotEngine:
         cache is gathered from its table row — everywhere else. The
         prefill, chunk and verify programs gather on either path."""
         leaves = self.pool.layers[0]
-        if self._eva:
-            # Always through the table: the composed row IS the cache.
+        if self._eva or self._cca:
+            # Always through the table: the composed row IS the cache (EVA);
+            # the convolution state is one row a slot beside it (CCA).
             # Where the kernel does not take the leaves the sublayer sums
             # the same rows in jax.numpy (models/transformer.py).
             return "table"
@@ -1363,6 +1472,8 @@ class SlotEngine:
         bucket and a chunked-prefill plan was scheduled instead."""
         if self._eva:
             return self._start_eva(slot, prompt, p, max_new, sargs, sampled)
+        if self._cca:
+            return self._start_cca(slot, prompt, p, max_new, sargs, sampled)
         pool, ps = self.pool, self.page_size
         n_pages = pool.pages_needed(p, max_new)
         # Adoption cap: the tail must keep >= 1 real token (the first-
@@ -1541,6 +1652,52 @@ class SlotEngine:
         self._pf_queue.append(slot)
         return None
 
+    def _start_cca(self, slot, prompt, p, max_new, sargs, sampled):
+        """Admission of a CCA slot: every page bound up front as in the
+        plain layout (nothing adopted: the engine has no prefix cache), the
+        prefill planned as SEGMENTS of at most a chunk that never overlap
+        (a recomputed position would find the convolution state ahead of
+        it), the last padded to its bucket. One segment runs here and its
+        token is returned; more are spent by :meth:`step` like any chunk
+        plan (``None`` is returned)."""
+        pool = self.pool
+        n_pages = pool.pages_needed(p, max_new)
+        own = self._alloc_pages(n_pages)
+        if own is None:
+            raise InsufficientPages(
+                f"need {n_pages} pages, {pool.pages_free} free (slot {slot}, "
+                f"prompt {p} + {max_new} new @ page_size {self.page_size})")
+        pool.bind(slot, own)
+        c = (self.prefill_chunk_tokens if self.prefill_chunk_tokens > 0
+             else self.prefill_len)
+        chunks = [(m, min(c, p - m), m + c >= p) for m in range(0, p, c)]
+        st = {
+            "slot": slot, "prompt": prompt, "p": p, "chunks": chunks,
+            "idx": 0, "sampled": sampled, "sargs": sargs,
+        }
+        if len(chunks) == 1:
+            return self._run_chunk(st, *chunks[0])
+        self._pf[slot] = st
+        self.prefilling[slot] = True
+        self._pf_queue.append(slot)
+        return None
+
+    def _run_cca_segment(self, st, m, r, final):
+        """One prefill segment of a CCA slot: ``r`` real tokens at position
+        ``m``, padded to the narrowest bucket."""
+        pool, slot = self.pool, st["slot"]
+        width = next(b for b in self.prefill_buckets if b >= r)
+        toks = np.zeros((1, width), np.int32)
+        toks[0, :r] = st["prompt"][m : m + r]
+        prefill = (self._prefill_sampled if final and st["sampled"]
+                   else self._prefill_greedy)
+        new_pool, first = prefill(
+            pool.layers, self.params, toks, np.int32(r), np.int32(m),
+            np.array(pool.page_tables[slot]), np.int32(slot), *st["sargs"],
+        )
+        pool.layers = new_pool
+        return int(first) if final else None
+
     def _run_eva_segment(self, st, m, r, final):
         """One prefill segment of an EVA slot (``r`` real tokens at
         absolute ``m``, padded to the narrowest bucket), then what its end
@@ -1667,6 +1824,8 @@ class SlotEngine:
                          width=w, final=final):
             if self._eva:
                 return self._run_eva_segment(st, m, w, final)
+            if self._cca:
+                return self._run_cca_segment(st, m, w, final)
             toks = np.ascontiguousarray(prompt[m : m + w][None])
             row = np.array(pool.page_tables[st["slot"]])
             prefill = (
@@ -1763,6 +1922,11 @@ class SlotEngine:
                 sums, rows = self._eva_rows(act, lengths)
                 sp.note(summary_rows_read=int(sums.sum()),
                         window_rows_read=int(rows.sum()))
+            if rnd is not None and rnd.moe is not None:
+                # Of ``experts_total`` (layer, expert) pairs held here.
+                sp.note(experts_touched=int(rnd.moe[0]),
+                        expert_tokens_max=int(rnd.moe[1]),
+                        experts_total=self.cfg.num_layers * len(self.cfg.held))
             if pre_events:
                 row_t = np.zeros((1, self.slots), np.int32)
                 row_v = np.zeros((1, self.slots), bool)
@@ -1998,6 +2162,10 @@ class SlotEngine:
             made = np.array(made)
             toks = np.asarray(toks)
             valid = np.asarray(valid)
+            if not rnd.spec and len(rnd.out) > 6:  # routed experts
+                rnd.moe = np.asarray(rnd.out[6])
+                self.stats["moe_experts_touched"] += int(rnd.moe[0])
+                self.stats["moe_tokens_routed"] += int(rnd.moe[2])
             if nxt is not None:
                 nxt.was_active, nxt.lengths = active.copy(), lengths.copy()
             touched = self._touched
@@ -2295,6 +2463,10 @@ class SlotEngine:
     def _refuse_eva_handoff(self) -> None:
         """Slot handoff moves a plain page list: an EVA slot's composed
         row (kinds, windows done, forming pages) is not in that bundle."""
+        if self._cca:
+            raise CcaUnsupported(
+                "slot export / import is not extended to CCA: the bundle "
+                "would have to carry the slot's convolution state")
         if self._eva:
             raise EvaUnsupported(
                 "slot export/import is not extended to EVA's composed "

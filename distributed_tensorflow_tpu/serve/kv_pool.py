@@ -47,6 +47,22 @@ prefill segment for every whole chunk of the window so far), never attended.
 ``pages_per_slot`` counts the attended entries: ``(windows - 1) * sum_pages +
 window_pages`` (248 at 32768 positions, windows of 2048, pages of 16).
 
+**State that is not rows (a CCA config, ``cfg.cca_time0`` set).** CCA's
+convolutions and value shift need, of the tokens before, the last
+``cfg.cca_hist`` positions' PRE-convolution latents and shifted value half:
+not rows of the cache, and not recomputable from it. The pool owns them as
+one more leaf of every layer, ``cca`` ``(slots, cca_hist, cca_state_width)``
+beside ``k`` / ``v``: indexed by SLOT, not by page, so it is kept with the
+slot's table row and released with it, and it rides the same donation
+through every engine program as the pages do (the decode round's registers:
+round n+1 is queued from round n's leaf with no host read). Zeroing is the
+prefill program's: a segment at position 0 reads zeros whatever the slot's
+last owner left (the equations' "positions before 0 are zero"), every
+segment writes the state behind its last REAL token, every decode step
+behind its token, a masked lane leaves it alone. Nothing that walks pages
+(export / import, the prefix cache) is extended to it; the engine refuses
+those for such a config.
+
 **When a page is released.** At a window's end (:meth:`PagedKVPool.
 roll_window`, on the host between two rounds): the slot drops its reference
 on each of the window's pages, so a page returns to the free list unless the
@@ -76,6 +92,7 @@ from __future__ import annotations
 from collections import OrderedDict
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from distributed_tensorflow_tpu.models.decoding import init_cache
@@ -188,6 +205,16 @@ class PagedKVPool:
         self.layers = init_cache(
             cfg, self.num_pages, page_size, sharding=kv_sharding
         )["layers"]
+        # CCA (cfg.cca_time0 set): the per-slot convolution state of the
+        # module docstring, one leaf a layer beside its pages.
+        self.cca = bool(getattr(cfg, "cca", False))
+        if self.cca:
+            if kv_sharding is not None:
+                raise ValueError("a CCA pool is not sharded")
+            for layer in self.layers:
+                layer["cca"] = jnp.zeros(
+                    (self.slots, cfg.cca_hist, cfg.cca_state_width),
+                    cfg.compute_dtype)
         # Page 0 is TRASH (reserved, refcount pinned). LIFO free lists
         # (the page or slot freed last is the likeliest still resident in a
         # cache hierarchy), each with a companion set that keeps the
@@ -440,6 +467,8 @@ class PagedKVPool:
         """
         if not 0 <= slot < self.slots:
             raise ValueError(f"slot {slot} outside [0, {self.slots})")
+        if self.cca:
+            raise ValueError("a CCA pool's slot state is not in a page payload")
         row = self.page_tables[slot]
         bound = [int(pid) for pid in row if pid != TRASH_PAGE]
         idx = np.asarray(bound, np.int32)

@@ -95,3 +95,111 @@ def test_paged_decode_kernel_compiles_for_evabyte_rows(one_chip,
         arg((slots,), jnp.int32),
     ).compile()
     assert "= f32[16,32,128]" in _kernel_line(compiled)
+
+
+def _zaya_layer(one_chip, layers=1):
+    """ZAYA1's block at the published widths (benchmarks/configs), cut to
+    ``layers`` layers and a 4,096-row vocabulary so that it compiles in
+    seconds: the model, its parameters as shapes on the described chip."""
+    import json
+
+    from distributed_tensorflow_tpu.models.transformer import (
+        TransformerConfig,
+        TransformerLM,
+    )
+
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                        "configs", "zaya1-8b.json")
+    with open(path) as fh:
+        mcfg = dict(json.load(fh)["transformer_config"],
+                    num_layers=layers, vocab_size=4096)
+    cfg = TransformerConfig(**mcfg, compute_dtype=jnp.bfloat16)
+    model = TransformerLM(cfg)
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                       sharding=one_chip), shapes)
+    return cfg, model, params
+
+
+def test_zaya_decode_round_compiles_for_v5e(one_chip, monkeypatch):
+    """One layer of the decode round as ``zaya1-8b.reason-closed`` runs it:
+    32 slots of 4096, pages of 16. The expert products lower to XLA:TPU's
+    grouped-matmul kernel (two ``ragged-dot`` custom calls a layer behind
+    their group metadata), attention to the paged kernel at 4 query rows a
+    kv head, and no program holds a (tokens, experts, width) product."""
+    from distributed_tensorflow_tpu.models.decoding import decode_step
+
+    # The paged kernel asks the default backend whether to interpret; here
+    # that is the CPU, and the program under test is the chip's.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, model, params = _zaya_layer(one_chip)
+    slots, ps, pps = 32, 16, 256
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = [{"k": arg((slots * pps + 1, 2, ps, 128), jnp.bfloat16),
+             "v": arg((slots * pps + 1, 2, ps, 128), jnp.bfloat16),
+             "cca": arg((slots, cfg.cca_hist, cfg.cca_state_width),
+                        jnp.bfloat16)}]
+
+    def step(params, pool, tables, active, lengths, tok):
+        dest = tables[jnp.arange(slots), lengths // ps]
+        cache = {"layers": pool, "len": lengths, "pages": tables,
+                 "write_page": jnp.where(active, dest, 0),
+                 "attend": jnp.where(active, lengths + 1, 0),
+                 "n_real": active.astype(jnp.int32),
+                 "route_mask": active[:, None]}
+        cache, logits = decode_step(model, params, cache, tok[:, None])
+        return cache["layers"], logits.argmax(-1), cache["moe_counts"]
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, pool, arg((slots, pps), jnp.int32), arg((slots,), jnp.bool_),
+        arg((slots,), jnp.int32), arg((slots,), jnp.int32)).compile()
+    text = compiled.as_text()
+    # The instruction's own name and result, left of its "custom-call(".
+    calls = [l.split(" custom-call(")[0].strip() for l in text.splitlines()
+             if "tpu_custom_call" in l and " custom-call(" in l]
+    assert sum(c.startswith("%ragged-dot-metadata") for c in calls) >= 1
+    products = [c for c in calls if c.startswith("%ragged-dot")
+                and "metadata" not in c]
+    assert len(products) == 2 and all("f32[32," in c for c in products)
+    # The paged kernel, its 4 query rows a kv head padded to 16.
+    assert sum("bf16[32,2,16,128]" in c for c in calls) == 1
+    assert "[32,16," not in text  # nothing dense over all experts
+
+
+def test_zaya_prefill_chunk_costs_its_tokens_not_sixteen_times(one_chip):
+    """A 1024-wide prefill chunk of one layer: the compiler's own count of
+    the program's FLOPs is that of 1024 tokens through ONE expert (25.8
+    GFLOP), the latent attention over 5120 rows and the projections, about
+    60 GFLOP; dense over all 16 experts would be 412 GFLOP in the experts
+    alone. And the head is formed for one row, not for 1024."""
+    cfg, model, params = _zaya_layer(one_chip)
+    width, rows = 1024, 4096 + 1024
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def chunk(params, k, v, state, tokens, n_real, start):
+        cache = {"layers": [{"k": k, "v": v, "cca": state[None]}],
+                 "len": start, "n_real": n_real[None],
+                 "route_mask": (jnp.arange(width) < n_real)[None]}
+        logits, cache = model.apply(
+            {"params": params}, tokens, cache=cache,
+            logit_rows=(n_real - 1)[None])
+        return logits, cache["layers"][0]["cca"]
+
+    compiled = jax.jit(chunk).lower(
+        params, arg((1, 2, rows, 128), jnp.bfloat16),
+        arg((1, 2, rows, 128), jnp.bfloat16),
+        arg((cfg.cca_hist, cfg.cca_state_width), jnp.bfloat16),
+        arg((1, width), jnp.int32), arg((), jnp.int32),
+        arg((), jnp.int32)).compile()
+    flops = compiled.cost_analysis()["flops"]
+    one_expert = 2 * 3 * 2048 * 2048 * width
+    assert one_expert < flops < 4 * one_expert, flops
+    assert f"f32[1,1,{cfg.vocab_size}]" in compiled.as_text()
+    assert f"[1,{width},{cfg.vocab_size}]" not in compiled.as_text()
