@@ -679,55 +679,65 @@ def attention_sublayer(cfg, x, attend, train: bool = False, cache=None,
         vs = jax.lax.dynamic_update_slice(
             cache["v"], vh, (0, 0, cache["len"], 0)
         )
-        qh = to_heads(q4).reshape(b, kv, group, s, dh)
-        scores = jnp.einsum(
-            "bkgqd,bkTd->bkgqT",
-            qh,
-            ks.astype(cfg.compute_dtype) if quant == "int8" else ks,
-            preferred_element_type=jnp.float32,
-        ) / np.sqrt(dh)
-        if quant == "int8":
-            scores = scores * k_scale[:, :, None, None, :]
-        q_pos = cache["len"] + jnp.arange(s)  # (s,)
-        key_pos = jnp.arange(ks.shape[2])  # (S_max,)
-        if self_mask is not None:
-            # Tree-speculation verify: in-block keys are gated by the
-            # static ancestor mask (write order != causal order inside the
-            # block), the committed prefix is fully visible, and stale
-            # rows past the block stay hidden. attention_window cannot
-            # compose with a tree block (positions are non-monotone in
-            # write order) — the serving engine rejects that pairing at
-            # construction.
-            if getattr(cfg, "attention_window", None) is not None:
-                raise ValueError(
-                    "self_mask (tree attention) is incompatible with "
-                    "attention_window"
-                )
-            in_block = (key_pos[None, :] >= cache["len"]) & (
-                key_pos[None, :] < cache["len"] + s
-            )
-            rel = jnp.clip(key_pos - cache["len"], 0, s - 1)
-            allowed = (key_pos[None, :] < cache["len"]) | (
-                in_block & self_mask[:, rel]
+        if cache.get("flash") and self_mask is None and quant != "int8":
+            # A prefill chunk on an engine whose shapes the kernel takes
+            # (``SlotEngine.prefill_path``): the block of query rows at the
+            # traced offset ``len`` attends the live keys by blocks. No
+            # (s, S_max) scores, no expanded kv heads, no dead key read.
+            attn = A.chunk_flash_attention(
+                to_heads(q4), ks, vs, cache["len"],
+                window=getattr(cfg, "attention_window", None),
             )
         else:
-            allowed = key_pos[None, :] <= q_pos[:, None]  # (s, S_max)
-            if getattr(cfg, "attention_window", None) is not None:
-                allowed &= (
-                    key_pos[None, :] > q_pos[:, None] - cfg.attention_window
+            # The verify programs (self_mask), the int8 cache, the gather
+            # path's one-row decode and every off-tile shape: dense over
+            # all S_max positions.
+            qh = to_heads(q4).reshape(b, kv, group, s, dh)
+            scores = jnp.einsum(
+                "bkgqd,bkTd->bkgqT",
+                qh,
+                ks.astype(cfg.compute_dtype) if quant == "int8" else ks,
+                preferred_element_type=jnp.float32,
+            ) / np.sqrt(dh)
+            if quant == "int8":
+                scores = scores * k_scale[:, :, None, None, :]
+            q_pos = cache["len"] + jnp.arange(s)  # (s,)
+            key_pos = jnp.arange(ks.shape[2])  # (S_max,)
+            if self_mask is not None:
+                # Tree-speculation verify: in-block keys are gated by the
+                # static ancestor mask (write order != causal order inside the
+                # block), the committed prefix is fully visible, and stale
+                # rows past the block stay hidden. attention_window cannot
+                # compose with a tree block (positions are non-monotone in
+                # write order) — the serving engine rejects that pairing at
+                # construction.
+                if getattr(cfg, "attention_window", None) is not None:
+                    raise ValueError(
+                        "self_mask (tree attention) is incompatible with "
+                        "attention_window"
+                    )
+                in_block = (key_pos[None, :] >= cache["len"]) & (
+                    key_pos[None, :] < cache["len"] + s
                 )
-        scores = jnp.where(allowed[None, None, None, :, :], scores, A.NEG_INF)
-        weights = jax.nn.softmax(scores, -1)
-        if quant == "int8":
-            weights = weights * v_scale[:, :, None, None, :]
-        attn = jnp.einsum(
-            "bkgqT,bkTd->bkgqd", weights, vs.astype(jnp.float32)
-        ).astype(cfg.compute_dtype)
-        attn = (
-            attn.reshape(b, cfg.num_heads, s, dh)
-            .transpose(0, 2, 1, 3)
-            .reshape(b, s, qw)
-        )
+                rel = jnp.clip(key_pos - cache["len"], 0, s - 1)
+                allowed = (key_pos[None, :] < cache["len"]) | (
+                    in_block & self_mask[:, rel]
+                )
+            else:
+                allowed = key_pos[None, :] <= q_pos[:, None]  # (s, S_max)
+                if getattr(cfg, "attention_window", None) is not None:
+                    allowed &= (
+                        key_pos[None, :] > q_pos[:, None] - cfg.attention_window
+                    )
+            scores = jnp.where(allowed[None, None, None, :, :], scores, A.NEG_INF)
+            weights = jax.nn.softmax(scores, -1)
+            if quant == "int8":
+                weights = weights * v_scale[:, :, None, None, :]
+            attn = jnp.einsum(
+                "bkgqT,bkTd->bkgqd", weights, vs.astype(jnp.float32)
+            ).astype(cfg.compute_dtype)
+            attn = attn.reshape(b, cfg.num_heads, s, dh)
+        attn = attn.transpose(0, 2, 1, 3).reshape(b, s, qw)
         cache = {"k": ks, "v": vs, "len": cache["len"] + s}
         if quant == "int8":
             cache["k_scale"] = k_scale

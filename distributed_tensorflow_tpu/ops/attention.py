@@ -2342,3 +2342,250 @@ def _paged_decode_call(q, k_pages, v_pages, page_tables, lens, *, scale,
     if row_form:
         return out.reshape(slots, kv, group, dh).astype(dtype)
     return out[:, :, :group]
+
+
+# ---------------------------------------------------------------------------
+# Prefill-chunk attention: a block of query rows at a TRACED offset attends a
+# slot's whole logical cache, forward only.
+#
+# ``_flash_kernel``'s grid cell and ``_causal_kv_index``'s clamp with the
+# query offset as a scalar-prefetched operand in the static's place: a
+# serving chunk starts at ``cache["len"]``, which only the device knows. The
+# cache stays unexpanded, (B, KV, S_max, dh): one grid cell holds the whole
+# query group of a kv head, so a K and a V block are fetched once a group
+# and no ``expand_kv`` copy exists. Key blocks past the chunk's last row, and
+# before its first row's window, are neither copied nor computed.
+# ---------------------------------------------------------------------------
+
+
+def _chunk_flash_kernel(
+    off_ref,
+    q_ref,
+    k_ref,
+    v_ref,
+    o_ref,
+    acc_ref,
+    m_ref,
+    l_ref,
+    *,
+    block_kv: int,
+    num_kv: int,
+    sq: int,
+    s: float,
+    window: int | None,
+):
+    """One (batch·kv head, q-block, kv-block) grid cell, kv innermost:
+    ``_flash_kernel``'s online softmax for each of the ``group`` query heads
+    that share the cell's K and V block, with its tiles TRANSPOSED: scores
+    (bkv, bq), so that the reductions over keys run down the sublanes and
+    the statistics are (1, bq) rows, two vregs a head where a (bq, 1) column
+    is thirty-two; the accumulator (dh, bq), turned back once a q-block. On
+    a v5e at ``starcoder2-3b``'s shape, 1024 rows behind 3072: 0.58 ms
+    against 1.77 for the untransposed cell, whose time was its statistics'
+    (PERF.md, PR 36). Query row ``r`` of q-block ``qi`` stands at position
+    ``off + qi·bq + r``. Scores, statistics and the accumulator are f32; the
+    scale multiplies the f32 scores (the dense cached branch divides them:
+    no rounding of q·s to the operand dtype); over a bf16 V a probability
+    enters the value product as two bf16 halves in one pass
+    (``_group_tile``: 16 of its bits where Mosaic's own f32 product keeps
+    8)."""
+    off = off_ref[0]
+    qi = pl.program_id(1)
+    j = pl.program_id(2)
+    _, group, bq, _ = q_ref.shape
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, m_ref.dtype)
+        l_ref[...] = jnp.zeros(l_ref.shape, l_ref.dtype)
+
+    first_q = off + qi * bq
+    last_q = first_q + bq - 1
+    needed = j * block_kv <= last_q
+    if window is not None:
+        needed &= (j + 1) * block_kv - 1 >= first_q - (window - 1)
+
+    @pl.when(needed)
+    def _compute():
+        k_blk = k_ref[0]  # (bkv, D), shared by the group
+        k_pos = j * block_kv + lax.broadcasted_iota(jnp.int32, (block_kv, 1), 0)
+        # Rows past the chunk's end lie in its last block too: their
+        # probability is exactly zero, which only a finite value survives.
+        v_blk = jnp.where(k_pos < off + sq, v_ref[0], 0)
+        bf16 = v_blk.dtype == jnp.bfloat16
+        if not bf16:
+            v_blk = v_blk.astype(jnp.float32)
+        q_pos = first_q + lax.broadcasted_iota(jnp.int32, (1, bq), 1)
+        mask = k_pos <= q_pos  # (bkv, bq)
+        if window is not None:
+            mask &= k_pos > q_pos - window
+        # Unrolled: the heads' VPU and MXU work interleave (twice as fast
+        # as a fori_loop on the chip).
+        for g in range(group):
+            logits = lax.dot_general(
+                k_blk, q_ref[0, g], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * s  # (bkv, bq)
+            logits = jnp.where(mask, logits, NEG_INF)
+            m = m_ref[g]  # (1, bq)
+            m_new = jnp.maximum(m, logits.max(axis=0, keepdims=True))
+            m_safe = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
+            correction = jnp.exp(m - m_safe)
+            p = jnp.exp(logits - m_safe)
+            l_ref[g] = l_ref[g] * correction + p.sum(axis=0, keepdims=True)
+            if bf16:
+                hi = p.astype(jnp.bfloat16)
+                lo = (p - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+                p = jnp.concatenate([hi, lo], axis=1)  # (bkv, 2 bq)
+            pv = lax.dot_general(
+                v_blk, p, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # V^T P^T: (D, bq), or the two halves side by side
+            if bf16:
+                pv = pv[:, :bq] + pv[:, bq:]
+            acc_ref[g] = acc_ref[g] * correction + pv
+            m_ref[g] = m_safe + jnp.where(m_new <= NEG_INF / 2, NEG_INF, 0.0)
+
+    @pl.when(j == num_kv - 1)
+    def _finalize():
+        for g in range(group):
+            o_ref[0, g] = (
+                acc_ref[g] / jnp.maximum(l_ref[g], 1e-30)
+            ).T.astype(o_ref.dtype)
+
+
+def _chunk_kv_index(block_q: int, block_kv: int, num_kv: int,
+                    window: int | None):
+    """:func:`_causal_kv_index`'s clamp with the prefetched offset in the
+    static's place: the mapped block stays constant across a q-tile's
+    skipped cells, so their copies are elided."""
+
+    def kv_index(bk, i, j, off_ref):
+        off = off_ref[0]
+        last_block = jnp.clip(
+            (off + (i + 1) * block_q - 1) // block_kv, 0, num_kv - 1)
+        blk = jnp.minimum(j, last_block)
+        if window is not None:
+            first_block = jnp.clip(
+                (off + i * block_q - (window - 1)) // block_kv, 0, num_kv - 1)
+            blk = jnp.maximum(blk, first_block)
+        return (bk, blk, 0)
+
+    return kv_index
+
+
+def _tile_block(cap: int, seq: int, rows: int) -> int:
+    """The largest divisor of ``seq`` up to ``cap`` that is a whole number
+    of 128 lanes, or else of ``rows`` (a sublane tile)."""
+    for unit in (128, rows):
+        for b in range(min(cap, seq) // unit * unit, 0, -unit):
+            if seq % b == 0:
+                return b
+    raise ValueError(f"{seq} rows are no whole number of {rows}-row tiles")
+
+
+def chunk_flash_fits(dtype, head_dim: int, rows) -> bool:
+    """Whether :func:`chunk_flash_attention` takes a logical cache of
+    ``dtype`` with heads of ``head_dim`` at the row counts ``rows`` (the
+    cache's ``S_max`` and every chunk width): the head a whole number of
+    128 lanes and every count a whole number of the dtype's sublane tiles,
+    so that every block is cut on a tile. A rule on shapes, the same off
+    the TPU as on it (:func:`paged_decode_fits`)."""
+    tile = _sublane_rows(dtype)
+    return head_dim % 128 == 0 and all(int(n) % tile == 0 for n in rows)
+
+
+def chunk_flash_attention(
+    q,
+    k,
+    v,
+    q_offset,
+    *,
+    window: int | None = None,
+    interpret: bool | None = None,
+):
+    """Causal attention of a block of query rows over a logical cache.
+
+    ``q`` (B, H, s, head_dim), already rotated, its row ``r`` at position
+    ``q_offset + r``; ``k`` / ``v`` (B, kv_heads, S_max, head_dim),
+    unexpanded, holding the chunk's own rows at ``[q_offset, q_offset + s)``
+    already; ``q_offset`` any int32 scalar, traced or not, with ``q_offset +
+    s <= S_max``. A row attends the positions up to its own, the last
+    ``window`` of them where one is given. Returns (B, H, s, head_dim) in
+    ``q``'s dtype. No (s, S_max) score matrix exists: the key blocks a chunk
+    cannot see are neither copied nor computed, whatever they hold.
+
+    Scores and softmax statistics are f32 over the operands' own dtype, and
+    a probability keeps 16 bits in the value product over a bf16 cache
+    (f32 over an f32 one), as in :func:`paged_decode_attention`'s group
+    form."""
+    b, h, sq, dh = q.shape
+    kv, s_max = k.shape[1], k.shape[2]
+    if k.shape != v.shape or k.shape[0] != b or k.shape[3] != dh or h % kv:
+        raise ValueError(
+            f"q {q.shape} does not fit cache k {k.shape} / v {v.shape}")
+    if sq > s_max:
+        raise ValueError(f"{sq} query rows exceed the cache's {s_max}")
+    if not chunk_flash_fits(k.dtype, dh, (s_max, sq)):
+        raise ValueError(
+            f"chunk of {sq} rows over a cache {k.shape} of {k.dtype} is off "
+            f"the tile: the dense branch serves it")
+    if window is not None and window < 1:
+        raise ValueError(f"window must be >= 1, got {window}")
+    if window is not None and window >= s_max:
+        window = None  # it hides no position of this cache
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    rows = _sublane_rows(k.dtype)
+    # Blocks of 512 x 512 at up to 12 query heads a kv head (measured:
+    # 0.58 / 0.30 / 0.15 ms behind 3072 / 1024 / 0 rows; 256 x 256 0.75 /
+    # 0.37 / 0.17; 512 x 1024 0.60 / - / 0.18): a cell holds the group's q
+    # rows and its f32 accumulator, about 6k rows between them.
+    block_q = _tile_block(max(rows, min(512, 6144 // (h // kv))), sq, rows)
+    return _chunk_flash_call(
+        q, k, v, jnp.asarray(q_offset, jnp.int32).reshape(1),
+        scale=_scale(q, None), window=window, block_q=block_q,
+        block_kv=_tile_block(512, s_max, rows), interpret=bool(interpret))
+
+
+# Jitted like _paged_decode_call: a model's layers trace the kernel once.
+@functools.partial(
+    jax.jit,
+    static_argnames=("scale", "window", "block_q", "block_kv", "interpret"),
+)
+def _chunk_flash_call(q, k, v, offset, *, scale, window, block_q, block_kv,
+                      interpret):
+    b, h, sq, dh = q.shape
+    kv, s_max = k.shape[1], k.shape[2]
+    group = h // kv
+    num_kv = s_max // block_kv
+    kernel = functools.partial(
+        _chunk_flash_kernel, block_kv=block_kv, num_kv=num_kv, sq=sq,
+        s=scale, window=window)
+    kv_index = _chunk_kv_index(block_q, block_kv, num_kv, window)
+    q_spec = pl.BlockSpec(
+        (1, group, block_q, dh), lambda bk, i, j, off: (bk, 0, i, 0))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b * kv, sq // block_q, num_kv),
+            in_specs=[
+                q_spec,
+                pl.BlockSpec((1, block_kv, dh), kv_index),
+                pl.BlockSpec((1, block_kv, dh), kv_index),
+            ],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((group, dh, block_q), jnp.float32),
+                pltpu.VMEM((group, 1, block_q), jnp.float32),
+                pltpu.VMEM((group, 1, block_q), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b * kv, group, sq, dh), q.dtype),
+        interpret=interpret,
+        name="prefill_chunk_attention",
+    )(offset, q.reshape(b * kv, group, sq, dh),
+      k.reshape(b * kv, s_max, dh), v.reshape(b * kv, s_max, dh))
+    return out.reshape(b, h, sq, dh)

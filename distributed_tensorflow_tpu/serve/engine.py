@@ -210,6 +210,7 @@ from distributed_tensorflow_tpu.models.transformer import (
 )
 from distributed_tensorflow_tpu.obs import trace as _trace
 from distributed_tensorflow_tpu.ops.attention import (
+    chunk_flash_fits,
     paged_decode_fits,
     paged_decode_form,
 )
@@ -476,6 +477,7 @@ class SlotEngine:
         self.prefix = PrefixCache(self.pool) if prefix_cache else None
         self.decode_path = self._decode_path()
         self.decode_kernel_form = self._decode_kernel_form()
+        self.prefill_path = self._prefill_path()
 
         # Per-slot host registers. Fixed dtypes — the jit signatures (and
         # therefore the zero-recompile guarantee) depend on them.
@@ -530,6 +532,7 @@ class SlotEngine:
             # No counter: fixed with the decode program, kept here for
             # whoever reads the rounds' counts beside it.
             "decode_kernel_form": self.decode_kernel_form,
+            "prefill_path": self.prefill_path,
         }
         # EVA: positions each slot's request ends at (prompt + budget),
         # which sizes the pages a window roll binds.
@@ -593,6 +596,10 @@ class SlotEngine:
                 (byte-identical — the forward never writes below m0) and
                 unbound tail entries land in the trash page."""
                 cache = gather_cache(pool_layers, row, prefix_len)
+                if self.prefill_path == "flash":
+                    # The cached branch attends the chunk by blocks where
+                    # the cache says so, as "pages" says the table path.
+                    cache["flash"] = True
                 logits, cache = model.apply(
                     {"params": params}, tokens, cache=cache,
                     logit_rows=(length - prefix_len - 1)[None],
@@ -1198,7 +1205,10 @@ class SlotEngine:
         leaf dtype's sublane tiles, 16 rows of bf16 or 8 of f32, the head
         size a whole number of lanes); ``"gather"`` — every slot's logical
         cache is gathered from its table row — everywhere else. The
-        prefill, chunk and verify programs gather on either path."""
+        prefill, chunk and verify programs gather on either path; how a
+        prefill or chunk program then attends its gathered row is
+        ``prefill_path``'s to say, and the verify programs attend it
+        densely."""
         leaves = self.pool.layers[0]
         if self._eva or self._cca:
             # Always through the table: the composed row IS the cache (EVA);
@@ -1209,6 +1219,26 @@ class SlotEngine:
         if set(leaves) == {"k", "v"} and paged_decode_fits(leaves["k"]):
             return "table"
         return "gather"
+
+    def _prefill_path(self) -> str:
+        """How the prefill and chunk programs (``prefill_fn``) attend the
+        row they gather, fixed here once like ``decode_path``: ``"flash"``
+        — the chunk's query rows attend the live keys by blocks at the
+        traced offset (``ops.attention.chunk_flash_attention``) — when the
+        pool's leaves are plain ``k`` / ``v`` rows and the kernel takes the
+        shapes (``ops.attention.chunk_flash_fits``: the head a whole number
+        of lanes, ``max_len`` and every ``prefill_buckets`` width a whole
+        number of the leaf dtype's sublane tiles); ``"dense"`` — scores
+        over all ``max_len`` positions, masked — everywhere else: int8
+        pages, the CPU smoke shapes with heads of 8-32, and the EVA and CCA
+        prefill programs, whose sublayers have dense sites of their own."""
+        leaves = self.pool.layers[0]
+        if self._eva or self._cca or set(leaves) != {"k", "v"}:
+            return "dense"
+        k = leaves["k"]
+        fits = chunk_flash_fits(
+            k.dtype, k.shape[3], (self.max_len, *self.prefill_buckets))
+        return "flash" if fits else "dense"
 
     def _decode_kernel_form(self) -> str | None:
         """How the paged kernel of the plain decode program forms its two
@@ -1399,7 +1429,8 @@ class SlotEngine:
         # program's first call makes its tracing slower (warm-up is
         # measurably longer three frames deeper).
         p = int(prompt.size)
-        with _trace.span("engine.start", flight=False, prompt_len=p) as sp:
+        with _trace.span("engine.start", flight=False, prompt_len=p,
+                         path=self.prefill_path) as sp:
             matched0 = self.stats["prefix_tokens_matched"]
             if p < 1:
                 raise ValueError("prompt must contain at least one token")
@@ -1821,7 +1852,7 @@ class SlotEngine:
         # Only the final chunk blocks (on its token); the others return as
         # soon as the program is queued.
         with _trace.span("engine.prefill_chunk", flight=False, offset=m,
-                         width=w, final=final):
+                         width=w, final=final, path=self.prefill_path):
             if self._eva:
                 return self._run_eva_segment(st, m, w, final)
             if self._cca:
@@ -2719,6 +2750,10 @@ class ShardedSlotEngine(SlotEngine):
         # GSPMD does not partition a Pallas call: reading the pages in
         # place here would need shard_map over the kv heads.
         return "gather"
+
+    def _prefill_path(self) -> str:
+        # The same reason, for the chunk's kernel.
+        return "dense"
 
     def _place_params(self, candidate):
         # Swap candidates stage through the SAME rule-table shardings as

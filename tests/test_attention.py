@@ -465,3 +465,100 @@ def test_flash_packed_rope_fallback_grads_match_fused(monkeypatch, window):
     np.testing.assert_allclose(
         np.asarray(g_fb), np.asarray(g_fused), rtol=1e-4, atol=1e-4
     )
+
+
+# -- the prefill chunk's kernel: a traced query offset over a logical cache --
+
+_CHUNK_S, _CHUNK_MAX = 256, 1024  # on-tile, dh 128: block_kv 512, page 16
+
+
+def _chunk_dense(q, k, v, offset, window):
+    """The dense cached branch's own lines (``models/transformer.py``):
+    f32 scores over all S_max positions, masked, a softmax, f32 values."""
+    b, h, s, dh = q.shape
+    kv = k.shape[1]
+    qh = q.reshape(b, kv, h // kv, s, dh)
+    scores = jnp.einsum("bkgqd,bkTd->bkgqT", qh, k,
+                        preferred_element_type=jnp.float32,
+                        precision="highest") / np.sqrt(dh)
+    q_pos = offset + jnp.arange(s)
+    key_pos = jnp.arange(k.shape[2])
+    allowed = key_pos[None, :] <= q_pos[:, None]
+    if window is not None:
+        allowed &= key_pos[None, :] > q_pos[:, None] - window
+    scores = jnp.where(allowed[None, None, None], scores, A.NEG_INF)
+    weights = jax.nn.softmax(scores, -1)
+    return jnp.einsum("bkgqT,bkTd->bkgqd", weights, v.astype(jnp.float32),
+                      precision="highest").reshape(b, h, s, dh)
+
+
+def _chunk_case(kv, group, dtype, seed, batch=1):
+    r = np.random.default_rng(seed)
+    mk = lambda heads, rows: jnp.asarray(
+        r.standard_normal((batch, heads, rows, 128)), dtype)
+    return mk(kv * group, _CHUNK_S), mk(kv, _CHUNK_MAX), mk(kv, _CHUNK_MAX)
+
+
+@pytest.mark.parametrize("window", [None, 200], ids=["full", "window200"])
+@pytest.mark.parametrize("kv,group", [(2, 12), (2, 1)],
+                         ids=["kv2-group12", "kv2-group1"])
+@pytest.mark.parametrize("offset", [0, 512, 48, 331, _CHUNK_MAX - _CHUNK_S],
+                         ids=["zero", "block", "page", "odd", "end"])
+def test_chunk_flash_matches_the_dense_branch(offset, kv, group, window):
+    """Offsets 0, a key-block boundary, a page multiple off it, an odd one
+    (a final chunk starts at ``p - w``) and the cache's end, TRACED: one
+    compiled program serves them all."""
+    q, k, v = _chunk_case(kv, group, jnp.float32, seed=group)
+    fn = jax.jit(lambda q, k, v, off: A.chunk_flash_attention(
+        q, k, v, off, window=window))
+    got = fn(q, k, v, jnp.int32(offset))
+    assert got.shape == q.shape and got.dtype == q.dtype
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(_chunk_dense(q, k, v, offset, window)),
+        atol=3e-6, rtol=3e-6)
+
+
+@pytest.mark.parametrize("group", [12, 1], ids=["group12", "group1"])
+def test_chunk_flash_bf16_cache_keeps_16_bits_of_every_probability(group):
+    """A bf16 cache (the benchmark's): each probability enters the value
+    product as two bf16 halves, so what is left against the f32 dense
+    lines is the rounding of the output itself. (A product that rounded
+    the probabilities to bf16 reads 1.8 and 1.2 times that here.)"""
+    q, k, v = _chunk_case(2, group, jnp.bfloat16, seed=5)
+    got = A.chunk_flash_attention(q, k, v, jnp.int32(331), window=None)
+    assert got.dtype == jnp.bfloat16
+    want = np.asarray(_chunk_dense(q, k, v, 331, None))
+    rounding = np.abs(
+        np.asarray(jnp.asarray(want, jnp.bfloat16), np.float32) - want).max()
+    err = np.abs(np.asarray(got, np.float32) - want).max()
+    assert err <= 1.05 * rounding + 1e-6, (err, rounding)
+
+
+@pytest.mark.parametrize("offset,window", [(48, None), (331, 200), (0, None)],
+                         ids=["page", "odd-window", "zero"])
+def test_chunk_flash_reads_no_dead_key(offset, window):
+    """Every row past the chunk's end holds NaN, those of the chunk's last
+    key block among them: nothing dead reaches a product."""
+    q, k, v = _chunk_case(2, 3, jnp.float32, seed=7)
+    dead = offset + _CHUNK_S
+    kn, vn = k.at[:, :, dead:].set(jnp.nan), v.at[:, :, dead:].set(jnp.nan)
+    got = np.asarray(A.chunk_flash_attention(
+        q, kn, vn, jnp.int32(offset), window=window))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(
+        got, np.asarray(_chunk_dense(q, k, v, offset, window)),
+        atol=3e-6, rtol=3e-6)
+
+
+def test_chunk_flash_takes_on_tile_shapes_and_says_so():
+    fits = A.chunk_flash_fits
+    assert fits(jnp.bfloat16, 128, (4096, 1024))  # starcoder2-3b's cell
+    assert fits(jnp.float32, 128, (32, 16, 8))
+    assert not fits(jnp.bfloat16, 128, (4096, 1000))  # a bucket off the tile
+    assert not fits(jnp.bfloat16, 128, (4096, 1024, 8))  # 8 rows of bf16
+    assert not fits(jnp.float32, 32, (64, 16))  # the CPU smoke heads
+    q, k, v = _chunk_case(2, 1, jnp.float32, seed=0)
+    with pytest.raises(ValueError, match="off the tile"):
+        A.chunk_flash_attention(q[:, :, :251], k, v, 0)
+    with pytest.raises(ValueError, match="does not fit cache"):
+        A.chunk_flash_attention(q, k, v[:, :1], 0)

@@ -11,6 +11,7 @@ hook, as ``ShardedSlotEngine`` does), on the churn matrices of
 Shapes are small: off the TPU the kernel runs in interpret mode.
 """
 
+import time
 from dataclasses import replace
 
 import jax
@@ -270,6 +271,79 @@ def test_decode_path_is_fixed_by_what_the_engine_sees(want, form, make):
     assert engine.decode_path == want
     assert engine.decode_kernel_form == form
     assert engine.stats["decode_kernel_form"] == form
+
+
+class DenseEngine(SlotEngine):
+    """The same engine with the dense prefill lines, whatever its shapes."""
+
+    def _prefill_path(self):
+        return "dense"
+
+
+@pytest.mark.parametrize("want,make", [
+    ("flash", lambda: _engine(page_size=8)),
+    ("flash", lambda: _engine(_BF16, page_size=16)),
+    ("flash", lambda: _engine(_GQA, page_size=8, prefill_buckets=(8,))),
+    # What the kernel does not take, and what no cell runs: all dense.
+    ("dense", lambda: _engine(_SMALL, page_size=8)),
+    ("dense", lambda: _engine(replace(CFG, kv_cache_dtype="int8"),
+                              page_size=8)),
+    ("dense", lambda: _engine(_BF16, page_size=16, prefill_buckets=(8,))),
+    ("dense", lambda: _engine(page_size=4, max_len=36, prefill_len=12)),
+    ("dense", lambda: _engine(replace(CFG, max_seq_len=32),
+                              cls=ShardedSlotEngine, tp=2, page_size=8)),
+], ids=["f32", "bf16", "gqa-bucket8", "head32", "int8-kv",
+        "bf16-bucket8", "rows-off-tile", "sharded"])
+def test_prefill_path_is_fixed_by_what_the_engine_sees(want, make):
+    """``prefill_path`` beside ``decode_path``: from the pool's leaves, the
+    head size and the chunk widths, by no option; reported beside the
+    counters and on the spans of an admission."""
+    engine = make()
+    assert engine.prefill_path == want
+    assert engine.stats["prefill_path"] == want
+    t0 = time.monotonic()
+    engine.start(engine.acquire_slot(), [1, 2, 3], max_new_tokens=1)
+    ((_, _, attrs),) = trace.closed("engine.start", t0)
+    assert attrs["path"] == want
+
+
+def _prefix_chunk_requests():
+    """A long prompt (two whole chunks and a final chunk that starts at
+    the odd offset ``p - w``), a second that adopts its first three pages
+    and is chunked behind them, and short prompts padded to each bucket."""
+    rng = np.random.default_rng(17)
+    a = rng.integers(1, 64, 45).tolist()
+    b = a[:24] + rng.integers(1, 64, 30).tolist()
+    return [(a, {"max_new_tokens": 6}), (b, {"max_new_tokens": 5}),
+            (a[:5], {"max_new_tokens": 4}), (b[:27], {"max_new_tokens": 4}),
+            (rng.integers(1, 64, 11).tolist(), {"max_new_tokens": 7})]
+
+
+@pytest.mark.parametrize("variant", ["learned-mha", "rope-gqa-window"])
+def test_flash_prefill_serves_the_dense_prefills_tokens(variant):
+    """Chunked prefill, prefix adoption and a padded final chunk through
+    the kernel: greedy tokens identical to the same engine on the dense
+    lines, no recompile in either (``_drive`` asserts it)."""
+    from tests.test_serve_chunked import _drive as drive_chunked
+
+    cfg = replace(CFG, max_seq_len=96)
+    if variant == "rope-gqa-window":
+        cfg = replace(cfg, position="rope", num_kv_heads=1,
+                      attention_window=40)
+    params = TransformerLM(cfg).init(
+        jax.random.PRNGKey(1), jnp.zeros((1, 8), jnp.int32))["params"]
+    kw = dict(slots=2, max_len=96, prefill_len=16, page_size=8,
+              prefill_buckets=(8,), prefix_cache=True)
+    flash, dense = SlotEngine(cfg, params, **kw), DenseEngine(cfg, params, **kw)
+    assert (flash.prefill_path, dense.prefill_path) == ("flash", "dense")
+    t0 = time.monotonic()
+    got = drive_chunked(flash, _prefix_chunk_requests())
+    chunks = [a for _, _, a in trace.closed("engine.prefill_chunk", t0)]
+    assert chunks and {a["path"] for a in chunks} == {"flash"}
+    # The final chunk of the 45-token prompt starts at 45 - 16.
+    assert 29 in {a["offset"] for a in chunks}
+    assert flash.stats["prefix_tokens_matched"] >= 24
+    assert got == drive_chunked(dense, _prefix_chunk_requests())
 
 
 def test_kv_rows_read_counts_live_pages_on_the_table_path(params):

@@ -132,10 +132,11 @@ def test_one_round_closes_each_span_once_nested_at_most_twelve(params):
     assert a["sched.admit"][2] == {"admitted": 1}
     assert c["sched.admit"][2] == {"admitted": 0}
     assert a["sched.queue_wait"][2] == {"lane": 1, "prompt_len": 20}
+    # dh 8 is no head size the chunk's kernel takes either: dense prefill.
     assert a["engine.start"][2] == {"prompt_len": 20, "matched": 0,
-                                    "chunks": 3}
+                                    "chunks": 3, "path": "dense"}
     assert c["engine.prefill_chunk"][2] == {"offset": 0, "width": 8,
-                                            "final": False}
+                                            "final": False, "path": "dense"}
     # dh 8 is no head size the table path takes: the gather path reads
     # every slot's whole row, 2 slots of 48. The round the admitting call
     # read was queued by the call before it, ahead of its reading: it ran

@@ -203,3 +203,61 @@ def test_zaya_prefill_chunk_costs_its_tokens_not_sixteen_times(one_chip):
     assert one_expert < flops < 4 * one_expert, flops
     assert f"f32[1,1,{cfg.vocab_size}]" in compiled.as_text()
     assert f"[1,{width},{cfg.vocab_size}]" not in compiled.as_text()
+
+
+def test_sc2_prefill_chunk_holds_no_score_matrix(one_chip, monkeypatch):
+    """``starcoder2-3b``'s ``prefill_fn`` as the two ``sc2-3b`` cells build
+    it (16 slots of 4096, pages of 16, one bucket of 1024; cut to one layer
+    and a 4,096-row vocabulary so that it compiles in seconds), lowered for
+    the v5e: the chunk attends through the ``prefill_chunk_attention``
+    custom call at 12 query heads a kv head, and the program holds no
+    float32 array of (..., 1024, 4096): the dense lines' scores, 403 MB a
+    layer, and their softmax."""
+    import json
+    import re
+
+    from distributed_tensorflow_tpu.models.transformer import (
+        TransformerConfig,
+        TransformerLM,
+    )
+    from distributed_tensorflow_tpu.serve.engine import SlotEngine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                        "configs", "starcoder2-3b.json")
+    with open(path) as fh:
+        conf = json.load(fh)
+    mcfg = dict(conf["transformer_config"], num_layers=1, vocab_size=4096)
+    cfg = TransformerConfig(**mcfg, compute_dtype=jnp.bfloat16)
+    shapes = jax.eval_shape(lambda: TransformerLM(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    serve = conf["serve_config"]
+    engine = SlotEngine(
+        cfg,
+        jax.tree_util.tree_map(
+            lambda a: jnp.zeros(a.shape, jnp.bfloat16), shapes),
+        slots=serve["slots"], max_len=serve["serve_max_len"],
+        prefill_len=serve["prefill_len"],
+    )
+    assert engine.prefill_path == "flash"
+    assert engine.prefill_buckets == (1024,) and engine.page_size == 16
+
+    def arg(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    scalar = lambda dt: jax.ShapeDtypeStruct((), dt, sharding=one_chip)
+    compiled = engine._prefill_greedy.lower(
+        jax.tree_util.tree_map(arg, engine.pool.layers),
+        jax.tree_util.tree_map(arg, engine.params),
+        jax.ShapeDtypeStruct((1, 1024), jnp.int32, sharding=one_chip),
+        scalar(jnp.int32), scalar(jnp.int32),
+        arg(engine.pool.page_tables[0]),
+        scalar(jnp.float32), scalar(jnp.int32), scalar(jnp.float32),
+        scalar(jnp.uint32),
+    ).compile()
+    text = compiled.as_text()
+    (call,) = [l.split(" custom-call(")[0].strip() for l in text.splitlines()
+               if "tpu_custom_call" in l and " custom-call(" in l]
+    assert call.startswith("%prefill_chunk_attention")
+    assert "bf16[2,12,1024,128]" in call
+    assert not re.search(r"f32\[[\d,]*1024,4096\]", text)

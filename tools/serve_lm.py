@@ -176,7 +176,8 @@ def build_stack(serve_cfg, cfg, params, deploy_cfg=None):
             draft_window=getattr(serve_cfg, "draft_window", 16),
         )
         built.note(decode_path=engine.decode_path,
-                   decode_kernel_form=engine.decode_kernel_form)
+                   decode_kernel_form=engine.decode_kernel_form,
+                   prefill_path=engine.prefill_path)
     # What the build fixes (mesh width, bytes per device, dtype labels) is
     # mirrored to /metrics here, once: sync_engine never walks the params.
     metrics.bind_engine(engine)
