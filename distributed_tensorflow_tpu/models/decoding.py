@@ -237,7 +237,10 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
         return buf
 
     return {
-        "layers": [layer() for _ in range(cfg.num_layers)],
+        # A layer_pattern config: only its attention layers ('*') hold rows
+        # (the serving pool adds an 'M' layer's slot state beside them).
+        "layers": [layer() if kind == "*" else {} for kind in (
+            getattr(cfg, "layer_pattern", None) or "*" * cfg.num_layers)],
         "len": jnp.zeros((), jnp.int32),
     }
 

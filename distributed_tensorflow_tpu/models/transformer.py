@@ -27,8 +27,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from distributed_tensorflow_tpu.models.mamba import mamba_mixer
 from distributed_tensorflow_tpu.models.moe import routed_experts
 from distributed_tensorflow_tpu.ops import attention as A
+from distributed_tensorflow_tpu.ops.grouped_matmul import grouped_matmul_fits
 from distributed_tensorflow_tpu.ops.rope import apply_rope as _rotate_heads
 from distributed_tensorflow_tpu.ops.rope import rope_tables
 
@@ -56,10 +58,16 @@ class EvaUnsupported(ValueError):
     raised where the feature is asked for, never a silent fallback."""
 
 
-class CcaUnsupported(ValueError):
-    """A feature that is not extended to a CCA config (``cca_time0`` set) or
-    to routed experts (``num_experts`` set): raised where the feature is asked
-    for, never a silent fallback."""
+class SlotStateUnsupported(ValueError):
+    """A feature that is not extended to a config whose slots carry state
+    beside K/V rows (CCA's convolution latents, ``cca_time0`` set; a Mamba-2
+    layer's recurrent state, an ``M`` in ``layer_pattern``) or to routed
+    experts (``num_experts`` set): raised where the feature is asked for,
+    never a silent fallback."""
+
+
+# The name the exception had while CCA's was the only slot state.
+CcaUnsupported = SlotStateUnsupported
 
 
 @dataclass(frozen=True)
@@ -102,7 +110,9 @@ class TransformerConfig:
     # attention kernels — no position table params, relative offsets in the
     # dot product, fused-elementwise cost on TPU. Completes the
     # GQA + sliding-window + RoPE modern-attention trio.
-    position: str = "learned"  # 'learned' | 'rope'
+    # 'none': no position signal at all (a hybrid whose recurrent layers
+    # carry the order).
+    position: str = "learned"  # 'learned' | 'rope' | 'none'
     rope_theta: float = 10000.0
     # KV-cache storage dtype for decode (None = compute_dtype): 'int8'
     # stores per-row-quantized keys/values (symmetric absmax over head_dim,
@@ -178,23 +188,53 @@ class TransformerConfig:
     # Routed experts in place of the block's dense MLP, on when
     # ``num_experts`` > 0 (``models/moe.py``): an MLP router of width
     # ``router_hidden`` over all ``num_experts``, top-1, gated-SiLU experts
-    # of width ``expert_width``, no token dropped.
+    # of width ``expert_width``, no token dropped. The widths lie on the
+    # tiles of ``ops/grouped_matmul.py`` or the config is refused.
     # ``experts_held``: the experts THIS chip holds (all by default); the
     # layer routes over all of them and returns the part of the result the
     # held ones give.
+    # ``router_hidden`` 0 is the other router the layer has, a linear one:
+    # sigmoid scores of one matrix, the ``experts_per_token``
+    # largest of score + held bias chosen, their scores renormalised to sum
+    # to one and scaled by ``router_scale``.
+    # ``expert_act``: 'swiglu' (gate, up, down) or 'relu2' (up, down:
+    # relu(.)^2, no gate). ``shared_expert_width`` > 0 adds one always-on
+    # expert of that width on every token, unweighted.
     num_experts: int = 0
     router_hidden: int = 0
     expert_width: int = 0
     experts_held: tuple | None = None
+    experts_per_token: int = 1
+    router_scale: float = 1.0
+    expert_act: str = "swiglu"  # 'swiglu' | 'relu2'
+    shared_expert_width: int = 0
+    # Layers of ONE pre-norm sublayer each (None: every layer is attention
+    # then MLP, :class:`Block`): a string of ``num_layers`` kinds, ``M`` a
+    # Mamba-2 mixer (``models/mamba.py``), ``E`` the routed expert layer
+    # (``models/moe.py``), ``*`` attention (:func:`attention_sublayer`).
+    # An ``M`` layer has ``ssm_heads`` heads of ``ssm_head_dim`` channels, a
+    # state of ``ssm_state`` a channel, B and C shared by ``ssm_groups``
+    # groups of heads, a causal depthwise convolution of ``ssm_conv`` taps,
+    # and scans a prefill segment in blocks of ``ssm_block`` positions. The
+    # serving cache holds K/V pages for ``*`` layers, the recurrent and
+    # convolution state of every slot for ``M`` layers, nothing for ``E``.
+    layer_pattern: str | None = None
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_block: int = 128
 
     def __post_init__(self):
         # Every string-enum field that SELECTS behavior is validated here:
         # a typo ('Rope', 'rotary') must not silently pick the other path.
         # (attention also accepts callables; kv_cache_dtype None = compute
         # dtype.)
-        if self.position not in ("learned", "rope"):
+        if self.position not in ("learned", "rope", "none"):
             raise ValueError(
-                f"position must be 'learned' or 'rope', got {self.position!r}"
+                f"position must be 'learned', 'rope' or 'none', got "
+                f"{self.position!r}"
             )
         if self.kv_cache_dtype not in (None, "int8"):
             raise ValueError(
@@ -275,11 +315,41 @@ class TransformerConfig:
                     "a CCA config attends with attention='dense' (no flash, "
                     "blockwise or ring path takes its q and k)")
         if self.num_experts:
-            if (self.num_experts < 2 or self.router_hidden < 1
-                    or self.expert_width < 1):
+            if (self.num_experts < 2 or self.expert_width < 1
+                    or self.router_hidden < 0):
                 raise ValueError(
-                    "routed experts need num_experts >= 2 and positive "
-                    "router_hidden and expert_width")
+                    "routed experts need num_experts >= 2, a positive "
+                    "expert_width and router_hidden >= 0 (0: the linear "
+                    "router, which has no hidden layer)")
+            if not 1 <= self.experts_per_token <= self.num_experts:
+                raise ValueError(
+                    f"experts_per_token {self.experts_per_token} outside "
+                    f"[1, num_experts {self.num_experts}]")
+            if self.experts_per_token > 1 and self.router_hidden:
+                raise ValueError(
+                    "the MLP router picks one expert: experts_per_token > 1 "
+                    "needs the linear router, router_hidden 0")
+            if self.expert_act not in ("swiglu", "relu2"):
+                raise ValueError(
+                    f"expert_act must be 'swiglu' or 'relu2', got "
+                    f"{self.expert_act!r}")
+            # The experts' matrices as ``models/moe.py`` lays them.
+            d, wide = self.d_model, self.expert_width
+            mats = [((wide, d), False),
+                    ((d, 2 * wide), False) if self.expert_act == "swiglu"
+                    else ((wide, d), True)]
+            if not all(grouped_matmul_fits(jax.ShapeDtypeStruct(
+                    (1, *shape), self.compute_dtype), t)
+                       for shape, t in mats):
+                raise ValueError(
+                    f"experts of d_model {d} and expert_width {wide} in "
+                    f"{jnp.dtype(self.compute_dtype).name} are off the tiles "
+                    "of ops/grouped_matmul.py: d_model must be a whole "
+                    "number of 128 lanes and expert_width of the dtype's "
+                    "sublane rows (of 64 under 'swiglu', whose gate | up is "
+                    "2 * expert_width lanes)")
+            if self.shared_expert_width < 0:
+                raise ValueError("shared_expert_width must be >= 0")
             if self.weight_dtype is not None:
                 raise CcaUnsupported(
                     "weight-only quantisation is not extended to routed "
@@ -296,8 +366,40 @@ class TransformerConfig:
                         f"{self.num_experts}")
                 # A list from a JSON file: the config stays hashable.
                 object.__setattr__(self, "experts_held", held)
-        elif self.experts_held is not None:
-            raise ValueError("experts_held needs num_experts")
+        elif (self.experts_held is not None or self.shared_expert_width
+              or self.experts_per_token != 1):
+            raise ValueError(
+                "experts_held, experts_per_token and shared_expert_width "
+                "need num_experts")
+        if self.layer_pattern is not None:
+            pat = self.layer_pattern
+            if (not isinstance(pat, str) or len(pat) != self.num_layers
+                    or set(pat) - set("ME*")):
+                raise ValueError(
+                    f"layer_pattern {pat!r} must be num_layers "
+                    f"{self.num_layers} kinds, each of 'M', 'E', '*'")
+            if "E" in pat and not self.num_experts:
+                raise ValueError("an 'E' layer needs num_experts")
+            if "M" in pat:
+                if min(self.ssm_heads, self.ssm_head_dim, self.ssm_state,
+                       self.ssm_groups, self.ssm_block) < 1 or self.ssm_conv < 2:
+                    raise ValueError(
+                        "an 'M' layer needs positive ssm_heads, ssm_head_dim, "
+                        "ssm_state, ssm_groups and ssm_block, and ssm_conv "
+                        ">= 2 taps")
+                if self.ssm_heads % self.ssm_groups:
+                    raise ValueError(
+                        f"ssm_groups {self.ssm_groups} must divide ssm_heads "
+                        f"{self.ssm_heads}")
+            for name in ("eva_window", "cca_time0", "kv_cache_dtype",
+                         "weight_dtype", "residual_dtype"):
+                if getattr(self, name) is not None:
+                    raise SlotStateUnsupported(
+                        f"{name} is not extended to a layer_pattern config")
+            if self.dropout_rate or self.num_pred_heads != 1:
+                raise SlotStateUnsupported(
+                    "dropout and prediction heads are not extended to a "
+                    "layer_pattern config")
         if self.weight_dtype is not None or self.quant_group_size:
             # Lazy import: quant.py is standalone (flax/jax only), but the
             # module-level import order models/__init__ establishes should
@@ -346,6 +448,29 @@ class TransformerConfig:
         """Values one position of the CCA state holds: the pre-convolution
         q and k latents and the shifted half of v's projection."""
         return (self.num_heads + self.kv_heads + self.kv_heads // 2) * self.dh
+
+    @property
+    def ssm(self) -> bool:
+        """Whether a layer holds a recurrent state."""
+        return self.layer_pattern is not None and "M" in self.layer_pattern
+
+    @property
+    def ssm_inner(self) -> int:
+        """Channels of a Mamba-2 mixer: heads x head width."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """Channels the mixer's convolution runs over: x, B and C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def expert_layers(self) -> int:
+        """Layers with routed experts."""
+        if not self.num_experts:
+            return 0
+        return (self.num_layers if self.layer_pattern is None
+                else self.layer_pattern.count("E"))
 
     @property
     def held(self) -> tuple:
@@ -411,6 +536,15 @@ def block_norm(cfg, name: str):
         return RMSNorm(eps=cfg.norm_eps, unit_offset=cfg.norm_unit_offset,
                        dtype=cfg.compute_dtype, name=name)
     return nn.LayerNorm(dtype=cfg.compute_dtype, name=name)
+
+
+def _f32_norm(cfg, name: str):
+    """:func:`block_norm` computed and handed on in float32: what a router
+    reads."""
+    if cfg.norm == "rms":
+        return RMSNorm(eps=cfg.norm_eps, unit_offset=cfg.norm_unit_offset,
+                       name=name)
+    return nn.LayerNorm(dtype=jnp.float32, name=name)
 
 
 def _attention_fn(cfg: TransformerConfig, prefer_packed: bool = False) -> Callable:
@@ -1182,12 +1316,8 @@ class Block(nn.Module):
             with scope("mlp"):
                 # The router reads the norm in float32; the experts take it
                 # in compute_dtype.
-                norm = (RMSNorm(eps=cfg.norm_eps,
-                                unit_offset=cfg.norm_unit_offset, name="ln2")
-                        if cfg.norm == "rms"
-                        else nn.LayerNorm(dtype=jnp.float32, name="ln2"))
                 y, router_state, counts = routed_experts(
-                    self, cfg, norm(x), router_state,
+                    self, cfg, _f32_norm(cfg, "ln2")(x), router_state,
                     None if cache is None else cache.get("route_mask"))
                 x = x + y.astype(x.dtype)
             if cache is None:
@@ -1206,6 +1336,48 @@ class Block(nn.Module):
                 h = nn.Dropout(cfg.dropout_rate, deterministic=not train)(h)
             x = x + h
         return x if cache is None else (x, cache)
+
+
+class PatternBlock(nn.Module):
+    """One layer of a ``layer_pattern`` config: ONE pre-norm sublayer and
+    its residual, of ``kind`` ``M`` (``models/mamba.mamba_mixer``), ``E``
+    (``models/moe.routed_experts``) or ``*`` (:func:`attention_sublayer`: the
+    attention every other config runs). Returns ``x`` without a cache and
+    ``(x, cache)`` with one; the cache is the layer's own leaves (K/V pages or
+    a gathered row for ``*``, ``ssm`` / ``conv`` for ``M``, none for ``E``)
+    beside what every layer shares, and an ``E`` layer's carries
+    ``moe_counts`` back."""
+
+    cfg: TransformerConfig
+    kind: str
+
+    @nn.compact
+    def __call__(self, x, attend, train: bool = False, cache=None):
+        cfg = self.cfg
+        scope = _phase_scope(cache is not None)
+        if self.kind == "*":
+            with scope("attn"):
+                out = attention_sublayer(cfg, x, attend, train=train,
+                                         cache=cache)
+            return out[0] if cache is None else out
+        if self.kind == "E":
+            with scope("mlp"):
+                y, _, counts = routed_experts(
+                    self, cfg, _f32_norm(cfg, "ln1")(x), None,
+                    None if cache is None else cache.get("route_mask"))
+                x = x + y.astype(x.dtype)
+            return x if cache is None else (x, dict(cache, moe_counts=counts))
+        with scope("ssm"):
+            if cache is not None and "ssm" not in cache:
+                raise SlotStateUnsupported(
+                    "a cache of K/V rows holds no recurrent state: a "
+                    "layer_pattern config with 'M' layers is served through "
+                    "SlotEngine's pool")
+            h = block_norm(cfg, "ln1")(x)
+            if cache is None:
+                return x + mamba_mixer(self, cfg, h)
+            y, state = mamba_mixer(self, cfg, h, cache)
+            return x + y, dict(cache, **state)
 
 
 class TransformerLM(nn.Module):
@@ -1236,11 +1408,12 @@ class TransformerLM(nn.Module):
         x = embed(tokens)
         if cfg.residual_dtype is not None:
             x = x.astype(cfg.residual_dtype)
-        if cfg.position == "rope":
+        if cfg.position in ("rope", "none"):
             # No position table at all: positions enter as the q/k rotation
             # inside every attention sublayer (ops/rope.py). The blocks
             # receive the caller's global positions (sequence shards) or
-            # default to cache-offset arange inside the sublayer.
+            # default to cache-offset arange inside the sublayer. ('none':
+            # nor there.)
             pass
         else:
             pos_embed = nn.Embed(
@@ -1273,6 +1446,10 @@ class TransformerLM(nn.Module):
             )
             router = None  # routed experts: the layer before's router vector
             for i in range(cfg.num_layers):
+                if cfg.layer_pattern:
+                    x = PatternBlock(cfg, cfg.layer_pattern[i],
+                                     name=f"block_{i}")(x, attend, train)
+                    continue
                 x = block_cls(cfg, name=f"block_{i}")(
                     x, attend, train, positions=rope_positions,
                     router_state=router,
@@ -1289,6 +1466,19 @@ class TransformerLM(nn.Module):
             router, counts = None, []
             for i in range(cfg.num_layers):
                 layer = dict(cache["layers"][i], **shared)
+                if cfg.layer_pattern:
+                    if self_mask is not None:
+                        raise SlotStateUnsupported(
+                            "tree attention (self_mask) is not extended to a "
+                            "layer_pattern config")
+                    x, layer = PatternBlock(
+                        cfg, cfg.layer_pattern[i], name=f"block_{i}")(
+                        x, attend, train=train, cache=layer)
+                    if "moe_counts" in layer:
+                        counts.append(layer.pop("moe_counts"))
+                    new_layers.append(
+                        {k_: v_ for k_, v_ in layer.items() if k_ not in shared})
+                    continue
                 out = Block(cfg, name=f"block_{i}")(
                     x, attend, train=train, cache=layer,
                     positions=rope_positions, self_mask=self_mask,

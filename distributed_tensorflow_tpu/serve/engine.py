@@ -133,20 +133,34 @@ round's record carries ``summary_rows_read`` / ``window_rows_read``. Slot
 export/import, speculation, chunking off and the sharded engine refuse
 such a config (``EvaUnsupported``).
 
-A CCA config (``cfg.cca_time0`` set; ``models/transformer.
-cca_attention_sublayer``) runs the same engine on plain K/V pages plus the
-pool's per-slot convolution state (``serve/kv_pool.py``: the ``cca`` leaf of
-every layer, donated through every program with the pages): decode always
-goes through the table (``table_forward``, which also tells the model which
-lanes are live: a masked lane leaves its state alone and reaches no expert);
-prefill is planned as segments that never overlap (``_start_cca``), each
-through ``cca_prefill_fn``, which reads zeros for the state at position 0
-and writes the state behind the segment's last real token. A config with
-routed experts (``cfg.num_experts``; ``models/moe.py``) makes ``step_fn``
-return three counts beside its tokens, which ``engine.round`` and ``stats``
-carry (``experts_touched``, ``expert_tokens_max``; ``moe_tokens_routed``,
-``moe_experts_touched``). The prefix cache, speculation, slot export/import
-and the sharded engine refuse such a config (``CcaUnsupported``).
+A SEGMENTED config is one whose slots carry state beside their K/V rows, or
+whose tokens are routed: a CCA config (``cfg.cca_time0`` set;
+``models/transformer.cca_attention_sublayer``; the pool's ``cca`` leaf of
+every layer), a ``layer_pattern`` config (layers of ONE kind each,
+``models/transformer.PatternBlock``: ``M`` a Mamba-2 mixer whose layer holds
+the per-slot leaves ``ssm`` and ``conv`` and no pages, ``E`` routed experts
+that hold nothing, ``*`` attention that holds the pages, so one page table a
+slot serves the ``*`` layers alone), and any config with routed experts
+(``cfg.num_experts``; ``models/moe.py``). It runs the same engine; the state
+leaves ride the donated pool through every program as the pages do
+(``serve/kv_pool.py``), so a round run ahead is queued from the round
+before's state with no host read. Decode always goes through the table
+(``table_forward``, which also tells the model which lanes are live: a masked
+lane leaves its state alone and reaches no expert); prefill is planned as
+segments that never overlap (``_start_segments``), each through
+``segment_prefill_fn``, which reads zeros for the state at position 0 (a
+reused slot starts from zeros), writes the state behind the segment's last
+real token and keeps the padding from the experts: a prompt prefilled in
+chunks gives the logits of one prefilled whole. With routed experts
+``step_fn`` returns three counts beside its tokens, which ``engine.round``
+and ``stats`` carry (``experts_touched``, ``expert_tokens_max``, of (token,
+expert) pairs; ``moe_tokens_routed``, ``moe_experts_touched``); with a
+recurrent state ``engine.round`` carries ``ssm_lanes``,
+``engine.prefill_chunk`` ``ssm_blocks`` and ``stats`` ``ssm_state_bytes`` and
+``ssm_tokens_scanned``. The prefix cache (an adopted boundary would need a
+snapshot of the state), speculation and the sharded engine refuse a
+segmented config, and slot export/import one with slot state
+(``SlotStateUnsupported``; ``CcaUnsupported`` is its older name).
 
 Tracing (``obs/trace.py``; always on, no switch): the host side of a round
 closes ``engine.round`` around ``engine.prefill_chunk`` (one per chunk
@@ -204,8 +218,8 @@ from distributed_tensorflow_tpu.models.decoding import (
     tree_rejection_verify_row,
 )
 from distributed_tensorflow_tpu.models.transformer import (
-    CcaUnsupported,
     EvaUnsupported,
+    SlotStateUnsupported,
     TransformerLM,
 )
 from distributed_tensorflow_tpu.obs import trace as _trace
@@ -341,29 +355,40 @@ class SlotEngine:
                     f"({c}) that divides eva_window {cfg.eva_window}: a "
                     f"prefill segment never straddles a window")
         self._cca = bool(getattr(cfg, "cca", False))
+        self._ssm = bool(getattr(cfg, "ssm", False))
         self._moe = bool(getattr(cfg, "num_experts", 0))
-        if self._cca:
-            # What the per-slot convolution state is not extended to refuses
-            # here, by name, rather than serving something else.
+        # Configs whose prefill is planned as padded segments that never
+        # overlap and whose decode always goes through the table, with the
+        # live lanes and real rows told to the model: slots that carry state
+        # beside their rows (CCA, a Mamba-2 layer), routed experts (an idle
+        # lane or a padding row must reach none), any layer_pattern.
+        self._segmented = (self._cca or self._moe or
+                           getattr(cfg, "layer_pattern", None) is not None)
+        if self._segmented:
+            # What the slot state and the segment plan are not extended to
+            # refuses here, by name, rather than serving something else.
+            what, why_spec, why_prefix = (
+                ("CCA", "a rejected draft would have to roll the convolution "
+                 "state back", "an adopted boundary needs a snapshot of the "
+                 "convolution state at it") if self._cca
+                else ("a recurrent state", "a rejected draft would have to "
+                      "roll the state back", "an adopted boundary needs a "
+                      "snapshot of the state at it") if self._ssm
+                else ("routed experts", "the verify programs do not keep "
+                      "idle lanes from the experts", "their prefill is "
+                      "planned as padded segments, which adopt nothing"))
             if spec_k:
-                raise CcaUnsupported(
-                    "speculation (spec_k > 0) is not extended to CCA: a "
-                    "rejected draft would have to roll the convolution "
-                    "state back")
+                raise SlotStateUnsupported(
+                    f"speculation (spec_k > 0) is not extended to {what}: "
+                    f"{why_spec}")
             if prefix_cache:
-                raise CcaUnsupported(
-                    "the prefix cache is not extended to CCA: an adopted "
-                    "boundary needs a snapshot of the convolution state at "
-                    "it (pass prefix_cache=False)")
-        if self._moe and not self._cca:
-            raise CcaUnsupported(
-                "routed experts (num_experts) are served only with CCA "
-                "(cca_time0 / cca_time1): the engine keeps masked lanes "
-                "from the experts, and counts the experts a round touches, "
-                "on the CCA programs alone")
-        if (self._cca or self._moe) and getattr(self, "tp", 1) > 1:
-            raise CcaUnsupported(
-                "ShardedSlotEngine has no path for CCA or routed experts")
+                raise SlotStateUnsupported(
+                    f"the prefix cache is not extended to {what}: "
+                    f"{why_prefix} (pass prefix_cache=False)")
+            if getattr(self, "tp", 1) > 1:
+                raise SlotStateUnsupported(
+                    "ShardedSlotEngine has no path for CCA, a recurrent "
+                    "state or routed experts")
         self.cfg = cfg
         # Place params through the same path swap candidates stage through
         # (``_place_params``): a checkpoint bundle arrives as host numpy,
@@ -525,10 +550,15 @@ class SlotEngine:
             "eva_windows_rolled": 0,
             "eva_summary_pages_adopted": 0,
             "eva_window_pages_released": 0,
-            # Routed experts, over the decode rounds read so far: tokens that
-            # reached a held expert, and (layer, expert) pairs with a token.
+            # Routed experts, over the decode rounds read so far: (token,
+            # expert) pairs that reached a held expert, and (layer, expert)
+            # pairs with a token.
             "moe_tokens_routed": 0,
             "moe_experts_touched": 0,
+            # A recurrent state: what the pool holds of it (no counter), and
+            # the prompt tokens the prefill segments scanned.
+            "ssm_state_bytes": self.pool.state_bytes if self._ssm else 0,
+            "ssm_tokens_scanned": 0,
             # No counter: fixed with the decode program, kept here for
             # whoever reads the rounds' counts beside it.
             "decode_kernel_form": self.decode_kernel_form,
@@ -679,32 +709,40 @@ class SlotEngine:
 
             return eva_prefill_fn
 
-        def make_cca_prefill(sampled: bool):
-            def cca_prefill_fn(
+        def make_segment_prefill(sampled: bool):
+            state_leaves = self.pool.state_leaves
+
+            def segment_prefill_fn(
                 pool_layers, params, tokens, n_real, abs_start, row, slot,
                 temp, top_k, top_p, seed,
             ):
-                """One prefill segment of a CCA slot: ``n_real`` tokens
-                (padded to the bucket) at position ``abs_start``, appended
-                behind the slot's gathered rows (a bucket's worth of trash
-                entries behind the row takes the padding's junk rows). The
-                slot's convolution state (the pool's ``cca`` leaf, row
-                ``slot``) is read where the segment continues a prompt and
-                is ZERO where it starts one (``abs_start`` 0: a reused slot
-                starts from zeros), and is written back as it stands behind
-                the last real token: the next segment, or the first decode
-                round, continues from it. Padding reaches no expert."""
+                """One prefill segment of a slot of a segmented config:
+                ``n_real`` tokens (padded to the bucket) at position
+                ``abs_start``, appended behind the slot's gathered rows (a
+                bucket's worth of trash entries behind the row takes the
+                padding's junk rows). The slot's state (the pool's ``cca``,
+                or ``ssm`` and ``conv``, leaves of the layers that have
+                them, row ``slot``) is read where the segment continues a
+                prompt and is ZERO where it starts one (``abs_start`` 0: a
+                reused slot starts from zeros), and is written back as it
+                stands behind the last real token: the next segment, or the
+                first decode round, continues from it. Padding reaches no
+                expert."""
                 width = tokens.shape[1]
                 table = jnp.concatenate([
                     row, jnp.full((-(-width // ps),), TRASH_PAGE, row.dtype)])
                 cache = gather_cache(
-                    [{k: l[k] for k in ("k", "v")} for l in pool_layers],
-                    table, abs_start)
+                    [{k: l[k] for k in ("k", "v") if k in l}
+                     for l in pool_layers], table, abs_start)
                 for cl, pl in zip(cache["layers"], pool_layers):
-                    cl["cca"] = jnp.where(
-                        abs_start == 0, 0, pl["cca"][slot])[None]
+                    for name in state_leaves:
+                        if name in pl:
+                            cl[name] = jnp.where(
+                                abs_start == 0, 0, pl[name][slot])[None]
                 cache["n_real"] = n_real[None]
                 cache["route_mask"] = (jnp.arange(width) < n_real)[None]
+                if self.prefill_path == "flash":
+                    cache["flash"] = True
                 logits, cache = model.apply(
                     {"params": params}, tokens, cache=cache,
                     logit_rows=(n_real - 1)[None],
@@ -713,8 +751,12 @@ class SlotEngine:
                 with jax.named_scope("kv.scatter"):
                     new_pool = []
                     for pl, cl in zip(pool_layers, cache["layers"]):
-                        layer = {"cca": pl["cca"].at[slot].set(cl["cca"][0])}
-                        for name in ("k", "v"):
+                        layer = {}
+                        for name in pl:
+                            if name in state_leaves:
+                                layer[name] = pl[name].at[slot].set(
+                                    cl[name][0])
+                                continue
                             logical = cl[name][0]  # (kv, rows, dh)
                             kv, dh = logical.shape[0], logical.shape[-1]
                             layer[name] = pl[name].at[table].set(jnp.swapaxes(
@@ -722,7 +764,7 @@ class SlotEngine:
                         new_pool.append(layer)
                 return new_pool, first
 
-            return cca_prefill_fn
+            return segment_prefill_fn
 
         def _select(sampled, last, temp, top_k, top_p, seed):
             with jax.named_scope("sample"):
@@ -790,8 +832,8 @@ class SlotEngine:
                     "write_page": jnp.where(active, dest, TRASH_PAGE),
                     "attend": jnp.where(active, lengths + 1, 0),
                 }
-                if self._cca:
-                    # A masked lane feeds no real row: its convolution state
+                if self._segmented:
+                    # A masked lane feeds no real row: its slot's state
                     # stands, and its token reaches no expert.
                     cache["n_real"] = active.astype(jnp.int32)
                     cache["route_mask"] = active[:, None]
@@ -859,8 +901,8 @@ class SlotEngine:
                 if counts is None:
                     return out
                 # Routed experts: (layer, expert) pairs with a token, the
-                # most tokens one of them got, the tokens routed; read back
-                # with the round's tokens.
+                # most (token, expert) pairs one of them got, the pairs
+                # routed; read back with the round's tokens.
                 return out + (jnp.stack([
                     (counts > 0).sum(), counts.max(), counts.sum()
                 ]).astype(jnp.int32),)
@@ -1136,7 +1178,8 @@ class SlotEngine:
         # member, and the compile-count assert covers the lot.
         donate = (0,)  # the pool's leaves, through every program
         prefill_of = (make_eva_prefill if self._eva
-                      else make_cca_prefill if self._cca else make_prefill)
+                      else make_segment_prefill if self._segmented
+                      else make_prefill)
         self._prefill_greedy = self._jit_program(
             prefill_of(False), "prefill", donate
         )
@@ -1210,9 +1253,10 @@ class SlotEngine:
         ``prefill_path``'s to say, and the verify programs attend it
         densely."""
         leaves = self.pool.layers[0]
-        if self._eva or self._cca:
+        if self._eva or self._segmented:
             # Always through the table: the composed row IS the cache (EVA);
-            # the convolution state is one row a slot beside it (CCA).
+            # the slot's state is one row a slot beside it, and the model is
+            # told which lanes are live (a segmented config).
             # Where the kernel does not take the leaves the sublayer sums
             # the same rows in jax.numpy (models/transformer.py).
             return "table"
@@ -1231,14 +1275,26 @@ class SlotEngine:
         number of the leaf dtype's sublane tiles); ``"dense"`` — scores
         over all ``max_len`` positions, masked — everywhere else: int8
         pages, the CPU smoke shapes with heads of 8-32, and the EVA and CCA
-        prefill programs, whose sublayers have dense sites of their own."""
-        leaves = self.pool.layers[0]
-        if self._eva or self._cca or set(leaves) != {"k", "v"}:
+        prefill programs, whose sublayers have dense sites of their own. A
+        segment program's gathered row is a bucket's worth of trash pages
+        longer than ``max_len``: those lengths must fit too."""
+        k = self._k_leaf()
+        if self._eva or self._cca or k is None or any(
+                "k" in l and set(l) != {"k", "v"} for l in self.pool.layers):
             return "dense"
-        k = leaves["k"]
-        fits = chunk_flash_fits(
-            k.dtype, k.shape[3], (self.max_len, *self.prefill_buckets))
+        ps = self.page_size
+        rows = [self.max_len, *self.prefill_buckets]
+        if self._segmented:
+            rows += [self.max_len + -(-w // ps) * ps
+                     for w in self.prefill_buckets]
+        fits = chunk_flash_fits(k.dtype, k.shape[3], rows)
         return "flash" if fits else "dense"
+
+    def _k_leaf(self):
+        """The ``k`` leaf of the first layer that holds pages (every layer
+        but a layer_pattern config's ``M`` and ``E`` ones); None if none
+        does."""
+        return next((l["k"] for l in self.pool.layers if "k" in l), None)
 
     def _decode_kernel_form(self) -> str | None:
         """How the paged kernel of the plain decode program forms its two
@@ -1249,8 +1305,9 @@ class SlotEngine:
         fact of the build and no option. ``None`` where ``step_fn`` does
         not reach the kernel: the gather path, and an EVA pool whose leaves
         the kernel does not take."""
-        k = self.pool.layers[0]["k"]
-        if self.decode_path != "table" or not paged_decode_fits(k):
+        k = self._k_leaf()
+        if (self.decode_path != "table" or k is None
+                or not paged_decode_fits(k)):
             return None
         return paged_decode_form(self.cfg.num_heads // k.shape[1])
 
@@ -1503,8 +1560,9 @@ class SlotEngine:
         bucket and a chunked-prefill plan was scheduled instead."""
         if self._eva:
             return self._start_eva(slot, prompt, p, max_new, sargs, sampled)
-        if self._cca:
-            return self._start_cca(slot, prompt, p, max_new, sargs, sampled)
+        if self._segmented:
+            return self._start_segments(slot, prompt, p, max_new, sargs,
+                                        sampled)
         pool, ps = self.pool, self.page_size
         n_pages = pool.pages_needed(p, max_new)
         # Adoption cap: the tail must keep >= 1 real token (the first-
@@ -1683,12 +1741,13 @@ class SlotEngine:
         self._pf_queue.append(slot)
         return None
 
-    def _start_cca(self, slot, prompt, p, max_new, sargs, sampled):
-        """Admission of a CCA slot: every page bound up front as in the
+    def _start_segments(self, slot, prompt, p, max_new, sargs, sampled):
+        """Admission of a slot of a segmented config (slot state, routed
+        experts): every page bound up front as in the
         plain layout (nothing adopted: the engine has no prefix cache), the
         prefill planned as SEGMENTS of at most a chunk that never overlap
-        (a recomputed position would find the convolution state ahead of
-        it), the last padded to its bucket. One segment runs here and its
+        (a recomputed position would find the slot's state ahead of it,
+        and would count twice at the experts), the last padded to its bucket. One segment runs here and its
         token is returned; more are spent by :meth:`step` like any chunk
         plan (``None`` is returned)."""
         pool = self.pool
@@ -1713,9 +1772,9 @@ class SlotEngine:
         self._pf_queue.append(slot)
         return None
 
-    def _run_cca_segment(self, st, m, r, final):
-        """One prefill segment of a CCA slot: ``r`` real tokens at position
-        ``m``, padded to the narrowest bucket."""
+    def _run_segment(self, st, m, r, final):
+        """One prefill segment of a segmented config's slot: ``r`` real
+        tokens at position ``m``, padded to the narrowest bucket."""
         pool, slot = self.pool, st["slot"]
         width = next(b for b in self.prefill_buckets if b >= r)
         toks = np.zeros((1, width), np.int32)
@@ -1727,6 +1786,8 @@ class SlotEngine:
             np.array(pool.page_tables[slot]), np.int32(slot), *st["sargs"],
         )
         pool.layers = new_pool
+        if self._ssm:
+            self.stats["ssm_tokens_scanned"] += r
         return int(first) if final else None
 
     def _run_eva_segment(self, st, m, r, final):
@@ -1852,11 +1913,16 @@ class SlotEngine:
         # Only the final chunk blocks (on its token); the others return as
         # soon as the program is queued.
         with _trace.span("engine.prefill_chunk", flight=False, offset=m,
-                         width=w, final=final, path=self.prefill_path):
+                         width=w, final=final, path=self.prefill_path) as sp:
             if self._eva:
                 return self._run_eva_segment(st, m, w, final)
-            if self._cca:
-                return self._run_cca_segment(st, m, w, final)
+            if self._segmented:
+                if self._ssm:
+                    # SSD blocks the segment's bucket scans, over the layers.
+                    width = next(b for b in self.prefill_buckets if b >= w)
+                    sp.note(ssm_blocks=-(-width // self.cfg.ssm_block)
+                            * self.cfg.layer_pattern.count("M"))
+                return self._run_segment(st, m, w, final)
             toks = np.ascontiguousarray(prompt[m : m + w][None])
             row = np.array(pool.page_tables[st["slot"]])
             prefill = (
@@ -1957,7 +2023,11 @@ class SlotEngine:
                 # Of ``experts_total`` (layer, expert) pairs held here.
                 sp.note(experts_touched=int(rnd.moe[0]),
                         expert_tokens_max=int(rnd.moe[1]),
-                        experts_total=self.cfg.num_layers * len(self.cfg.held))
+                        experts_total=(self.cfg.expert_layers
+                                       * len(self.cfg.held)))
+            if self._ssm:
+                # Live lanes whose recurrent state the round advanced.
+                sp.note(ssm_lanes=int(act.sum()) if rnd is not None else 0)
             if pre_events:
                 row_t = np.zeros((1, self.slots), np.int32)
                 row_v = np.zeros((1, self.slots), bool)
@@ -2494,10 +2564,12 @@ class SlotEngine:
     def _refuse_eva_handoff(self) -> None:
         """Slot handoff moves a plain page list: an EVA slot's composed
         row (kinds, windows done, forming pages) is not in that bundle."""
-        if self._cca:
-            raise CcaUnsupported(
-                "slot export / import is not extended to CCA: the bundle "
-                "would have to carry the slot's convolution state")
+        if self.pool.state_leaves:
+            raise SlotStateUnsupported(
+                "slot export / import is not extended to per-slot state: "
+                "the bundle would have to carry the slot's "
+                + ("convolution state" if self._cca
+                   else "recurrent and convolution state"))
         if self._eva:
             raise EvaUnsupported(
                 "slot export/import is not extended to EVA's composed "

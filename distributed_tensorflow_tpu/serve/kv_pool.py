@@ -63,6 +63,18 @@ behind its token, a masked lane leaves it alone. Nothing that walks pages
 (export / import, the prefix cache) is extended to it; the engine refuses
 those for such a config.
 
+**Layers of one kind each (a ``layer_pattern`` config).** A layer holds the
+leaves of its kind: ``k`` / ``v`` pages for an attention layer (``*``) alone,
+so one page table a slot serves those layers and a page costs their rows
+only; for a Mamba-2 layer (``M``) two per-slot leaves in the place CCA's has,
+``ssm`` ``(slots, ssm_heads, ssm_head_dim, ssm_state)`` in float32 (the
+recurrent state: it is summed into at every token, and the family asks
+servers for a float32 one) and ``conv`` ``(slots, ssm_conv - 1,
+ssm_conv_width)`` in the cache's dtype (the convolution's last inputs);
+nothing for an expert layer (``E``). The state leaves are zeroed, kept and
+released with the slot exactly as ``cca`` is, and refused by the same
+walkers; :attr:`PagedKVPool.state_bytes` counts them.
+
 **When a page is released.** At a window's end (:meth:`PagedKVPool.
 roll_window`, on the host between two rounds): the slot drops its reference
 on each of the window's pages, so a page returns to the free list unless the
@@ -205,15 +217,26 @@ class PagedKVPool:
         self.layers = init_cache(
             cfg, self.num_pages, page_size, sharding=kv_sharding
         )["layers"]
-        # CCA (cfg.cca_time0 set): the per-slot convolution state of the
-        # module docstring, one leaf a layer beside its pages.
+        # State that is not rows (the module docstring): CCA's per-slot
+        # convolution latents, one leaf a layer beside its pages; a Mamba-2
+        # layer's recurrent and convolution state, two leaves and no pages.
         self.cca = bool(getattr(cfg, "cca", False))
-        if self.cca:
-            if kv_sharding is not None:
-                raise ValueError("a CCA pool is not sharded")
-            for layer in self.layers:
+        pattern = getattr(cfg, "layer_pattern", None) or ""
+        self.state_leaves = (("cca",) if self.cca
+                             else ("ssm", "conv") if "M" in pattern else ())
+        if self.state_leaves and kv_sharding is not None:
+            raise ValueError("a pool with per-slot state is not sharded")
+        for i, layer in enumerate(self.layers):
+            if self.cca:
                 layer["cca"] = jnp.zeros(
                     (self.slots, cfg.cca_hist, cfg.cca_state_width),
+                    cfg.compute_dtype)
+            elif pattern[i:i + 1] == "M":
+                layer["ssm"] = jnp.zeros(
+                    (self.slots, cfg.ssm_heads, cfg.ssm_head_dim,
+                     cfg.ssm_state), jnp.float32)
+                layer["conv"] = jnp.zeros(
+                    (self.slots, cfg.ssm_conv - 1, cfg.ssm_conv_width),
                     cfg.compute_dtype)
         # Page 0 is TRASH (reserved, refcount pinned). LIFO free lists
         # (the page or slot freed last is the likeliest still resident in a
@@ -263,6 +286,16 @@ class PagedKVPool:
         )
 
     @property
+    def state_bytes(self) -> int:
+        """Bytes of the per-slot state leaves (``state_leaves``), which
+        ``hbm_bytes`` includes: what is held by slot and not by page."""
+        return sum(
+            layer[name].size * layer[name].dtype.itemsize
+            for layer in self.layers for name in self.state_leaves
+            if name in layer
+        )
+
+    @property
     def hbm_bytes_per_slot(self) -> float:
         return self.hbm_bytes / self.slots
 
@@ -274,7 +307,8 @@ class PagedKVPool:
         byte-diet ratio's numerator/denominator: at int8 the same HBM
         backs proportionally more pages, which is the page-capacity gain
         ``bench_serving`` demonstrates in-run."""
-        return self.hbm_bytes / (self.num_pages * self.page_size)
+        return (self.hbm_bytes - self.state_bytes) / (
+            self.num_pages * self.page_size)
 
     def pages_needed(self, prompt_len: int, max_new_tokens: int) -> int:
         """Pages a request of ``prompt_len + max_new_tokens`` positions
@@ -467,8 +501,10 @@ class PagedKVPool:
         """
         if not 0 <= slot < self.slots:
             raise ValueError(f"slot {slot} outside [0, {self.slots})")
-        if self.cca:
-            raise ValueError("a CCA pool's slot state is not in a page payload")
+        if self.state_leaves:
+            raise ValueError(
+                f"a pool's slot state {self.state_leaves} is not in a page "
+                "payload")
         row = self.page_tables[slot]
         bound = [int(pid) for pid in row if pid != TRASH_PAGE]
         idx = np.asarray(bound, np.int32)

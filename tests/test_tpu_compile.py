@@ -125,10 +125,10 @@ def _zaya_layer(one_chip, layers=1):
 
 def test_zaya_decode_round_compiles_for_v5e(one_chip, monkeypatch):
     """One layer of the decode round as ``zaya1-8b.reason-closed`` runs it:
-    32 slots of 4096, pages of 16. The expert products lower to XLA:TPU's
-    grouped-matmul kernel (two ``ragged-dot`` custom calls a layer behind
-    their group metadata), attention to the paged kernel at 4 query rows a
-    kv head, and no program holds a (tokens, experts, width) product."""
+    32 slots of 4096, pages of 16. The expert products lower to the
+    grouped-matmul kernel (two ``grouped_matmul`` custom calls a layer,
+    ``ops/grouped_matmul.py``), attention to the paged kernel at 4 query rows
+    a kv head, and no program holds a (tokens, experts, width) product."""
     from distributed_tensorflow_tpu.models.decoding import decode_step
 
     # The paged kernel asks the default backend whether to interpret; here
@@ -162,10 +162,9 @@ def test_zaya_decode_round_compiles_for_v5e(one_chip, monkeypatch):
     # The instruction's own name and result, left of its "custom-call(".
     calls = [l.split(" custom-call(")[0].strip() for l in text.splitlines()
              if "tpu_custom_call" in l and " custom-call(" in l]
-    assert sum(c.startswith("%ragged-dot-metadata") for c in calls) >= 1
-    products = [c for c in calls if c.startswith("%ragged-dot")
-                and "metadata" not in c]
+    products = [c for c in calls if c.startswith("%grouped_matmul")]
     assert len(products) == 2 and all("f32[32," in c for c in products)
+    assert "ragged-dot" not in text
     # The paged kernel, its 4 query rows a kv head padded to 16.
     assert sum("bf16[32,2,16,128]" in c for c in calls) == 1
     assert "[32,16," not in text  # nothing dense over all experts
@@ -261,3 +260,131 @@ def test_sc2_prefill_chunk_holds_no_score_matrix(one_chip, monkeypatch):
     assert call.startswith("%prefill_chunk_attention")
     assert "bf16[2,12,1024,128]" in call
     assert not re.search(r"f32\[[\d,]*1024,4096\]", text)
+
+
+def _nemotron_layers(one_chip, pattern):
+    """The Nemotron-H stage at the published widths (benchmarks/configs), cut
+    to the layers of ``pattern`` and an 8,192-row vocabulary (4,096 is the
+    Mamba mixer's width) so that it compiles in seconds: the model, its parameters as shapes on the
+    described chip."""
+    import json
+
+    from distributed_tensorflow_tpu.models.transformer import (
+        TransformerConfig,
+        TransformerLM,
+    )
+
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                        "configs", "nemotron3-nano-30b.json")
+    with open(path) as fh:
+        mcfg = dict(json.load(fh)["transformer_config"], vocab_size=8192,
+                    layer_pattern=pattern, num_layers=len(pattern))
+    cfg = TransformerConfig(**mcfg, compute_dtype=jnp.bfloat16)
+    model = TransformerLM(cfg)
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                       sharding=one_chip), shapes)
+    return cfg, model, params
+
+
+def test_nemotron_decode_round_compiles_for_v5e(one_chip, monkeypatch):
+    """One layer of each kind of the decode round as
+    ``nemotron3-nano.reason-closed-64`` runs it: 64 slots of 4096, pages of
+    16. The routed products lower to two ``grouped_matmul`` custom calls
+    (``ops/grouped_matmul.py``) over 384 = 64 x 6 sorted pairs, the first
+    over ``moe_up`` as it lies ((width, d_model): no relayout of 1.3 GB of
+    weights a layer), attention to the paged kernel at 16 query rows
+    a kv head, nothing is dense over all 128 experts, and the recurrent
+    state (64 x 64 x 64 x 128 float32, 134 MB) is an operand that the
+    program's result aliases: updated where it lies, once in and once out."""
+    import re
+
+    from distributed_tensorflow_tpu.models.decoding import decode_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, model, params = _nemotron_layers(one_chip, "ME*")
+    slots, ps, pps = 64, 16, 256
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = [{"ssm": arg((slots, 64, 64, 128), jnp.float32),
+             "conv": arg((slots, 3, 6144), jnp.bfloat16)}, {},
+            {"k": arg((slots * pps + 1, 2, ps, 128), jnp.bfloat16),
+             "v": arg((slots * pps + 1, 2, ps, 128), jnp.bfloat16)}]
+
+    def step(params, pool, tables, active, lengths, tok):
+        dest = tables[jnp.arange(slots), lengths // ps]
+        cache = {"layers": pool, "len": lengths, "pages": tables,
+                 "write_page": jnp.where(active, dest, 0),
+                 "attend": jnp.where(active, lengths + 1, 0),
+                 "n_real": active.astype(jnp.int32),
+                 "route_mask": active[:, None]}
+        cache, logits = decode_step(model, params, cache, tok[:, None])
+        return cache["layers"], logits.argmax(-1), cache["moe_counts"]
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, pool, arg((slots, pps), jnp.int32), arg((slots,), jnp.bool_),
+        arg((slots,), jnp.int32), arg((slots,), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [l.split(" custom-call(")[0].strip() for l in text.splitlines()
+             if "tpu_custom_call" in l and " custom-call(" in l]
+    products = [c for c in calls if c.startswith("%grouped_matmul")]
+    assert len(products) == 2 and all("f32[384," in c for c in products)
+    assert sum("bf16[64,2,16,128]" in c for c in calls) == 1  # paged kernel
+    assert "[64,128,1856]" not in text and "[384,128," not in text
+    assert not re.search(r"bf16\[128,(1856,2688|2688,1856)\]\S* copy\(",
+                         text)
+    # The state leaf goes in and comes out in place: the donated operands
+    # (ssm, conv, k, v) are aliased to the results, ONE fusion takes the
+    # state (it gives the new state and the read-out together), and no copy
+    # of it is made.
+    alias = text[text.index("input_output_alias"):].split("\n")[0]
+    assert alias.count("may-alias") + alias.count("must-alias") >= 4
+    state = "f32[64,64,64,128]"
+    (param,) = re.findall(r"(%\S+) = " + re.escape(state) + r"\S* parameter",
+                          text[text.index("ENTRY"):])
+    users = [l for l in text[text.index("ENTRY"):].splitlines()
+             if param + "," in l or param + ")" in l]
+    assert len(users) == 1 and " fusion(" in users[0], users
+    assert not re.search(re.escape(state) + r"\S* copy\(", text)
+
+
+def test_nemotron_prefill_chunk_costs_its_pairs_and_scans_in_blocks(one_chip):
+    """A 1024-wide prefill chunk of one Mamba and one expert layer: the
+    compiler's own count of the program's FLOPs holds the experts at the
+    chunk's 6,144 pairs through ONE expert each (123 GFLOP), not all 128
+    experts (2.6 TFLOP); the scan is the SSD form, whose only loop is the one
+    over the chunk's 8 blocks; and the head is formed for one row."""
+    import re
+
+    cfg, model, params = _nemotron_layers(one_chip, "ME")
+    width = 1024
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def chunk(params, ssm, conv, tokens, n_real, start):
+        cache = {"layers": [{"ssm": ssm[None], "conv": conv[None]}, {}],
+                 "len": start, "n_real": n_real[None],
+                 "route_mask": (jnp.arange(width) < n_real)[None]}
+        logits, cache = model.apply(
+            {"params": params}, tokens, cache=cache,
+            logit_rows=(n_real - 1)[None])
+        return logits, cache["layers"][0], cache["moe_counts"]
+
+    compiled = jax.jit(chunk).lower(
+        params, arg((64, 64, 128), jnp.float32), arg((3, 6144), jnp.bfloat16),
+        arg((1, width), jnp.int32), arg((), jnp.int32),
+        arg((), jnp.int32)).compile()
+    flops = compiled.cost_analysis()["flops"]
+    pairs = 2 * 2 * 2688 * 1856 * 6 * width  # 6,144 rows x one expert
+    assert pairs < flops < 4 * pairs, flops
+    text = compiled.as_text()
+    trips = [int(n) for n in re.findall(
+        r'known_trip_count[^\d]*(\d+)', text)]
+    assert all(n <= 128 for n in trips), trips  # never one trip a position
+    assert f"f32[1,1,{cfg.vocab_size}]" in text
+    assert f"[1,{width},{cfg.vocab_size}]" not in text

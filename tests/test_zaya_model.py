@@ -274,7 +274,7 @@ def test_partial_rope_rotates_the_leading_dimensions_only():
     ({"experts_held": [3, 1]}, ValueError, "experts_held"),
     ({"experts_held": []}, ValueError, "experts_held"),
     ({"num_experts": 0, "experts_held": [0]}, ValueError, "num_experts"),
-    ({"router_hidden": 0}, ValueError, "router_hidden"),
+    ({"experts_per_token": 2}, ValueError, "picks one expert"),
     ({"expert_width": 0}, ValueError, "expert_width"),
     ({"cca_time1": None}, ValueError, "go together"),
     ({"cca_time0": 0}, ValueError, "cca_time0"),

@@ -24,6 +24,7 @@ from benchmarks import weights_zaya
 from distributed_tensorflow_tpu.models.transformer import (
     CcaUnsupported,
     TransformerConfig,
+    TransformerLM,
 )
 from distributed_tensorflow_tpu.obs import trace
 from distributed_tensorflow_tpu.serve.engine import (
@@ -305,10 +306,33 @@ def test_the_engine_refuses_by_name(params, kw, match):
         make_engine(params, **kw)
 
 
-def test_routed_experts_without_cca_refuse(params):
+def test_routed_experts_without_cca_are_served(params):
+    """The same expert layer behind plain attention (no CCA): the engine
+    serves it, in segments and through the table as it serves CCA; the
+    tokens are the uncached forward's, and an idle lane reaches no expert
+    (a round routes one token a layer for each ACTIVE slot, of three)."""
     cfg = toy_cfg(cca_time0=None, cca_time1=None)
-    with pytest.raises(CcaUnsupported, match="only with CCA"):
-        make_engine(params, cfg=cfg)
+    model = TransformerLM(cfg)
+    prompt = tokens(21, seed=50)
+    plain = model.init(jax.random.PRNGKey(5), prompt[None])["params"]
+    eng = make_engine(plain, cfg=cfg)
+    assert eng.decode_path == "table" and not eng.pool.state_leaves
+    t_lo = (trace.closed("engine.round") or [(0, 0, None)])[-1][1]
+    slot = eng.acquire_slot()
+    first, _ = eng.start(slot, prompt, max_new_tokens=8)
+    toks = [] if first is None else [first]
+    while eng.active[slot] or eng.prefilling[slot]:
+        t, v, _ = eng.step()
+        toks += [int(x) for x in t[v[:, slot], slot]]
+    seq = np.concatenate([prompt, np.asarray(toks[:-1], np.int32)])
+    want = model.apply({"params": plain}, seq[None])[0, len(prompt) - 1:]
+    assert toks == [int(x) for x in want.argmax(-1)]
+    recs = [r[2] for r in trace.closed("engine.round", t_lo, float("inf"))
+            if r[0] > t_lo and r[2].get("active")]
+    assert recs and all(r["experts_touched"] == 3 for r in recs)
+    assert eng.stats["moe_tokens_routed"] == 3 * len(recs)
+    with pytest.raises(CcaUnsupported, match="prefix cache.*routed experts"):
+        make_engine(plain, cfg=cfg, prefix_cache=True)
 
 
 def test_the_sharded_engine_refuses(params):
@@ -349,7 +373,8 @@ def test_build_stack_serves_it_through_the_scheduler(params):
                             port=0, slo="off")
     # The scheduler's thread is outside the fixture's (thread-local)
     # precision: set it for the process, or its first round is a new program.
-    before = jax.config.jax_default_matmul_precision
+    # (Read here, inside the fixture's block, the setting is the fixture's
+    # and not the process's: the process goes back to its default, None.)
     jax.config.update("jax_default_matmul_precision", "highest")
     engine, scheduler, _, server = build_stack(serve_cfg, toy_cfg(), params)
     try:
@@ -365,7 +390,7 @@ def test_build_stack_serves_it_through_the_scheduler(params):
     finally:
         scheduler.stop()
         server.server_close()
-        jax.config.update("jax_default_matmul_precision", before)
+        jax.config.update("jax_default_matmul_precision", None)
     alone = make_engine(params, slots=1, max_len=64)
     slot = alone.acquire_slot()
     first, _ = alone.start(slot, prompt, max_new_tokens=10)
