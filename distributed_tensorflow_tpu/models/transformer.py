@@ -1039,13 +1039,10 @@ def _eva_through_table(cfg, cache, q4, k4, v4, phi, mu):
         vs = write(vs, cache["sum_page"], row, sv)
     q = q4[:, 0].reshape(b, kv, 1, dh)
     if A.paged_decode_fits(ks):
-        # 32 kv heads make a page 16 times StarCoder2's: a chunk of 1 MiB
-        # a buffer (8 pages; four buffers, K and V, double) measured best.
-        page_bytes = kv * ps * dh * ks.dtype.itemsize
+        # 32 kv heads make a page 16 times StarCoder2's: the kernel sizes
+        # its chunk by the page's bytes (8 pages here, 1 MiB a buffer).
         attn = A.paged_decode_attention(
-            q, ks, vs, cache["pages"], cache["attend"],
-            pages_per_chunk=max(1, min(32, (1 << 20) // page_bytes)),
-        )
+            q, ks, vs, cache["pages"], cache["attend"])
     else:
         attn = _table_attention_sum(q, ks, vs, cache["pages"], cache["attend"])
     return attn.reshape(b, 1, heads * dh), dict(cache, k=ks, v=vs)

@@ -44,6 +44,7 @@ import warnings
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -1947,12 +1948,21 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 # The pool keeps a leaf as (pages, kv_heads, page_size, head_dim) and a slot
 # as one row of physical page ids (serve/kv_pool.py). The kernel takes that
 # row and the slot's live length as scalar-prefetched operands, copies the
-# live pages of one chunk from HBM into a VMEM buffer, page by page, while
-# it computes on the chunk before, and runs an online softmax over the
-# chunks: no logical (slots, kv, max_len, dh) cache exists anywhere, and a
-# slot costs what its live length costs. One invocation walks every slot, so
-# the copy chain also crosses from one slot's last chunk to the next slot's
-# first.
+# live pages of one chunk from HBM into a VMEM buffer while it computes on
+# the chunk before, and runs an online softmax over the chunks: no logical
+# (slots, kv, max_len, dh) cache exists anywhere, and a slot costs what its
+# live length costs. One invocation walks every slot, so the copy chain also
+# crosses from one slot's last chunk to the next slot's first.
+#
+# The copy chain (PR 38) pays by the chunk and the step, not by the page. A
+# chunk is 1 MiB a buffer and a step 128 KiB, both in pages by the page's
+# bytes (paged_decode_chain: 128 and 16 pages of 2 kv heads, 8 and 1 of 32).
+# Starting a chunk reads a step's table entries ahead of its first push and
+# copies the step with ONE descriptor a leaf where the ids ascend by one
+# (kv_pool.alloc_pages hands out ascending runs, so most steps do), page by
+# page elsewhere and for what a chunk leaves under a step. Waiting for a
+# chunk is one descriptor a leaf of the whole buffer's shape: a DMA
+# semaphore counts bytes, not copies. Only live pages are ever copied.
 #
 # How a head's chunk (t rows of K and of V) meets its query rows is the
 # kernel's TILE, and there are two (paged_decode_form picks by the group
@@ -2091,6 +2101,7 @@ def _paged_decode_kernel(
     scale: float,
     window: int | None,
     chunk_pages: int,
+    run: int,
 ):
     slots = q_ref.shape[0]
     _, kv, ps, dh = k_hbm.shape
@@ -2124,31 +2135,74 @@ def _paged_decode_kernel(
             slots,
         )
 
-    def for_pages(b, p0, pages, c, buf, start: bool):
-        """Start, or wait for, the copies of chunk ``c`` of slot ``b``."""
-        first = b * pps + p0 + c * chunk_pages
+    leaves = ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1))
 
-        def body(i, _):
-            # A wait needs the shapes of its copy alone, not its source.
-            pid = tables_ref[first + i] if start else 0
-            for hbm, vmem, s in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
-                copy = pltpu.make_async_copy(
-                    hbm.at[pid], vmem.at[buf, i], sems.at[s, buf])
-                if start:
-                    copy.start()
-                else:
-                    copy.wait()
+    def start_chunk(b, p0, pages, c, buf):
+        """Start the copies of chunk ``c`` of slot ``b``, ``run`` pages a
+        step: their table entries read ahead of the first push, and ONE
+        descriptor a leaf where the ids ascend by one (the pages lie side
+        by side in the pool). What is left under a step goes page by page,
+        in the static sizes of its count's bits."""
+        first = b * pps + p0 + c * chunk_pages
+        n = jnp.minimum(pages - c * chunk_pages, chunk_pages)
+
+        def singles(i0, pids):
+            for j, pid in enumerate(pids):
+                for hbm, vmem, s in leaves:
+                    pltpu.make_async_copy(
+                        hbm.at[pid], vmem.at[buf, i0 + j],
+                        sems.at[s, buf]).start()
+
+        def step(i0, count):
+            pids = [tables_ref[first + i0 + j] for j in range(count)]
+            if count < max(run, 2):
+                return singles(i0, pids)
+            adjacent = pids[1] == pids[0] + 1
+            for j in range(2, count):
+                adjacent &= pids[j] == pids[0] + j
+
+            @pl.when(adjacent)
+            def _():
+                for hbm, vmem, s in leaves:
+                    pltpu.make_async_copy(
+                        hbm.at[pl.ds(pids[0], count)],
+                        vmem.at[buf, pl.ds(i0, count)],
+                        sems.at[s, buf]).start()
+
+            pl.when(jnp.logical_not(adjacent))(lambda: singles(i0, pids))
+
+        def steps(g, _):
+            step(g * run, run)
             return 0
 
-        lax.fori_loop(0, jnp.minimum(pages - c * chunk_pages, chunk_pages),
-                      body, 0)
+        lax.fori_loop(0, n // run, steps, 0)
+        for bit in range(run.bit_length() - 1):
+            pl.when((n >> bit) & 1 == 1)(
+                lambda: step((n >> (bit + 1)) << (bit + 1), 1 << bit))
+
+    def wait_chunk(pages, c, buf):
+        """Wait for chunk ``c``'s copies. A DMA semaphore counts bytes, so a
+        whole chunk is awaited by ONE descriptor a leaf of the buffer's
+        shape, however many copies filled it, and a slot's last, partial
+        chunk by one of each static size among its page count's bits. (A
+        wait needs the shapes of its copy alone, not its source.)"""
+        n = jnp.minimum(pages - c * chunk_pages, chunk_pages)
+        for bit in range(chunk_pages.bit_length()):
+            size = 1 << bit
+
+            @pl.when((n >> bit) & 1 == 1)
+            def _():
+                for hbm, vmem, s in leaves:
+                    pltpu.make_async_copy(
+                        hbm.at[pl.ds(0, size)], vmem.at[buf, pl.ds(0, size)],
+                        sems.at[s, buf]).wait()
 
     b0 = next_live(0)
 
     @pl.when(b0 < slots)
     def _():
         _, _, p0, pages = span(b0)
-        for_pages(b0, p0, pages, 0, 0, start=True)
+        start_chunk(b0, p0, pages, 0, 0)
 
     def slot_body(b, g):
         n, lo, p0, pages = span(b)
@@ -2160,16 +2214,18 @@ def _paged_decode_kernel(
             g, state = carry
             buf = g % 2
 
-            @pl.when(c + 1 < chunks)
-            def _():
-                for_pages(b, p0, pages, c + 1, 1 - buf, start=True)
+            # The next chunk to attend: this slot's, or the first of the
+            # next live slot.
+            more = c + 1 < chunks
 
-            @pl.when((c + 1 == chunks) & (nb < slots))
+            @pl.when(more | (nb < slots))
             def _():
-                _, _, p0n, pagesn = span(nb)
-                for_pages(nb, p0n, pagesn, 0, 1 - buf, start=True)
+                bn = jnp.where(more, b, nb)
+                _, _, p0n, pagesn = span(bn)
+                start_chunk(bn, p0n, pagesn, jnp.where(more, c + 1, 0),
+                            1 - buf)
 
-            for_pages(b, p0, pages, c, buf, start=False)
+            wait_chunk(pages, c, buf)
             live = live_rows((p0 + c * chunk_pages) * ps, n, lo)
             new_state = []
             for h in range(kv):
@@ -2209,6 +2265,16 @@ def paged_decode_form(group: int) -> str:
     group 4 1.571 / 1.194; an f32 pool (pages of 8, floor 0.854): group 1
     0.961 / 0.974, group 2 0.962 / 0.968; 2 kv heads, group 12, window
     4096, 32 pages a chunk (``starcoder2-3b``; floor 0.054): 0.543 / 0.203.
+    Since PR 38 (:func:`paged_decode_chain`: 128 pages a chunk, 16 a copy
+    step at 2 kv heads; 500-1,900 rows a slot; ms, PR 37's chain / this
+    one over table rows of ascending neighbours / over scattered rows):
+    group 12, 16 slots, floor 0.021: 0.077 / 0.035 / 0.056; group 4, 32
+    slots, floor 0.046: 0.167 / 0.070 / 0.118; group 16, 64 slots, floor
+    0.092: 0.341 / 0.146 / 0.246; the row form at 32 kv heads, floor 0.726:
+    0.800 / 0.800 / 0.800. At 128 pages a chunk the group tile passes over
+    up to 2,047 dead rows of a slot's last chunk and is still faster alone
+    (0.038 ms at group 4) than at 32 (0.059): its cost is the chunk's, not
+    the row's.
     A group that was not measured stays on the group form."""
     return "row" if group <= 2 else "group"
 
@@ -2224,6 +2290,58 @@ def paged_decode_fits(pages) -> bool:
             and pages.shape[3] % 128 == 0)
 
 
+def paged_decode_chain(pages, pages_per_slot: int) -> tuple[int, int]:
+    """``(pages a chunk, pages a copy step)`` of the kernel's copy chain over
+    a pool leaf ``pages`` (pages, kv_heads, page_size, head_dim) and table
+    rows of ``pages_per_slot``: functions of one page's bytes, like the form
+    of the group, and no argument or configuration chooses.
+
+    A chunk is 1 MiB a buffer (four of them: K and V, double): 128 pages of
+    2 kv heads, 8 of EvaByte's 32. A step is as many pages as make 128 KiB,
+    a power of two: 16 pages of 8 KiB, one of EvaByte's, whose single-page
+    copies already run at the HBM rate. The kernel alone on a v5e at cell
+    6's shape (32 slots of 500-1,900 rows, 2 kv heads, group 4, bf16; the
+    rows' bytes at the HBM rate 0.0495 ms; PR 38), ms by pages a chunk:
+    a wait a page and a start a page as PR 30 wrote it 0.179 at 32; one wait
+    a chunk 0.161; starts unrolled by 8 0.155 at 32, 0.129 at 64, 0.117 at
+    128; one descriptor a run of 8 adjacent pages 0.085 at 64 and 0.074 at
+    128, of 16 0.082 and **0.071** (70% of the bytes' rate; over 1,500-3,500
+    rows 78%); of 2 or 4 none faster than page by page (a copy of 16 or
+    32 KiB costs the chain as much as one of 64). EvaByte's shape reads 0.879
+    under every one of these and 0.888 at 16 pages a chunk."""
+    page_bytes = math.prod(pages.shape[1:]) * pages.dtype.itemsize
+    chunk = max(1, min(pages_per_slot, (1 << 20) // page_bytes))
+    run = max(1, min(chunk, (128 << 10) // page_bytes))
+    return chunk, 1 << (run.bit_length() - 1)
+
+
+def paged_decode_copies(page_tables, lens, pages, *, window=None):
+    """``(copies, live pages)`` a leaf of one call of
+    :func:`paged_decode_attention` over these host ``page_tables`` and
+    ``lens`` (numpy) and a pool leaf shaped like ``pages``: the kernel's
+    rule (``_paged_decode_kernel``'s ``start_chunk``) counted on the host,
+    one descriptor for a step whose ids ascend by one and one a page
+    elsewhere. (Chunks hold whole steps, so a slot's steps lie ``run``
+    apart from its first live page.)"""
+    tables, n = np.asarray(page_tables), np.asarray(lens, np.int64)
+    _, run = paged_decode_chain(pages, tables.shape[1])
+    ps = pages.shape[2]
+    steps = tables.shape[1] // run
+    ids, live = tables[:, : steps * run], -(-n // ps)
+    if window is not None:  # the row from each slot's first live page
+        p0 = np.maximum(n - window, 0) // ps
+        live = live - p0
+        at = np.minimum(p0[:, None] + np.arange(steps * run),
+                        tables.shape[1] - 1)
+        ids = np.take_along_axis(tables, at, axis=1)
+    ids = ids.reshape(-1, steps, run)
+    whole = np.arange(steps) < (live // run)[:, None]
+    adjacent = (np.diff(ids, axis=2) == 1).all(axis=2)  # all, at one page
+    copies = ((whole & adjacent).sum() + run * (whole & ~adjacent).sum()
+              + (live % run).sum())
+    return int(copies), int(live.sum())
+
+
 def paged_decode_attention(
     q,
     k_pages,
@@ -2232,7 +2350,7 @@ def paged_decode_attention(
     lens,
     *,
     window: int | None = None,
-    pages_per_chunk: int = 32,
+    pages_per_chunk: int | None = None,
     interpret: bool | None = None,
 ):
     """Attention of one query token per slot over that slot's pages, in place.
@@ -2250,8 +2368,11 @@ def paged_decode_attention(
     Scores, softmax state and the value product accumulate in f32 over the
     operands' own dtype, as the dense cached branch of
     ``models/transformer.py`` does. Only the pages ``ceil(lens/page_size)``
-    (less the window's skip) are copied from HBM, ``pages_per_chunk`` of
-    them a buffer. How the two products are formed follows from ``group``
+    (less the window's skip) are copied from HBM, a chunk of them a buffer
+    and a step of neighbouring pages a descriptor, both sized by the page's
+    bytes (:func:`paged_decode_chain`; ``pages_per_chunk`` is for tests
+    that want small chunks: no call site of the models gives it). How the
+    two products are formed follows from ``group``
     alone (:func:`paged_decode_form`): up to two query rows a kv head
     stream K past the row and finish on the VPU, where a probability keeps
     all its f32 bits; larger groups stream past latched K and V tiles, where
@@ -2272,21 +2393,25 @@ def paged_decode_attention(
         raise ValueError(f"window must be >= 1, got {window}")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    chunk_pages, run = paged_decode_chain(k_pages, page_tables.shape[1])
+    if pages_per_chunk is not None:  # the tests' small chunks
+        chunk_pages = min(int(pages_per_chunk), page_tables.shape[1])
+        run = min(run, 1 << (chunk_pages.bit_length() - 1))
     return _paged_decode_call(
         q, k_pages, v_pages, page_tables, lens,
-        scale=_scale(q, None), window=window,
-        chunk_pages=min(int(pages_per_chunk), page_tables.shape[1]),
-        interpret=bool(interpret),
+        scale=_scale(q, None), window=window, chunk_pages=chunk_pages,
+        run=run, interpret=bool(interpret),
     )
 
 
 # Jitted, so that a model's layers trace and lower the kernel once between
 # them (30 separate pallas_calls cost the serving engine seconds of warm-up).
 @functools.partial(
-    jax.jit, static_argnames=("scale", "window", "chunk_pages", "interpret")
+    jax.jit,
+    static_argnames=("scale", "window", "chunk_pages", "run", "interpret"),
 )
 def _paged_decode_call(q, k_pages, v_pages, page_tables, lens, *, scale,
-                       window, chunk_pages, interpret):
+                       window, chunk_pages, run, interpret):
     slots, kv, group, dh = q.shape
     ps = k_pages.shape[2]
     dtype = q.dtype
@@ -2313,7 +2438,7 @@ def _paged_decode_call(q, k_pages, v_pages, page_tables, lens, *, scale,
         q = jnp.pad(q, ((0, 0), (0, 0), (0, gp - group), (0, 0)))
     kernel = functools.partial(
         _paged_decode_kernel, scale=scale, window=window,
-        chunk_pages=chunk_pages,
+        chunk_pages=chunk_pages, run=run,
     )
     out = pl.pallas_call(
         kernel,

@@ -171,7 +171,10 @@ blocking read of the round's outputs: the device finishing) and
 notes what the round works on: ``active``, ``live_tokens``, ``chunks_run``
 and ``kv_rows_read`` (the K and V positions per layer a micro-step of its
 decode program reads: the active slots' live pages through the table,
-``slots * max_len`` wherever a program gathers);
+``slots * max_len`` wherever a program gathers), and ``kv_copies`` and
+``kv_pages`` (those positions in pages, and the copies a layer that bring
+them in: the paged kernel's chain starts one descriptor for a step of
+neighbouring pages, a gather takes a page an index; ``stats`` sums both);
 ``engine.start`` covers an admission and ``engine.warmup`` the program set's
 compiles. ``engine.round`` also says ``ahead``: whether the round whose
 tokens the call returns was queued before the round before it was read
@@ -225,6 +228,7 @@ from distributed_tensorflow_tpu.models.transformer import (
 from distributed_tensorflow_tpu.obs import trace as _trace
 from distributed_tensorflow_tpu.ops.attention import (
     chunk_flash_fits,
+    paged_decode_copies,
     paged_decode_fits,
     paged_decode_form,
 )
@@ -545,6 +549,10 @@ class SlotEngine:
             "spec_verifies": 0,
             "plain_rounds": 0,
             "rounds_ahead": 0,
+            # Over the decode rounds read so far: the copies a layer that
+            # brought their K and V pages in, and those pages.
+            "kv_copies": 0,
+            "kv_pages_copied": 0,
             "prefill_chunks": 0,
             "prefill_tokens_last_iter": 0,
             "eva_windows_rolled": 0,
@@ -1345,6 +1353,27 @@ class SlotEngine:
         first = np.maximum(n - window, 0) // ps if window else 0
         return int(((-(-n // ps) - first) * ps).sum())
 
+    def _kv_copies(self, act, lengths, spec) -> tuple[int, int] | None:
+        """``(copies, pages)`` a layer that a decode round over the ``act``
+        slots at ``lengths`` makes to bring in the pages it reads
+        (``_kv_rows_read`` in pages). Through the paged kernel, its copy
+        chain's own rule over the host's table rows
+        (``ops.attention.paged_decode_copies``): one descriptor for a step
+        of pages whose ids ascend by one, one a page elsewhere. A program
+        that gathers takes every page by its own index: a copy a page."""
+        if not act.any():
+            return None
+        if self.decode_kernel_form is None or spec:
+            pages = self._kv_rows_read(act, lengths, spec) // self.page_size
+            return pages, pages
+        if self._eva:
+            attend = sum(self._eva_rows(act, lengths))
+        else:
+            attend = lengths[act].astype(np.int64) + 1
+        return paged_decode_copies(
+            self.pool.page_tables[act], attend, self._k_leaf(),
+            window=getattr(self.cfg, "attention_window", None))
+
     def _jit_program(self, fn, kind, donate):
         """Compile hook: the base engine jits on the default device; the
         sharded engine overrides this to jit the SAME program under its
@@ -2015,6 +2044,12 @@ class SlotEngine:
                     act, lengths, rnd is not None and rnd.spec),
                 ahead=rnd is not None and rnd.ahead,
             )
+            chain = self._kv_copies(
+                act, lengths, rnd is not None and rnd.spec)
+            if chain is not None:
+                sp.note(kv_copies=chain[0], kv_pages=chain[1])
+                self.stats["kv_copies"] += chain[0]
+                self.stats["kv_pages_copied"] += chain[1]
             if self._eva:
                 sums, rows = self._eva_rows(act, lengths)
                 sp.note(summary_rows_read=int(sums.sum()),

@@ -238,12 +238,13 @@ class PagedKVPool:
                 layer["conv"] = jnp.zeros(
                     (self.slots, cfg.ssm_conv - 1, cfg.ssm_conv_width),
                     cfg.compute_dtype)
-        # Page 0 is TRASH (reserved, refcount pinned). LIFO free lists
-        # (the page or slot freed last is the likeliest still resident in a
-        # cache hierarchy), each with a companion set that keeps the
-        # double-free check O(1) under churn.
-        self._free_pages: list[int] = list(range(self.num_pages - 1, 0, -1))
-        self._free_page_set: set[int] = set(self._free_pages)
+        # Page 0 is TRASH (reserved, refcount pinned). Free pages are a mask
+        # over the ids, so that :meth:`alloc_pages` sees them as runs of
+        # neighbours; free slots a LIFO list with a companion set that keeps
+        # the double-free check O(1) under churn.
+        self._page_free = np.ones(self.num_pages, bool)
+        self._page_free[TRASH_PAGE] = False
+        self._pages_free = self.num_pages - 1
         self.refcount = np.zeros(self.num_pages, np.int64)
         self.refcount[TRASH_PAGE] = 1  # pinned — never allocatable
         self.page_tables = np.full(
@@ -266,7 +267,7 @@ class PagedKVPool:
 
     @property
     def pages_free(self) -> int:
-        return len(self._free_pages)
+        return self._pages_free
 
     @property
     def pages_allocatable(self) -> int:
@@ -366,33 +367,54 @@ class PagedKVPool:
     # -- page bookkeeping --------------------------------------------------
 
     def alloc_pages(self, n: int) -> list[int] | None:
-        """Claim ``n`` physical pages (refcount 1 each), or None if the
-        free list is short — the caller may evict prefix-cache entries and
-        retry. All-or-nothing: no partial claims to unwind."""
-        if n > len(self._free_pages):
+        """Claim ``n`` physical pages (refcount 1 each), or None if too few
+        are free — the caller may evict prefix-cache entries and retry.
+        All-or-nothing: no partial claims to unwind. The pages come as
+        neighbours, in ascending order: the smallest run of free ids that
+        holds the request whole, else the largest runs first. A table row
+        whose ids ascend by one is what the paged decode kernel copies with
+        one descriptor a step (``ops.attention.paged_decode_chain``); popped
+        from a list in the order rows were released they came apart a
+        little more with every admission (3.4-3.6 live pages a copy over
+        cell 6's churn where this reads 6.5-6.8: PERF.md, PR 38)."""
+        if n > self._pages_free:
             return None
-        pages = [self._free_pages.pop() for _ in range(n)]
-        for pid in pages:
-            self._free_page_set.discard(pid)
-            self.refcount[pid] = 1
-        return pages
+        if n == 0:
+            return []
+        free = np.flatnonzero(self._page_free)
+        starts = np.flatnonzero(np.diff(free, prepend=-1) != 1)
+        sizes = np.diff(starts, append=free.size)
+        fits = np.flatnonzero(sizes >= n)
+        if fits.size:
+            first = starts[fits[np.argmin(sizes[fits])]]
+            pages = free[first : first + n]
+        else:
+            order = np.argsort(-sizes, kind="stable")
+            need = n - np.cumsum(sizes[order]) + sizes[order]  # before each
+            pages = np.sort(np.concatenate([
+                free[starts[i] : starts[i] + min(sizes[i], left)]
+                for i, left in zip(order, need) if left > 0]))
+        self._page_free[pages] = False
+        self._pages_free -= n
+        self.refcount[pages] = 1
+        return pages.tolist()
 
     def incref(self, pid: int) -> None:
         if pid == TRASH_PAGE or not 0 < pid < self.num_pages:
             raise ValueError(f"incref of invalid page {pid}")
-        if pid in self._free_page_set:
+        if self._page_free[pid]:
             raise ValueError(f"incref of free page {pid}")
         self.refcount[pid] += 1
 
     def decref(self, pid: int) -> None:
         if pid == TRASH_PAGE or not 0 < pid < self.num_pages:
             raise ValueError(f"decref of invalid page {pid}")
-        if pid in self._free_page_set:
+        if self._page_free[pid]:
             raise ValueError(f"double free of page {pid}")
         self.refcount[pid] -= 1
         if self.refcount[pid] == 0:
-            self._free_pages.append(pid)
-            self._free_page_set.add(pid)
+            self._page_free[pid] = True
+            self._pages_free += 1
 
     def bind(self, slot: int, page_ids: list[int]) -> None:
         """Point ``slot``'s table at ``page_ids`` (prefix-adopted pages

@@ -331,7 +331,7 @@ def test_a_page_the_prefix_cache_holds_is_not_freed_by_the_roll(params):
     while eng.lengths[slot] < W:
         eng.step()
     assert (pool.refcount[cached] == 1).all()  # the cache's, not the slot's
-    assert not set(cached) & set(pool._free_pages)
+    assert not pool._page_free[list(cached)].any()
     assert eng.stats["eva_window_pages_released"] == W // C
 
 

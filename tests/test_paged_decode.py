@@ -26,6 +26,8 @@ from distributed_tensorflow_tpu.models.transformer import (
 from distributed_tensorflow_tpu.obs import trace
 from distributed_tensorflow_tpu.ops.attention import (
     paged_decode_attention,
+    paged_decode_chain,
+    paged_decode_copies,
     paged_decode_form,
 )
 from distributed_tensorflow_tpu.serve.engine import (
@@ -118,30 +120,98 @@ def _kernel_call(fn, *args):
 _FORMS = [(2, 1, "row"), (3, 2, "row"), (2, 12, "group"), (1, 3, "group")]
 _FORM_IDS = [f"kv{kv}-group{g}-{form}" for kv, g, form in _FORMS]
 
+# The copy chain's cases, at 8 pages a chunk and therefore 8 pages a copy
+# step (``paged_decode_chain``; pages of 8 rows, rows of 24 pages): each a
+# list of slots ``(live pages, layout of the slot's table row)`` and a
+# window. A slot's length ends 3 rows short of its last page's end.
+# Layouts: "scatter" ids in no order; "up" / "down" one run of neighbours,
+# ascending / descending; "up@4" a run of 16 neighbours from logical page 4
+# (it crosses the chunk's edges at 8 and 16 and fills the step between
+# them) among scattered pages.
+_CHAINS = {
+    # The last chunk's page count, by what the wait handles: one page, a
+    # half, a half and one, the whole chunk less one.
+    "last-1": ([(9, "up"), (17, "scatter"), (1, "up")], None),
+    "last-half": ([(12, "scatter"), (20, "up")], None),
+    "last-half-and-1": ([(13, "up"), (21, "scatter")], None),
+    "last-whole-less-1": ([(15, "scatter"), (23, "up"), (7, "up")], None),
+    "ends-on-a-chunk": ([(8, "up"), (16, "scatter"), (24, "up")], None),
+    # One slot's last chunk hands over to the next LIVE slot's first.
+    "handover-over-dead-slots": (
+        [(13, "up"), (0, "up"), (0, "scatter"), (3, "scatter"), (0, "up"),
+         (17, "up")], None),
+    # 43 positions: whole pages below the window are skipped, and a slot's
+    # first live page is no multiple of a step.
+    "window-skips-pages": ([(9, "up"), (17, "scatter"), (24, "up")], 43),
+    "runs-ascending": ([(24, "up"), (16, "up"), (19, "up")], None),
+    "runs-descending": ([(24, "down"), (16, "down"), (11, "down")], None),
+    "scattered": ([(24, "scatter"), (16, "scatter"), (11, "scatter")], None),
+    "run-crosses-a-chunk": ([(24, "up@4"), (21, "up@4"), (18, "up@4")], None),
+}
+_CHAIN_PS, _CHAIN_PPS, _CHAIN_CHUNK = 8, 24, 8
 
-@pytest.mark.parametrize("window", [None, 11], ids=["full", "window11"])
-@pytest.mark.parametrize("kv,group,form", _FORMS, ids=_FORM_IDS)
-def test_kernel_matches_dense_over_ragged_lengths(kv, group, form, window):
-    ps, pps = 8, 6
-    max_len = ps * pps
-    # Among them a slot of length 0, lengths that end mid-page, and one
-    # (2 * ps) that ends exactly where a chunk of two pages does.
-    lens = np.array([1, ps - 1, ps, ps + 1, 2 * ps, max_len - 1, 0, max_len],
-                    np.int32)
+
+def _chain_pool(rng, kv, slots_spec):
+    """Pool leaves, table rows laid out as ``slots_spec`` says, lengths."""
+    ps, pps = _CHAIN_PS, _CHAIN_PPS
+    slots = len(slots_spec)
+    pages = 2 * slots * pps + 1
+    k = jnp.asarray(rng.standard_normal((pages, kv, ps, 128)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((pages, kv, ps, 128)), jnp.float32)
+    # Odd ids for the scattered rows (no two of them neighbours), a block of
+    # even-and-odd neighbours a slot for the runs.
+    odd = rng.permutation(np.arange(1, slots * pps, 2))
+    tables = np.zeros((slots, pps), np.int32)
+    lens = np.zeros(slots, np.int32)
+    for b, (live, layout) in enumerate(slots_spec):
+        block = slots * pps + 1 + b * pps + np.arange(pps)
+        scatter = odd[(b * pps // 2 + np.arange(pps)) % odd.size]
+        tables[b] = {"scatter": scatter, "up": block, "down": block[::-1],
+                     "up@4": np.concatenate(
+                         [scatter[:4], block[:16], scatter[4:8]])}[layout]
+        lens[b] = max(0, live * ps - 3)
+    return k, v, tables, lens
+
+
+_RAGGED = [pytest.param(kv, g, form, window, None,
+                        id=f"kv{kv}-group{g}-{form}-{wid}")
+           for kv, g, form in _FORMS
+           for window, wid in ((None, "full"), (11, "window11"))]
+_CHAINED = [pytest.param(kv, g, form, None, name,
+                         id=f"kv{kv}-group{g}-{form}-{name}")
+            for kv, g, form in (_FORMS[0], _FORMS[2]) for name in _CHAINS]
+
+
+@pytest.mark.parametrize("kv,group,form,window,chain", _RAGGED + _CHAINED)
+def test_kernel_matches_dense_over_ragged_lengths(kv, group, form, window,
+                                                  chain):
     assert paged_decode_form(group) == form
     rng = np.random.default_rng(group)
-    k, v, tables = _pool(rng, jnp.float32, kv, ps, pps, lens.size)
+    if chain is None:
+        ps, pps, chunk = 8, 6, 2
+        max_len = ps * pps
+        # Among them a slot of length 0, lengths that end mid-page, and one
+        # (2 * ps) that ends exactly where a chunk of two pages does. Two
+        # pages a chunk: the longest slots take three chunks, so the copy
+        # chain crosses chunks and slots.
+        lens = np.array(
+            [1, ps - 1, ps, ps + 1, 2 * ps, max_len - 1, 0, max_len],
+            np.int32)
+        k, v, tables = _pool(rng, jnp.float32, kv, ps, pps, lens.size)
+    else:
+        slots_spec, window = _CHAINS[chain]
+        chunk = _CHAIN_CHUNK
+        k, v, tables, lens = _chain_pool(rng, kv, slots_spec)
     q = jnp.asarray(rng.standard_normal((lens.size, kv, group, 128)),
                     jnp.float32)
-    # Two pages a chunk: the longest slots take three chunks, so the copy
-    # chain crosses chunks and slots.
     got = paged_decode_attention(
         q, k, v, jnp.asarray(tables), jnp.asarray(lens), window=window,
-        pages_per_chunk=2,
+        pages_per_chunk=chunk,
     )
     want = _dense(q, k, v, tables, lens, window)
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-6, rtol=2e-6)
-    assert not np.asarray(got)[6].any()  # lens 0: nothing read, zeros
+    # lens 0: nothing read, zeros
+    assert not np.asarray(got)[lens == 0].any()
 
 
 @pytest.mark.parametrize("group,form,slack", [
@@ -197,28 +267,89 @@ def test_kernel_form_follows_from_the_shapes_alone():
     assert new.outvars[0].aval.dtype == jnp.float32
 
 
-def test_kernel_reads_only_the_live_pages():
-    """Every page the lengths do not reach is poisoned: a kernel that
-    copied a dead page of a row, or a page of no row, would read NaN."""
-    ps, pps, kv = 8, 6, 1
-    lens = np.array([ps + 1, 0, 3 * ps], np.int32)
+@pytest.mark.parametrize("chain", [None, *_CHAINS])
+def test_kernel_reads_only_the_live_pages(chain):
+    """Every page the lengths (and the window) do not reach is poisoned: a
+    kernel that copied a dead page of a row, a neighbour beyond a run's
+    live end, or a page of no row, would read NaN."""
     rng = np.random.default_rng(3)
-    k, v, tables = _pool(rng, jnp.float32, kv, ps, pps, lens.size)
+    if chain is None:
+        ps, pps, kv, chunk, window = 8, 6, 1, 2, None
+        lens = np.array([ps + 1, 0, 3 * ps], np.int32)
+        k, v, tables = _pool(rng, jnp.float32, kv, ps, pps, lens.size)
+    else:
+        (slots_spec, window), kv, ps = _CHAINS[chain], 1, _CHAIN_PS
+        chunk = _CHAIN_CHUNK
+        k, v, tables, lens = _chain_pool(rng, kv, slots_spec)
     live = {int(p) for b, n in enumerate(lens)
-            for p in tables[b, : -(-int(n) // ps)]}
+            for p in tables[b, (max(0, int(n) - window) // ps
+                                if window else 0): -(-int(n) // ps)]}
     dead = np.array([p for p in range(k.shape[0]) if p not in live])
     k, v = k.at[dead].set(jnp.nan), v.at[dead].set(jnp.nan)
     q = jnp.asarray(rng.standard_normal((lens.size, kv, 4, 128)),
                     jnp.float32)
     got = np.asarray(paged_decode_attention(
-        q, k, v, jnp.asarray(tables), jnp.asarray(lens), pages_per_chunk=2
+        q, k, v, jnp.asarray(tables), jnp.asarray(lens), window=window,
+        pages_per_chunk=chunk,
     ))
     assert np.isfinite(got).all()
     np.testing.assert_allclose(
         got, _dense(q, np.nan_to_num(np.asarray(k)),
-                    np.nan_to_num(np.asarray(v)), tables, lens, None),
+                    np.nan_to_num(np.asarray(v)), tables, lens, window),
         atol=2e-6, rtol=2e-6,
     )
+
+
+def _copies_one_by_one(tables, lens, chunk, run, ps, window):
+    """The kernel's chain walked in Python: a chunk at a time, a step of
+    ``run`` pages at a time, what is left of a chunk page by page."""
+    copies = pages = 0
+    for row, n in zip(tables, lens):
+        p0 = max(0, int(n) - window) // ps if window else 0
+        live = -(-int(n) // ps) - p0
+        pages += live
+        for c0 in range(0, live, chunk):
+            ids = row[p0 + c0: p0 + min(live, c0 + chunk)]
+            for s0 in range(0, len(ids) - run + 1, run):
+                step = ids[s0: s0 + run]
+                adjacent = run > 1 and (np.diff(step) == 1).all()
+                copies += 1 if adjacent else run
+            copies += len(ids) % run
+    return copies, pages
+
+
+@pytest.mark.parametrize("chain", list(_CHAINS))
+def test_copies_counted_on_the_host_follow_the_kernels_rule(chain):
+    """``paged_decode_copies`` (what ``engine.round`` notes as
+    ``kv_copies`` / ``kv_pages``) against the chain walked page by page, at
+    the sizes the chain takes for this leaf: rows of 24 pages of 4 KiB make
+    one chunk of 24 pages and steps of 16."""
+    slots_spec, window = _CHAINS[chain]
+    k, _, tables, lens = _chain_pool(np.random.default_rng(1), 1, slots_spec)
+    chunk, run = paged_decode_chain(k, _CHAIN_PPS)
+    assert (chunk, run) == (24, 16)
+    got = paged_decode_copies(tables, lens, k, window=window)
+    assert got == _copies_one_by_one(tables, lens, chunk, run, _CHAIN_PS,
+                                     window)
+    if chain == "runs-ascending":
+        # 24, 16 and 19 pages: one step of 16 neighbours each, the rest
+        # page by page.
+        assert got == (3 + 8 + 0 + 3, 24 + 16 + 19)
+    if chain in ("runs-descending", "scattered"):
+        assert got[0] == got[1]  # a copy a page
+
+
+@pytest.mark.parametrize("kv,ps,dtype,pps,want", [
+    (2, 16, jnp.bfloat16, 256, (128, 16)),  # the 2-kv-head cells: 8 KiB
+    (32, 16, jnp.bfloat16, 248, (8, 1)),  # evabyte-6.5b: 128 KiB a page
+    (2, 8, jnp.float32, 6, (6, 4)),  # a row shorter than a chunk
+    (1, 16, jnp.bfloat16, 4096, (256, 32)),
+], ids=["2kv-bf16", "evabyte", "short-row", "1kv"])
+def test_chain_sizes_follow_from_the_pages_bytes(kv, ps, dtype, pps, want):
+    """A chunk of 1 MiB a buffer and a copy step of 128 KiB, a power of two
+    of pages, whatever the model: no argument chooses."""
+    leaf = jax.ShapeDtypeStruct((9, kv, ps, 128), dtype)
+    assert paged_decode_chain(leaf, pps) == want
 
 
 def test_kernel_refuses_shapes_that_do_not_fit():
@@ -368,6 +499,35 @@ def test_kv_rows_read_counts_live_pages_on_the_table_path(params):
     assert engine.compile_count() == base
 
 
+def test_kv_copies_counts_a_step_of_neighbours_as_one(params):
+    """``engine.round`` notes the descriptors a layer its paged kernel's
+    chain starts and the live pages they carry, ``engine.stats`` sums both:
+    rows of 6 pages of 8 KiB make steps of 4 pages, a fresh pool hands out
+    neighbours, so the slot of 4 live pages costs one copy and the slot of
+    2 two. A program that gathers takes every page of every row, a copy
+    each."""
+    engine = SlotEngine(CFG, params, slots=3, max_len=48, prefill_len=32,
+                        page_size=8, prefix_cache=False)
+    for n in (9, 30):
+        engine.start(engine.acquire_slot(), list(range(1, n + 1)),
+                     max_new_tokens=4)
+    t0 = time.monotonic()
+    engine.step()
+    ((_, _, attrs),) = trace.closed("engine.round", t0, float("inf"))
+    assert (attrs["kv_copies"], attrs["kv_pages"]) == (2 + 1, 2 + 4)
+    assert attrs["kv_pages"] * 8 == attrs["kv_rows_read"]
+    assert (engine.stats["kv_copies"], engine.stats["kv_pages_copied"]) == (
+        3, 6)
+    gather = GatherEngine(CFG, params, slots=3, max_len=48, prefill_len=32,
+                          page_size=8)
+    gather.start(gather.acquire_slot(), list(range(1, 31)), max_new_tokens=4)
+    t0 = time.monotonic()
+    gather.step()
+    ((_, _, attrs),) = trace.closed("engine.round", t0, float("inf"))
+    assert (attrs["kv_copies"], attrs["kv_pages"]) == (3 * 6, 3 * 6)
+    assert attrs["kv_pages"] * 8 == attrs["kv_rows_read"]
+
+
 def _registers(engine, lengths):
     """Slots 0.. active at ``lengths``, set on the host registers alone:
     ``_kv_rows_read`` reads nothing else, so no program is compiled."""
@@ -502,3 +662,64 @@ def test_table_path_matches_whole_row_attention(variant):
         assert engine.decode_path == path
         got[path] = _drive(engine, requests)
     assert got["table"] == got["gather"]
+
+
+# -- the benchmark's readers of the kernel and its chain --------------------
+
+
+def _reader(name):
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                        "layer_metrics", name + ".py")
+    spec = importlib.util.spec_from_file_location(name.replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+@pytest.mark.parametrize("trace,share", [
+    ({"op_time_s": {"paged_decode_attention.3": 0.5, "fusion.1": 9.0},
+      "module_calls": {"jit_step_fn": 100}}, 100.0 * 0.8 / 0.5),
+    ({"op_time_s": {"fusion.1": 9.0}, "module_calls": {"jit_step_fn": 100}},
+     None),
+    (None, None),
+], ids=["kernel-timed", "no-such-kernel", "untraced"])
+def test_the_kernels_share_in_starcoder2s_cells(trace, share):
+    """``kernels.paged_decode_roofline``: the attended rows' bytes (30 KiB a
+    row over the 30 layers) at the HBM rate over the custom calls' time;
+    nothing where no such call was timed."""
+    import json
+    import os
+
+    from benchmarks import counts
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", "starcoder2-3b.json")) as fh:
+        cfg = json.load(fh)["transformer_config"]
+    row = counts.kv_bytes_per_token(cfg)
+    assert row == 30 * 1024
+    # (start, end, active slots, their live positions): the fifth round
+    # starts behind the traced second and is not counted.
+    rounds = [(0.2 * i, 0.2 * i + 0.1, 16, 984) for i in range(4)]
+    rounds.append((1.5, 1.6, 16, 50000))
+    c = {"trace": trace, "t_open": 0.0, "trace_s": 1.0, "model_cfg": cfg,
+         "counters": {"decode_rounds": rounds},
+         # 100 rounds of 1,000 rows take 0.8 s at this rate.
+         "peaks": {"hbm_bytes_per_s": row * 1000 * 100 / 0.8}}
+    got = _reader("kernels.paged_decode_roofline")(c)
+    assert got is None if share is None else abs(got - share) < 1e-9
+
+
+@pytest.mark.parametrize("rounds,want", [
+    ([{"kv_copies": 10, "kv_pages": 64}, {"kv_copies": 6, "kv_pages": 64},
+      {"active": 0}], 8.0),
+    ([{"active": 3, "kv_rows_read": 40}], None),  # a program with no count
+    (None, None),  # a program with no rings
+], ids=["counted", "no-count", "no-rings"])
+def test_pages_per_copy_reads_the_rounds_counts(monkeypatch, rounds, want):
+    from benchmarks import program_spans
+
+    monkeypatch.setattr(program_spans, "rounds", lambda c: rounds)
+    assert _reader("kv.decode_pages_per_copy")({}) == want

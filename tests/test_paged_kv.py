@@ -247,6 +247,38 @@ def test_paged_pool_double_free_and_stale_table():
         pool.incref(TRASH_PAGE)
 
 
+def test_alloc_pages_hands_out_runs_of_neighbours_after_churn():
+    """Pages come as neighbours in ascending order, whatever was freed in
+    between: the smallest run of free ids that holds the request whole,
+    else the largest runs first. Rows of neighbours are what the paged
+    decode kernel copies a step at a time."""
+    pool = PagedKVPool(CFG, slots=4, max_len=64, page_size=8)
+    rows = {}
+    for n in (5, 8, 3, 6):
+        slot = pool.alloc()
+        rows[slot] = pool.alloc_pages(n)
+        pool.bind(slot, rows[slot])
+    for pages in rows.values():
+        assert np.array_equal(np.diff(pages), np.ones(len(pages) - 1))
+    (a, five), (b, eight), (c, three), _ = rows.items()
+    tail = pool.pages_free  # the untouched run behind the four rows
+    pool.free(c)
+    pool.free(a)
+    # Holes of 5 and 3 (apart: the row of 8 lies between) and the tail:
+    # a request goes into the smallest hole that holds it whole ...
+    assert pool.alloc_pages(3) == three
+    assert pool.alloc_pages(4) == five[:4]
+    # ... and, where none does, takes the largest runs first, sorted. The
+    # freed row of 8 and the page left of the row of 5 are one run of 9.
+    pool.free(b)
+    assert pool.pages_free == tail + 1 + 8
+    got = pool.alloc_pages(tail + 8)
+    assert got == five[4:] + eight[:7] + list(
+        range(pool.num_pages - tail, pool.num_pages))
+    assert pool.alloc_pages(2) is None and pool.alloc_pages(1) == eight[7:]
+    assert pool.alloc_pages(0) == []
+
+
 def test_paged_pool_refcount_sharing():
     pool = PagedKVPool(CFG, slots=2, max_len=32, page_size=8)
     cache = PrefixCache(pool)

@@ -14,7 +14,10 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from distributed_tensorflow_tpu.ops.attention import paged_decode_attention
+from distributed_tensorflow_tpu.ops.attention import (
+    paged_decode_attention,
+    paged_decode_chain,
+)
 
 pytestmark = [pytest.mark.serve, pytest.mark.paged]
 
@@ -40,21 +43,36 @@ def _kernel_line(compiled) -> str:
     return line
 
 
-@pytest.mark.parametrize("dtype,slots,kv,group,ps,pps,window,result", [
+# The paged kernel's copy chain (PR 38) waits for a whole chunk with ONE
+# descriptor a leaf of the buffer's shape and copies a step of neighbouring
+# pages with one. Tier-1 runs the kernel in interpret mode, where a copy is
+# done when it is started and a wait of the wrong byte count cannot hang:
+# what holds the byte counts is that PR's run on the chip (PERF.md, PR 38),
+# and here only that Mosaic takes the chain at the cells' shapes, inside the
+# VMEM it is given (it refuses a kernel over the limit).
+@pytest.mark.parametrize("dtype,slots,kv,group,ps,pps,window,chain,result", [
     # starcoder2-3b as benchmarks/configs runs it: 16 slots of 4096, GQA
     # 24 / 2, pages of 16, the window equal to serve_max_len. Group 12
-    # stays on the form it has: q padded to 16 rows a head, bf16 out.
-    (jnp.bfloat16, 16, 2, 12, 16, 256, 4096, "bf16[16,2,16,128]"),
+    # stays on the form it has: q padded to 16 rows a head, bf16 out; a
+    # page of 8 KiB makes chunks of 128 pages and steps of 16.
+    (jnp.bfloat16, 16, 2, 12, 16, 256, 4096, (128, 16), "bf16[16,2,16,128]"),
+    # zaya1-8b: 32 slots, 4 query rows a kv head, no window.
+    (jnp.bfloat16, 32, 2, 4, 16, 256, None, (128, 16), "bf16[32,2,16,128]"),
+    # nemotron3-nano-30b's attention layer: 64 slots, 16 rows a kv head.
+    (jnp.bfloat16, 64, 2, 16, 16, 256, None, (128, 16), "bf16[64,2,16,128]"),
     # An f32 pool at its own tile, MHA, a window that skips pages: the
-    # row form, flat f32 rows out.
-    (jnp.float32, 4, 2, 1, 8, 12, 20, "f32[4,2,128]"),
-], ids=["sc2-3b-bf16", "f32-page8-window"])
+    # row form, flat f32 rows out; a row of 12 pages is one chunk.
+    (jnp.float32, 4, 2, 1, 8, 12, 20, (12, 8), "f32[4,2,128]"),
+], ids=["sc2-3b-bf16", "zaya1-8b-bf16", "nemotron3-nano-bf16",
+        "f32-page8-window"])
 def test_paged_decode_kernel_compiles_for_v5e(one_chip, dtype, slots, kv,
-                                              group, ps, pps, window, result):
+                                              group, ps, pps, window, chain,
+                                              result):
     def arg(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     pages = slots * pps + 1
+    assert paged_decode_chain(arg((pages, kv, ps, 128), dtype), pps) == chain
     compiled = jax.jit(
         lambda q, k, v, tables, lens: paged_decode_attention(
             q, k, v, tables, lens, window=window, interpret=False)
@@ -68,14 +86,15 @@ def test_paged_decode_kernel_compiles_for_v5e(one_chip, dtype, slots, kv,
     assert f"= {result}" in _kernel_line(compiled)
 
 
-@pytest.mark.parametrize("pages_per_chunk", [8, 32], ids=["chunk8", "chunk32"])
+@pytest.mark.parametrize("pages_per_chunk", [None, 32],
+                         ids=["chain8", "chunk32"])
 def test_paged_decode_kernel_compiles_for_evabyte_rows(one_chip,
                                                        pages_per_chunk):
     """evabyte-6.5b as benchmarks/configs runs it: 16 slots, 32 kv heads of
     128 and no groups, pages of 16, a composed row of 248 pages (15 windows
     of summaries and one window of K/V rows): the row form, at the 8 pages
-    a chunk ``models/transformer._eva_through_table`` picks (a page is 16
-    times StarCoder2's) and at the default 32, whose 16 MiB of chunk
+    a chunk and one page a copy that the chain takes for a page of 128 KiB
+    (16 times StarCoder2's), and at 32 pages a chunk, whose 16 MiB of chunk
     buffers fit only because the call asks Mosaic for the VMEM its shapes
     need (Mosaic refuses a kernel over the limit it was given)."""
     def arg(shape, dt):
@@ -83,6 +102,8 @@ def test_paged_decode_kernel_compiles_for_evabyte_rows(one_chip,
 
     slots, kv, ps, pps = 16, 32, 16, 248
     pages = slots * (pps + 8) + 1
+    assert paged_decode_chain(
+        arg((pages, kv, ps, 128), jnp.bfloat16), pps) == (8, 1)
     compiled = jax.jit(
         lambda q, k, v, tables, lens: paged_decode_attention(
             q, k, v, tables, lens, pages_per_chunk=pages_per_chunk,
