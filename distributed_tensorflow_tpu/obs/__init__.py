@@ -33,7 +33,10 @@ all report into:
 * :mod:`perf <.perf>` — live MFU / tokens-per-second gauges from the
   ``utils/flops.py`` math, device-memory watermarks, and the recompile
   sentinel that turns the serving engine's zero-recompile-after-warmup
-  invariant into an alerting runtime counter.
+  invariant into an alerting runtime counter; and the ONE ``jax.monitoring``
+  registration, ``install_runtime_spans()``, which puts every trace,
+  lowering and XLA compile, and (through ``trace``) every garbage
+  collection, into the span rings.
 * :mod:`slo <.slo>` — declarative SLO rules (selector, aggregation,
   threshold, sustain window) evaluated on a ticker; sustained breaches
   bump ``slo_breach_total``, hit the trace + flight-recorder planes, and
@@ -59,6 +62,7 @@ from distributed_tensorflow_tpu.obs.aggregate import (
 from distributed_tensorflow_tpu.obs.perf import (
     PerfGauges,
     RecompileSentinel,
+    install_runtime_spans,
     update_memory_gauges,
 )
 from distributed_tensorflow_tpu.obs.recorder import (
@@ -95,6 +99,7 @@ __all__ = [
     "write_process_snapshot",
     "PerfGauges",
     "RecompileSentinel",
+    "install_runtime_spans",
     "update_memory_gauges",
     "SloMonitor",
     "SloRule",

@@ -14,15 +14,21 @@ eval window, so a scraper sees the fleet's compute efficiency live:
   ``peak_bytes_in_use`` watermarks from ``Device.memory_stats()``. The CPU
   backend returns None there; the gauges are then simply not touched
   (graceful null — no fake zeros in the scrape).
+* :func:`install_runtime_spans` — the program's ONE ``jax.monitoring``
+  registration (a time-span, an event and a scalar listener, installed
+  once, never removed) and the garbage-collection hook of
+  :mod:`~distributed_tensorflow_tpu.obs.trace`. Every jaxpr trace, MLIR
+  lowering and backend compile becomes a span (``jax.trace``,
+  ``jax.lower``, ``xla.compile``, attribute ``fun``; ``jax.trace`` also
+  ``inner``, the traces nested in it and folded into it; ``xla.compile``
+  also ``cache``: ``"hit"``, ``"miss"`` or ``"off"``) in the span rings,
+  and ``xla.compile`` in the flight recorder too.
 * :class:`RecompileSentinel` — the serving engine's zero-recompile-after-
   warmup invariant was a test-only ``compile_count()`` assert; this makes
-  it an ALERTING runtime metric. Primary signal: a ``jax.monitoring``
-  event-duration listener on ``backend_compile`` events (fires once per
-  XLA compilation). jax 0.4.x has no per-listener unregister (only a
-  global ``clear_event_listeners``), so ONE module-level dispatcher is
-  registered process-wide on first use and forwards to whichever sentinels
-  are currently open — ``close()`` detaches a sentinel without touching
-  the global listener list. Version-guarded fallback: when the monitoring
+  it an ALERTING runtime metric. Primary signal: the listener above, on
+  backend compiles (one a XLA compilation), forwarded to whichever
+  sentinels are currently open — ``close()`` detaches a sentinel without
+  touching the listener. Version-guarded fallback: when the monitoring
   API is missing (or listener mode is explicitly declined), the sentinel
   counts deltas of an externally-polled compile-cache size
   (``SlotEngine.compile_count()`` feeds :meth:`RecompileSentinel.poll`
@@ -35,14 +41,17 @@ eval window, so a scraper sees the fleet's compute efficiency live:
 from __future__ import annotations
 
 import threading
+import time
 
 from distributed_tensorflow_tpu.obs import registry as _registry
+from distributed_tensorflow_tpu.obs import trace as _trace
 
 __all__ = [
     "PerfGauges",
     "update_memory_gauges",
     "RecompileSentinel",
     "monitoring_available",
+    "install_runtime_spans",
 ]
 
 
@@ -163,40 +172,100 @@ def update_memory_gauges(registry=None) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# recompile sentinel
+# compile spans and the recompile sentinel: one jax.monitoring registration
 # ---------------------------------------------------------------------------
 
 _dispatch_lock = threading.Lock()
 _dispatch_installed = False
 _active_sentinels: list["RecompileSentinel"] = []
 
+# jax's timed compile stages (jax._src.dispatch.log_elapsed_time: a
+# time.time() start and end, and the function's name) -> span names.
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_COMPILE_SPANS = {
+    _TRACE_EVENT: "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "xla.compile",
+}
+# Fired on the compiling thread inside the backend-compile interval. A
+# lookup that finds nothing is a miss even where the entry is then too small
+# or too quick to write (cache_misses fires only on a write); a hit follows
+# the lookup and overrides it.
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "miss",
+    "/jax/compilation_cache/cache_misses": "miss",
+    "/jax/compilation_cache/cache_hits": "hit",
+}
+# Per thread: .v = (cache outcome, time.time()) of the last cache event;
+# .depth = jaxpr traces open, .inner = traces folded into the open one.
+_compiling = threading.local()
+
 
 def monitoring_available() -> bool:
-    """Version guard: does this jax expose the event-duration listener the
-    sentinel's primary signal needs?"""
+    """Version guard: does this jax expose the listeners the compile spans
+    and the sentinel's primary signal need?"""
     try:
-        from jax import monitoring  # noqa: F401
-
-        return callable(getattr(monitoring, "register_event_duration_secs_listener", None))
-    except Exception:  # noqa: BLE001
+        from jax import monitoring
+    except ImportError:
         return False
+    return all(callable(getattr(monitoring, f, None)) for f in (
+        "register_event_time_span_listener", "register_event_listener",
+        "register_scalar_listener"))
 
 
-def _dispatch(event: str, duration=None, **kw) -> None:
-    # One XLA compilation records exactly one backend_compile duration;
-    # the jaxpr-trace/MLIR-lowering events around it would double count.
-    if "backend_compile" not in event:
+def _on_event(event: str, **kw) -> None:
+    outcome = _CACHE_EVENTS.get(event)
+    if outcome is not None:
+        _compiling.v = (outcome, time.time())
+
+
+def _on_scalar(event: str, value, **kw) -> None:
+    # jax records its start time as a scalar when a timed stage opens.
+    if event == _TRACE_EVENT:
+        _compiling.depth = getattr(_compiling, "depth", 0) + 1
+
+
+def _on_time_span(event: str, start: float, end: float, **kw) -> None:
+    name = _COMPILE_SPANS.get(event)
+    if name is None:
         return
-    with _dispatch_lock:
-        targets = list(_active_sentinels)
-    for s in targets:
-        s._on_compile_event()
+    try:
+        attrs = {"fun": str(kw.get("fun_name", ""))}
+        if event == _TRACE_EVENT:
+            # A trace inside another (every inner jit, every jnp function
+            # called under an outer jit: 15,000 a serving warm-up) is
+            # folded into the outermost one, which covers it: the ring
+            # keeps a start-up's traces and their union is the same.
+            depth = getattr(_compiling, "depth", 0)
+            _compiling.depth = max(depth - 1, 0)
+            inner = getattr(_compiling, "inner", 0)
+            if depth > 1:
+                _compiling.inner = inner + 1
+                return
+            _compiling.inner = 0
+            attrs["inner"] = inner
+        to_mono = time.monotonic() - time.time()
+        t0, t1 = start + to_mono, end + to_mono
+        if name != "xla.compile":
+            _trace.interval(name, t0, t1, **attrs)
+            return
+        seen, _compiling.v = getattr(_compiling, "v", None), None
+        attrs["cache"] = (seen[0] if seen is not None
+                          and start <= seen[1] <= end else "off")
+        _trace.interval(name, t0, t1, **attrs)
+        _trace.flight_interval(name, t0, t1, attrs)
+    except Exception:  # noqa: BLE001 — a listener must not fail the compile
+        pass
+    if name == "xla.compile":
+        with _dispatch_lock:
+            targets = list(_active_sentinels)
+        for s in targets:
+            s._on_compile_event()
 
 
 def _ensure_dispatcher() -> bool:
-    """Register the process-wide listener once (jax 0.4.x cannot unregister
-    a single listener, so it is never removed — it forwards to the
-    currently-open sentinels only)."""
+    """Register the process-wide listeners once (they are never removed:
+    they forward to the currently-open sentinels only)."""
     global _dispatch_installed
     with _dispatch_lock:
         if _dispatch_installed:
@@ -205,9 +274,24 @@ def _ensure_dispatcher() -> bool:
             return False
         from jax import monitoring
 
-        monitoring.register_event_duration_secs_listener(_dispatch)
+        for name in _COMPILE_SPANS.values():
+            _trace._rings.ring(name)
+        monitoring.register_event_time_span_listener(_on_time_span)
+        monitoring.register_event_listener(_on_event)
+        monitoring.register_scalar_listener(_on_scalar)
         _dispatch_installed = True
         return True
+
+
+def install_runtime_spans() -> None:
+    """Record every garbage collection and every trace, lowering and XLA
+    compile from now on, in the span rings (idempotent; no switch). Called
+    by ``utils/compile_cache.enable_compilation_cache`` before the backend
+    exists, and by ``tools/serve_lm.build_stack`` and
+    ``parallel/data_parallel.build_lm_train_step`` for callers that skipped
+    it. Touches no backend."""
+    _trace.install_gc_spans()
+    _ensure_dispatcher()
 
 
 class RecompileSentinel:

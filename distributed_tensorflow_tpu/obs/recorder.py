@@ -11,8 +11,9 @@ plus instantaneous events) and dumps it as JSONL when something goes wrong:
 * :func:`install_excepthook` chains onto ``sys.excepthook`` so ANY unhandled
   exception in an obs-enabled process ships its timeline.
 
-Recording cost is one lock + deque.append (the deque is bounded, so memory is
-fixed). Dumping is the only I/O, and it only happens on the failure path.
+Recording cost is one re-entrant lock + deque.append (the deque is bounded,
+so memory is fixed). Dumping is the only I/O, and it only happens on the
+failure path.
 """
 
 from __future__ import annotations
@@ -43,7 +44,10 @@ class FlightRecorder:
         if capacity <= 0:
             raise ValueError(f"capacity must be > 0, got {capacity}")
         self.capacity = capacity
-        self._lock = threading.Lock()
+        # Re-entrant: a full garbage collection is recorded from the
+        # ``gc.callbacks`` hook (obs/trace.py), which can run on a thread
+        # that is inside ``record`` already.
+        self._lock = threading.RLock()
         self._events: deque[dict] = deque(maxlen=capacity)
         self._seq = 0
 

@@ -22,6 +22,14 @@ closed span goes to three places:
   ``flight=False`` and stay out of it: its 1,024 events would otherwise hold
   four seconds of rounds and nothing else.
 
+Two kinds of span are recorded by the process itself, not by a ``with``
+block (:func:`install_gc_spans` here, the compile listener in
+:mod:`~distributed_tensorflow_tpu.obs.perf`; both installed once by
+``obs.install_runtime_spans()``, before the first compile): every garbage
+collection, as ``py.gc.<generation>``, and every jaxpr trace, lowering and
+XLA compile, as ``jax.trace``, ``jax.lower`` and ``xla.compile``. Those are
+the two events that stop every Python thread at once.
+
 There is no switch: spans are always on, and "tracing off" is no profiler
 session. A span closed with no session open costs about 2 µs
 (``tests/test_obs_trace.py`` holds it under a loose ceiling; the benchmark's
@@ -36,6 +44,7 @@ checkpoint_save → …). Span ids are a process-local counter; the recorded
 
 from __future__ import annotations
 
+import gc
 import itertools
 import sys
 import threading
@@ -47,11 +56,14 @@ from distributed_tensorflow_tpu.obs import recorder as _recorder
 
 __all__ = [
     "Span", "SpanRings", "span", "interval", "closed", "names",
-    "trace_event", "current_span", "RING_CAPACITY",
+    "trace_event", "current_span", "RING_CAPACITY", "install_gc_spans",
+    "flight_interval",
 ]
 
-# A minute and a half of serving: 45 s is about 1,100 decode rounds.
-RING_CAPACITY = 4096
+# A whole benchmark run: cell 1 closes about 5,000 engine.round spans over
+# its lead and 45 s window, and collects its youngest generation a few
+# times a second (PERF.md §6, PR 39).
+RING_CAPACITY = 16384
 
 _ids = itertools.count(1)
 _local = threading.local()
@@ -75,17 +87,24 @@ def _process_index() -> int:
     return _process
 
 
-def _annotation(name: str, attrs):
-    """A TraceAnnotation for an opening span, or None in a process without
-    jax (which can hold no profiler session either)."""
+def _resolve_annotate():
+    """utils/profiler.annotate, once the process has imported jax; None
+    before (a process without jax can hold no profiler session either)."""
     global _annotate
-    if _annotate is None:
-        if "jax" not in sys.modules:
-            return None
+    if _annotate is None and "jax" in sys.modules:
         from distributed_tensorflow_tpu.utils.profiler import annotate
 
         _annotate = annotate
-    return _annotate(name, **attrs) if attrs else _annotate(name)
+    return _annotate
+
+
+def _annotation(name: str, attrs):
+    """A TraceAnnotation for an opening span, or None in a process without
+    jax."""
+    annotate = _resolve_annotate()
+    if annotate is None:
+        return None
+    return annotate(name, **attrs) if attrs else annotate(name)
 
 
 class SpanRings:
@@ -100,12 +119,19 @@ class SpanRings:
         self._rings: dict[str, deque] = {}
         self._lock = threading.Lock()
 
-    def record(self, name: str, t0: float, t1: float, attrs=None) -> None:
+    def ring(self, name: str) -> deque:
+        """``name``'s ring, made empty on first use."""
         ring = self._rings.get(name)
         if ring is None:
             with self._lock:
                 ring = self._rings.setdefault(
                     name, deque(maxlen=self.capacity))
+        return ring
+
+    def record(self, name: str, t0: float, t1: float, attrs=None) -> None:
+        ring = self._rings.get(name)
+        if ring is None:
+            ring = self.ring(name)
         ring.append((t0, t1, attrs))
 
     def closed(self, name: str, t_lo: float = float("-inf"),
@@ -241,6 +267,19 @@ def interval(name: str, t0: float, t1: float, **attrs: Any) -> None:
     _rings.record(name, t0, t1, attrs or None)
 
 
+def flight_interval(name: str, t0: float, t1: float, attrs: dict) -> None:
+    """An interval of ``time.monotonic`` ends into the flight recorder as a
+    span event of the thread's open span. Its ``process`` is the index as
+    far as a span has resolved it (0 before): the hooks that call this may
+    run before the backend exists, and must not create it."""
+    stack = getattr(_local, "stack", None)
+    _recorder.get_recorder().record(
+        kind="span", name=name, span_id=next(_ids),
+        parent_id=stack[-1].span_id if stack else 0,
+        process=_process or 0, t_wall=time.time() - (time.monotonic() - t0),
+        t_mono=t0, end_mono=t1, duration_s=round(t1 - t0, 6), attrs=attrs)
+
+
 def closed(name: str, t_lo: float = float("-inf"),
            t_hi: float = float("inf")) -> list[tuple]:
     """``(t0, t1, attrs)`` of every closed span or interval of ``name``
@@ -265,3 +304,56 @@ def trace_event(name: str, **attrs: Any) -> None:
         parent_id=parent.span_id if parent is not None else 0,
         **attrs,
     )
+
+
+# ---------------------------------------------------------------------------
+# garbage collections
+# ---------------------------------------------------------------------------
+
+_GC_NAMES = ("py.gc.0", "py.gc.1", "py.gc.2")
+_gc_open = None  # (name, inside, annotation, t0) of the collection under way
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: one span a collection, in a ring per
+    generation, opened as a profiler annotation at ``start`` and closed at
+    ``stop`` (so a pause sits in the host plane on the device trace's
+    clock). CPython runs one collection at a time and calls both phases on
+    the thread that collects. The hook reads the thread's span stack and
+    never writes it, takes no lock (the rings exist from the install; the
+    flight recorder's lock is re-entrant), never resolves
+    ``jax.process_index()`` (a collection during start-up must not touch
+    the backend) and never raises."""
+    global _gc_open
+    try:
+        if phase == "start":
+            stack = getattr(_local, "stack", None)
+            name = _GC_NAMES[info["generation"]]
+            ann = _annotate(name) if _annotate is not None else None
+            if ann is not None:
+                ann.__enter__()
+            _gc_open = (name, stack[-1].name if stack else "", ann,
+                        time.monotonic())
+            return
+        opened, _gc_open = _gc_open, None
+        if opened is None:
+            return
+        t1 = time.monotonic()
+        name, inside, ann, t0 = opened
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        attrs = {"collected": info["collected"], "inside": inside}
+        _rings.record(name, t0, t1, attrs)
+        if name == "py.gc.2":
+            flight_interval(name, t0, t1, attrs)
+    except Exception:  # noqa: BLE001 — a collection must never fail
+        _gc_open = None
+
+
+def install_gc_spans() -> None:
+    """Record every garbage collection from now on (idempotent)."""
+    for name in _GC_NAMES:
+        _rings.ring(name)
+    _resolve_annotate()
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)

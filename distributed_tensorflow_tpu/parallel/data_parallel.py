@@ -479,7 +479,9 @@ def build_lm_train_step(cfg, tx, mesh: Mesh, donate: bool = False):
         TransformerLM,
         next_token_loss,
     )
+    from distributed_tensorflow_tpu.obs import install_runtime_spans
 
+    install_runtime_spans()  # the step's trace, lowering and compile
     model = TransformerLM(cfg)
 
     def _shard_step(p, o, g, tokens, key):

@@ -101,8 +101,15 @@ def enable_compilation_cache() -> str:
     (every CLI does, right after flag parsing). Called after backend init it
     leaves LIBTPU_INIT_ARGS untouched (the budget in force stays at the XLA
     default AND the attention gate keeps sizing for that default —
-    ops/attention._fused_bwd_scratch_limit)."""
+    ops/attention._fused_bwd_scratch_limit).
+
+    It also installs the program's start-up spans
+    (``obs.install_runtime_spans``: every trace, lowering, XLA compile and
+    garbage collection from here on), which touch no backend."""
     _configure_tpu_vmem_budget()
+    from distributed_tensorflow_tpu.obs import install_runtime_spans
+
+    install_runtime_spans()
     from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if from_env:
         return from_env

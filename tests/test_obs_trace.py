@@ -1,8 +1,11 @@
 """What a closed span does (``obs/trace.py``): the ring per span name and
 ``closed()``, the profiler annotation under an open session, the flight
 recorder for the spans that are not per-round, and the cost of a span with
-no session open."""
+no session open. And the spans the process records by itself
+(``obs.install_runtime_spans``): every trace, lowering and XLA compile, and
+every garbage collection."""
 
+import gc
 import glob
 import os
 import subprocess
@@ -27,13 +30,14 @@ def fresh_recorder():
     obs.set_recorder(prev)
 
 
-def test_rare_span_survives_ten_thousand_others():
-    """One ring per NAME: 10,000 round spans fill their own ring to its
+def test_rare_span_survives_twenty_thousand_others():
+    """One ring per NAME: 20,000 round spans fill their own ring to its
     capacity and leave the one warm-up span where it was."""
     t_lo = time.monotonic()
     with trace.span("t1.rare", flight=False, program="p"):
         pass
-    for i in range(10_000):
+    assert trace.RING_CAPACITY < 20_000
+    for i in range(20_000):
         with trace.span("t1.round", flight=False):
             pass
     rare = trace.closed("t1.rare", t_lo)
@@ -127,11 +131,13 @@ def test_span_under_a_profiler_session_is_in_the_host_plane(tmp_path):
 
     from distributed_tensorflow_tpu.utils import profiler
 
+    obs.install_runtime_spans()
     with profiler.trace(str(tmp_path)):
         with obs.span("t6.round", flight=False, active=3) as sp:
             with obs.span("t6.dispatch", flight=False):
                 jnp.ones(8).block_until_ready()
             sp.note(chunks_run=0)
+        gc.collect(2)
     with obs.span("t6.after_the_session", flight=False):
         pass
     (path,) = glob.glob(os.path.join(
@@ -142,9 +148,11 @@ def test_span_under_a_profiler_session_is_in_the_host_plane(tmp_path):
             continue
         for line in plane.lines:
             for ev in line.events:
-                if ev.name.startswith("t6."):
+                if ev.name.startswith(("t6.", "py.gc.2")):
                     found[ev.name] = (ev.start_ns, ev.duration_ns,
                                       dict(ev.stats))
+    # the collection is an annotation from its start to its stop
+    assert found.pop("py.gc.2")[0] > found["t6.round"][0]
     assert set(found) == {"t6.round", "t6.dispatch"}
     assert found["t6.round"][2] == {"active": 3, "chunks_run": 0}
     r0, rd, _ = found["t6.round"]
@@ -176,6 +184,11 @@ def test_obs_imports_and_traces_without_jax():
         "with obs.span('a.c'):\n"
         "    pass\n"
         "assert len(trace.closed('a.b')) == 1\n"
+        "import gc\n"
+        "trace.install_gc_spans()\n"
+        "with obs.span('a.d'):\n"
+        "    gc.collect(2)\n"
+        "assert trace.closed('py.gc.2')[-1][2]['inside'] == 'a.d'\n"
         "assert 'jax' not in sys.modules, 'obs pulled jax in'\n"
     )
     proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
@@ -198,3 +211,151 @@ def test_closed_span_is_cheap_with_no_session_open():
         return (time.perf_counter() - t0) / n
 
     assert min(per_span() for _ in range(5)) < 25e-6
+
+
+# ---------------------------------------------------------------------------
+# the spans the process records by itself
+# ---------------------------------------------------------------------------
+
+
+def _records(name, sp):
+    """``name``'s records that lie inside span ``sp``, give or take a few
+    ms (jax stamps its ends with time.time())."""
+    return [r for r in trace.closed(name, sp.t_mono, sp.end_mono)
+            if sp.t_mono - 0.005 <= r[0] and r[1] <= sp.end_mono + 0.005]
+
+
+def test_first_call_leaves_trace_lower_and_compile_spans(fresh_recorder):
+    import jax
+    import jax.numpy as jnp
+
+    obs.install_runtime_spans()
+
+    @jax.jit
+    def t10_inner(x):
+        return jnp.tanh(x) * 3.0
+
+    def t10_fresh(x):
+        return t10_inner(x) + 1.0
+
+    x = jnp.ones((5,))  # its own compiles stay outside the span
+    x.block_until_ready()
+    with obs.span("t10.first_call") as sp:
+        jax.jit(t10_fresh)(x).block_until_ready()
+    mine = {name: [r for r in _records(name, sp)
+                   if "t10_fresh" in r[2]["fun"]]
+            for name in ("jax.trace", "jax.lower", "xla.compile")}
+    assert all(len(v) == 1 for v in mine.values()), mine
+    (tr,), (lo,), (co,) = mine.values()
+    assert tr[1] <= lo[0] + 0.005 and lo[1] <= co[0] + 0.005  # in order
+    # the inner jit's trace, and the jnp functions', fold into the outer
+    assert tr[2]["fun"] == "t10_fresh" and tr[2]["inner"] >= 2
+    assert not [r for r in _records("jax.trace", sp)
+                if r[2]["fun"] in ("t10_inner", "tanh")]
+    assert co[2] == {"fun": "jit(t10_fresh)", "cache": "off"}  # tests: off
+    flight = [e for e in fresh_recorder.events() if e["name"] == "xla.compile"
+              and e["attrs"]["fun"] == "jit(t10_fresh)"]
+    assert len(flight) == 1 and flight[0]["parent_id"] == sp.span_id
+    assert flight[0]["t_mono"] == pytest.approx(co[0])
+    assert not [e for e in fresh_recorder.events()
+                if e["name"] in ("jax.trace", "jax.lower")]
+
+
+def test_compile_span_reads_the_persistent_cache(tmp_path):
+    """``cache`` reads "miss" on a cold persistent cache, then "hit" once the
+    in-memory caches are cleared (a process of its own: the suite runs with
+    the persistent cache off)."""
+    code = (
+        "import jax, jax.numpy as jnp\n"
+        "from distributed_tensorflow_tpu.utils.compile_cache import "
+        "enable_compilation_cache\n"
+        "from distributed_tensorflow_tpu.obs import trace\n"
+        "enable_compilation_cache()\n"
+        "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)\n"
+        "jax.config.update('jax_persistent_cache_min_entry_size_bytes', -1)\n"
+        "def t11_cached(x):\n"
+        "    return jnp.cos(x) * 2.0\n"
+        "x = jnp.ones((3,))\n"
+        "for _ in range(2):\n"
+        "    jax.jit(t11_cached)(x).block_until_ready()\n"
+        "    jax.clear_caches()\n"
+        "got = [r[2]['cache'] for r in trace.closed('xla.compile')\n"
+        "       if r[2]['fun'] == 'jit(t11_cached)']\n"
+        "assert got == ['miss', 'hit'], got\n"
+    )
+    env = dict(os.environ, JAX_ENABLE_COMPILATION_CACHE="true",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path), JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=240, env=env)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+
+
+def test_full_collection_inside_a_span(fresh_recorder):
+    obs.install_runtime_spans()
+    with obs.span("t12.outer") as sp:
+        gc.collect(2)
+    (rec,) = _records("py.gc.2", sp)
+    assert rec[2]["inside"] == "t12.outer"
+    assert isinstance(rec[2]["collected"], int)
+    gc.collect(2)  # outside any span
+    assert trace.closed("py.gc.2")[-1][2]["inside"] == ""
+    assert trace.current_span() is None  # the stack left as it was
+    full = [e for e in fresh_recorder.events() if e["name"] == "py.gc.2"]
+    assert len(full) == 2 and full[0]["parent_id"] == sp.span_id
+
+
+def test_installing_twice_leaves_one_listener_and_one_callback():
+    from jax._src import monitoring
+
+    from distributed_tensorflow_tpu.obs import perf
+
+    for _ in range(2):
+        obs.install_runtime_spans()
+    assert gc.callbacks.count(trace._on_gc) == 1
+    assert monitoring.get_event_time_span_listeners().count(
+        perf._on_time_span) == 1
+    assert monitoring.get_event_listeners().count(perf._on_event) == 1
+    assert monitoring.get_scalar_listeners().count(perf._on_scalar) == 1
+    assert not [f for f in monitoring.get_event_duration_listeners()
+                if getattr(f, "__module__", "").startswith(
+                    "distributed_tensorflow_tpu")]
+
+
+def test_a_collection_at_start_up_touches_no_backend():
+    """The hooks are installed before the backend exists, and a collection
+    then must not create it: libtpu reads LIBTPU_INIT_ARGS once, at the
+    backend's creation."""
+    code = (
+        "import gc\n"
+        "from distributed_tensorflow_tpu.utils.compile_cache import "
+        "enable_compilation_cache\n"
+        "from distributed_tensorflow_tpu import obs\n"
+        "from distributed_tensorflow_tpu.obs import trace\n"
+        "enable_compilation_cache()\n"
+        "with obs.span('t13.start_up', flight=False):\n"
+        "    gc.collect(2)\n"
+        "from jax._src.xla_bridge import backends_are_initialized\n"
+        "assert not backends_are_initialized()\n"
+        "assert trace.closed('py.gc.2')[-1][2]['inside'] == 't13.start_up'\n"
+    )
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=120, env=env)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
+def test_collection_callbacks_are_cheap():
+    """The hook's pair of calls for one collection, with no profiler session
+    open: a few µs here; the ceiling is loose for the suite's other
+    workers. The chip's numbers are PERF.md §6's (PR 39)."""
+    obs.install_runtime_spans()
+    start, stop = {"generation": 0}, {"generation": 0, "collected": 0}
+
+    def per_pair(n=20_000):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            trace._on_gc("start", start)
+            trace._on_gc("stop", stop)
+        return (time.perf_counter() - t0) / n
+
+    assert min(per_pair() for _ in range(5)) < 25e-6

@@ -72,6 +72,7 @@ def build_stack(serve_cfg, cfg, params, deploy_cfg=None):
     Python frame above a jitted program's first call slows its tracing."""
     from distributed_tensorflow_tpu import obs
 
+    obs.install_runtime_spans()
     build_t0 = time.monotonic()
     from distributed_tensorflow_tpu.serve import (
         Scheduler,
