@@ -889,29 +889,19 @@ def _attend_through_table(cfg, cache, q4, k4, v4):
     ``pages`` (its row of the page table), ``write_page`` (the physical page
     its new row goes to: the engine points a masked lane at its trash page)
     and ``attend`` (how many positions it attends: ``len + 1``, 0 for a
-    masked lane). The new K and V row is written straight into the pool,
-    then ``ops.attention.paged_decode_attention`` reads the live pages
+    masked lane). The new K and V row is written straight into the pool
+    (:func:`_write_rows`: a page copy a lane that attends, in place), then
+    ``ops.attention.paged_decode_attention`` reads the live pages
     where they lie: no (B, kv, S_max, dh) cache is gathered or written
     back. ``q4`` (B, 1, H, dh) and ``k4`` / ``v4`` (B, 1, kv, dh) are already
     rotated. Returns ``(attn (B, 1, d_model), cache with the new leaves)``."""
     b, s, heads, dh = q4.shape
     if s != 1:
         raise ValueError(f"a paged cache takes one token per slot, got {s}")
-    pages, kv, ps, _ = cache["k"].shape
-    # The pool seen as rows of dh: a (B * kv)-row scatter in the leaf's own
-    # layout. Indexing (page, :, offset) instead makes XLA:TPU relay the
-    # whole pool out around every write (kv heads next to dh), which cost
-    # more than the gather this path removes.
-    rows = (
-        (cache["write_page"][:, None] * kv + jnp.arange(kv)[None, :]) * ps
-        + (cache["len"] % ps)[:, None]
-    ).reshape(-1)
-
-    def write(leaf, new):
-        flat = leaf.reshape(pages * kv * ps, dh)
-        return flat.at[rows].set(new.reshape(b * kv, dh)).reshape(leaf.shape)
-
-    ks, vs = write(cache["k"], k4), write(cache["v"], v4)
+    kv, ps = cache["k"].shape[1:3]
+    ks, vs = _write_rows(cache["k"], cache["v"], k4[:, 0], v4[:, 0],
+                         cache["write_page"], cache["len"] % ps,
+                         cache["attend"] > 0)
     q = q4[:, 0].reshape(b, kv, heads // kv, dh)
     if A.paged_decode_fits(ks):
         attn = A.paged_decode_attention(
@@ -924,6 +914,32 @@ def _attend_through_table(cfg, cache, q4, k4, v4):
         # program): the engine sends every other one down the gather path.
         attn = _table_attention_sum(q, ks, vs, cache["pages"], cache["attend"])
     return attn.reshape(b, 1, heads * dh), dict(cache, k=ks, v=vs)
+
+
+def _write_rows(k_leaf, v_leaf, k_rows, v_rows, page, offset, live):
+    """Row ``offset[b]`` of every kv head of page ``page[b]`` of a layer's
+    pool leaves (pages, kv, page_size, dh) set to ``k_rows[b]`` /
+    ``v_rows[b]`` (B, kv, dh), for the lanes ``live`` marks. Where the
+    leaves fit the paged kernels (:func:`ops.attention.paged_decode_fits`)
+    the page-copy kernel ``paged_row_write`` writes them in place and a lane
+    that is not live writes nothing. Elsewhere (a page or head size off the
+    chip's tiles: the CPU tests) a row scatter on the leaf seen as rows of
+    dh writes every lane, a masked one where its page points (the engine's
+    trash page). Indexing (page, :, offset) instead would make XLA:TPU relay
+    the whole pool out around every write."""
+    if A.paged_decode_fits(k_leaf):
+        return A.paged_row_write(k_leaf, v_leaf, k_rows, v_rows, page, offset,
+                                 live)
+    pages, kv, ps, dh = k_leaf.shape
+    rows = ((page[:, None] * kv + jnp.arange(kv)[None, :]) * ps
+            + offset[:, None]).reshape(-1)
+
+    def write(leaf, new):
+        flat = leaf.reshape(pages * kv * ps, dh)
+        return flat.at[rows].set(
+            new.reshape(-1, dh).astype(leaf.dtype)).reshape(leaf.shape)
+
+    return write(k_leaf, k_rows), write(v_leaf, v_rows)
 
 
 def _table_attention_sum(q, ks, vs, tables, attend):
@@ -1008,35 +1024,32 @@ def _eva_through_table(cfg, cache, q4, k4, v4, phi, mu):
     when the token completes no chunk). A chunk is one page: after the new
     row is written the page is read back, pooled (:func:`eva_summaries`) and
     its summary row written at ``(len % window) // chunk`` modulo the page.
-    Attention is ``paged_decode_attention`` over the composed table where
-    the pool's leaves fit it (one query row a kv head: the kernel's row
-    form, in chunks of 1 MiB a buffer), and the same sum in ``jax.numpy``
-    where they do not (a page or head size off the chip's tiles: the CPU
-    tests)."""
+    Both writes go through :func:`_write_rows`: the page-copy kernel where
+    the leaves fit it, which writes the summary of a lane that fills its
+    chunk and nothing else. Attention is ``paged_decode_attention`` over
+    the composed table where the pool's leaves fit it (one query row a kv
+    head: the kernel's row form, in chunks of 1 MiB a buffer), and the same
+    sum in ``jax.numpy`` where they do not (a page or head size off the
+    chip's tiles: the CPU tests)."""
     b, s, heads, dh = q4.shape
     if s != 1:
         raise ValueError(f"a paged cache takes one token per slot, got {s}")
-    pages, kv, ps, _ = cache["k"].shape
+    kv, ps = cache["k"].shape[1:3]
     w, c = int(cfg.eva_window), int(cfg.eva_chunk)
     if c != ps:
         raise EvaUnsupported(f"eva_chunk {c} must be the page size {ps}")
-    head_rows = jnp.arange(kv)[None, :]
-
-    def write(leaf, page, offset, new):
-        rows = ((page[:, None] * kv + head_rows) * ps + offset[:, None])
-        flat = leaf.reshape(pages * kv * ps, dh)
-        return flat.at[rows.reshape(-1)].set(
-            new.reshape(b * kv, dh).astype(leaf.dtype)).reshape(leaf.shape)
-
-    offset = cache["len"] % ps
-    ks = write(cache["k"], cache["write_page"], offset, k4)
-    vs = write(cache["v"], cache["write_page"], offset, v4)
+    live = cache["attend"] > 0
+    ks, vs = _write_rows(cache["k"], cache["v"], k4[:, 0], v4[:, 0],
+                         cache["write_page"], cache["len"] % ps, live)
     with jax.named_scope("eva.summary"):
         sk, sv = eva_summaries(
             ks[cache["write_page"]], vs[cache["write_page"]], phi, mu)
         row = ((cache["len"] % w) // c) % ps
-        ks = write(ks, cache["sum_page"], row, sk)
-        vs = write(vs, cache["sum_page"], row, sv)
+        # Only a token that fills its chunk has a summary to keep (a lane in
+        # 16 a round at EvaByte's chunk): the page kernel writes those lanes
+        # alone; the scatter writes every lane, the rest into the trash page.
+        fills = live & ((cache["len"] + 1) % c == 0)
+        ks, vs = _write_rows(ks, vs, sk, sv, cache["sum_page"], row, fills)
     q = q4[:, 0].reshape(b, kv, 1, dh)
     if A.paged_decode_fits(ks):
         # 32 kv heads make a page 16 times StarCoder2's: the kernel sizes

@@ -2470,6 +2470,157 @@ def _paged_decode_call(q, k_pages, v_pages, page_tables, lens, *, scale,
 
 
 # ---------------------------------------------------------------------------
+# The decode round's row writes: one new row a kv head into one page of each
+# of a layer's two pool leaves, a page copy a live lane, in place.
+# ---------------------------------------------------------------------------
+
+
+def _paged_row_write_kernel(page_ref, off_ref, live_ref, k_rows, v_rows,
+                            k_in, v_in, k_out, v_out, k_buf, v_buf, sems):
+    """Every live lane's page of each leaf is copied into VMEM at once, then,
+    lane by lane, row ``off`` of every kv head is set and the page copied
+    back. A lane that is not live issues nothing. ``sems`` (2, 2, slots):
+    [in | out, k | v, lane]; the copies back are awaited after the last."""
+    slots = k_rows.shape[0]
+    _, kv, ps, dh = k_in.shape
+    leaves = ((k_in, k_out, k_buf, k_rows, 0), (v_in, v_out, v_buf, v_rows, 1))
+
+    def copies(b, way):
+        """Lane ``b``'s page copies of both leaves: in (0) or out (1)."""
+        out = []
+        for src, dst, buf, _, s in leaves:
+            a, z = (src.at[page_ref[b]], buf.at[b]) if way == 0 else (
+                buf.at[b], dst.at[page_ref[b]])
+            out.append(pltpu.make_async_copy(a, z, sems.at[way, s, b]))
+        return out
+
+    def each_live(body):
+        def step(b, carry):
+            pl.when(live_ref[b] != 0)(functools.partial(body, b))
+            return carry
+
+        lax.fori_loop(0, slots, step, 0)
+
+    def start_in(b):
+        for c in copies(b, 0):
+            c.start()
+
+    at_row = lax.broadcasted_iota(jnp.int32, (kv, ps, dh), 1)
+
+    def set_row(b):
+        for c in copies(b, 0):
+            c.wait()
+        for _, _, buf, rows, _ in leaves:
+            # f32 for the select: the chip's VPU has no bf16 lanes, and a
+            # bf16 value goes through f32 and back unchanged.
+            buf[b] = jnp.where(
+                at_row == off_ref[b],
+                rows[b].astype(jnp.float32)[:, None, :],
+                buf[b].astype(jnp.float32)).astype(buf.dtype)
+        for c in copies(b, 1):
+            c.start()
+
+    def wait_out(b):
+        for c in copies(b, 1):
+            c.wait()
+
+    each_live(start_in)
+    each_live(set_row)
+    each_live(wait_out)
+
+
+def paged_row_write(k_pages, v_pages, k_rows, v_rows, pages, offsets, live, *,
+                    interpret: bool | None = None):
+    """The decode round's new K and V rows written into a layer's pool
+    leaves, in place: for each lane ``b`` with ``live[b]``, row
+    ``offsets[b]`` of every kv head of page ``pages[b]`` of ``k_pages`` /
+    ``v_pages`` (pages, kv_heads, page_size, head_dim) becomes ``k_rows[b]``
+    / ``v_rows[b]`` (slots, kv_heads, head_dim, cast to the leaf's dtype). A
+    lane that is not live writes nothing, wherever its page points. The live
+    lanes' pages are distinct (each a slot's own page: the engine's pool
+    guarantees it), since each lane's page goes back whole. Returns the two
+    leaves, aliased to the ones given (inside a program that donates the
+    pool, nothing of it is copied).
+
+    XLA's scatter of the same rows into the leaf seen as (pages * kv *
+    page_size, head_dim) writes one row of 256 B an update, and pays by the
+    row whatever its bytes. Here a live lane costs a copy of its page in and
+    out of VMEM, 128 KiB each way at EvaByte's 32 kv heads and 8 KiB at two,
+    all the lanes' copies in flight together. Alone on a v5e, bf16, pages
+    of 16, 16 lanes unless named (PERF.md §6; us a call, scatter / this
+    kernel): 32 kv heads 77.4 / 20.4, one lane of 16 live (EvaByte's
+    summaries) 77.8 / 7.6; 2 kv heads 10.1 / 7.5, 32 lanes 14.6 / 10.2, 64
+    lanes 23.8 / 15.0. No crossover: the kernel is taken wherever
+    :func:`paged_decode_fits` takes the leaf, the read kernel's rule. Mosaic
+    refuses a copy of one bf16 row (or two) into the tiled leaf; a copy of
+    the 8-row half of the page that holds the row read 13.6 / 8.7 / 7.3 /
+    10.3 / 16.4 on the same cases, and leans on XLA keeping 8-row tiles in
+    HBM, so the whole page is copied."""
+    slots, kv, dh = k_rows.shape
+    if (k_pages.shape != v_pages.shape or v_rows.shape != k_rows.shape
+            or k_pages.shape[1::2] != (kv, dh)):
+        raise ValueError(
+            f"rows k {k_rows.shape} / v {v_rows.shape} do not fit pages k "
+            f"{k_pages.shape} / v {v_pages.shape}")
+    if not all(a.shape == (slots,) for a in (pages, offsets, live)):
+        raise ValueError(
+            f"pages {pages.shape} / offsets {offsets.shape} / live "
+            f"{live.shape} do not fit {slots} slots")
+    if not paged_decode_fits(k_pages):
+        raise ValueError(
+            f"pages {k_pages.shape} of {k_pages.dtype} are off the tile: the "
+            f"scatter serves them")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _paged_row_write_call(
+        k_pages, v_pages, k_rows.astype(k_pages.dtype),
+        v_rows.astype(v_pages.dtype), pages.astype(jnp.int32),
+        offsets.astype(jnp.int32), live.astype(jnp.int32),
+        interpret=bool(interpret))
+
+
+# Jitted like _paged_decode_call: a model's layers trace the kernel once.
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _paged_row_write_call(k_pages, v_pages, k_rows, v_rows, pages, offsets,
+                          live, *, interpret):
+    slots = k_rows.shape[0]
+    buf = (slots,) + k_pages.shape[1:]
+    buf_bytes = math.prod(buf) * k_pages.dtype.itemsize
+    return pl.pallas_call(
+        _paged_row_write_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(1,),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.VMEM),
+                pl.BlockSpec(memory_space=pltpu.VMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=[
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM(buf, k_pages.dtype),
+                pltpu.VMEM(buf, v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2, slots)),
+            ],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
+                   jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)],
+        # Operands count the three prefetched scalars: the leaves are 5, 6.
+        input_output_aliases={5: 0, 6: 1},
+        interpret=interpret,
+        name="paged_row_write",
+        # Both leaves' pages of every lane, the rows, and room besides.
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=2 * buf_bytes + 4 * k_rows.size
+            * k_rows.dtype.itemsize + (4 << 20)),
+    )(pages, offsets, live, k_rows, v_rows, k_pages, v_pages)
+
+
+# ---------------------------------------------------------------------------
 # Prefill-chunk attention: a block of query rows at a TRACED offset attends a
 # slot's whole logical cache, forward only.
 #

@@ -129,7 +129,9 @@ re-formed into the forming pages); between two rounds, or two segments, a
 slot that has filled a window is ROLLED on the host (``_roll_window``, span
 ``engine.window_roll``). ``stats`` counts ``eva_windows_rolled``,
 ``eva_window_pages_released`` and ``eva_summary_pages_adopted``, and a
-round's record carries ``summary_rows_read`` / ``window_rows_read``. Slot
+round's record carries ``summary_rows_read`` / ``window_rows_read`` and
+``eva_summary_writes`` (the lanes whose token filled a chunk: the only
+summaries a round writes where the pages fit the page-copy kernel). Slot
 export/import, speculation, chunking off and the sharded engine refuse
 such a config (``EvaUnsupported``).
 
@@ -174,7 +176,10 @@ decode program reads: the active slots' live pages through the table,
 ``slots * max_len`` wherever a program gathers), and ``kv_copies`` and
 ``kv_pages`` (those positions in pages, and the copies a layer that bring
 them in: the paged kernel's chain starts one descriptor for a step of
-neighbouring pages, a gather takes a page an index; ``stats`` sums both);
+neighbouring pages, a gather takes a page an index; ``stats`` sums both),
+and ``kv_row_writes`` (the lanes whose new K and V rows the round wrote, a
+layer: ``ops.attention.paged_row_write`` copies each such lane's page in and
+out, and a masked lane writes nothing; ``stats`` sums it);
 ``engine.start`` covers an admission and ``engine.warmup`` the program set's
 compiles. ``engine.round`` also says ``ahead``: whether the round whose
 tokens the call returns was queued before the round before it was read
@@ -553,6 +558,11 @@ class SlotEngine:
             # brought their K and V pages in, and those pages.
             "kv_copies": 0,
             "kv_pages_copied": 0,
+            # Over the decode rounds read so far: the lanes whose new K and V
+            # rows a round wrote (a layer), and on EVA the lanes whose token
+            # filled a chunk, whose summary it wrote.
+            "kv_row_writes": 0,
+            "eva_summary_writes": 0,
             "prefill_chunks": 0,
             "prefill_tokens_last_iter": 0,
             "eva_windows_rolled": 0,
@@ -2050,10 +2060,18 @@ class SlotEngine:
                 sp.note(kv_copies=chain[0], kv_pages=chain[1])
                 self.stats["kv_copies"] += chain[0]
                 self.stats["kv_pages_copied"] += chain[1]
+            # Counted from the registers the round ran with: no sync.
+            writes = int(act.sum()) if rnd is not None else 0
+            sp.note(kv_row_writes=writes)
+            self.stats["kv_row_writes"] += writes
             if self._eva:
                 sums, rows = self._eva_rows(act, lengths)
+                fills = 0 if rnd is None else int(
+                    (act & ((lengths + 1) % self.page_size == 0)).sum())
                 sp.note(summary_rows_read=int(sums.sum()),
-                        window_rows_read=int(rows.sum()))
+                        window_rows_read=int(rows.sum()),
+                        eva_summary_writes=fills)
+                self.stats["eva_summary_writes"] += fills
             if rnd is not None and rnd.moe is not None:
                 # Of ``experts_total`` (layer, expert) pairs held here.
                 sp.note(experts_touched=int(rnd.moe[0]),

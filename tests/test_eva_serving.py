@@ -318,6 +318,35 @@ def test_a_roll_frees_the_window_and_attend_counts_both_kinds(params):
     assert eng.stats["eva_window_pages_released"] == W // C
 
 
+def test_round_counts_the_rows_and_summaries_it_writes(params):
+    """``engine.round`` notes ``kv_row_writes`` (the active lanes) and
+    ``eva_summary_writes`` (the active lanes whose token fills its chunk:
+    (len + 1) % page_size == 0), from the registers the round ran with;
+    ``engine.stats`` sums both. Two slots two positions apart: a round's
+    lengths follow from its ``live_tokens``."""
+    import time
+
+    eng = make_engine(params, prefix_cache=False)
+    t0 = time.monotonic()
+    for p in (5, 7):
+        eng.start(eng.acquire_slot(), tokens(p, seed=p), max_new_tokens=20)
+    for _ in range(12):
+        eng.step()
+    recs = [a for _, _, a in trace.closed("engine.round", t0, float("inf"))]
+    both = [a for a in recs if a["active"] == 2]
+    assert len(both) >= 10
+    for a in recs:
+        assert a["kv_row_writes"] == a["active"]
+    for a in both:
+        first = (a["live_tokens"] - 2) // 2
+        assert a["eva_summary_writes"] == sum(
+            (n + 1) % C == 0 for n in (first, first + 2))
+    assert {a["eva_summary_writes"] for a in both} == {0, 1}
+    assert eng.stats["kv_row_writes"] == sum(a["kv_row_writes"] for a in recs)
+    assert eng.stats["eva_summary_writes"] == sum(
+        a["eva_summary_writes"] for a in recs)
+
+
 def test_a_page_the_prefix_cache_holds_is_not_freed_by_the_roll(params):
     eng = make_engine(params)
     pool = eng.pool

@@ -29,6 +29,7 @@ from distributed_tensorflow_tpu.ops.attention import (
     paged_decode_chain,
     paged_decode_copies,
     paged_decode_form,
+    paged_row_write,
 )
 from distributed_tensorflow_tpu.serve.engine import (
     ShardedSlotEngine,
@@ -364,6 +365,99 @@ def test_kernel_refuses_shapes_that_do_not_fit():
                                jnp.zeros((2,), jnp.int32))
 
 
+# -- the row write ------------------------------------------------------------
+
+
+def _scatter_rows(leaf, new, pages, offsets):
+    """The scatter the page-copy kernel stands in for: every lane's row into
+    the leaf seen as (pages * kv * page_size, head_dim)."""
+    n, kv, ps, dh = leaf.shape
+    rows = ((pages[:, None] * kv + np.arange(kv)[None, :]) * ps
+            + offsets[:, None]).reshape(-1)
+    return leaf.reshape(n * kv * ps, dh).at[rows].set(
+        new.reshape(-1, dh).astype(leaf.dtype)).reshape(leaf.shape)
+
+
+def _write_case(kv, slots=6, ps=16, seed=0):
+    """A bf16 pool of 3 pages a lane and new f32 rows: the lanes' pages
+    scattered over it, offsets 0, 7 and 15 among them, lane 2 masked with
+    its page at a real page of its own."""
+    rng = np.random.default_rng(seed)
+    n = 3 * slots + 1
+    k = jnp.asarray(rng.standard_normal((n, kv, ps, 128)), jnp.bfloat16)
+    v = jnp.asarray(rng.standard_normal((n, kv, ps, 128)), jnp.bfloat16)
+    rows = [jnp.asarray(rng.standard_normal((slots, kv, 128)), jnp.float32)
+            for _ in range(2)]
+    pages = rng.permutation(np.arange(1, n))[:slots].astype(np.int32)
+    offsets = np.array([0, 7, 15, 3, 15, 0], np.int32)[:slots]
+    live = np.arange(slots) != 2
+    return k, v, rows, pages, offsets, live
+
+
+@pytest.mark.parametrize("kv", [2, 32], ids=["2kv", "32kv-evabyte"])
+def test_row_write_matches_the_scatter(kv):
+    """Every page but the trash page is bitwise what ``.at[rows].set`` gives
+    with the masked lane sent to the trash page, the kernel's input leaves
+    are left as they were (a copy where nothing is donated), and the masked
+    lane's own page is untouched."""
+    k, v, (kr, vr), pages, offsets, live = _write_case(kv)
+    got_k, got_v = paged_row_write(k, v, kr, vr, jnp.asarray(pages),
+                                   jnp.asarray(offsets), jnp.asarray(live))
+    trash = np.where(live, pages, TRASH_PAGE)
+    for got, leaf, new in ((got_k, k, kr), (got_v, v, vr)):
+        want = _scatter_rows(leaf, new, trash, offsets)
+        assert got.dtype == leaf.dtype
+        np.testing.assert_array_equal(np.asarray(got[1:]),
+                                      np.asarray(want[1:]))
+        np.testing.assert_array_equal(np.asarray(got[TRASH_PAGE]),
+                                      np.asarray(leaf[TRASH_PAGE]))
+        np.testing.assert_array_equal(np.asarray(got[pages[2]]),
+                                      np.asarray(leaf[pages[2]]))
+
+
+def test_row_write_with_no_lane_live_changes_nothing():
+    k, v, (kr, vr), pages, offsets, _ = _write_case(2)
+    got = paged_row_write(k, v, kr, vr, jnp.asarray(pages),
+                          jnp.asarray(offsets), jnp.zeros(6, bool))
+    for g, leaf in zip(got, (k, v)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(leaf))
+
+
+@pytest.mark.parametrize("kv", [2, 32], ids=["2kv", "32kv-evabyte"])
+def test_summary_write_touches_only_the_lanes_that_fill_a_chunk(kv):
+    """EVA's second call: ``live`` = the lanes whose token fills its chunk
+    ((len + 1) % page_size == 0); their summary row at (len % window) //
+    chunk lands in their forming page, and nothing else of the pool moves."""
+    k, v, (sk, sv), pages, _, _ = _write_case(kv, seed=1)
+    window, ps = 256, 16
+    lengths = np.array([15, 30, 47, 63, 200, 255], np.int32)
+    fills = (lengths + 1) % ps == 0
+    assert fills.tolist() == [True, False, True, True, False, True]
+    row = (lengths % window) // ps % ps
+    got_k, got_v = paged_row_write(k, v, sk, sv, jnp.asarray(pages),
+                                   jnp.asarray(row), jnp.asarray(fills))
+    for got, leaf, new in ((got_k, k, sk), (got_v, v, sv)):
+        got, leaf = np.asarray(got), np.asarray(leaf)
+        moved = (got != leaf).any(axis=(1, 3))  # (pages, rows)
+        want = np.zeros_like(moved)
+        want[pages[fills], row[fills]] = True
+        np.testing.assert_array_equal(moved, want)
+        np.testing.assert_array_equal(
+            got[pages[fills], :, row[fills]],
+            np.asarray(new.astype(leaf.dtype))[fills])
+
+
+def test_row_write_refuses_shapes_that_do_not_fit():
+    k = jnp.zeros((4, 2, 8, 128), jnp.bfloat16)  # a page of 8 bf16 rows
+    lanes = jnp.zeros((2,), jnp.int32)
+    with pytest.raises(ValueError, match="off the tile"):
+        paged_row_write(k, k, jnp.zeros((2, 2, 128)), jnp.zeros((2, 2, 128)),
+                        lanes, lanes, lanes)
+    with pytest.raises(ValueError, match="do not fit"):
+        paged_row_write(k, k, jnp.zeros((2, 4, 128)), jnp.zeros((2, 4, 128)),
+                        lanes, lanes, lanes)
+
+
 # -- which engines take which path -----------------------------------------
 
 
@@ -490,10 +584,12 @@ def test_kv_rows_read_counts_live_pages_on_the_table_path(params):
     for n in (9, 17):
         engine.start(engine.acquire_slot(), list(range(1, n + 1)),
                      max_new_tokens=4)
-    t0 = time.monotonic()
+    t0, writes0 = time.monotonic(), engine.stats["kv_row_writes"]
     engine.step()
     ((_, _, attrs),) = trace.closed("engine.round", t0, float("inf"))
     assert attrs["active"] == 2 and attrs["live_tokens"] == 9 + 17
+    assert attrs["kv_row_writes"] == 2
+    assert engine.stats["kv_row_writes"] - writes0 == 2
     # Lengths 9 and 17 attend 10 and 18 positions: 2 and 3 pages of 8.
     assert attrs["kv_rows_read"] == (2 + 3) * 8
     assert engine.compile_count() == base
@@ -557,6 +653,9 @@ def test_kv_rows_read_from_the_registers(want, kw, cfg):
 
 
 def test_inactive_lane_writes_only_the_trash_page(params):
+    """A round writes the active slot's row into its own page and nothing
+    else: at these shapes the page-copy kernel takes the writes, and a
+    masked lane issues none, the trash page included."""
     engine = SlotEngine(CFG, params, slots=3, max_len=48, prefill_len=24,
                         page_size=8, prefix_cache=False)
     engine.warmup()
@@ -574,8 +673,7 @@ def test_inactive_lane_writes_only_the_trash_page(params):
         for leaf in ("k", "v"):
             changed = {int(p) for p in np.nonzero(
                 (b[leaf] != a[leaf]).any(axis=(1, 2, 3)))[0]}
-            assert wrote in changed
-            assert changed <= {wrote, TRASH_PAGE}
+            assert changed == {wrote}
             # One new row a round, and nothing else of that page.
             rows = (b[leaf][wrote] != a[leaf][wrote]).any(axis=(0, 2))
             assert list(np.nonzero(rows)[0]) == [11 % 8, 12 % 8]
@@ -723,3 +821,20 @@ def test_pages_per_copy_reads_the_rounds_counts(monkeypatch, rounds, want):
 
     monkeypatch.setattr(program_spans, "rounds", lambda c: rounds)
     assert _reader("kv.decode_pages_per_copy")({}) == want
+
+
+@pytest.mark.parametrize("trace,want", [
+    ({"op_time_s": {"paged_row_write.2": 0.03, "paged_row_write.7": 0.01,
+                    "fusion.1": 9.0},
+      "module_calls": {"jit_step_fn": 150, "jit_step_fn_sampled": 50,
+                       "jit_prefill_fn": 9}}, 1000.0 * 0.04 / 200),
+    ({"op_time_s": {"fusion bf16[2097664,128]": 0.25},
+      "module_calls": {"jit_step_fn": 200}}, None),  # the scatter's program
+    (None, None),
+], ids=["kernel-timed", "scatter", "untraced"])
+def test_decode_write_ms_reads_the_kernels_time_a_round(trace, want):
+    """``kv.decode_write_ms``: the ``paged_row_write`` custom calls' device
+    time over the decode program's calls, in ms; nothing where no such call
+    was timed."""
+    got = _reader("kv.decode_write_ms")({"trace": trace})
+    assert got is None if want is None else abs(got - want) < 1e-12

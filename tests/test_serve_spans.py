@@ -142,16 +142,17 @@ def test_one_round_closes_each_span_once_nested_at_most_twelve(params):
     # read was queued by the call before it, ahead of its reading: it ran
     # with slot 0 alone, one token on from its prompt of 5; the chunk
     # call's round went out from the host behind the chunk. A gather takes
-    # every page by its own index: as many copies as pages.
+    # every page by its own index: as many copies as pages. Each round wrote
+    # the one active lane's rows.
     pages = 2 * 48 // engine.page_size
     assert a["engine.round"][2] == {"active": 1, "live_tokens": 6,
                                     "chunks_run": 0, "kv_rows_read": 2 * 48,
                                     "kv_copies": pages, "kv_pages": pages,
-                                    "ahead": True}
+                                    "kv_row_writes": 1, "ahead": True}
     assert c["engine.round"][2] == {"active": 1, "live_tokens": 7,
                                     "chunks_run": 1, "kv_rows_read": 2 * 48,
                                     "kv_copies": pages, "kv_pages": pages,
-                                    "ahead": False}
+                                    "kv_row_writes": 1, "ahead": False}
     assert a["sched.deliver"][2] == c["sched.deliver"][2] == {"produced": 1}
     assert c["engine.dispatch"][2] is None
     assert engine._flight is None and engine.prefilling.any()

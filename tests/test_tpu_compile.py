@@ -283,6 +283,94 @@ def test_sc2_prefill_chunk_holds_no_score_matrix(one_chip, monkeypatch):
     assert not re.search(r"f32\[[\d,]*1024,4096\]", text)
 
 
+def _one_layer(one_chip, name, **over):
+    """A configuration of ``benchmarks/configs`` cut to one layer (and the
+    overrides), its model, and its parameters as bf16 shapes on the
+    described chip."""
+    import json
+
+    from distributed_tensorflow_tpu.models.transformer import (
+        TransformerConfig,
+        TransformerLM,
+    )
+
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                        "configs", name + ".json")
+    with open(path) as fh:
+        mcfg = dict(json.load(fh)["transformer_config"], num_layers=1, **over)
+    cfg = TransformerConfig(**mcfg, compute_dtype=jnp.bfloat16)
+    model = TransformerLM(cfg)
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                       sharding=one_chip), shapes)
+    return cfg, model, params
+
+
+def _row_writes_in_place(text, leaf):
+    """The custom calls ``paged_row_write`` of an optimised module, after
+    checking that no scatter and no copy of a pool ``leaf`` (its shape, or
+    the rows-of-dh view the scatter took) stands in the module."""
+    import re
+
+    n, kv, ps, dh = leaf
+    for shape in (f"bf16[{n},{kv},{ps},{dh}]", f"bf16[{n * kv * ps},{dh}]"):
+        assert not re.search(re.escape(shape) + r"\S* copy\(", text), shape
+    assert " scatter(" not in text and "scatter_" not in text
+    alias = text[text.index("input_output_alias"):].split("\n")[0]
+    assert alias.count("may-alias") + alias.count("must-alias") >= 2
+    return [l.split(" custom-call(")[0].strip() for l in text.splitlines()
+            if "tpu_custom_call" in l and " custom-call(" in l
+            and l.strip().startswith("%paged_row_write")]
+
+
+@pytest.mark.parametrize("name,kv,calls", [
+    # evabyte.sessions-closed: the rows, and the summaries of the lanes that
+    # fill a chunk, 32 kv heads of 128 a page.
+    ("evabyte-6.5b", 32, 2),
+    # sc2-3b's cells: 16 slots of 4096, 2 kv heads.
+    ("starcoder2-3b", 2, 1),
+], ids=["evabyte", "sc2-3b"])
+def test_decode_round_writes_rows_by_page_copy(one_chip, monkeypatch, name,
+                                               kv, calls):
+    """One layer of the decode round as the cell runs it (16 slots, pages of
+    16, 4097 pages a leaf), its pool donated: the new rows go through
+    ``paged_row_write`` custom calls, with no scatter in the module and no
+    copy of a pool leaf, so the leaves are written where they lie."""
+    from distributed_tensorflow_tpu.models.decoding import decode_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eva = name == "evabyte-6.5b"
+    cfg, model, params = _one_layer(
+        one_chip, name, **({} if eva else {"vocab_size": 4096}))
+    slots, ps, pps = 16, 16, 256
+    leaf = (slots * pps + 1, kv, ps, 128)
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = [{"k": arg(leaf, jnp.bfloat16), "v": arg(leaf, jnp.bfloat16)}]
+
+    def step(params, pool, tables, active, lengths, tok):
+        lanes = jnp.arange(slots)
+        dest = tables[lanes, lengths // ps]
+        cache = {"layers": pool, "len": lengths, "pages": tables,
+                 "write_page": jnp.where(active, dest, 0),
+                 "attend": jnp.where(active, lengths + 1, 0)}
+        if eva:  # the forming page's entry, as eva_table_forward finds it
+            cache["sum_page"] = tables[lanes, pps - 1]
+        cache, logits = decode_step(model, params, cache, tok[:, None])
+        return cache["layers"], logits.argmax(-1)
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, pool, arg((slots, pps), jnp.int32), arg((slots,), jnp.bool_),
+        arg((slots,), jnp.int32), arg((slots,), jnp.int32)).compile()
+    writes = _row_writes_in_place(compiled.as_text(), leaf)
+    assert len(writes) == calls
+    assert all(f"bf16[{leaf[0]},{kv},16,128]" in w for w in writes)
+
+
 def _nemotron_layers(one_chip, pattern):
     """The Nemotron-H stage at the published widths (benchmarks/configs), cut
     to the layers of ``pattern`` and an 8,192-row vocabulary (4,096 is the
