@@ -227,6 +227,9 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
         return jnp.zeros(shape, dt, device=sharding)
 
     def layer():
+        if getattr(cfg, "mla", False):
+            # Latent attention: one row a position, c | k_pe | padding.
+            return {"lat": zeros((batch, 1, max_len, cfg.mla_row), dtype)}
         buf = {
             "k": zeros((batch, kv, max_len, dh), dtype),
             "v": zeros((batch, kv, max_len, dh), dtype),

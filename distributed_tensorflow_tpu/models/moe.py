@@ -40,7 +40,10 @@ Two routers, by what the model has, both float32 whatever ``compute_dtype``:
 * ``cfg.router_hidden`` 0, the linear router (:func:`route_linear`, top-k):
   ``s = sigmoid(h W_r)``; the k largest of ``s + b`` are chosen (the bias
   enters the choice only); their weights are ``cfg.router_scale * s[e] /
-  (sum of the k s[e] + 1e-20)``.
+  (sum of the k s[e] + 1e-20)``. With ``cfg.router_score`` 'softmax' (the
+  DeepSeek-V2 router) ``s = softmax(h W_r)`` over all experts, no bias, and
+  with ``cfg.router_renorm`` False the weights are ``cfg.router_scale *
+  s[e]`` as they are.
 """
 
 from __future__ import annotations
@@ -86,16 +89,23 @@ def route(mod, cfg, h32, r_prev):
 
 def route_linear(mod, cfg, h32):
     """(expert ids (T, k) int32, their weights (T, k) f32) of the tokens
-    ``h32`` (T, d) f32 under the linear sigmoid router (``router`` and
-    ``router_bias`` on ``mod``)."""
-    s = jax.nn.sigmoid(_f32_dense(cfg, cfg.num_experts, "router")(h32))
-    bias = mod.param("router_bias", nn.initializers.zeros,
-                     (cfg.num_experts,))
-    _, expert = jax.lax.top_k(s + bias.astype(jnp.float32),
-                              cfg.experts_per_token)
+    ``h32`` (T, d) f32 under the linear router (``router`` on ``mod``):
+    sigmoid scores with the choice-only ``router_bias``, or, with
+    ``cfg.router_score`` 'softmax', softmax scores and no bias."""
+    logits = _f32_dense(cfg, cfg.num_experts, "router")(h32)
+    if cfg.router_score == "softmax":
+        s = jax.nn.softmax(logits, -1)
+        _, expert = jax.lax.top_k(s, cfg.experts_per_token)
+    else:
+        s = jax.nn.sigmoid(logits)
+        bias = mod.param("router_bias", nn.initializers.zeros,
+                         (cfg.num_experts,))
+        _, expert = jax.lax.top_k(s + bias.astype(jnp.float32),
+                                  cfg.experts_per_token)
     picked = jnp.take_along_axis(s, expert, -1)
-    weight = cfg.router_scale * picked / (
-        picked.sum(-1, keepdims=True) + 1e-20)
+    weight = cfg.router_scale * picked
+    if cfg.router_renorm:
+        weight = weight / (picked.sum(-1, keepdims=True) + 1e-20)
     return expert.astype(jnp.int32), weight
 
 

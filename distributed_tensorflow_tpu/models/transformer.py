@@ -32,7 +32,12 @@ from distributed_tensorflow_tpu.models.moe import routed_experts
 from distributed_tensorflow_tpu.ops import attention as A
 from distributed_tensorflow_tpu.ops.grouped_matmul import grouped_matmul_fits
 from distributed_tensorflow_tpu.ops.rope import apply_rope as _rotate_heads
-from distributed_tensorflow_tpu.ops.rope import rope_tables
+from distributed_tensorflow_tpu.ops.rope import (
+    rope_tables,
+    yarn_inv_freq,
+    yarn_mscale,
+    yarn_tables,
+)
 
 
 def default_compute_dtype():
@@ -61,9 +66,10 @@ class EvaUnsupported(ValueError):
 class SlotStateUnsupported(ValueError):
     """A feature that is not extended to a config whose slots carry state
     beside K/V rows (CCA's convolution latents, ``cca_time0`` set; a Mamba-2
-    layer's recurrent state, an ``M`` in ``layer_pattern``) or to routed
-    experts (``num_experts`` set): raised where the feature is asked for,
-    never a silent fallback."""
+    layer's recurrent state, an ``M`` in ``layer_pattern``), to routed
+    experts (``num_experts`` set) or to rows that are not K and V (latent
+    attention's, ``kv_lora_rank`` set): raised where the feature is asked
+    for, never a silent fallback."""
 
 
 # The name the exception had while CCA's was the only slot state.
@@ -225,6 +231,38 @@ class TransformerConfig:
     ssm_groups: int = 1
     ssm_conv: int = 4
     ssm_block: int = 128
+    # Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 section
+    # 2.1), on when ``kv_lora_rank`` > 0 (:func:`mla_attention_sublayer`): a
+    # position is cached as ONE row, its normalised latent c
+    # (``kv_lora_rank``) and one rotated key part k_pe
+    # (``qk_rope_head_dim``) that every head shares; heads of
+    # ``qk_nope_head_dim`` + ``qk_rope_head_dim`` for q.k and ``v_head_dim``
+    # for v are expanded from c, or the queries are folded into it
+    # (absorbed). ``q_lora_rank`` (a low-rank query path) is refused. The
+    # rope part takes YaRN where ``yarn_factor`` > 0: frequencies stretched
+    # over ``yarn_original_len`` positions between ``yarn_beta_fast`` and
+    # ``yarn_beta_slow`` rotations, the tables times
+    # m(``yarn_mscale``) / m(``yarn_mscale_all_dim``) and the logits times
+    # m(``yarn_mscale_all_dim``)^2, m(x) = 0.1 x ln(factor) + 1
+    # (:func:`ops.rope.yarn_mscale`).
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    q_lora_rank: int | None = None
+    yarn_factor: float = 0.0
+    yarn_original_len: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+    # Routed experts behind ``dense_layers`` leading layers whose MLP is the
+    # dense one of width ``d_ff`` (DeepSeek's first_k_dense_replace).
+    # ``router_score`` 'softmax' and ``router_renorm`` False are the
+    # DeepSeek-V2 linear router (``models/moe.route_linear``).
+    dense_layers: int = 0
+    router_score: str = "sigmoid"  # 'sigmoid' | 'softmax'
+    router_renorm: bool = True
 
     def __post_init__(self):
         # Every string-enum field that SELECTS behavior is validated here:
@@ -371,6 +409,28 @@ class TransformerConfig:
             raise ValueError(
                 "experts_held, experts_per_token and shared_expert_width "
                 "need num_experts")
+        if self.router_score not in ("sigmoid", "softmax"):
+            raise ValueError(
+                f"router_score must be 'sigmoid' or 'softmax', got "
+                f"{self.router_score!r}")
+        if (self.router_score != "sigmoid" or not self.router_renorm) and (
+                not self.num_experts or self.router_hidden):
+            raise ValueError(
+                "router_score and router_renorm are the linear router's: "
+                "they need num_experts and router_hidden 0")
+        if self.dense_layers:
+            if (not self.num_experts or self.layer_pattern is not None
+                    or not 0 < self.dense_layers < self.num_layers):
+                raise ValueError(
+                    f"dense_layers {self.dense_layers} must lie in [1, "
+                    f"num_layers {self.num_layers}) of a config with routed "
+                    "experts and no layer_pattern")
+        if self.mla:
+            self._check_mla()
+        elif self.q_lora_rank is not None or self.yarn_factor:
+            raise ValueError(
+                "q_lora_rank and yarn_factor are latent attention's: they "
+                "need kv_lora_rank > 0")
         if self.layer_pattern is not None:
             pat = self.layer_pattern
             if (not isinstance(pat, str) or len(pat) != self.num_layers
@@ -412,6 +472,65 @@ class TransformerConfig:
                 self.weight_dtype, self.quant_group_size, self.d_model,
                 self.d_ff,
             )
+
+    def _check_mla(self):
+        """What latent attention is and is not extended to, by name."""
+        if min(self.qk_nope_head_dim, self.qk_rope_head_dim,
+               self.v_head_dim) < 1 or self.qk_rope_head_dim % 2:
+            raise ValueError(
+                "latent attention needs positive qk_nope_head_dim and "
+                "v_head_dim and an even, positive qk_rope_head_dim")
+        if self.position != "rope":
+            raise ValueError("latent attention rotates its rope part: "
+                             "position must be 'rope'")
+        if self.yarn_factor and (self.yarn_factor <= 1
+                                 or self.yarn_original_len < 1):
+            raise ValueError(
+                f"yarn_factor {self.yarn_factor} must be > 1 with a "
+                f"positive yarn_original_len")
+        refused = {
+            "q_lora_rank": "a low-rank query path (q-LoRA)",
+            "kv_cache_dtype": "int8 K/V pages (a latent row has no scale "
+                              "plane)",
+            "weight_dtype": "weight-only quantisation",
+            "num_kv_heads": "grouped kv heads (every head reads the one "
+                            "latent row)",
+            "attention_window": "a sliding window",
+            "eva_window": "EVA attention",
+            "cca_time0": "CCA",
+            "layer_pattern": "a layer_pattern",
+            "residual_dtype": "a float32 residual stream",
+        }
+        for name, what in refused.items():
+            if getattr(self, name) is not None:
+                raise SlotStateUnsupported(
+                    f"{name}: {what} is not extended to latent attention")
+        if self.dropout_rate or self.remat or self.attention != "dense":
+            raise SlotStateUnsupported(
+                "latent attention is served, not trained: dropout, remat "
+                "and the flash, blockwise and ring attention paths are not "
+                "extended to it (attention must be 'dense')")
+
+    @property
+    def mla(self) -> bool:
+        """Whether attention is latent (``kv_lora_rank`` set)."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def mla_row(self) -> int:
+        """Lanes of one position's row in a latent pool: c and k_pe, padded
+        to a whole number of 128 lanes (576 -> 640 at DeepSeek-V2's
+        widths), so that the paged kernels take a page as it lies."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def mla_scale(self) -> float:
+        """The logits' scale: (nope + rope)^-1/2, times YaRN's
+        m(``yarn_mscale_all_dim``)^2 where YaRN is on."""
+        m = (yarn_mscale(self.yarn_factor, self.yarn_mscale_all_dim)
+             if self.yarn_factor and self.yarn_mscale_all_dim else 1.0)
+        return m * m / float(
+            np.sqrt(self.qk_nope_head_dim + self.qk_rope_head_dim))
 
     @property
     def kv_heads(self) -> int:
@@ -469,7 +588,8 @@ class TransformerConfig:
         """Layers with routed experts."""
         if not self.num_experts:
             return 0
-        return (self.num_layers if self.layer_pattern is None
+        return (self.num_layers - self.dense_layers
+                if self.layer_pattern is None
                 else self.layer_pattern.count("E"))
 
     @property
@@ -942,18 +1062,19 @@ def _write_rows(k_leaf, v_leaf, k_rows, v_rows, page, offset, live):
     return write(k_leaf, k_rows), write(v_leaf, v_rows)
 
 
-def _table_attention_sum(q, ks, vs, tables, attend):
+def _table_attention_sum(q, ks, vs, tables, attend, scale=None):
     """What ``paged_decode_attention`` computes, in ``jax.numpy``, for pool
     leaves the kernel does not take (a page or head size off the chip's
     tiles: the CPU tests): ``q`` (B, kv, group, dh) over the rows of
-    ``tables`` (B, pages), the first ``attend`` (B,) of them live."""
+    ``tables`` (B, pages), the first ``attend`` (B,) of them live; scores
+    times ``scale`` (dh^-1/2 by default)."""
     b, kv, _, dh = q.shape
     rows_k = ks[tables].transpose(0, 2, 1, 3, 4).reshape(b, kv, -1, dh)
     rows_v = vs[tables].transpose(0, 2, 1, 3, 4).reshape(b, kv, -1, dh)
     live = jnp.arange(rows_k.shape[2])[None, :] < attend[:, None]
     scores = jnp.einsum(
-        "bkgd,bkTd->bkgT", q, rows_k, preferred_element_type=jnp.float32
-    ) / np.sqrt(dh)
+        "bkgd,bkTd->bkgT", q, rows_k, preferred_element_type=jnp.float32)
+    scores = scores / np.sqrt(dh) if scale is None else scores * scale
     live = live[:, None, None, :]
     weights = jnp.where(
         live, jax.nn.softmax(jnp.where(live, scores, A.NEG_INF), -1), 0.0)
@@ -1268,6 +1389,304 @@ def cca_attention_sublayer(mod, cfg, x, train: bool = False, cache=None,
     return x + attn, cache
 
 
+def mla_prefill_form(cfg, queries: int, rows: int) -> str:
+    """The form in which ``queries`` rows of latent attention attend a
+    gathered row of ``rows`` positions: the one of fewer FLOPs by these
+    counts, a function of the shapes and of nothing else. ``"expanded"``
+    makes every position's per-head K and V from its latent (2 r H (nope +
+    v) a position) and attends at width nope + rope for q.k and v for the
+    values (2 H (nope + rope + v) a query a position); ``"absorbed"`` folds
+    each query into the latent (2 H r (nope + v) a query, in and out) and
+    attends the rows as they are (2 H (2 r + rope) a query a position). At
+    DeepSeek-V2's widths over a row of 32768 the absorbed form wins up to
+    169 queries: a 128-wide tail over a cached document is absorbed, a
+    256-wide one and every whole chunk expanded."""
+    h, r = cfg.num_heads, cfg.kv_lora_rank
+    nope, rope, v = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    expanded = 2 * rows * r * h * (nope + v) + 2 * queries * rows * h * (
+        nope + rope + v)
+    absorbed = 2 * queries * h * r * (nope + v) + 2 * queries * rows * h * (
+        2 * r + rope)
+    return "absorbed" if absorbed < expanded else "expanded"
+
+
+def mla_prefill_widths(cfg, rows: int, chunk: int) -> tuple[int, ...]:
+    """The prefill widths a latent-attention engine compiles below its
+    chunk of ``chunk`` over a row of ``rows``: the widest power of two that
+    ``mla_prefill_form`` absorbs, then its doublings under the chunk, so
+    that a tail over a cached prefix pads to at most twice its width. At
+    DeepSeek-V2's widths over 32768 rows with chunks of 1024: 128 (absorbed),
+    256 and 512 (expanded)."""
+    w = 1
+    while w * 2 < chunk and mla_prefill_form(cfg, w * 2, rows) == "absorbed":
+        w *= 2
+    widths = []
+    while w < chunk:
+        widths.append(w)
+        w *= 2
+    return tuple(widths)
+
+
+def _mla_rope(cfg, positions):
+    """cos/sin tables (..., rope/2) of the rope part at ``positions``: YaRN
+    where the config has it, plain RoPE elsewhere."""
+    rope = cfg.qk_rope_head_dim
+    if not cfg.yarn_factor:
+        return rope_tables(rope, 0, cfg.rope_theta, positions=positions)
+    inv = yarn_inv_freq(rope, cfg.rope_theta, cfg.yarn_factor,
+                        cfg.yarn_original_len, cfg.yarn_beta_fast,
+                        cfg.yarn_beta_slow)
+    scale = (yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+             / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+    return yarn_tables(positions, inv, scale)
+
+
+def _mla_pairs_to_halves(x):
+    """DeepSeek-V2 lays a rope part out as interleaved pairs (x0, x1), (x2,
+    x3), ... and turns them into halves [x0 x2 ... | x1 x3 ...] before its
+    rotate-half: the same permutation here, so that the weights are read as
+    published."""
+    return x.reshape(*x.shape[:-1], -1, 2).swapaxes(-1, -2).reshape(x.shape)
+
+
+def _mla_attend_rows(cfg, q_nope, q_pe, lat, q_pos, n_rows, w_kb, form):
+    """Latent attention of queries ``q_nope`` (B, s, H, nope) and ``q_pe``
+    (B, s, H, rope), rotated, over the rows ``lat`` (B, S, row) of
+    positions 0 .. S-1 ([c | k_pe | padding]), causally at ``q_pos`` (s,),
+    keys below ``n_rows`` (traced) and no further, a block of rows at a
+    time with an online softmax in f32: blocks wholly past ``n_rows`` are
+    neither read nor computed. ``form`` (:func:`mla_prefill_form`) says
+    whether each block's K and V are expanded from its latents by ``w_kb``
+    (r, H, nope + v) or the queries are folded into the latent. Returns
+    (B, s, H, v) in the compute dtype."""
+    b, s, heads, nope = q_nope.shape
+    r, rope, vd = cfg.kv_lora_rank, cfg.qk_rope_head_dim, cfg.v_head_dim
+    dt = cfg.compute_dtype
+    blk = min(lat.shape[1], 1024)
+    if lat.shape[1] % blk:
+        lat = jnp.pad(lat, ((0, 0), (0, -lat.shape[1] % blk), (0, 0)))
+    w_uk, w_uv = w_kb[..., :nope].astype(dt), w_kb[..., nope:].astype(dt)
+    absorbed = form == "absorbed"
+    if absorbed:
+        with jax.named_scope("mla.absorb"):
+            q_main = jnp.einsum("bqhd,chd->bqhc", q_nope.astype(dt), w_uk,
+                                preferred_element_type=jnp.float32).astype(dt)
+    else:
+        q_main = q_nope.astype(dt)
+    q_pe = q_pe.astype(dt)
+    width = r if absorbed else vd
+
+    def body(i, carry):
+        m, l, acc = carry
+        rows = jax.lax.dynamic_slice_in_dim(lat, i * blk, blk, axis=1)
+        c, k_pe = rows[..., :r], rows[..., r:r + rope]
+        if absorbed:
+            k_main, vals = c, c
+            s_main = jnp.einsum("bqhc,btc->bhqt", q_main, k_main,
+                                preferred_element_type=jnp.float32)
+        else:
+            with jax.named_scope("mla.expand"):
+                kv = jnp.einsum("btc,chd->bthd", c, w_kb.astype(dt),
+                                preferred_element_type=jnp.float32).astype(dt)
+            k_main, vals = kv[..., :nope], kv[..., nope:]
+            s_main = jnp.einsum("bqhd,bthd->bhqt", q_main, k_main,
+                                preferred_element_type=jnp.float32)
+        sc = (s_main + jnp.einsum("bqhd,btd->bhqt", q_pe, k_pe,
+                                  preferred_element_type=jnp.float32)
+              ) * cfg.mla_scale
+        key = i * blk + jnp.arange(blk)
+        sc = jnp.where((key[None, :] <= q_pos[:, None])[None, None], sc,
+                       A.NEG_INF)
+        m_new = jnp.maximum(m, sc.max(-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(sc - m_new)
+        l = alpha * l + p.sum(-1, keepdims=True)
+        spec = "bhqt,btc->bhqc" if absorbed else "bhqt,bthc->bhqc"
+        pv = jnp.einsum(spec, p, vals.astype(jnp.float32))
+        return m_new, l, alpha * acc + pv
+
+    carry = (jnp.full((b, heads, s, 1), A.NEG_INF, jnp.float32),
+             jnp.zeros((b, heads, s, 1), jnp.float32),
+             jnp.zeros((b, heads, s, width), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0, (n_rows + blk - 1) // blk, body, carry)
+    out = (acc / l).transpose(0, 2, 1, 3)  # (B, s, H, width)
+    if absorbed:
+        with jax.named_scope("mla.unabsorb"):
+            out = jnp.einsum("bqhc,chd->bqhd", out.astype(dt), w_uv,
+                             preferred_element_type=jnp.float32)
+    return out.astype(dt)
+
+
+def _mla_flash(cfg, q_nope, q_pe, lat, offset, w_kb, form):
+    """:func:`_mla_attend_rows` of one slot's prefill chunk through the
+    chunk kernel (``ops.attention.chunk_flash_attention``), for a gathered
+    row ``lat`` (1, S, row) whose shapes the kernel takes; the chunk's rows
+    start at ``offset``. Absorbed: the H queries [q~ | q_pe | 0] of a row
+    are one kv head's group over the latent rows as they lie, each row its
+    own value (the output's first r lanes are softmax . c). Expanded: every
+    head's K [k_nope | k_pe | 0] and V [v | 0] made from the whole row, at
+    the width of 128 lanes that holds both (256 at DeepSeek-V2's widths).
+    Returns (1, s, H, v) in the compute dtype."""
+    _, s, heads, nope = q_nope.shape
+    r, rope, vd = cfg.kv_lora_rank, cfg.qk_rope_head_dim, cfg.v_head_dim
+    dt = cfg.compute_dtype
+    pad = lambda x, n: jnp.pad(x, [(0, 0)] * (x.ndim - 1)
+                               + [(0, n - x.shape[-1])])
+    heads_first = lambda x: x.transpose(0, 2, 1, 3)
+    if form == "absorbed":
+        with jax.named_scope("mla.absorb"):
+            q_lat = jnp.einsum("bqhd,chd->bqhc", q_nope.astype(dt),
+                               w_kb[..., :nope].astype(dt),
+                               preferred_element_type=jnp.float32)
+        q = pad(jnp.concatenate([q_lat.astype(dt), q_pe.astype(dt)], -1),
+                cfg.mla_row)
+        rows = lat[:, None].astype(dt)
+        o = A.chunk_flash_attention(heads_first(q), rows, rows, offset,
+                                    scale=cfg.mla_scale)
+        with jax.named_scope("mla.unabsorb"):
+            out = jnp.einsum("bhqc,chd->bqhd", o[..., :r].astype(dt),
+                             w_kb[..., nope:].astype(dt),
+                             preferred_element_type=jnp.float32)
+        return out.astype(dt)
+    width = -(-max(nope + rope, vd) // 128) * 128
+    with jax.named_scope("mla.expand"):
+        kv = jnp.einsum("bSc,chd->bhSd", lat[..., :r].astype(dt),
+                        w_kb.astype(dt),
+                        preferred_element_type=jnp.float32).astype(dt)
+        k_pe = jnp.broadcast_to(lat[:, None, :, r:r + rope].astype(dt),
+                                kv.shape[:3] + (rope,))
+        k = pad(jnp.concatenate([kv[..., :nope], k_pe], -1), width)
+        v = pad(kv[..., nope:], width)
+    q = pad(jnp.concatenate([q_nope.astype(dt), q_pe.astype(dt)], -1), width)
+    o = A.chunk_flash_attention(heads_first(q), k, v, offset,
+                                scale=cfg.mla_scale)
+    return heads_first(o[..., :vd]).astype(dt)
+
+
+def _write_latent_rows(leaf, rows, page, offset, live):
+    """Row ``offset[b]`` of page ``page[b]`` of a latent pool leaf (pages,
+    1, page_size, row) set to ``rows[b]`` (B, row), for the lanes ``live``
+    marks: the page-copy kernel where the leaf fits it (a lane that is not
+    live writes nothing), a row scatter elsewhere (the CPU tests' toys; a
+    masked lane writes where its page points, the trash page)."""
+    if A.paged_decode_fits(leaf):
+        return A.paged_row_write(leaf, None, rows[:, None], None, page,
+                                 offset, live)
+    pages, _, ps, width = leaf.shape
+    flat = leaf.reshape(pages * ps, width)
+    return flat.at[page * ps + offset].set(rows.astype(leaf.dtype)).reshape(
+        leaf.shape)
+
+
+def mla_attention_sublayer(mod, cfg, x, cache=None, positions=None):
+    """Pre-norm multi-head latent attention (DeepSeek-V2, arXiv:2405.04434
+    section 2.1; no q-LoRA) + residual, called from ``mod``'s
+    ``@nn.compact`` body. With h = norm(x), H heads:
+
+    1. ``q = h W_q``, each head [q_nope | q_pe]; ``[c | k_pe] = h W_kva``,
+       ``c <- RMSNorm(c)`` (``kv_norm``); ``[k_nope | v]_h = c W_kvb,h``;
+    2. q_pe and k_pe (one a position, every head's) are rotated (YaRN where
+       the config has it); logit ``(q_nope . k_nope + q_pe . k_pe) *
+       cfg.mla_scale``, causal softmax, ``o_h = softmax . v_h``, ``o W_o``.
+
+    Absorbed, with W_kvb,h = [W_UK,h | W_UV,h]: ``q~_h = q_nope,h W_UK,h^T``
+    (r wide), logit ``(q~_h . c + q_pe,h . k_pe) * scale``, ``o_h = (softmax
+    . c) W_UV,h``: the same numbers, and the cache is never expanded.
+
+    A position is cached as one row [c | k_pe | zeros] of ``cfg.mla_row``
+    lanes (``lat``). Three paths:
+
+    * ``cache=None``: a whole sequence from position 0, expanded.
+    * a cache with ``pages``: the engine's decode round, one token a slot;
+      the row is written into the pool (:func:`_write_latent_rows`) and the
+      absorbed queries, H rows of one slot against its one row a position,
+      attend the pages where they lie (``ops.attention.
+      paged_decode_attention`` with the row as its own value, ``mla.decode``;
+      its sum in ``jax.numpy`` where the pool is off the kernel's tiles).
+    * a dense cache ``{'lat', 'len'}``: one prefill chunk appended at ``len``
+      behind a slot's gathered rows; it attends in the form
+      :func:`mla_prefill_form` gives its shapes, through the chunk kernel
+      where the shapes are on its tiles (:func:`_mla_flash`) and in
+      ``jax.numpy`` blocks elsewhere (:func:`_mla_attend_rows`).
+    """
+    heads, r = cfg.num_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    dt = cfg.compute_dtype
+    h = block_norm(cfg, "ln1")(x)
+    b, s, d = h.shape
+    init = nn.initializers.lecun_normal()
+    w_q = mod.param("q_proj", init, (d, heads * (nope + rope)))
+    w_kva = mod.param("kv_a", init, (d, r + rope))
+    w_kb = mod.param("kv_b", nn.initializers.lecun_normal(
+        in_axis=0, out_axis=(1, 2)), (r, heads, nope + vd))
+    w_o = mod.param("o_proj", init, (heads * vd, d))
+    hd = h.astype(dt)
+    q = jnp.einsum("bsd,de->bse", hd, w_q.astype(dt),
+                   preferred_element_type=jnp.float32).astype(dt)
+    q = q.reshape(b, s, heads, nope + rope)
+    kva = jnp.einsum("bsd,de->bse", hd, w_kva.astype(dt),
+                     preferred_element_type=jnp.float32)
+    c = RMSNorm(eps=cfg.norm_eps, dtype=dt, name="kv_norm")(kva[..., :r])
+    if positions is None:
+        start = 0 if cache is None or "pages" in cache else cache["len"]
+        positions = (start + jnp.arange(s, dtype=jnp.int32))[None]
+    cos, sin = _mla_rope(cfg, positions)  # (B or 1, s, rope/2)
+    q_pe = _rotate_heads(_mla_pairs_to_halves(q[..., nope:]), cos, sin)
+    k_pe = _rotate_heads(_mla_pairs_to_halves(kva[..., None, r:]).astype(dt),
+                         cos, sin)[:, :, 0]
+    row = jnp.concatenate(
+        [c, k_pe, jnp.zeros((b, s, cfg.mla_row - r - rope), dt)], -1)
+    q_nope = q[..., :nope]
+    if cache is None:
+        attn = _mla_attend_rows(cfg, q_nope, q_pe, row, jnp.arange(s), s,
+                                w_kb, "expanded")
+    elif "pages" in cache:
+        if s != 1:
+            raise ValueError(f"a paged cache takes one token per slot, got {s}")
+        leaf = _write_latent_rows(
+            cache["lat"], row[:, 0], cache["write_page"],
+            cache["len"] % cache["lat"].shape[2], cache["attend"] > 0)
+        with jax.named_scope("mla.absorb"):
+            q_lat = jnp.einsum("bhd,chd->bhc", q_nope[:, 0],
+                               w_kb[..., :nope].astype(dt),
+                               preferred_element_type=jnp.float32)
+            q_row = jnp.concatenate(
+                [q_lat.astype(dt), q_pe[:, 0],
+                 jnp.zeros((b, heads, cfg.mla_row - r - rope), dt)], -1)
+        with jax.named_scope("mla.decode"):
+            if A.paged_decode_fits(leaf):
+                o_lat = A.paged_decode_attention(
+                    q_row[:, None], leaf, None, cache["pages"],
+                    cache["attend"], scale=cfg.mla_scale)[:, 0]
+            else:
+                o_lat = _table_attention_sum(
+                    q_row[:, None], leaf, leaf, cache["pages"],
+                    cache["attend"], scale=cfg.mla_scale)[:, 0]
+        with jax.named_scope("mla.unabsorb"):
+            attn = jnp.einsum("bhc,chd->bhd", o_lat[..., :r].astype(dt),
+                              w_kb[..., nope:].astype(dt),
+                              preferred_element_type=jnp.float32)
+        attn = attn.astype(dt)[:, None]
+        cache = dict(cache, lat=leaf)
+    else:
+        lat = jax.lax.dynamic_update_slice(
+            cache["lat"], row[:, None].astype(cache["lat"].dtype),
+            (0, 0, cache["len"], 0))
+        form = mla_prefill_form(cfg, s, lat.shape[2])
+        if b == 1 and A.chunk_flash_fits(lat.dtype, cfg.mla_row,
+                                         (lat.shape[2], s)):
+            attn = _mla_flash(cfg, q_nope, q_pe, lat[:, 0], cache["len"],
+                              w_kb, form)
+        else:
+            attn = _mla_attend_rows(cfg, q_nope, q_pe, lat[:, 0],
+                                    cache["len"] + jnp.arange(s),
+                                    cache["len"] + s, w_kb, form)
+        cache = dict(cache, lat=lat, len=cache["len"] + s)
+    out = jnp.einsum("bse,ed->bsd", attn.reshape(b, s, heads * vd),
+                     w_o.astype(dt), preferred_element_type=jnp.float32)
+    return x + out.astype(x.dtype), cache
+
+
 def _phase_scope(cached: bool):
     """``jax.named_scope`` on the cached branch (the serving engine's
     programs: op metadata only, the program is unchanged), nothing on the
@@ -1277,6 +1696,9 @@ def _phase_scope(cached: bool):
 
 class Block(nn.Module):
     cfg: TransformerConfig
+    # A leading layer of a config with routed experts whose MLP is the dense
+    # one (``cfg.dense_layers``).
+    dense_mlp: bool = False
 
     @nn.compact
     def __call__(self, x, attend, train: bool = False, cache=None,
@@ -1308,6 +1730,13 @@ class Block(nn.Module):
                     self, cfg, x, train=train, cache=cache,
                     positions=positions,
                 )
+            elif cfg.mla:
+                if self_mask is not None:
+                    raise SlotStateUnsupported(
+                        "tree attention (self_mask) is not extended to "
+                        "latent attention")
+                x, cache = mla_attention_sublayer(
+                    self, cfg, x, cache=cache, positions=positions)
             elif cfg.eva:
                 if self_mask is not None:
                     raise EvaUnsupported(
@@ -1322,7 +1751,7 @@ class Block(nn.Module):
                     positions=positions, self_mask=self_mask,
                 )
 
-        if cfg.num_experts:
+        if cfg.num_experts and not self.dense_mlp:
             with scope("mlp"):
                 # The router reads the norm in float32; the experts take it
                 # in compute_dtype.
@@ -1460,11 +1889,12 @@ class TransformerLM(nn.Module):
                     x = PatternBlock(cfg, cfg.layer_pattern[i],
                                      name=f"block_{i}")(x, attend, train)
                     continue
-                x = block_cls(cfg, name=f"block_{i}")(
+                dense = i < cfg.dense_layers
+                x = block_cls(cfg, dense, name=f"block_{i}")(
                     x, attend, train, positions=rope_positions,
                     router_state=router,
                 )
-                if cfg.num_experts:
+                if cfg.num_experts and not dense:
                     x, router = x
         else:
             # Cache layout: {'layers': [{'k','v'}, ...], 'len': scalar} — one
@@ -1489,12 +1919,13 @@ class TransformerLM(nn.Module):
                     new_layers.append(
                         {k_: v_ for k_, v_ in layer.items() if k_ not in shared})
                     continue
-                out = Block(cfg, name=f"block_{i}")(
+                dense = i < cfg.dense_layers
+                out = Block(cfg, dense, name=f"block_{i}")(
                     x, attend, train=train, cache=layer,
                     positions=rope_positions, self_mask=self_mask,
                     router_state=router,
                 )
-                if cfg.num_experts:
+                if cfg.num_experts and not dense:
                     x, layer, router = out
                     counts.append(layer.pop("moe_counts"))
                 else:

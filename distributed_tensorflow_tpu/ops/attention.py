@@ -2091,18 +2091,20 @@ def _paged_decode_kernel(
     tables_ref,
     lens_ref,
     q_ref,
-    k_hbm,
-    v_hbm,
-    o_ref,
-    k_buf,
-    v_buf,
-    sems,
-    *w_buf,
+    *refs,
     scale: float,
     window: int | None,
     chunk_pages: int,
     run: int,
+    shared: bool,
 ):
+    # ``shared``: one leaf whose rows are keys and values both (latent
+    # attention's rows), copied once.
+    if shared:
+        k_hbm, o_ref, k_buf, sems, *w_buf = refs
+        v_hbm, v_buf = k_hbm, k_buf
+    else:
+        k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, *w_buf = refs
     slots = q_ref.shape[0]
     _, kv, ps, dh = k_hbm.shape
     pps = tables_ref.shape[0] // slots
@@ -2118,7 +2120,8 @@ def _paged_decode_kernel(
     # A dead row of a live chunk is multiplied by a probability of exactly
     # zero, which only a finite value survives.
     k_buf[...] = jnp.zeros(k_buf.shape, k_buf.dtype)
-    v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+    if not shared:
+        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
 
     def span(b):
         """(positions attended, first position, first page, pages) of slot b."""
@@ -2135,7 +2138,8 @@ def _paged_decode_kernel(
             slots,
         )
 
-    leaves = ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1))
+    leaves = ((k_hbm, k_buf, 0),) if shared else (
+        (k_hbm, k_buf, 0), (v_hbm, v_buf, 1))
 
     def start_chunk(b, p0, pages, c, buf):
         """Start the copies of chunk ``c`` of slot ``b``, ``run`` pages a
@@ -2308,9 +2312,13 @@ def paged_decode_chain(pages, pages_per_slot: int) -> tuple[int, int]:
     128, of 16 0.082 and **0.071** (70% of the bytes' rate; over 1,500-3,500
     rows 78%); of 2 or 4 none faster than page by page (a copy of 16 or
     32 KiB costs the chain as much as one of 64). EvaByte's shape reads 0.879
-    under every one of these and 0.888 at 16 pages a chunk."""
+    under every one of these and 0.888 at 16 pages a chunk.
+
+    Where a row holds more, a chunk is a power of two of pages: a latent
+    row's page of 20 KiB gives 32 (512 rows), not 51."""
     page_bytes = math.prod(pages.shape[1:]) * pages.dtype.itemsize
-    chunk = max(1, min(pages_per_slot, (1 << 20) // page_bytes))
+    fit = max(1, (1 << 20) // page_bytes)
+    chunk = min(pages_per_slot, 1 << (fit.bit_length() - 1))
     run = max(1, min(chunk, (128 << 10) // page_bytes))
     return chunk, 1 << (run.bit_length() - 1)
 
@@ -2350,6 +2358,7 @@ def paged_decode_attention(
     lens,
     *,
     window: int | None = None,
+    scale: float | None = None,
     pages_per_chunk: int | None = None,
     interpret: bool | None = None,
 ):
@@ -2377,12 +2386,18 @@ def paged_decode_attention(
     stream K past the row and finish on the VPU, where a probability keeps
     all its f32 bits; larger groups stream past latched K and V tiles, where
     it keeps 16.
+
+    ``v_pages`` None: the rows of ``k_pages`` are the values too, copied
+    once (latent attention's rows, whose value is the latent: the kernel is
+    then named ``mla_decode_attention``). ``scale`` multiplies the scores
+    (head_dim^-1/2 by default).
     """
     slots, kv, _, dh = q.shape
-    if k_pages.shape != v_pages.shape or k_pages.shape[1::2] != (kv, dh):
+    if (v_pages is not None and k_pages.shape != v_pages.shape) or (
+            k_pages.shape[1::2] != (kv, dh)):
         raise ValueError(
             f"q {q.shape} does not fit pages k {k_pages.shape} / v "
-            f"{v_pages.shape}"
+            f"{None if v_pages is None else v_pages.shape}"
         )
     if page_tables.shape[0] != slots or lens.shape != (slots,):
         raise ValueError(
@@ -2399,7 +2414,7 @@ def paged_decode_attention(
         run = min(run, 1 << (chunk_pages.bit_length() - 1))
     return _paged_decode_call(
         q, k_pages, v_pages, page_tables, lens,
-        scale=_scale(q, None), window=window, chunk_pages=chunk_pages,
+        scale=_scale(q, scale), window=window, chunk_pages=chunk_pages,
         run=run, interpret=bool(interpret),
     )
 
@@ -2436,9 +2451,11 @@ def _paged_decode_call(q, k_pages, v_pages, page_tables, lens, *, scale,
         rows = _sublane_rows(dtype)
         gp = -(-group // rows) * rows
         q = jnp.pad(q, ((0, 0), (0, 0), (0, gp - group), (0, 0)))
+    shared = v_pages is None
+    leaves = (k_pages,) if shared else (k_pages, v_pages)
     kernel = functools.partial(
         _paged_decode_kernel, scale=scale, window=window,
-        chunk_pages=chunk_pages, run=run,
+        chunk_pages=chunk_pages, run=run, shared=shared,
     )
     out = pl.pallas_call(
         kernel,
@@ -2447,23 +2464,21 @@ def _paged_decode_call(q, k_pages, v_pages, page_tables, lens, *, scale,
             grid=(1,),
             in_specs=[
                 pl.BlockSpec(memory_space=pltpu.VMEM),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
+                *(pl.BlockSpec(memory_space=pl.ANY) for _ in leaves),
             ],
             out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
             scratch_shapes=[
-                pltpu.VMEM(buf, k_pages.dtype),
-                pltpu.VMEM(buf, v_pages.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
+                *(pltpu.VMEM(buf, leaf.dtype) for leaf in leaves),
+                pltpu.SemaphoreType.DMA((len(leaves), 2)),
                 *scratch,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-        name="paged_decode_attention",
+        name="mla_decode_attention" if shared else "paged_decode_attention",
         **params,
     )(page_tables.reshape(-1).astype(jnp.int32), lens.astype(jnp.int32),
-      q, k_pages, v_pages)
+      q, *leaves)
     if row_form:
         return out.reshape(slots, kv, group, dh).astype(dtype)
     return out[:, :, :group]
@@ -2475,15 +2490,19 @@ def _paged_decode_call(q, k_pages, v_pages, page_tables, lens, *, scale,
 # ---------------------------------------------------------------------------
 
 
-def _paged_row_write_kernel(page_ref, off_ref, live_ref, k_rows, v_rows,
-                            k_in, v_in, k_out, v_out, k_buf, v_buf, sems):
+def _paged_row_write_kernel(page_ref, off_ref, live_ref, *refs):
     """Every live lane's page of each leaf is copied into VMEM at once, then,
     lane by lane, row ``off`` of every kv head is set and the page copied
-    back. A lane that is not live issues nothing. ``sems`` (2, 2, slots):
-    [in | out, k | v, lane]; the copies back are awaited after the last."""
-    slots = k_rows.shape[0]
-    _, kv, ps, dh = k_in.shape
-    leaves = ((k_in, k_out, k_buf, k_rows, 0), (v_in, v_out, v_buf, v_rows, 1))
+    back. A lane that is not live issues nothing. ``refs`` are, for n leaves
+    (two: k and v; one: a latent row), the n row arrays, the n leaves in,
+    out and their n VMEM buffers, then ``sems`` (2, n, slots): [in | out,
+    leaf, lane]; the copies back are awaited after the last."""
+    n = (len(refs) - 1) // 4
+    rows_in, ins, outs, bufs = (refs[i * n:(i + 1) * n] for i in range(4))
+    sems = refs[-1]
+    slots = rows_in[0].shape[0]
+    _, kv, ps, dh = ins[0].shape
+    leaves = tuple(zip(ins, outs, bufs, rows_in, range(n)))
 
     def copies(b, way):
         """Lane ``b``'s page copies of both leaves: in (0) or out (1)."""
@@ -2555,13 +2574,19 @@ def paged_row_write(k_pages, v_pages, k_rows, v_rows, pages, offsets, live, *,
     refuses a copy of one bf16 row (or two) into the tiled leaf; a copy of
     the 8-row half of the page that holds the row read 13.6 / 8.7 / 7.3 /
     10.3 / 16.4 on the same cases, and leans on XLA keeping 8-row tiles in
-    HBM, so the whole page is copied."""
+    HBM, so the whole page is copied.
+
+    ``v_pages`` and ``v_rows`` None: one leaf (latent attention's row),
+    returned alone."""
     slots, kv, dh = k_rows.shape
-    if (k_pages.shape != v_pages.shape or v_rows.shape != k_rows.shape
+    one = v_pages is None
+    if (not one and (k_pages.shape != v_pages.shape
+                     or v_rows.shape != k_rows.shape)
             or k_pages.shape[1::2] != (kv, dh)):
         raise ValueError(
-            f"rows k {k_rows.shape} / v {v_rows.shape} do not fit pages k "
-            f"{k_pages.shape} / v {v_pages.shape}")
+            f"rows k {k_rows.shape} / v "
+            f"{None if one else v_rows.shape} do not fit pages k "
+            f"{k_pages.shape} / v {None if one else v_pages.shape}")
     if not all(a.shape == (slots,) for a in (pages, offsets, live)):
         raise ValueError(
             f"pages {pages.shape} / offsets {offsets.shape} / live "
@@ -2572,52 +2597,47 @@ def paged_row_write(k_pages, v_pages, k_rows, v_rows, pages, offsets, live, *,
             f"scatter serves them")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    return _paged_row_write_call(
-        k_pages, v_pages, k_rows.astype(k_pages.dtype),
-        v_rows.astype(v_pages.dtype), pages.astype(jnp.int32),
-        offsets.astype(jnp.int32), live.astype(jnp.int32),
-        interpret=bool(interpret))
+    leaves = (k_pages,) if one else (k_pages, v_pages)
+    rows = (k_rows,) if one else (k_rows, v_rows)
+    out = _paged_row_write_call(
+        leaves, tuple(r.astype(l.dtype) for r, l in zip(rows, leaves)),
+        pages.astype(jnp.int32), offsets.astype(jnp.int32),
+        live.astype(jnp.int32), interpret=bool(interpret))
+    return out[0] if one else tuple(out)
 
 
 # Jitted like _paged_decode_call: a model's layers trace the kernel once.
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _paged_row_write_call(k_pages, v_pages, k_rows, v_rows, pages, offsets,
-                          live, *, interpret):
-    slots = k_rows.shape[0]
-    buf = (slots,) + k_pages.shape[1:]
-    buf_bytes = math.prod(buf) * k_pages.dtype.itemsize
+def _paged_row_write_call(leaves, rows, pages, offsets, live, *, interpret):
+    slots, n = rows[0].shape[0], len(leaves)
+    buf = (slots,) + leaves[0].shape[1:]
+    buf_bytes = math.prod(buf) * leaves[0].dtype.itemsize
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
         _paged_row_write_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(1,),
-            in_specs=[
-                pl.BlockSpec(memory_space=pltpu.VMEM),
-                pl.BlockSpec(memory_space=pltpu.VMEM),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=[
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * n
+            + [any_spec] * n,
+            out_specs=[any_spec] * n,
             scratch_shapes=[
-                pltpu.VMEM(buf, k_pages.dtype),
-                pltpu.VMEM(buf, v_pages.dtype),
-                pltpu.SemaphoreType.DMA((2, 2, slots)),
+                *(pltpu.VMEM(buf, leaf.dtype) for leaf in leaves),
+                pltpu.SemaphoreType.DMA((2, n, slots)),
             ],
         ),
-        out_shape=[jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
-                   jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)],
-        # Operands count the three prefetched scalars: the leaves are 5, 6.
-        input_output_aliases={5: 0, 6: 1},
+        out_shape=[jax.ShapeDtypeStruct(leaf.shape, leaf.dtype)
+                   for leaf in leaves],
+        # Operands count the three prefetched scalars and the n row arrays:
+        # the leaves follow them.
+        input_output_aliases={3 + n + i: i for i in range(n)},
         interpret=interpret,
         name="paged_row_write",
-        # Both leaves' pages of every lane, the rows, and room besides.
+        # Every leaf's page of every lane, the rows, and room besides.
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=2 * buf_bytes + 4 * k_rows.size
-            * k_rows.dtype.itemsize + (4 << 20)),
-    )(pages, offsets, live, k_rows, v_rows, k_pages, v_pages)
+            vmem_limit_bytes=n * buf_bytes + 2 * n * rows[0].size
+            * rows[0].dtype.itemsize + (4 << 20)),
+    )(pages, offsets, live, *rows, *leaves)
 
 
 # ---------------------------------------------------------------------------
@@ -2779,6 +2799,7 @@ def chunk_flash_attention(
     q_offset,
     *,
     window: int | None = None,
+    scale: float | None = None,
     interpret: bool | None = None,
 ):
     """Causal attention of a block of query rows over a logical cache.
@@ -2795,7 +2816,7 @@ def chunk_flash_attention(
     Scores and softmax statistics are f32 over the operands' own dtype, and
     a probability keeps 16 bits in the value product over a bf16 cache
     (f32 over an f32 one), as in :func:`paged_decode_attention`'s group
-    form."""
+    form. ``scale`` multiplies the scores (head_dim^-1/2 by default)."""
     b, h, sq, dh = q.shape
     kv, s_max = k.shape[1], k.shape[2]
     if k.shape != v.shape or k.shape[0] != b or k.shape[3] != dh or h % kv:
@@ -2821,7 +2842,7 @@ def chunk_flash_attention(
     block_q = _tile_block(max(rows, min(512, 6144 // (h // kv))), sq, rows)
     return _chunk_flash_call(
         q, k, v, jnp.asarray(q_offset, jnp.int32).reshape(1),
-        scale=_scale(q, None), window=window, block_q=block_q,
+        scale=_scale(q, scale), window=window, block_q=block_q,
         block_kv=_tile_block(512, s_max, rows), interpret=bool(interpret))
 
 

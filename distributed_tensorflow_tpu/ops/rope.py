@@ -23,9 +23,13 @@ positions would quantize phases), cast back to the operand dtype at the end.
 
 from __future__ import annotations
 
-import jax.numpy as jnp
+import math
 
-__all__ = ["rope_cos_sin", "rope_tables", "apply_rope"]
+import jax.numpy as jnp
+import numpy as np
+
+__all__ = ["rope_cos_sin", "rope_tables", "apply_rope", "yarn_inv_freq",
+           "yarn_mscale", "yarn_tables"]
 
 
 def rope_cos_sin(positions, head_dim: int, theta: float = 10000.0):
@@ -71,3 +75,47 @@ def apply_rope(x, cos, sin):
     return jnp.concatenate(
         [x1 * c - x2 * s, x2 * c + x1 * s], axis=-1
     ).astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention multiplier ``0.1 * mscale * ln(factor) + 1`` (1 at a
+    factor of 1 or below)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(head_dim: int, theta: float, factor: float,
+                  original_len: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0) -> np.ndarray:
+    """YaRN's frequencies (Peng et al., arXiv:2309.00071, as DeepSeek-V2
+    computes them), float64 (head_dim // 2,): the plain ``theta^(-2i/d)``
+    (``extra``) for the fast pairs, the same divided by ``factor``
+    (``inter``) for the slow ones, and a linear ramp between pair
+    ``floor(dim(beta_fast))`` and ``ceil(dim(beta_slow))``, where
+    ``dim(b) = d ln(original_len / (2 pi b)) / (2 ln theta)`` is the pair
+    that turns ``b`` times over ``original_len`` positions."""
+    half = head_dim // 2
+    extra = 1.0 / theta ** (np.arange(0, head_dim, 2, dtype=np.float64)
+                            / head_dim)
+    inter = extra / factor
+
+    def dim(rotations):
+        return (head_dim * math.log(original_len / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim(beta_fast)), 0)
+    high = min(math.ceil(dim(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low) / (high - low),
+                   0.0, 1.0)
+    extra_share = 1.0 - ramp
+    return inter * (1.0 - extra_share) + extra * extra_share
+
+
+def yarn_tables(positions, inv_freq, scale: float = 1.0):
+    """cos/sin tables for :func:`apply_rope` from ``inv_freq`` (half,):
+    ``positions`` any integer shape -> f32 ``positions.shape + (half,)``,
+    each times ``scale`` (YaRN's ``mscale / mscale_all_dim``)."""
+    ang = positions.astype(jnp.float32)[..., None] * jnp.asarray(
+        inv_freq, jnp.float32)
+    return jnp.cos(ang) * scale, jnp.sin(ang) * scale

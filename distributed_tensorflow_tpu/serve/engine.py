@@ -164,6 +164,19 @@ snapshot of the state), speculation and the sharded engine refuse a
 segmented config, and slot export/import one with slot state
 (``SlotStateUnsupported``; ``CcaUnsupported`` is its older name).
 
+A latent-attention config (``cfg.kv_lora_rank``: DeepSeek-V2's MLA,
+``models/transformer.mla_attention_sublayer``) holds one leaf a layer,
+``lat``, a position's latent row, and takes the PLAIN page path and its
+prefix cache even with routed experts behind it (the plain programs tell
+the model which rows are padding or masked lanes, ``route_mask``):
+``decode_path`` "table" (the absorbed paged kernel), ``prefill_path``
+"latent" (a chunk attends its gathered row expanded or absorbed by its
+width; given no ``prefill_buckets`` the engine compiles the widths
+``mla_prefill_widths`` derives from the two forms' counts).
+``engine.round`` carries ``latent_rows``, ``engine.start`` and
+``engine.prefill_chunk`` ``mla_form`` and ``latent_rows_attended``.
+Speculation, the sharded engine and slot export/import refuse it.
+
 Tracing (``obs/trace.py``; always on, no switch): the host side of a round
 closes ``engine.round`` around ``engine.prefill_chunk`` (one per chunk
 spent), ``engine.dispatch`` (entry of the decode round to the jitted call's
@@ -229,6 +242,8 @@ from distributed_tensorflow_tpu.models.transformer import (
     EvaUnsupported,
     SlotStateUnsupported,
     TransformerLM,
+    mla_prefill_form,
+    mla_prefill_widths,
 )
 from distributed_tensorflow_tpu.obs import trace as _trace
 from distributed_tensorflow_tpu.ops.attention import (
@@ -366,13 +381,29 @@ class SlotEngine:
         self._cca = bool(getattr(cfg, "cca", False))
         self._ssm = bool(getattr(cfg, "ssm", False))
         self._moe = bool(getattr(cfg, "num_experts", 0))
+        self._mla = bool(getattr(cfg, "mla", False))
         # Configs whose prefill is planned as padded segments that never
         # overlap and whose decode always goes through the table, with the
         # live lanes and real rows told to the model: slots that carry state
         # beside their rows (CCA, a Mamba-2 layer), routed experts (an idle
-        # lane or a padding row must reach none), any layer_pattern.
-        self._segmented = (self._cca or self._moe or
-                           getattr(cfg, "layer_pattern", None) is not None)
+        # lane or a padding row must reach none), any layer_pattern. Latent
+        # attention with routed experts holds nothing but pages: it takes
+        # the plain page path and its prefix cache, the routing mask told
+        # to the model by the plain programs.
+        self._segmented = (
+            self._cca or (self._moe and not self._mla)
+            or getattr(cfg, "layer_pattern", None) is not None)
+        if self._mla:
+            # What latent rows are not extended to refuses here, by name
+            # (int8 pages and weight quantisation refuse in the config).
+            if spec_k:
+                raise SlotStateUnsupported(
+                    "speculation (spec_k > 0) is not extended to latent "
+                    "attention: its verify programs attend K and V rows")
+            if getattr(self, "tp", 1) > 1:
+                raise SlotStateUnsupported(
+                    "ShardedSlotEngine has no path for latent attention: "
+                    "no tensor parallel rule splits a latent row")
         if self._segmented:
             # What the slot state and the segment plan are not extended to
             # refuses here, by name, rather than serving something else.
@@ -432,7 +463,11 @@ class SlotEngine:
         # what turns prefix-cache hits into TTFT wins (without buckets the
         # padded tail costs the same compute as a cold prompt). The
         # largest bucket is always prefill_len — the cold-prompt path.
+        # Latent attention given none takes the widths its two forms call
+        # for (models/transformer.mla_prefill_widths).
         buckets = {int(b) for b in prefill_buckets}
+        if not buckets and self._mla:
+            buckets = set(mla_prefill_widths(cfg, max_len, prefill_len))
         for b in buckets:
             if not 1 <= b <= prefill_len:
                 raise ValueError(
@@ -597,6 +632,9 @@ class SlotEngine:
         # budget, eos, the page table), as uploaded with that dispatch.
         self._flight: _Round | None = None
         self._returned: _Round | None = None
+        # What the last one-shot prefill notes on engine.start (latent
+        # attention's form and rows; empty elsewhere).
+        self._mla_note: dict = {}
         self._touched = np.zeros(n, bool)
         self._dev_consts: tuple = ()
 
@@ -648,6 +686,10 @@ class SlotEngine:
                     # The cached branch attends the chunk by blocks where
                     # the cache says so, as "pages" says the table path.
                     cache["flash"] = True
+                if self._moe:
+                    # The padding behind the real rows reaches no expert.
+                    cache["route_mask"] = (jnp.arange(tokens.shape[1])
+                                           < length - prefix_len)[None]
                 logits, cache = model.apply(
                     {"params": params}, tokens, cache=cache,
                     logit_rows=(length - prefix_len - 1)[None],
@@ -854,6 +896,8 @@ class SlotEngine:
                     # A masked lane feeds no real row: its slot's state
                     # stands, and its token reaches no expert.
                     cache["n_real"] = active.astype(jnp.int32)
+                    cache["route_mask"] = active[:, None]
+                elif self._moe:
                     cache["route_mask"] = active[:, None]
                 cache, logits = decode_step(model, params, cache, tok[:, None])
                 return cache["layers"], logits, cache.get("moe_counts")
@@ -1271,10 +1315,11 @@ class SlotEngine:
         ``prefill_path``'s to say, and the verify programs attend it
         densely."""
         leaves = self.pool.layers[0]
-        if self._eva or self._segmented:
+        if self._eva or self._segmented or self._mla:
             # Always through the table: the composed row IS the cache (EVA);
             # the slot's state is one row a slot beside it, and the model is
-            # told which lanes are live (a segmented config).
+            # told which lanes are live (a segmented config); a gathered
+            # latent row would copy each slot's whole row a round.
             # Where the kernel does not take the leaves the sublayer sums
             # the same rows in jax.numpy (models/transformer.py).
             return "table"
@@ -1295,7 +1340,12 @@ class SlotEngine:
         pages, the CPU smoke shapes with heads of 8-32, and the EVA and CCA
         prefill programs, whose sublayers have dense sites of their own. A
         segment program's gathered row is a bucket's worth of trash pages
-        longer than ``max_len``: those lengths must fit too."""
+        longer than ``max_len``: those lengths must fit too. ``"latent"``:
+        latent attention's chunk attends its gathered latent rows by blocks
+        up to its last row, expanded or absorbed by its width
+        (``models/transformer.mla_prefill_form``)."""
+        if self._mla:
+            return "latent"
         k = self._k_leaf()
         if self._eva or self._cca or k is None or any(
                 "k" in l and set(l) != {"k", "v"} for l in self.pool.layers):
@@ -1310,9 +1360,10 @@ class SlotEngine:
 
     def _k_leaf(self):
         """The ``k`` leaf of the first layer that holds pages (every layer
-        but a layer_pattern config's ``M`` and ``E`` ones); None if none
-        does."""
-        return next((l["k"] for l in self.pool.layers if "k" in l), None)
+        but a layer_pattern config's ``M`` and ``E`` ones), latent
+        attention's ``lat``; None if none does."""
+        return next((l.get("k", l.get("lat")) for l in self.pool.layers
+                     if "k" in l or "lat" in l), None)
 
     def _decode_kernel_form(self) -> str | None:
         """How the paged kernel of the plain decode program forms its two
@@ -1589,7 +1640,7 @@ class SlotEngine:
             if self.sentinel is not None:
                 self.sentinel.poll(self.compile_count())
             sp.note(matched=self.stats["prefix_tokens_matched"] - matched0,
-                    chunks=0)
+                    chunks=0, **self._mla_note)
             return first, finished
 
     def _bind_and_prefill(self, slot, prompt, p, max_new, prefill, sargs,
@@ -1655,6 +1706,7 @@ class SlotEngine:
             row, *sargs,
         )
         pool.layers = new_pool
+        self._mla_note = self._mla_prefill_note(m0, width)
         if self.prefix is not None:
             self.prefix.record_lookup(m0, p)
             self.prefix.insert(prompt, page_ids)
@@ -1715,6 +1767,16 @@ class SlotEngine:
             self.stats["prefix_tokens_matched"] = self.prefix.tokens_matched
             self.stats["prefix_tokens_total"] = self.prefix.tokens_looked_up
         return None
+
+    def _mla_prefill_note(self, offset: int, width: int) -> dict:
+        """What a latent-attention prefill of ``width`` rows at ``offset``
+        notes on its span: the form its shapes take
+        (``models/transformer.mla_prefill_form``) and the latent rows its
+        attention reads, a layer (nothing on any other config)."""
+        if not self._mla:
+            return {}
+        return {"mla_form": mla_prefill_form(self.cfg, width, self.max_len),
+                "latent_rows_attended": int(offset + width)}
 
     def _alloc_pages(self, n: int):
         """``n`` pages from the pool, after asking the prefix cache to give
@@ -1962,6 +2024,7 @@ class SlotEngine:
                     sp.note(ssm_blocks=-(-width // self.cfg.ssm_block)
                             * self.cfg.layer_pattern.count("M"))
                 return self._run_segment(st, m, w, final)
+            sp.note(**self._mla_prefill_note(m, w))
             toks = np.ascontiguousarray(prompt[m : m + w][None])
             row = np.array(pool.page_tables[st["slot"]])
             prefill = (
@@ -2081,6 +2144,10 @@ class SlotEngine:
             if self._ssm:
                 # Live lanes whose recurrent state the round advanced.
                 sp.note(ssm_lanes=int(act.sum()) if rnd is not None else 0)
+            if self._mla:
+                # Positions the latent kernel read, over slots and layers.
+                sp.note(latent_rows=0 if rnd is None else self.cfg.num_layers
+                        * int((lengths[act].astype(np.int64) + 1).sum()))
             if pre_events:
                 row_t = np.zeros((1, self.slots), np.int32)
                 row_v = np.zeros((1, self.slots), bool)
@@ -2627,6 +2694,10 @@ class SlotEngine:
             raise EvaUnsupported(
                 "slot export/import is not extended to EVA's composed "
                 "page table")
+        if self._mla:
+            raise SlotStateUnsupported(
+                "slot export/import is not extended to latent attention: "
+                "the bundle carries K and V rows")
 
     def export_slot(self, slot: int, *, history=None) -> dict:
         """Capture ``slot``'s decode state as a host-serializable bundle.

@@ -23,9 +23,11 @@ length ``p`` (strictly above every full prompt page), so a shared page is
 written only with byte-identical content (the prefill program's
 whole-row scatter-back, which round-trips the gathered values).
 
-The :class:`PrefixCache` keys pages by the EXACT BYTES of the token prefix
-they complete (not a hash digest), so a lookup can never adopt a colliding
-request's KV; entries are LRU-evicted when the pool runs out of pages.
+The :class:`PrefixCache` keys pages by the EXACT token prefix they complete
+(the entry of the prefix before a page, by an id never handed out twice, and
+the raw bytes of the page's own tokens; not a hash digest), so a lookup can
+never adopt a colliding request's KV; entries are LRU-evicted when the pool
+runs out of pages.
 
 **Two kinds of page (an EVA config, ``cfg.eva_window`` set).** The same
 leaves ``(num_pages, heads, page_size, head_dim)`` and the same refcounts
@@ -91,9 +93,9 @@ the prefix cache alone, which gives it up (``evict``).
 
 **What a prefix adopts.** A prompt's finished windows are indexed by their
 summary pages (every page of a window, each keyed by the prefix its
-summaries end at, under a key one byte longer than any K/V page's), and the
-window the prompt ends in by its full K/V pages, keyed as in the plain
-layout. A request adopts the summary pages of every leading window cached
+summaries end at, under a key of its own kind), and the window the prompt
+ends in by its full K/V pages, chained from the summaries of the windows
+before it. A request adopts the summary pages of every leading window cached
 whole, then the chain of full K/V pages of the window after them
 (:meth:`PrefixCache.match_eva`); the summaries of the chunks it adopted as
 K/V pages are formed again from those rows by its first prefill segment.
@@ -652,17 +654,30 @@ class PagedKVPool:
 class PrefixCache:
     """Exact-prefix index over immutable full pages, refcounted + LRU.
 
-    Key: the raw bytes of the token prefix a page COMPLETES (int32,
-    little-endian) — exact matching, so adopting a cached page can never
-    splice a colliding request's KV (a digest could). Value: the physical
-    page id. The cache holds its own reference on every indexed page;
-    ``match`` adds one per adopting slot, ``evict_for`` drops LRU entries
-    (cache reference only — pages still referenced by live slots survive
-    until those slots free)."""
+    An entry is keyed by the prefix it extends and the raw bytes of the
+    tokens it adds: ``(node, page tokens)`` for a K/V page, ``("S", node,
+    span tokens)`` for an EVA summary page, where ``node`` is the id the
+    entry of the prefix before it was given when it was indexed (``ROOT``
+    for the empty prefix). Ids are handed out once and never again, so a key
+    names exactly one token prefix (by induction on its pages): adopting a
+    cached page can never splice a colliding request's KV (a digest could),
+    and a lookup hashes each token of the prompt once, where keying every
+    page by its whole prefix hashed a 28k-token prompt's 57 MB of prefixes
+    (about 80 ms of host time an admission). Value: the physical page id and
+    the entry's node. The cache holds its own reference on every indexed
+    page; ``match`` adds one per adopting slot, ``evict_for`` drops LRU
+    entries (cache reference only — pages still referenced by live slots
+    survive until those slots free). A lookup or an insert touches its
+    chain from the last entry back to the first, so an entry is always more
+    recent than every entry that hangs from it: eviction takes leaves first
+    and never leaves behind an entry that no lookup could reach."""
+
+    ROOT = 0
 
     def __init__(self, pool: PagedKVPool):
         self.pool = pool
-        self._entries: OrderedDict[bytes, int] = OrderedDict()
+        self._entries: OrderedDict[tuple, tuple[int, int]] = OrderedDict()
+        self._next_node = self.ROOT + 1
         # Cumulative token counters (the serve_prefix_hit_rate feed).
         self.tokens_matched = 0
         self.tokens_looked_up = 0
@@ -671,30 +686,80 @@ class PrefixCache:
         return len(self._entries)
 
     @staticmethod
-    def _key(prompt: np.ndarray, n_pages: int, page_size: int) -> bytes:
-        return prompt[: n_pages * page_size].astype("<i4").tobytes()
+    def _tokens(prompt: np.ndarray, lo: int, hi: int) -> bytes:
+        return prompt[lo:hi].astype("<i4").tobytes()
+
+    def _page_key(self, node: int, prompt: np.ndarray, i: int) -> tuple:
+        """Key of page ``i`` (0-based) of ``prompt`` after ``node``."""
+        ps = self.pool.page_size
+        return node, self._tokens(prompt, i * ps, (i + 1) * ps)
+
+    def _summary_key(self, node: int, prompt: np.ndarray, n_tokens: int):
+        """Key of the summary page whose summaries end at ``n_tokens``."""
+        span = self.pool.window // self.pool.sum_pages
+        return "S", node, self._tokens(prompt, n_tokens - span, n_tokens)
+
+    def _add(self, key: tuple, pid: int) -> int:
+        """Index ``pid`` under ``key`` unless an entry is there already; the
+        entry's node."""
+        hit = self._entries.get(key)
+        if hit is not None:
+            return hit[1]
+        self.pool.incref(int(pid))
+        node, self._next_node = self._next_node, self._next_node + 1
+        self._entries[key] = (int(pid), node)
+        return node
+
+    def _touch(self, keys: list) -> None:
+        """Make a chain's entries the most recent, its first entry last:
+        an entry is then always more recent than every entry that hangs
+        from it, and the LRU end is a leaf."""
+        for key in reversed(keys):
+            self._entries.move_to_end(key)
+
+    def _summary_chain(self, prompt: np.ndarray, n_tokens: int):
+        """The keys of the summary chain that ends at ``n_tokens`` (a whole
+        number of windows; empty at 0), or None where it is not cached
+        whole: what an EVA window's pages hang from."""
+        span = self.pool.window // self.pool.sum_pages
+        node, keys = self.ROOT, []
+        for end in range(span, n_tokens + 1, span):
+            key = self._summary_key(node, prompt, end)
+            hit = self._entries.get(key)
+            if hit is None:
+                return None
+            node = hit[1]
+            keys.append(key)
+        return keys
+
+    def _node(self, keys: list) -> int:
+        """The node at the end of a chain of keys (``ROOT`` if empty)."""
+        return self._entries[keys[-1]][1] if keys else self.ROOT
 
     def match(self, prompt: np.ndarray, max_pages: int) -> list[int]:
         """Longest chain of cached full pages covering a prefix of
         ``prompt`` (at most ``max_pages``). Each matched page is increffed
         for the adopting slot; entries touch LRU-recency."""
-        ps = self.pool.page_size
-        pages: list[int] = []
-        for i in range(1, max_pages + 1):
-            key = self._key(prompt, i, ps)
-            pid = self._entries.get(key)
-            if pid is None:
-                break
-            self._entries.move_to_end(key)
-            pages.append(pid)
+        pages, keys = self._walk(prompt, self.ROOT, 0, max_pages)
+        self._touch(keys)
         for pid in pages:
             self.pool.incref(pid)
         return pages
 
-    @staticmethod
-    def _summary_key(prompt: np.ndarray, n_tokens: int) -> bytes:
-        # One byte longer than any K/V page's key: the kinds never collide.
-        return b"S" + prompt[:n_tokens].astype("<i4").tobytes()
+    def _walk(self, prompt, node: int, first: int, n: int):
+        """Pages ``first`` .. ``first + n - 1`` of ``prompt`` found in a
+        chain from ``node``, up to the first one missing, and their keys."""
+        pages: list[int] = []
+        keys: list[tuple] = []
+        for i in range(first, first + n):
+            key = self._page_key(node, prompt, i)
+            hit = self._entries.get(key)
+            if hit is None:
+                break
+            pid, node = hit
+            pages.append(pid)
+            keys.append(key)
+        return pages, keys
 
     def match_eva(self, prompt: np.ndarray, cap_tokens: int):
         """EVA layout: the longest cached prefix of ``prompt`` of at most
@@ -706,26 +771,27 @@ class PrefixCache:
         ps, w = pool.page_size, pool.window
         span = w // pool.sum_pages  # positions one summary page covers
         sums: list[int] = []
-        done = 0
+        chain: list[tuple] = []
+        done, node = 0, self.ROOT
         while (done + 1) * w <= cap_tokens:
-            keys = [self._summary_key(prompt, done * w + (i + 1) * span)
-                    for i in range(pool.sum_pages)]
-            if any(k not in self._entries for k in keys):
+            keys, hits, at = [], [], node
+            for i in range(pool.sum_pages):
+                key = self._summary_key(at, prompt, done * w + (i + 1) * span)
+                hit = self._entries.get(key)
+                if hit is None:
+                    break
+                keys.append(key)
+                hits.append(hit)
+                at = hit[1]
+            if len(hits) < pool.sum_pages:
                 break
-            for k in keys:
-                self._entries.move_to_end(k)
-                sums.append(self._entries[k])
-            done += 1
+            chain += keys
+            sums += [pid for pid, _ in hits]
+            done, node = done + 1, at
         base = done * w // ps
-        wins: list[int] = []
-        for i in range(1, min(pool.window_pages,
-                              cap_tokens // ps - base) + 1):
-            key = self._key(prompt, base + i, ps)
-            pid = self._entries.get(key)
-            if pid is None:
-                break
-            self._entries.move_to_end(key)
-            wins.append(pid)
+        wins, keys = self._walk(prompt, node, base,
+                                min(pool.window_pages, cap_tokens // ps - base))
+        self._touch(chain + keys)
         for pid in sums + wins:
             pool.incref(pid)
         return sums, wins
@@ -733,16 +799,19 @@ class PrefixCache:
     def insert_summaries(self, prompt: np.ndarray, window: int,
                          page_ids) -> None:
         """Index the summary pages of ``prompt``'s finished window
-        ``window`` (all of them, each by the prefix its summaries end at)."""
+        ``window`` (all of them, each by the prefix its summaries end at),
+        behind the cached summaries of the windows before it."""
         w = self.pool.window
         span = w // self.pool.sum_pages
+        keys = self._summary_chain(prompt, window * w)
+        if keys is None:  # reached by no lookup
+            return
+        node = self._node(keys)
         for i, pid in enumerate(page_ids):
-            key = self._summary_key(prompt, window * w + (i + 1) * span)
-            if key in self._entries:
-                self._entries.move_to_end(key)
-                continue
-            self.pool.incref(int(pid))
-            self._entries[key] = int(pid)
+            keys.append(self._summary_key(
+                node, prompt, window * w + (i + 1) * span))
+            node = self._add(keys[-1], pid)
+        self._touch(keys)
 
     def record_lookup(self, matched_tokens: int, prompt_tokens: int) -> None:
         self.tokens_matched += matched_tokens
@@ -757,18 +826,19 @@ class PrefixCache:
     def insert(self, prompt: np.ndarray, page_ids, first_page: int = 0) -> None:
         """Index ``prompt``'s full pages (``page_ids[i]`` backs page
         ``first_page + i`` of the prompt; an EVA slot passes its current
-        window's pages and the page that window starts at).
+        window's pages and the page that window starts at, which hang from
+        the cached summaries of the windows before it).
         Already-indexed prefixes keep their existing (shared) page."""
         ps = self.pool.page_size
         n_full = min(len(page_ids), len(prompt) // ps - first_page)
+        keys = self._summary_chain(prompt, first_page * ps) if first_page else []
+        if keys is None:  # reached by no lookup
+            return
+        node = self._node(keys)
         for i in range(n_full):
-            key = self._key(prompt, first_page + i + 1, ps)
-            if key in self._entries:
-                self._entries.move_to_end(key)
-                continue
-            pid = int(page_ids[i])
-            self.pool.incref(pid)
-            self._entries[key] = pid
+            keys.append(self._page_key(node, prompt, first_page + i))
+            node = self._add(keys[-1], page_ids[i])
+        self._touch(keys)
 
     def evict_for(self, pages_wanted: int) -> int:
         """Drop LRU entries until the pool could satisfy ``pages_wanted``
@@ -777,12 +847,12 @@ class PrefixCache:
         resident and simply leaves the index."""
         evicted = 0
         while (self.pool.pages_free < pages_wanted) and self._entries:
-            _, pid = self._entries.popitem(last=False)
+            _, (pid, _) = self._entries.popitem(last=False)
             self.pool.decref(pid)
             evicted += 1
         return evicted
 
     def clear(self) -> None:
         while self._entries:
-            _, pid = self._entries.popitem(last=False)
+            _, (pid, _) = self._entries.popitem(last=False)
             self.pool.decref(pid)

@@ -100,7 +100,7 @@ def test_the_manifest_checks_and_holds_the_cell():
         64, 4096, 1024, 16, False, 0)
     for name in ("itl_p95_ms", "out_tok_s"):
         e = next(e for e in man["end_to_end"] if e["name"] == name)
-        assert e["workloads"][-1] == CELL
+        assert CELL in e["workloads"]
     mine = {p["name"] for p in man["per_layer"] if CELL in p["workloads"]}
     assert {"model.decode_roofline.nemotron", "model.serve_mfu.nemotron",
             "kernels.moe_grouped_roofline.nemotron",

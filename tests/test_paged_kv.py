@@ -306,6 +306,60 @@ def test_paged_pool_refcount_sharing():
     assert pool.pages_free == pool.num_pages - 1
 
 
+def test_prefix_cache_keys_are_exact_prefixes():
+    """A page is adopted only behind the exact prefix it was indexed
+    behind: the same tokens after another first page match nothing past
+    it."""
+    pool = PagedKVPool(CFG, slots=2, max_len=32, page_size=8)
+    cache = PrefixCache(pool)
+    prompt = np.arange(1, 25, dtype=np.int32)  # 3 full pages
+    pages = pool.alloc_pages(3)
+    cache.insert(prompt, pages)
+    other = prompt.copy()
+    other[:8] = 99  # page 0 differs, pages 1 and 2 are the same tokens
+    assert cache.match(other, 3) == []
+    assert cache.match(prompt, 3) == pages
+    assert cache.match(prompt[:16], 2) == pages[:2]
+    assert len(cache) == 3
+
+
+def _orphans(cache):
+    """Entries whose parent entry is not in the cache."""
+    nodes = {PrefixCache.ROOT} | {n for _, n in cache._entries.values()}
+    return [k for k in cache._entries
+            if (k[1] if k[0] == "S" else k[0]) not in nodes]
+
+
+def test_prefix_cache_evicts_leaves_first():
+    """Eviction takes a chain's last page before the pages it hangs from,
+    also after a lookup of a shorter prefix or an insert of a branch
+    touched the chain's head last: no entry is ever left behind that no
+    lookup could reach."""
+    pool = PagedKVPool(CFG, slots=2, max_len=32, page_size=8)
+    cache = PrefixCache(pool)
+    prompt = np.arange(1, 25, dtype=np.int32)  # 3 full pages
+    pages = pool.alloc_pages(3)
+    cache.insert(prompt, pages)
+    branch = prompt[:16].copy()
+    branch[8:] = 77  # shares page 0, then its own page 1
+    own = pool.alloc_pages(1)
+    cache.insert(branch, [pages[0], own[0]])
+    for pid in pages + own:
+        pool.decref(pid)  # the cache's references alone hold them now
+    for pid in cache.match(prompt[:8], 1):  # touches the head alone
+        pool.decref(pid)
+    order = []
+    while len(cache):
+        before = dict(cache._entries)
+        assert cache.evict_for(pool.pages_free + 1) == 1
+        (gone,) = set(before) - set(cache._entries)
+        order.append(before[gone][0])
+        assert not _orphans(cache)
+    assert order[-1] == pages[0]  # the shared head goes last
+    assert order.index(pages[2]) < order.index(pages[1])
+    assert pool.pages_free == pool.num_pages - 1
+
+
 def test_paged_pool_slot_bookkeeping():
     """The slot free list: every slot once, ``None`` at exhaustion, the
     slot freed last is the next one handed out, and the companion set that

@@ -497,3 +497,123 @@ def test_nemotron_prefill_chunk_costs_its_pairs_and_scans_in_blocks(one_chip):
     assert all(n <= 128 for n in trips), trips  # never one trip a position
     assert f"f32[1,1,{cfg.vocab_size}]" in text
     assert f"[1,{width},{cfg.vocab_size}]" not in text
+
+
+def _dsv2_layers(one_chip, layers=2):
+    """DeepSeek-V2-Lite at the published widths (benchmarks/configs), cut to
+    ``layers`` (the dense one and expert ones behind it) and an 8,192-row
+    vocabulary so that it compiles in seconds: the model, its parameters as
+    shapes on the described chip."""
+    import json
+
+    from distributed_tensorflow_tpu.models.transformer import (
+        TransformerConfig,
+        TransformerLM,
+    )
+
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                        "configs", "deepseek-v2-lite.json")
+    with open(path) as fh:
+        mcfg = dict(json.load(fh)["transformer_config"], vocab_size=8192,
+                    num_layers=layers)
+    cfg = TransformerConfig(**mcfg, compute_dtype=jnp.bfloat16)
+    model = TransformerLM(cfg)
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                       sharding=one_chip), shapes)
+    return cfg, model, params
+
+
+def test_dsv2_decode_round_compiles_for_v5e(one_chip, monkeypatch):
+    """The dense layer and one expert layer of the decode round as
+    ``dsv2-lite.docs-closed`` runs it: 32 slots of 32768, pages of 16 latent
+    rows of 640 lanes. Each layer's attention is ONE ``mla_decode_attention``
+    custom call over the leaf as it lies (16 absorbed query rows of a slot
+    against its one row a position, no copy of the pool, no expanded K or
+    V), its row write ONE ``paged_row_write`` of that one leaf, the routed
+    products two ``grouped_matmul`` calls over 192 = 32 x 6 pairs (padded to
+    the kernel's 256-row tile)."""
+    from distributed_tensorflow_tpu.models.decoding import decode_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, model, params = _dsv2_layers(one_chip)
+    slots, ps, pps = 32, 16, 2048
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    leaf = (slots * pps + 1, 1, ps, 640)
+    pool = [{"lat": arg(leaf, jnp.bfloat16)} for _ in range(2)]
+
+    def step(params, pool, tables, active, lengths, tok):
+        dest = tables[jnp.arange(slots), lengths // ps]
+        cache = {"layers": pool, "len": lengths, "pages": tables,
+                 "write_page": jnp.where(active, dest, 0),
+                 "attend": jnp.where(active, lengths + 1, 0),
+                 "route_mask": active[:, None]}
+        cache, logits = decode_step(model, params, cache, tok[:, None])
+        return cache["layers"], logits.argmax(-1), cache["moe_counts"]
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, pool, arg((slots, pps), jnp.int32), arg((slots,), jnp.bool_),
+        arg((slots,), jnp.int32), arg((slots,), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [l.split(" custom-call(")[0].strip() for l in text.splitlines()
+             if "tpu_custom_call" in l and " custom-call(" in l]
+    assert sum(c.startswith("%mla_decode_attention") for c in calls) == 2
+    assert sum("bf16[32,1,16,640]" in c for c in calls) == 2
+    assert sum(c.startswith("%paged_row_write") for c in calls) == 2
+    products = [c for c in calls if c.startswith("%grouped_matmul")]
+    assert len(products) == 2 and all("f32[256," in c for c in products)
+    assert "[32,32768," not in text  # no slot's row gathered or expanded
+    assert not [l for l in text.splitlines()
+                if "[65537,1,16,640]" in l and " copy(" in l]
+    alias = text[text.index("input_output_alias"):].split("\n")[0]
+    assert alias.count("may-alias") + alias.count("must-alias") >= 2
+
+
+@pytest.mark.parametrize("width,form", [(1024, "expanded"), (128, "absorbed")])
+def test_dsv2_prefill_chunk_compiles_in_the_form_of_its_width(
+        one_chip, monkeypatch, width, form):
+    """A prefill chunk of the dense and one expert layer over a gathered
+    row of 32768 latent positions, through the chunk kernel in the form of
+    its width (``models/transformer.mla_prefill_form``): a 1024-wide chunk
+    over every head's K and V expanded to 256 lanes (16 kv heads of one
+    query row group), a 128-wide tail with its 16 queries folded into the
+    latent over the rows as they lie (one kv head of 640 lanes). No score
+    matrix over the whole row; the head is formed for one row; the program
+    fits the chip beside the cell's 12.4 GB."""
+    from distributed_tensorflow_tpu.models.transformer import mla_prefill_form
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, model, params = _dsv2_layers(one_chip)
+    assert mla_prefill_form(cfg, width, 32768) == form
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def chunk(params, lat, tokens, n_real, start):
+        cache = {"layers": [{"lat": lat[0][None]}, {"lat": lat[1][None]}],
+                 "len": start, "route_mask": (jnp.arange(width) < n_real)[None]}
+        logits, cache = model.apply(
+            {"params": params}, tokens, cache=cache,
+            logit_rows=(n_real - 1)[None])
+        return logits, [l["lat"] for l in cache["layers"]]
+
+    lat = [arg((1, 32768, 640), jnp.bfloat16) for _ in range(2)]
+    compiled = jax.jit(chunk).lower(
+        params, lat, arg((1, width), jnp.int32), arg((), jnp.int32),
+        arg((), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [l.split(" custom-call(")[0].strip() for l in text.splitlines()
+             if "tpu_custom_call" in l and " custom-call(" in l]
+    attn = [c for c in calls if c.startswith("%prefill_chunk_attention")]
+    want = ("bf16[16,1,%d,256]" % width if form == "expanded"
+            else "bf16[1,16,%d,640]" % width)
+    assert len(attn) == 2 and all(want in c for c in attn), attn
+    assert "[1,16,%d,32768]" % width not in text
+    assert f"f32[1,1,{cfg.vocab_size}]" in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2e9, mem.temp_size_in_bytes
