@@ -1427,6 +1427,21 @@ def mla_prefill_widths(cfg, rows: int, chunk: int) -> tuple[int, ...]:
     return tuple(widths)
 
 
+def mla_rows_expanded(cfg, offset: int, width: int, rows: int, dtype) -> int:
+    """The latent rows a layer expands to K and V for a prefill of ``width``
+    queries at ``offset`` over a gathered row of ``rows`` positions of
+    ``dtype``: 0 in the absorbed form; in the expanded form those below the
+    chunk's end, rounded up to the key block of the kernel
+    (``ops.attention.mla_chunk_rows``) or, off its tiles, of the
+    ``jax.numpy`` loop (:func:`_mla_attend_rows`)."""
+    if mla_prefill_form(cfg, width, rows) == "absorbed":
+        return 0
+    if A.chunk_flash_fits(dtype, cfg.mla_row, (rows, width)):
+        return A.mla_chunk_rows(offset, width, rows, dtype)
+    blk = min(rows, 1024)
+    return -(-(offset + width) // blk) * blk
+
+
 def _mla_rope(cfg, positions):
     """cos/sin tables (..., rope/2) of the rope part at ``positions``: YaRN
     where the config has it, plain RoPE elsewhere."""
@@ -1518,17 +1533,17 @@ def _mla_attend_rows(cfg, q_nope, q_pe, lat, q_pos, n_rows, w_kb, form):
 
 
 def _mla_flash(cfg, q_nope, q_pe, lat, offset, w_kb, form):
-    """:func:`_mla_attend_rows` of one slot's prefill chunk through the
-    chunk kernel (``ops.attention.chunk_flash_attention``), for a gathered
-    row ``lat`` (1, S, row) whose shapes the kernel takes; the chunk's rows
-    start at ``offset``. Absorbed: the H queries [q~ | q_pe | 0] of a row
-    are one kv head's group over the latent rows as they lie, each row its
-    own value (the output's first r lanes are softmax . c). Expanded: every
-    head's K [k_nope | k_pe | 0] and V [v | 0] made from the whole row, at
-    the width of 128 lanes that holds both (256 at DeepSeek-V2's widths).
+    """:func:`_mla_attend_rows` of one slot's prefill chunk through a chunk
+    kernel, for a gathered row ``lat`` (1, S, row) whose shapes the kernels
+    take; the chunk's rows start at ``offset``. Absorbed
+    (``ops.attention.chunk_flash_attention``): the H queries [q~ | q_pe | 0]
+    of a row are one kv head's group over the latent rows as they lie, each
+    row its own value (the output's first r lanes are softmax . c).
+    Expanded (``ops.attention.mla_chunk_attention``): every head's K and V
+    made inside the kernel, from the rows below the chunk's end alone.
     Returns (1, s, H, v) in the compute dtype."""
     _, s, heads, nope = q_nope.shape
-    r, rope, vd = cfg.kv_lora_rank, cfg.qk_rope_head_dim, cfg.v_head_dim
+    r = cfg.kv_lora_rank
     dt = cfg.compute_dtype
     pad = lambda x, n: jnp.pad(x, [(0, 0)] * (x.ndim - 1)
                                + [(0, n - x.shape[-1])])
@@ -1548,19 +1563,10 @@ def _mla_flash(cfg, q_nope, q_pe, lat, offset, w_kb, form):
                              w_kb[..., nope:].astype(dt),
                              preferred_element_type=jnp.float32)
         return out.astype(dt)
-    width = -(-max(nope + rope, vd) // 128) * 128
     with jax.named_scope("mla.expand"):
-        kv = jnp.einsum("bSc,chd->bhSd", lat[..., :r].astype(dt),
-                        w_kb.astype(dt),
-                        preferred_element_type=jnp.float32).astype(dt)
-        k_pe = jnp.broadcast_to(lat[:, None, :, r:r + rope].astype(dt),
-                                kv.shape[:3] + (rope,))
-        k = pad(jnp.concatenate([kv[..., :nope], k_pe], -1), width)
-        v = pad(kv[..., nope:], width)
-    q = pad(jnp.concatenate([q_nope.astype(dt), q_pe.astype(dt)], -1), width)
-    o = A.chunk_flash_attention(heads_first(q), k, v, offset,
-                                scale=cfg.mla_scale)
-    return heads_first(o[..., :vd]).astype(dt)
+        return A.mla_chunk_attention(
+            q_nope.astype(dt), q_pe.astype(dt), lat, w_kb.astype(dt), offset,
+            scale=cfg.mla_scale)
 
 
 def _write_latent_rows(leaf, rows, page, offset, live):

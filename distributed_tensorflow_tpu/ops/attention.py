@@ -2886,3 +2886,256 @@ def _chunk_flash_call(q, k, v, offset, *, scale, window, block_q, block_kv,
     )(offset, q.reshape(b * kv, group, sq, dh),
       k.reshape(b * kv, s_max, dh), v.reshape(b * kv, s_max, dh))
     return out.reshape(b, h, sq, dh)
+
+
+# ---------------------------------------------------------------------------
+# Latent attention's prefill chunk in the expanded form: the chunk kernel's
+# online softmax over K and V made in VMEM from the latent rows.
+#
+# A slot's gathered row holds one latent row a position, [c | k_pe | 0]. A
+# grid cell takes a block of those rows and a group of heads' W_UK,h and
+# W_UV,h and makes K_h = [c W_UK,h | k_pe] and V_h = c W_UV,h in the compute
+# dtype, then attends them as ``_chunk_flash_kernel`` attends its blocks. No
+# K or V of the whole row exists in HBM, and a key block the chunk cannot
+# see is neither copied, expanded nor computed. The kernel's q-block is the
+# whole chunk, so a key block is expanded once a head a chunk; a cell takes
+# two heads, so a block of rows is read once for both. On a v5e at
+# DeepSeek-V2's widths, a chunk of 1024 rows behind 11264: 2.78 ms a call
+# on the host's clock (two heads a cell) against 2.95 (one) and 3.16 (q-blocks of 512, each
+# expanding the block again); key blocks of 256 and 1024 rows 2.92 and 2.73
+# (512 kept: its output is the XLA expansion's to the bit, the others' sum
+# in another order); the XLA expansion of the whole row of 32768 and the
+# plain chunk kernel after it 6.59 ms. A key block costs 4.3 us a head, 79%
+# of the bf16 peak for its expansion, scores and value product; a block on
+# the diagonal, masked, no more than one below it.
+# ---------------------------------------------------------------------------
+
+
+def _mla_chunk_kernel(
+    off_ref,
+    q_ref,
+    lat_ref,
+    w_ref,
+    o_ref,
+    acc_ref,
+    m_ref,
+    l_ref,
+    *,
+    block_kv: int,
+    s: float,
+    r: int,
+    nope: int,
+    pe: int,
+):
+    """One (batch·head group, kv-block) grid cell, kv innermost, over the
+    whole chunk's query rows. ``q_ref`` (1, group, sq, nope + row - pe):
+    each head's [q_nope | q_pe] laid against the lanes of ``lat_ref``'s rope
+    tile; ``lat_ref`` (1, bkv, row) the latent rows; ``w_ref`` (r, group·
+    (nope + dv)) each head's [W_UK,h | W_UV,h], its rows past the latent
+    zero. ``r``, ``nope`` and the rope tile's first lane ``pe`` are whole
+    numbers of 128 lanes (zero padded where the widths are not). The
+    product c W is f32, rounded to the compute dtype as the XLA expansion
+    rounds it; then the cell is ``_chunk_flash_kernel``'s: transposed
+    tiles, f32 scores, statistics and accumulator, and a probability
+    entering the value product as two bf16 halves."""
+    off = off_ref[0]
+    j = pl.program_id(1)
+    _, group, sq, _ = q_ref.shape
+    dt = q_ref.dtype
+    width = w_ref.shape[1] // group
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, m_ref.dtype)
+        l_ref[...] = jnp.zeros(l_ref.shape, l_ref.dtype)
+
+    @pl.when(j * block_kv < off + sq)
+    def _compute():
+        k_pos = j * block_kv + lax.broadcasted_iota(
+            jnp.int32, (block_kv, 1), 0)
+        # Rows past the chunk's end lie in its last block: zeroed, so that
+        # their K and V are finite and their probability exactly zero.
+        rows = jnp.where(k_pos < off + sq, lat_ref[0], 0).astype(dt)
+        c, k_pe = rows[:, :r], rows[:, pe:]
+        bf16 = dt == jnp.bfloat16
+        q_pos = off + lax.broadcasted_iota(jnp.int32, (1, sq), 1)
+        mask = k_pos <= q_pos  # (bkv, sq)
+        for g in range(group):
+            kv = jnp.dot(c, w_ref[:, g * width:(g + 1) * width],
+                         preferred_element_type=jnp.float32).astype(dt)
+            k_blk = jnp.concatenate([kv[:, :nope], k_pe], axis=1)
+            v_blk = kv[:, nope:]
+            if not bf16:
+                v_blk = v_blk.astype(jnp.float32)
+            logits = lax.dot_general(
+                k_blk, q_ref[0, g], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * s  # (bkv, sq)
+            logits = jnp.where(mask, logits, NEG_INF)
+            m = m_ref[g]  # (1, sq)
+            m_new = jnp.maximum(m, logits.max(axis=0, keepdims=True))
+            m_safe = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
+            correction = jnp.exp(m - m_safe)
+            p = jnp.exp(logits - m_safe)
+            l_ref[g] = l_ref[g] * correction + p.sum(axis=0, keepdims=True)
+            if bf16:
+                hi = p.astype(jnp.bfloat16)
+                lo = (p - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+                p = jnp.concatenate([hi, lo], axis=1)  # (bkv, 2 sq)
+            pv = lax.dot_general(
+                v_blk, p, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # V^T P^T: (dv, sq), or the two halves side by side
+            if bf16:
+                pv = pv[:, :sq] + pv[:, sq:]
+            acc_ref[g] = acc_ref[g] * correction + pv
+            m_ref[g] = m_safe + jnp.where(m_new <= NEG_INF / 2, NEG_INF, 0.0)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        for g in range(group):
+            o_ref[0, g] = (
+                acc_ref[g] / jnp.maximum(l_ref[g], 1e-30)
+            ).T.astype(o_ref.dtype)
+
+
+def _mla_chunk_block_kv(s_max: int, dtype) -> int:
+    """The key block of :func:`mla_chunk_attention` over a gathered row of
+    ``s_max`` latent rows of ``dtype``."""
+    return _tile_block(512, s_max, _sublane_rows(dtype))
+
+
+def mla_chunk_rows(q_offset: int, sq: int, s_max: int, dtype) -> int:
+    """The latent rows :func:`mla_chunk_attention` expands for each head
+    for a chunk of ``sq`` rows at ``q_offset``: those below the chunk's
+    end, rounded up to its key block. The kernel's rule, counted on the
+    host."""
+    blk = _mla_chunk_block_kv(s_max, dtype)
+    return min(s_max, -(-(int(q_offset) + int(sq)) // blk) * blk)
+
+
+def mla_chunk_attention(
+    q_nope,
+    q_pe,
+    lat,
+    w_kb,
+    q_offset,
+    *,
+    scale: float,
+    interpret: bool | None = None,
+):
+    """Latent attention of a prefill chunk in the expanded form, K and V made
+    inside the kernel from the latent rows it reads.
+
+    ``q_nope`` (B, s, H, nope) and ``q_pe`` (B, s, H, rope), rotated, row
+    ``i`` at position ``q_offset + i``; ``lat`` (B, S_max, row) one latent
+    row a position, [c | k_pe | padding] with ``c`` the first r lanes and
+    ``k_pe`` the rope lanes after it, holding the chunk's own rows already;
+    ``w_kb`` (r, H, nope + dv) each head's [W_UK,h | W_UV,h]. A row attends
+    the positions up to its own: logit ``(q_nope . c W_UK,h + q_pe . k_pe)
+    * scale``, value ``c W_UV,h``. Returns (B, s, H, dv) in ``q_nope``'s
+    dtype, the compute dtype: K and V are rounded to it as the XLA
+    expansion rounds them, and the rows are cast to it. The key blocks below
+    the chunk's end are the only ones read, expanded and computed
+    (:func:`mla_chunk_rows`), whatever the rest hold."""
+    b, sq, h, nope = q_nope.shape
+    rope = q_pe.shape[-1]
+    r, _, width = w_kb.shape
+    dv = width - nope
+    s_max, row = lat.shape[1], lat.shape[2]
+    if (q_pe.shape[:3] != (b, sq, h) or lat.shape[0] != b
+            or w_kb.shape[1] != h or r + rope > row or dv < 1):
+        raise ValueError(
+            f"q_nope {q_nope.shape} / q_pe {q_pe.shape} do not fit rows "
+            f"{lat.shape} and weights {w_kb.shape}")
+    if sq > s_max:
+        raise ValueError(f"{sq} query rows exceed the row's {s_max}")
+    if not chunk_flash_fits(lat.dtype, row, (s_max, sq)):
+        raise ValueError(
+            f"chunk of {sq} rows over latent rows {lat.shape} of {lat.dtype} "
+            f"is off the tile")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    dt = q_nope.dtype
+    lanes = lambda n: -(-n // 128) * 128
+    r_hi, nope_hi, dv_hi, pe = lanes(r), lanes(nope), lanes(dv), r // 128 * 128
+    # Each head's [W_UK,h | 0 | W_UV,h | 0] at whole tiles, its rows past the
+    # latent zero (the rope lanes that share c's last tile meet them): one
+    # free reshape at DeepSeek-V2's widths, where nothing is padded.
+    pad = lambda x, n: jnp.pad(x, ((0, r_hi - r), (0, 0),
+                                   (0, n - x.shape[-1])))
+    w = jnp.concatenate([pad(w_kb[..., :nope], nope_hi),
+                         pad(w_kb[..., nope:], dv_hi)], -1).astype(dt)
+    # q_pe against the lanes of the rope tile, where k_pe lies among them.
+    q = jnp.concatenate([
+        jnp.pad(q_nope, ((0, 0),) * 3 + ((0, nope_hi - nope),)),
+        jnp.pad(q_pe.astype(dt), ((0, 0),) * 3
+                + ((r - pe, row - r - rope),)),
+    ], -1).transpose(0, 2, 1, 3)
+    out = _mla_chunk_call(
+        q, lat, w.reshape(r_hi, h * (nope_hi + dv_hi)),
+        jnp.asarray(q_offset, jnp.int32).reshape(1), scale=float(scale),
+        block_kv=_mla_chunk_block_kv(s_max, lat.dtype),
+        group=2 if h % 2 == 0 else 1, r=r_hi, nope=nope_hi, pe=pe,
+        interpret=bool(interpret))
+    return out[..., :dv].transpose(0, 2, 1, 3)
+
+
+# Jitted like _paged_decode_call: a model's layers trace the kernel once.
+@functools.partial(
+    jax.jit,
+    static_argnames=("scale", "block_kv", "group", "r", "nope", "pe",
+                     "interpret"),
+)
+def _mla_chunk_call(q, lat, w, offset, *, scale, block_kv, group, r, nope, pe,
+                    interpret):
+    b, h, sq, qw = q.shape
+    s_max, row = lat.shape[1], lat.shape[2]
+    width = w.shape[1] // h
+    dv = width - nope
+    heads = h // group
+    kernel = functools.partial(
+        _mla_chunk_kernel, block_kv=block_kv, s=scale, r=r, nope=nope, pe=pe)
+    num_kv = s_max // block_kv
+
+    def lat_index(bg, j, off_ref):
+        # _chunk_kv_index's clamp: the blocks past the chunk's end map to its
+        # last, so that their copies are elided.
+        last = jnp.clip((off_ref[0] + sq - 1) // block_kv, 0, num_kv - 1)
+        return (bg // heads, jnp.minimum(j, last), 0)
+
+    item = jnp.dtype(q.dtype).itemsize
+    # Double-buffered blocks, the scratch, and the cell's f32 temporaries:
+    # a score tile, its probabilities, their halves and the expanded block.
+    vmem = (2 * (group * sq * (qw + dv) * item
+                 + block_kv * row * lat.dtype.itemsize + r * group * width
+                 * item)
+            + 4 * group * sq * (dv + 16)
+            + 4 * block_kv * (4 * sq + 2 * width) + (8 << 20))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b * heads, num_kv),
+            in_specs=[
+                pl.BlockSpec((1, group, sq, qw),
+                             lambda bg, j, off: (bg, 0, 0, 0)),
+                pl.BlockSpec((1, block_kv, row), lat_index),
+                pl.BlockSpec((r, group * width),
+                             lambda bg, j, off: (0, bg % heads)),
+            ],
+            out_specs=pl.BlockSpec((1, group, sq, dv),
+                                   lambda bg, j, off: (bg, 0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((group, dv, sq), jnp.float32),
+                pltpu.VMEM((group, 1, sq), jnp.float32),
+                pltpu.VMEM((group, 1, sq), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b * heads, group, sq, dv), q.dtype),
+        interpret=interpret,
+        name="mla_chunk_attention",
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=int(vmem)),
+    )(offset, q.reshape(b * heads, group, sq, qw), lat, w)
+    return out.reshape(b, h, sq, dv)

@@ -174,7 +174,8 @@ the model which rows are padding or masked lanes, ``route_mask``):
 width; given no ``prefill_buckets`` the engine compiles the widths
 ``mla_prefill_widths`` derives from the two forms' counts).
 ``engine.round`` carries ``latent_rows``, ``engine.start`` and
-``engine.prefill_chunk`` ``mla_form`` and ``latent_rows_attended``.
+``engine.prefill_chunk`` ``mla_form``, ``latent_rows_attended`` and
+``latent_rows_expanded`` (``stats["mla_rows_expanded"]`` sums the last).
 Speculation, the sharded engine and slot export/import refuse it.
 
 Tracing (``obs/trace.py``; always on, no switch): the host side of a round
@@ -244,6 +245,7 @@ from distributed_tensorflow_tpu.models.transformer import (
     TransformerLM,
     mla_prefill_form,
     mla_prefill_widths,
+    mla_rows_expanded,
 )
 from distributed_tensorflow_tpu.obs import trace as _trace
 from distributed_tensorflow_tpu.ops.attention import (
@@ -612,6 +614,9 @@ class SlotEngine:
             # the prompt tokens the prefill segments scanned.
             "ssm_state_bytes": self.pool.state_bytes if self._ssm else 0,
             "ssm_tokens_scanned": 0,
+            # Latent attention: the rows the prefill calls expanded to K and
+            # V, a layer (their spans' latent_rows_expanded, summed).
+            "mla_rows_expanded": 0,
             # No counter: fixed with the decode program, kept here for
             # whoever reads the rounds' counts beside it.
             "decode_kernel_form": self.decode_kernel_form,
@@ -1707,6 +1712,8 @@ class SlotEngine:
         )
         pool.layers = new_pool
         self._mla_note = self._mla_prefill_note(m0, width)
+        self.stats["mla_rows_expanded"] += self._mla_note.get(
+            "latent_rows_expanded", 0)
         if self.prefix is not None:
             self.prefix.record_lookup(m0, p)
             self.prefix.insert(prompt, page_ids)
@@ -1771,12 +1778,17 @@ class SlotEngine:
     def _mla_prefill_note(self, offset: int, width: int) -> dict:
         """What a latent-attention prefill of ``width`` rows at ``offset``
         notes on its span: the form its shapes take
-        (``models/transformer.mla_prefill_form``) and the latent rows its
-        attention reads, a layer (nothing on any other config)."""
+        (``models/transformer.mla_prefill_form``), the latent rows its
+        attention reads and those it expands to K and V
+        (``models/transformer.mla_rows_expanded``), a layer (nothing on any
+        other config)."""
         if not self._mla:
             return {}
         return {"mla_form": mla_prefill_form(self.cfg, width, self.max_len),
-                "latent_rows_attended": int(offset + width)}
+                "latent_rows_attended": int(offset + width),
+                "latent_rows_expanded": mla_rows_expanded(
+                    self.cfg, offset, width, self.max_len,
+                    self._k_leaf().dtype)}
 
     def _alloc_pages(self, n: int):
         """``n`` pages from the pool, after asking the prefix cache to give
@@ -2024,7 +2036,10 @@ class SlotEngine:
                     sp.note(ssm_blocks=-(-width // self.cfg.ssm_block)
                             * self.cfg.layer_pattern.count("M"))
                 return self._run_segment(st, m, w, final)
-            sp.note(**self._mla_prefill_note(m, w))
+            note = self._mla_prefill_note(m, w)
+            self.stats["mla_rows_expanded"] += note.get(
+                "latent_rows_expanded", 0)
+            sp.note(**note)
             toks = np.ascontiguousarray(prompt[m : m + w][None])
             row = np.array(pool.page_tables[st["slot"]])
             prefill = (

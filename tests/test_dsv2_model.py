@@ -32,6 +32,7 @@ from distributed_tensorflow_tpu.models.transformer import (
     _mla_attend_rows,
     mla_prefill_form,
     mla_prefill_widths,
+    mla_rows_expanded,
 )
 from distributed_tensorflow_tpu.ops import rope as rope_ops
 
@@ -156,27 +157,38 @@ def test_absorbed_and_expanded_attention_agree(s, start):
     assert np.abs(out[1][0] - want).max() < 1e-5
 
 
-@pytest.mark.parametrize("form", ["expanded", "absorbed"])
-def test_the_chunk_kernel_gives_what_the_blocks_give(form):
-    """A prefill chunk of 16 rows at position 40 of a row of 128 through
-    ``chunk_flash_attention`` (interpret mode here) in either form, against
-    the blockwise ``jax.numpy`` loop in the same form."""
+@pytest.mark.parametrize("form,start,rows", [
+    # Over two key blocks of 512 f32 rows: the chunk ends inside the second,
+    # and the first lies wholly below it (no mask).
+    pytest.param("expanded", 520, 1024, id="expanded"),
+    # The second block lies wholly past the chunk: neither read nor computed.
+    pytest.param("expanded", 0, 1024, id="expanded-at-0"),
+    pytest.param("absorbed", 40, 128, id="absorbed"),
+])
+def test_the_chunk_kernel_gives_what_the_blocks_give(form, start, rows):
+    """A prefill chunk of 16 rows at ``start`` of a row of ``rows`` through
+    its chunk kernel (interpret mode here): expanded, K and V made in the
+    kernel (``mla_chunk_attention``); absorbed, the queries folded into the
+    latent (``chunk_flash_attention``); against the blockwise ``jax.numpy``
+    loop in the same form. The rows past the chunk's end hold NaN for the
+    kernel: a read of one would show."""
     from distributed_tensorflow_tpu.models.transformer import _mla_flash
 
     cfg = toy_cfg()
     rng = np.random.default_rng(5)
-    r, nope, rope, v, h, s, start = 112, 32, 16, 32, 4, 16, 40
-    lat = np.zeros((1, 128, cfg.mla_row), np.float32)
-    lat[..., :r + rope] = rng.normal(size=(1, 128, r + rope))
+    r, nope, rope, v, h, s = 112, 32, 16, 32, 4, 16
+    lat = np.zeros((1, rows, cfg.mla_row), np.float32)
+    lat[..., :r + rope] = rng.normal(size=(1, rows, r + rope))
     q_nope = jnp.asarray(rng.normal(size=(1, s, h, nope)), jnp.float32)
     q_pe = jnp.asarray(rng.normal(size=(1, s, h, rope)), jnp.float32)
     w_kb = jnp.asarray(rng.normal(size=(r, h, nope + v)) / np.sqrt(r),
                        jnp.float32)
-    got = np.asarray(_mla_flash(cfg, q_nope, q_pe, jnp.asarray(lat),
-                                jnp.int32(start), w_kb, form))
     want = np.asarray(_mla_attend_rows(
         cfg, q_nope, q_pe, jnp.asarray(lat), start + jnp.arange(s),
         start + s, w_kb, form))
+    lat[:, start + s:] = np.nan
+    got = np.asarray(_mla_flash(cfg, q_nope, q_pe, jnp.asarray(lat),
+                                jnp.int32(start), w_kb, form))
     assert got.shape == (1, s, h, v)
     assert np.abs(got - want).max() < 1e-5
 
@@ -193,6 +205,23 @@ def test_the_prefill_form_follows_the_flop_counts():
     toy = toy_cfg()
     assert mla_prefill_form(toy, 38, 256) == "absorbed"
     assert mla_prefill_form(toy, 39, 256) == "expanded"
+
+
+@pytest.mark.parametrize("offset,width,dtype,want", [
+    (11264, 1024, jnp.bfloat16, 12288),  # a cold chunk: its end, to 512 rows
+    (0, 1024, jnp.bfloat16, 1024),
+    (31744, 1024, jnp.bfloat16, 32768),  # the last chunk of a 32k row
+    (22016, 256, jnp.bfloat16, 22528),   # an expanded tail
+    (22016, 128, jnp.bfloat16, 0),       # absorbed: nothing expanded
+    (1000, 1000, jnp.bfloat16, 2048),    # off the tile: the loop's 1024
+])
+def test_the_rows_expanded_follow_the_key_block(offset, width, dtype, want):
+    """What a span notes as ``latent_rows_expanded`` at DeepSeek-V2's widths
+    over a row of 32768: the rows below the chunk's end, rounded up to the
+    chunk kernel's key block, or to the ``jax.numpy`` loop's where the chunk
+    is off the kernel's tiles."""
+    full = TransformerConfig(**FULL)
+    assert mla_rows_expanded(full, offset, width, 32768, dtype) == want
 
 
 def test_the_prefill_widths_follow_the_forms():

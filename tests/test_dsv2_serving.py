@@ -128,8 +128,13 @@ def test_a_document_asked_again_adopts_its_latent_pages(params, monkeypatch):
 
 def test_both_prefill_forms_are_taken_and_said(params):
     """Chunks of 64 expand, the buckets of 8 and 16 absorb, and the spans say
-    which; a decode round notes the latent positions its kernel read."""
+    which, and how many latent rows a layer each expanded: those below the
+    chunk's end rounded up to the kernel's key block (the whole row of 256
+    f32 rows is one block at the toy's size), none where absorbed, and
+    ``stats["mla_rows_expanded"]`` their sum; a decode round notes the
+    latent positions its kernel read."""
     eng = make_engine(params)
+    expanded0 = eng.stats["mla_rows_expanded"]
     t_lo = max([r[1] for name in ("engine.round", "engine.start",
                                   "engine.prefill_chunk")
                 for r in trace.closed(name) or []], default=0.0)
@@ -152,6 +157,9 @@ def test_both_prefill_forms_are_taken_and_said(params):
               if r[0] > t_lo and r[2].get("mla_form")]
     assert [s["mla_form"] for s in starts] == ["absorbed"]
     assert starts[0]["latent_rows_attended"] == 16
+    assert [c["latent_rows_expanded"] for c in chunks] == [256, 256, 0]
+    assert starts[0]["latent_rows_expanded"] == 0
+    assert eng.stats["mla_rows_expanded"] - expanded0 == 512
     rounds = [r[2] for r in trace.closed("engine.round", t_lo, float("inf"))
               if r[0] > t_lo and r[2].get("active")]
     assert rounds and all(

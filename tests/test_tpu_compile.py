@@ -578,11 +578,12 @@ def test_dsv2_decode_round_compiles_for_v5e(one_chip, monkeypatch):
 def test_dsv2_prefill_chunk_compiles_in_the_form_of_its_width(
         one_chip, monkeypatch, width, form):
     """A prefill chunk of the dense and one expert layer over a gathered
-    row of 32768 latent positions, through the chunk kernel in the form of
+    row of 32768 latent positions, through a chunk kernel in the form of
     its width (``models/transformer.mla_prefill_form``): a 1024-wide chunk
-    over every head's K and V expanded to 256 lanes (16 kv heads of one
-    query row group), a 128-wide tail with its 16 queries folded into the
-    latent over the rows as they lie (one kv head of 640 lanes). No score
+    through ``mla_chunk_attention``, which makes every head's K and V
+    inside the kernel (no K or V of the whole row in HBM), a 128-wide tail
+    with its 16 queries folded into the latent over the rows as they lie
+    (``prefill_chunk_attention``, one kv head of 640 lanes). No score
     matrix over the whole row; the head is formed for one row; the program
     fits the chip beside the cell's 12.4 GB."""
     from distributed_tensorflow_tpu.models.transformer import mla_prefill_form
@@ -609,11 +610,20 @@ def test_dsv2_prefill_chunk_compiles_in_the_form_of_its_width(
     text = compiled.as_text()
     calls = [l.split(" custom-call(")[0].strip() for l in text.splitlines()
              if "tpu_custom_call" in l and " custom-call(" in l]
-    attn = [c for c in calls if c.startswith("%prefill_chunk_attention")]
-    want = ("bf16[16,1,%d,256]" % width if form == "expanded"
-            else "bf16[1,16,%d,640]" % width)
+    if form == "expanded":
+        attn = [c for c in calls if c.startswith("%mla_chunk_attention")]
+        want = "bf16[8,2,%d,128]" % width  # two heads a cell
+        assert not [c for c in calls
+                    if c.startswith("%prefill_chunk_attention")]
+        # No K or V of the whole row, expanded or broadcast, in HBM.
+        assert "[16,32768," not in text and "[1,16,32768," not in text
+    else:
+        attn = [c for c in calls if c.startswith("%prefill_chunk_attention")]
+        want = "bf16[1,16,%d,640]" % width
     assert len(attn) == 2 and all(want in c for c in attn), attn
     assert "[1,16,%d,32768]" % width not in text
     assert f"f32[1,1,{cfg.vocab_size}]" in text
     mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes < 2e9, mem.temp_size_in_bytes
+    # Measured: 67,011,072 B expanded (694,298,112 with K and V of the
+    # whole row made by XLA), 6,418,944 absorbed.
+    assert mem.temp_size_in_bytes < 1e8, mem.temp_size_in_bytes
